@@ -32,9 +32,14 @@ noise floors as the single-host series. ``scripts/lint_traces.py
 
 Usage::
 
-    python scripts/bench_multichip.py                 # 8 devices, defaults
-    python scripts/bench_multichip.py --devices 8 --iters 20 \
-        --out MULTICHIP_BENCH_r01.json
+    python scripts/bench_multichip.py --devices 4      # the four-chip host
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python scripts/bench_multichip.py --devices 8  # functional run, CPU mesh
+
+With fewer devices than asked it exits non-zero and says how many jax
+reports; it never re-executes itself onto a CPU mesh. The JSON line carries
+``device`` (platform, kind, count): on a CPU mesh its times are counts of
+host work, not device metrics.
 """
 
 from __future__ import annotations
@@ -638,42 +643,21 @@ def main(argv=None) -> int:
     p.add_argument("--device-spec", default=None,
                    help="cost-model device spec (default: autodetect)")
     p.add_argument("--out", default=None, help="also write the JSON to this path")
-    p.add_argument("--_subprocess", action="store_true", help=argparse.SUPPRESS)
     args = p.parse_args(argv)
 
     import jax
 
-    if len(jax.devices()) < args.devices and not args._subprocess:
-        # Backend already initialized with fewer devices: re-exec on a
-        # virtual CPU mesh (same pattern as __graft_entry__.dryrun_multichip).
-        import subprocess
+    from thunder_tpu.benchmarks import device_description
 
-        env = {
-            "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
-            "HOME": os.environ.get("HOME", "/root"),
-            "PYTHONPATH": REPO,
-            "JAX_PLATFORMS": "cpu",
-            "XLA_FLAGS": f"--xla_force_host_platform_device_count={args.devices}",
-            "THUNDER_TPU_ANNOTATE_TRACES": os.environ.get("THUNDER_TPU_ANNOTATE_TRACES", "1"),
-        }
-        for k in ("THUNDER_BENCH_EXECUTORS", "THUNDER_TPU_EVENTS", "THUNDER_TPU_METRICS"):
-            if os.environ.get(k):
-                env[k] = os.environ[k]
-        cmd = [sys.executable, os.path.abspath(__file__), "--_subprocess"] + [
-            a for a in (argv if argv is not None else sys.argv[1:])
-        ]
-        r = subprocess.run(cmd, env=env, capture_output=True, text=True, timeout=1200)
-        sys.stderr.write(r.stderr[-4000:] if len(r.stderr) > 4000 else r.stderr)
-        if r.returncode != 0:
-            print(f"bench_multichip subprocess failed:\n{r.stdout[-2000:]}", file=sys.stderr)
-            return r.returncode
-        line = r.stdout.strip().splitlines()[-1]
-        json.loads(line)  # malformed output must fail loudly
-        print(line)
-        if args.out:
-            with open(args.out, "w") as f:
-                f.write(line + "\n")
-        return 0
+    if len(jax.devices()) < args.devices:
+        # This process has called jax.devices() and so holds whatever chip
+        # there is; a child could not have it. No re-execution onto a CPU
+        # mesh: its numbers would come out under device metric names.
+        print(f"bench_multichip: --devices {args.devices} asked, jax reports "
+              f"{device_description()}. For a functional run on a virtual CPU mesh set "
+              f"JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count="
+              f"{args.devices} outside, as the tests do.", file=sys.stderr)
+        return 1
 
     # Annotated codegen so collective trace lines carry scopes in profiles.
     os.environ.setdefault("THUNDER_TPU_ANNOTATE_TRACES", "1")
@@ -681,6 +665,7 @@ def main(argv=None) -> int:
 
     _ensure_runtime()
     result = run(args)
+    result["device"] = device_description()
     line = json.dumps(result)
     print(line)
     if args.out:

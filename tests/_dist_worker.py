@@ -522,10 +522,7 @@ def scenario_moe_ep():
     from thunder_tpu.parallel import make_mesh
     from thunder_tpu.parallel.moe import moe_mlp, moe_mlp_dense_reference
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:
-        from jax.shard_map import shard_map
+    from jax import shard_map
 
     mesh = make_mesh(ep=8)
     E, d, hdim, n_total = 16, 32, 64, 64  # 2 experts/device, 8 tokens/device
@@ -540,7 +537,7 @@ def scenario_moe_ep():
         mesh=mesh,
         in_specs=(P("ep", None), P(), P("ep", None, None), P("ep", None, None)),
         out_specs=P("ep", None),
-        check_rep=False,
+        check_vma=False,
     )
     got = np.asarray(jax.jit(ep_fn)(x, rw, w1, w2))
     want = np.asarray(moe_mlp_dense_reference(x, rw, w1, w2, top_k=2))
@@ -566,7 +563,7 @@ def scenario_moe_ep():
         mesh=mesh,
         in_specs=(P("ep", None), P(), P("ep", None, None), P("ep", None, None)),
         out_specs=P("ep", None),
-        check_rep=False,
+        check_vma=False,
     )
     dropped = np.asarray(jax.jit(ep_tiny)(x, rw, w1, w2))
     assert dropped.shape == got.shape and np.isfinite(dropped).all()
@@ -585,10 +582,7 @@ def scenario_pipeline_pp():
     from thunder_tpu.parallel import make_mesh
     from thunder_tpu.parallel.pipeline import pipeline_apply
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:
-        from jax.shard_map import shard_map
+    from jax import shard_map
 
     mesh = make_mesh(pp=8)
     n_stages, n_micro, mb, d = 8, 4, 4, 16
@@ -609,7 +603,7 @@ def scenario_pipeline_pp():
             local, mesh=mesh,
             in_specs=(P("pp", None, None), P("pp", None), P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(W, b, xs)
 
     got = np.asarray(jax.jit(piped)(W, b, xs))
@@ -673,10 +667,7 @@ def scenario_ring_attention():
     from thunder_tpu.parallel import make_mesh
     from thunder_tpu.parallel.context import ring_attention
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:
-        from jax.shard_map import shard_map
+    from jax import shard_map
 
     mesh = make_mesh(sp=8)
     B, H, S, D = 2, 4, 64, 16
@@ -688,7 +679,7 @@ def scenario_ring_attention():
     spec = P(None, None, "sp", None)
     ring = shard_map(
         lambda q, k, v: ring_attention(q, k, v, "sp", causal=True),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_rep=False,
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
     )
     got = np.asarray(jax.jit(ring)(q, k, v))
     want = np.asarray(_full_attention(q, k, v))
@@ -716,10 +707,7 @@ def scenario_ulysses_attention():
     from thunder_tpu.parallel import make_mesh
     from thunder_tpu.parallel.context import ulysses_attention
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:
-        from jax.shard_map import shard_map
+    from jax import shard_map
 
     mesh = make_mesh(sp=4)
     B, H, S, D = 2, 8, 64, 16
@@ -731,7 +719,7 @@ def scenario_ulysses_attention():
     spec = P(None, None, "sp", None)
     uly = shard_map(
         lambda q, k, v: ulysses_attention(q, k, v, "sp", causal=True),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_rep=False,
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
     )
     got = np.asarray(jax.jit(uly)(q, k, v))
     want = np.asarray(_full_attention(q, k, v))
@@ -750,10 +738,7 @@ def scenario_long_context_train():
     from thunder_tpu.parallel import make_mesh
     from thunder_tpu.parallel.context import ring_attention
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:
-        from jax.shard_map import shard_map
+    from jax import shard_map
 
     mesh = make_mesh(sp=8)
     B, H, S, D, V = 2, 2, 128, 8, 32
@@ -772,7 +757,7 @@ def scenario_long_context_train():
         sp_attn = shard_map(
             attn_local, mesh=mesh,
             in_specs=(P(None, "sp", None), P()), out_specs=P(None, "sp", None),
-            check_rep=False,
+            check_vma=False,
         )
         h = sp_attn(x, wq)
         logits = h @ wo.T
@@ -864,10 +849,7 @@ def scenario_moe_capacity():
     from thunder_tpu.parallel import make_mesh
     from thunder_tpu.parallel.moe import moe_mlp
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:
-        from jax.shard_map import shard_map
+    from jax import shard_map
 
     mesh = make_mesh(ep=8)
     E, d, hdim, n_total, top_k, C = 16, 32, 64, 64, 2, 1  # n_local=8, C=1 << lossless
@@ -929,7 +911,7 @@ def scenario_moe_capacity():
         mesh=mesh,
         in_specs=(P("ep", None), P(), P("ep", None, None), P("ep", None, None)),
         out_specs=P("ep", None),
-        check_rep=False,
+        check_vma=False,
     )
     got = np.asarray(jax.jit(ep_fn)(
         jnp.asarray(x), jnp.asarray(rw), jnp.asarray(w1), jnp.asarray(w2)
@@ -1019,10 +1001,7 @@ def scenario_gpt_pipeline():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:
-        from jax.shard_map import shard_map
+    from jax import shard_map
 
     from thunder_tpu.parallel.gpt_pp import build_gpt_pp_fns, split_params_for_pp
     from thunder_tpu.parallel.pipeline import pipeline_1f1b, pipeline_apply
@@ -1057,12 +1036,12 @@ def scenario_gpt_pipeline():
                                           first_fn=first_fn, last_fn=last_fn,
                                           act_shape=act_shape, act_dtype=jnp.float32,
                                           out_shape=(), out_dtype=jnp.float32),
-            mesh=mesh, in_specs=in_specs, out_specs=P(), check_rep=False,
+            mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False,
         )(stacked, streams)
         return jnp.mean(losses)
 
     c_1f1b = jax.jit(shard_map(local_1f1b, mesh=mesh, in_specs=in_specs,
-                               out_specs=P(), check_rep=False)
+                               out_specs=P(), check_vma=False)
                      ).lower(stacked, streams).compile()
     c_gpipe = jax.jit(jax.grad(gpipe_mean)).lower(stacked, streams).compile()
     t1, tg = (c.memory_analysis().temp_size_in_bytes for c in (c_1f1b, c_gpipe))

@@ -1,0 +1,212 @@
+"""The CPU guards around ``chip_smoke.py`` (ISSUE 21).
+
+The smoke itself proves the chip; these prove, where there is no chip, that
+it refuses to run, that its rehearsal passes every stage on the 8-device CPU
+mesh, that the compile cache is where it was placed, that an unknown device
+has no peak, and that the sharded train step lowers for the TPU with its
+kernels as Mosaic calls (the partitioner cannot split one, so each runs
+inside ``jax.shard_map``)."""
+
+import dataclasses
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMOKE = os.path.join(REPO, "chip_smoke.py")
+
+
+def _run_smoke(*args, timeout=600, **env_extra):
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu", **env_extra)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    return subprocess.run([sys.executable, SMOKE, *args], capture_output=True, text=True,
+                          timeout=timeout, env=env, cwd=REPO)
+
+
+def test_refuses_a_cpu():
+    proc = _run_smoke()
+    assert proc.returncode != 0
+    assert "platform 'cpu'" in proc.stderr
+    # It named the device and printed no result.
+    assert "'platform': 'cpu'" in proc.stdout
+    assert not any(line.startswith("{") for line in proc.stdout.splitlines())
+
+
+def test_alone_it_prints_no_result(tmp_path):
+    """In a directory that holds the script and nothing else of the repo the
+    program cannot be imported: a non-zero exit and no result line."""
+    import shutil
+
+    shutil.copy(SMOKE, tmp_path / "chip_smoke.py")
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    proc = subprocess.run([sys.executable, "chip_smoke.py", "--rehearse"], capture_output=True,
+                          text=True, timeout=120, env=env, cwd=tmp_path)
+    assert proc.returncode != 0
+    assert "No module named 'thunder_tpu'" in proc.stderr
+    assert not any(line.startswith("{") for line in proc.stdout.splitlines())
+
+
+def test_rehearsal_passes_every_stage():
+    proc = _run_smoke("--rehearse")
+    assert proc.returncode == 0, proc.stdout[-4000:] + proc.stderr[-4000:]
+    lines = proc.stdout.strip().splitlines()
+    # The result line holds "ok" and "device" and no other key: the chip check
+    # refuses anything else (PR 21's first submission carried the stages here).
+    device = {"platform": "cpu", "kind": "cpu", "count": 8}
+    assert json.loads(lines[-1]) == {"ok": True, "device": device}
+    assert lines[-2].startswith("summary: ")
+    summary = json.loads(lines[-2][len("summary: "):])
+    assert summary["ok"] is True and summary["chip"] is False
+    assert summary["device"] == device
+    assert sorted(summary["stages"]) == [
+        "A_trainer", "B_gqa_rope", "C_dispatcher", "D_compile_cache", "E_four_chips"]
+    assert all(stage["ok"] for stage in summary["stages"].values())
+    assert "multichip" not in summary  # Stage E ran
+    assert summary["stages"]["E_four_chips"]["collectives"]["all-gather"] > 0
+    assert summary["stages"]["D_compile_cache"]["dir"] == os.path.join(REPO, ".jax_cache")
+
+
+class TestCompileCachePlacement:
+    """api._ensure_runtime: placed from outside, nothing is set; unset, the
+    cache is ``<checkout>/.jax_cache``. A fresh process each, since jax reads
+    JAX_COMPILATION_CACHE_DIR once, at import."""
+
+    PROBE = (
+        "import jax, thunder_tpu.api as api; api._ensure_runtime(); "
+        "print(jax.config.jax_compilation_cache_dir)"
+    )
+
+    def _probe(self, **env_extra):
+        env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu", **env_extra)
+        if "JAX_COMPILATION_CACHE_DIR" not in env_extra:
+            env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        proc = subprocess.run([sys.executable, "-c", self.PROBE], capture_output=True,
+                              text=True, timeout=120, env=env, cwd=REPO)
+        assert proc.returncode == 0, proc.stderr
+        return proc.stdout.strip().splitlines()[-1]
+
+    def test_unset_goes_to_the_checkout(self):
+        assert self._probe() == os.path.join(REPO, ".jax_cache")
+
+    def test_set_from_outside_is_untouched(self, tmp_path):
+        placed = str(tmp_path / "placed")  # not created: the owner's to make
+        assert self._probe(JAX_COMPILATION_CACHE_DIR=placed) == placed
+        assert not os.path.exists(os.path.join(placed, "native"))
+
+    def test_a_directory_from_outside_is_never_purged(self, tmp_path, monkeypatch):
+        import jax
+
+        from thunder_tpu.resilience import compile_cache
+
+        entry = tmp_path / "entry"
+        entry.write_bytes(b"x" * 32)
+        old = jax.config.jax_compilation_cache_dir
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        try:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert compile_cache.purge_on_error(RuntimeError("deserialize")) is False
+            assert entry.exists()
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+            assert compile_cache.purge_on_error(RuntimeError("deserialize")) is True
+            assert not entry.exists()
+        finally:
+            jax.config.update("jax_compilation_cache_dir", old)
+
+
+class TestPeakLookup:
+    def test_known_kinds(self):
+        from thunder_tpu.benchmarks import peak_tflops, tpu_generation
+
+        assert tpu_generation("TPU v5 lite") == "v5e"
+        assert tpu_generation("TPU v5e") == "v5e"
+        assert tpu_generation("TPU v5p") == "v5p"
+        assert tpu_generation("TPU v4") == "v4"
+        assert tpu_generation("TPU v6 lite") == "v6e"
+        assert peak_tflops("TPU v5 lite") == 197.0
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v9", "NVIDIA A100", ""])
+    def test_unknown_kind_raises(self, kind):
+        from thunder_tpu.benchmarks import peak_tflops, tpu_generation
+
+        with pytest.raises(ValueError, match="no peak is recorded"):
+            tpu_generation(kind)
+        with pytest.raises(ValueError, match="no peak is recorded"):
+            peak_tflops(kind)
+
+    def test_cost_model_keeps_the_cpu_spec_by_name(self):
+        from thunder_tpu.analysis.cost import resolve_device_spec
+
+        assert resolve_device_spec(None).name == "cpu"
+        assert resolve_device_spec("cpu").name == "cpu"
+
+
+@pytest.mark.parametrize("axes", [None, {"fsdp": 4}, {"dp": 4}, {"dp": 2, "fsdp": 2, "tp": 2}],
+                         ids=["one-chip", "fsdp4", "dp4", "dp2-fsdp2-tp2"])
+def test_train_step_lowers_for_tpu_with_mosaic_kernels(axes, monkeypatch):
+    """``build_train_step`` cross-lowered for the TPU from the CPU host, the
+    kernels as Mosaic calls rather than interpreted. Under a mesh this fails
+    with "Mosaic kernels cannot be automatically partitioned" unless every
+    claimed kernel runs inside ``jax.shard_map``."""
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import flashex, pallasex
+    from thunder_tpu.models import gpt
+    from thunder_tpu.parallel import build_train_step, gpt_param_specs, make_mesh, shard_pytree
+
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    monkeypatch.setattr(flashex, "_interpret", lambda: False)
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+
+    # pythia's shape at a tenth of the width, and full rope so that kernel lowers too
+    cfg = dataclasses.replace(gpt.name_to_config("pythia-410m"), n_layer=2, n_embd=128,
+                              n_head=2, intermediate_size=512, rotary_percentage=1.0,
+                              vocab_size=512, padded_vocab_size=512)
+    B, T = 8, 256
+    params = gpt.init_params(cfg, dtype=dtypes.bfloat16, seed=0)
+    idx = np.random.RandomState(0).randint(0, cfg.vocab_size, (B, T)).astype(np.int32)
+    tgt = np.roll(idx, -1, axis=1).astype(np.int32)
+    kwargs = {}
+    if axes is not None:
+        mesh = make_mesh(**axes)
+        specs = gpt_param_specs(cfg, mesh)
+        params = shard_pytree(params, mesh, specs)
+        kwargs = dict(mesh=mesh, param_specs=specs)
+    step, opt, extrace = build_train_step(cfg, params, idx, tgt, return_extrace=True, **kwargs)
+
+    from chip_smoke import kernel_claims
+
+    assert kernel_claims(extrace) == {
+        "apply_rope": "pallas", "sdpa_fwd_res": "flash", "sdpa_bwd_res": "flash",
+        "cross_entropy": "pallas", "cross_entropy_bwd": "pallas"}
+    text = step.trace(params, opt, idx, tgt).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("optimizer,axes", [("adamw", {"fsdp": 4}), ("sgd", {"dp": 4}), ("sgd", None)],
+                         ids=["adamw-fsdp4", "sgd-dp4", "sgd-one-chip"])
+def test_train_step_is_traced_once(optimizer, axes):
+    """The optimizer state goes in as the step hands it back. At the parent a
+    mesh step traced and compiled twice (the step counter came back with the
+    mesh in its type), a minute of XLA compile hidden in the second call."""
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.models import gpt
+    from thunder_tpu.parallel import build_train_step, gpt_param_specs, make_mesh, shard_pytree
+
+    cfg = gpt.name_to_config("llama-tiny")
+    params = gpt.init_params(cfg, dtype=dtypes.bfloat16, seed=0)
+    idx = np.random.RandomState(0).randint(0, cfg.vocab_size, (8, 64)).astype(np.int32)
+    tgt = np.roll(idx, -1, axis=1).astype(np.int32)
+    kwargs = {}
+    if axes is not None:
+        mesh = make_mesh(**axes)
+        specs = gpt_param_specs(cfg, mesh)
+        params = shard_pytree(params, mesh, specs)
+        kwargs = dict(mesh=mesh, param_specs=specs)
+    step, opt = build_train_step(cfg, params, idx, tgt, optimizer=optimizer, **kwargs)
+    for _ in range(3):
+        params, opt, loss = step(params, opt, idx, tgt)
+    assert np.isfinite(float(loss))
+    assert step._cache_size() == 1

@@ -168,9 +168,9 @@ class TestHierAllReduceLowering:
         def flat(a):
             return jax.lax.psum(a, ("dcn", "dp"))
 
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
-        kw = dict(mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+        kw = dict(mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
         got = shard_map(hier, **kw)(x)
         want = shard_map(flat, **kw)(x)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),

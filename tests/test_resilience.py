@@ -180,6 +180,17 @@ class TestExecutorDemotion:
         # warm path serves the demoted entry
         assert np.array_equal(np.asarray(jf(X)), baseline)
 
+    def test_recovery_is_a_warning_that_names_the_exception(self, caplog):
+        """A caller who did not ask for recovery can see that it happened:
+        every demotion/escalation warns on the ``thunder_tpu`` logger."""
+        _toy_executor()
+        jf = ttpu.jit(_fn, executors=["toyex", "jax"], chaos="kernel_raise@toyex*1")
+        with caplog.at_level("WARNING", logger="thunder_tpu"):
+            jf(X)
+        msgs = [r.getMessage() for r in caplog.records]
+        assert any("recovered from a kernel failure" in m and "InjectedKernelError" in m
+                   and "demoting" in m for m in msgs), msgs
+
     def test_warm_entry_failure_demotes(self, tmp_path):
         """Unstaged (op-by-op) entries re-enter kernel impls every call, so
         a kernel fault on a WARM entry must evict + demote + recompile —

@@ -1477,39 +1477,38 @@ def _ensure_runtime() -> None:
     # descriptor-keyed compiled-fusion cache, SURVEY.md §2.2 — here the
     # cache survives processes, so warm-start recompiles of the same
     # program are file reads, not 80-second XLA runs). Opt out with
-    # THUNDER_TPU_NO_COMPILE_CACHE=1. A user-configured cache (dir already
-    # set, or the JAX_PERSISTENT_CACHE_* env knobs) is respected untouched.
+    # THUNDER_TPU_NO_COMPILE_CACHE=1. The cache is placed from outside with
+    # jax's own JAX_COMPILATION_CACHE_DIR: jax carries that into its config
+    # before this runs, and then nothing is set here (the same holds for a
+    # directory set programmatically, and the JAX_PERSISTENT_CACHE_* knobs).
+    # Unset, the cache lives in the checkout, at a path that does not move.
+    # A directory that cannot be made is an error.
     import os
 
     if not os.environ.get("THUNDER_TPU_NO_COMPILE_CACHE"):
-        try:
-            cache_dir = jax.config.jax_compilation_cache_dir
-            if not cache_dir:
-                cache_dir = os.environ.get(
-                    "THUNDER_TPU_COMPILE_CACHE", os.path.expanduser("~/.cache/thunder_tpu_xla")
-                )
-                os.makedirs(cache_dir, exist_ok=True)
-                jax.config.update("jax_compilation_cache_dir", cache_dir)
-                _set_unless_user_configured(
-                    jax, "jax_persistent_cache_min_compile_time_secs", 1.0
-                )
-                _set_unless_user_configured(
-                    jax, "jax_persistent_cache_min_entry_size_bytes", 0
-                )
-            if _cache_dir_logged["dir"] != cache_dir:
-                # First sight of this cache dir in the process: the chaos
-                # cache_corrupt seam may truncate an entry here (no-op unless
-                # armed), then the sweep removes corrupted/truncated entries
-                # (torn writes from a crashed or disk-full predecessor) so a
-                # poisoned entry recompiles instead of crashing the load
-                # (resilience/compile_cache.py).
-                from thunder_tpu.resilience.compile_cache import sweep_corrupt_entries
+        from thunder_tpu.resilience import compile_cache
 
-                chaos_mod.corrupt_cache_seam(cache_dir)
-                sweep_corrupt_entries(cache_dir)
-            _log_cache_dir_once(cache_dir)
-        except Exception:
-            pass  # older jax without the persistent-cache config
+        cache_dir = jax.config.jax_compilation_cache_dir
+        if not cache_dir:
+            cache_dir = compile_cache.default_cache_dir()
+            os.makedirs(cache_dir, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
+            _set_unless_user_configured(
+                jax, "jax_persistent_cache_min_compile_time_secs", 1.0
+            )
+            _set_unless_user_configured(
+                jax, "jax_persistent_cache_min_entry_size_bytes", 0
+            )
+        if _cache_dir_logged["dir"] != cache_dir:
+            # First sight of this cache dir in the process: the chaos
+            # cache_corrupt seam may truncate an entry here (no-op unless
+            # armed), then the sweep removes corrupted/truncated entries
+            # (torn writes from a crashed or disk-full predecessor) so a
+            # poisoned entry recompiles instead of crashing the load
+            # (resilience/compile_cache.py).
+            chaos_mod.corrupt_cache_seam(cache_dir)
+            compile_cache.sweep_corrupt_entries(cache_dir)
+        _log_cache_dir_once(cache_dir)
 
 
 def _set_unless_user_configured(jax_mod, name: str, value) -> None:

@@ -16,7 +16,7 @@ from __future__ import annotations
 from typing import Any, Callable, Optional, Sequence
 
 
-def shard_map_callable(fn: Callable, mesh, in_specs, out_specs, *, check_rep: bool = False,
+def shard_map_callable(fn: Callable, mesh, in_specs, out_specs, *, check_vma: bool = False,
                        trace_lines=None, schedule=None) -> Callable:
     """Wrap a pure callable in shard_map over ``mesh`` and jit it.
 
@@ -29,14 +29,9 @@ def shard_map_callable(fn: Callable, mesh, in_specs, out_specs, *, check_rep: bo
     forever. Unconfigured, the wrapper is one dict probe per call."""
     import jax
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer jax
-        from jax.shard_map import shard_map  # type: ignore
-
     from thunder_tpu.resilience import watchdog
 
-    inner = shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_rep)
+    inner = jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma)
     return watchdog.wrap(
         jax.jit(inner),
         fn_name=getattr(fn, "__name__", "shard_map"),

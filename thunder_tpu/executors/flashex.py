@@ -38,8 +38,12 @@ splash is mask-structured instead, so masks are handled by shape class):
   in-executor padding with segment-ids (reference bar: sdpaex.py:49 pads
   head dims to stay on the fast path).
 
-Tuning knobs (env): THUNDER_FLASH_IMPL=splash|legacy,
-THUNDER_FLASH_BQ/BKV/BQ_DKV/BKV_DKV, THUNDER_FLASH_FUSED_BWD=1|0.
+Under a device mesh (``parallel.build_train_step(mesh=...)``) every kernel
+call runs per batch shard inside ``jax.shard_map`` (executors/kernel_mesh.py):
+the SPMD partitioner cannot split a Mosaic custom call.
+
+Tuning knobs (env): THUNDER_FLASH_BQ/BKV/BQ_DKV/BKV_DKV,
+THUNDER_FLASH_FUSED_BWD=1|0.
 Block-size defaults (1024) were measured end-to-end on v5e: open_llama_3b
 train iter 0.6979 (512) -> 0.6950 s (1024); fwd 1.1647 -> 1.1546 s (r4
 ablations; 2048 regressed to 0.7080).
@@ -49,13 +53,12 @@ from __future__ import annotations
 
 import math
 import os
-from functools import lru_cache, partial
-from typing import Optional
+from functools import lru_cache
 
 import numpy as np
 
 from thunder_tpu.core.proxies import TensorProxy, pyval
-from thunder_tpu.executors.jaxex import enable_x64 as jaxex_enable_x64
+from thunder_tpu.executors.kernel_mesh import per_batch_shard
 from thunder_tpu.extend import OperatorExecutor, add_default_executor, register_executor
 from thunder_tpu.resilience import chaos
 
@@ -65,10 +68,6 @@ add_default_executor(ex, front=True)
 
 _PAD = 128  # sequence alignment quantum (Mosaic lane width)
 _NEG_BIG = -1e9  # additive-mask entries at or below this count as "masked"
-
-
-def _impl_name() -> str:
-    return os.environ.get("THUNDER_FLASH_IMPL", "splash")
 
 
 def _blk(name: str, dflt: int) -> int:
@@ -174,9 +173,6 @@ def _sdpa_checker(*args, **kwargs) -> bool:
     if not (_on_tpu() and float(pyval(b["dropout_p"])) == 0.0 and _shapes_ok(q, k)
             and _dtype_ok(q, k, b["value"])):
         return False
-    if _impl_name() == "legacy":
-        S, L = q.shape[-2], k.shape[-2]
-        return b["attn_mask"] is None and S == L and S % _PAD == 0
     kind = _mask_kind(b["attn_mask"], q, k)
     if kind == "no":
         return False
@@ -188,9 +184,6 @@ def _sdpa_checker(*args, **kwargs) -> bool:
 def _bwd_checker(g, query, key, value, attn_mask=None, is_causal=False, scale=None, enable_gqa=False) -> bool:
     if not (_on_tpu() and _shapes_ok(query, key) and _dtype_ok(query, key, value)):
         return False
-    if _impl_name() == "legacy":
-        S, L = query.shape[-2], key.shape[-2]
-        return attn_mask is None and S == L and S % _PAD == 0
     return _mask_kind(attn_mask, query, key) != "no"
 
 
@@ -280,16 +273,21 @@ def _splash_sdpa(q, k, v, *, causal: bool, scale: float, kv_valid=None, q_valid=
     )
     qs = (q * jnp.asarray(scale, dtype=q.dtype)).astype(q.dtype)
 
-    with jaxex_enable_x64(False):
+    # The kernel's index maths is 32-bit; scope out the runtime's x64 mode
+    # while it traces.
+    with jax.enable_x64(False):
         if need_seg:
             qv = jnp.ones((B, Tq), dtype=jnp.bool_) if q_valid is None else q_valid
             kvv = jnp.ones((B, Tkv), dtype=jnp.bool_) if kv_valid is None else kv_valid
-            qv = jnp.pad(qv, ((0, 0), (0, pq)))
-            kvv = jnp.pad(kvv, ((0, 0), (0, pkv)))
-            seg = sk.SegmentIds(q=qv.astype(jnp.int32), kv=kvv.astype(jnp.int32))
-            out = jax.vmap(kernel, in_axes=(0, 0, 0, sk.SegmentIds(q=0, kv=0)))(qs, k, v, seg)
+            qv = jnp.pad(qv, ((0, 0), (0, pq))).astype(jnp.int32)
+            kvv = jnp.pad(kvv, ((0, 0), (0, pkv))).astype(jnp.int32)
+            batched = jax.vmap(kernel, in_axes=(0, 0, 0, sk.SegmentIds(q=0, kv=0)))
+            out = per_batch_shard(
+                lambda q, k, v, sq, skv: batched(q, k, v, sk.SegmentIds(q=sq, kv=skv)),
+                qs, k, v, qv, kvv,
+            )
         else:
-            out = jax.vmap(kernel)(qs, k, v)
+            out = per_batch_shard(jax.vmap(kernel), qs, k, v)
     return out[..., :Tq, :] if pq else out
 
 
@@ -415,36 +413,6 @@ def _sdpa_runtime(q, k, v, attn_mask, causal: bool, scale: float):
 
 
 # =============================================================================
-# Legacy kernel (THUNDER_FLASH_IMPL=legacy; unmasked, aligned shapes only)
-# =============================================================================
-
-
-def _legacy_flash(q, k, v, *, causal: bool, sm_scale: float):
-    import jax
-    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes, flash_attention
-
-    S = q.shape[-2]
-    # r3 block sweep: fwd 512 measured 1.6× faster than 128 at S=2048 on
-    # v5e; bwd 512 vs 256 cut the open_llama_3b train step 0.888→0.807.
-    def fit(pref):
-        b = min(pref, S)
-        while S % b:
-            b //= 2
-        return max(b, 1)
-
-    b = fit(512)
-    sizes = BlockSizes(
-        block_q=b, block_k_major=b, block_k=b, block_b=1,
-        block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b, block_q_dkv=b,
-        block_k_major_dq=b, block_k_dq=b, block_q_dq=b,
-    )
-    # The kernel's internal index math assumes 32-bit Python-int weak types;
-    # scope out the runtime's x64 mode while tracing it.
-    with jaxex_enable_x64(False):
-        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale, block_sizes=sizes)
-
-
-# =============================================================================
 # Claimed implementations
 # =============================================================================
 
@@ -466,8 +434,6 @@ def _sdpa_impl(*args, **kwargs):
     H, D = q.shape[-3], q.shape[-1]
     scale = float(b["scale"]) if b["scale"] is not None else 1.0 / math.sqrt(D)
     k, v = _expand_gqa(k, v, H)
-    if _impl_name() == "legacy":
-        return _legacy_flash(q, k, v, causal=bool(b["is_causal"]), sm_scale=scale)
     return _sdpa_runtime(q, k, v, b["attn_mask"], bool(b["is_causal"]), scale)
 
 
@@ -480,11 +446,8 @@ def _sdpa_bwd_impl(g, query, key, value, attn_mask=None, is_causal=False, scale=
     sm_scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
     k, v = _expand_gqa(key, value, H)
 
-    if _impl_name() == "legacy":
-        f = partial(_legacy_flash, causal=bool(is_causal), sm_scale=sm_scale)
-    else:
-        f = lambda q, k, v: _sdpa_runtime(q, k, v, attn_mask, bool(is_causal), sm_scale)
-    with jaxex_enable_x64(False):
+    f = lambda q, k, v: _sdpa_runtime(q, k, v, attn_mask, bool(is_causal), sm_scale)
+    with jax.enable_x64(False):
         _, vjp = jax.vjp(f, query, k, v)
         dq, dk, dv = vjp(g)
 
@@ -506,7 +469,7 @@ def residual_eligible(q, k, v) -> bool:
     """The attention-residual pass asks before rewriting: both sides must be
     claimable without padding or masks (the no-recompute path keeps the
     simplest geometry; everything else stays on the recompute composite)."""
-    if not (_on_tpu() and _impl_name() == "splash" and _dtype_ok(q, k, v)):
+    if not (_on_tpu() and _dtype_ok(q, k, v)):
         return False
     if len(q.shape) != 4 or len(k.shape) != 4:
         return False
@@ -540,8 +503,8 @@ def _splash_fwd_res(q, k, v, *, causal: bool, scale: float):
         True,
     )
     qs = (q * jnp.asarray(scale, dtype=q.dtype)).astype(q.dtype)
-    with jaxex_enable_x64(False):
-        out, (lse,) = jax.vmap(kernel)(qs, k, v)
+    with jax.enable_x64(False):
+        out, (lse,) = per_batch_shard(jax.vmap(kernel), qs, k, v)
     return out, lse[..., :Tq].astype(jnp.float32)
 
 
@@ -596,8 +559,8 @@ def _sdpa_bwd_res_impl(g, query, key, value, out, lse, attn_mask=None, is_causal
         )
         return grads[3], grads[4], grads[5]
 
-    with jaxex_enable_x64(False):
-        dqs, dk, dv = jax.vmap(one)(qs, k, v, out, lse.astype(jnp.float32), g)
+    with jax.enable_x64(False):
+        dqs, dk, dv = per_batch_shard(jax.vmap(one), qs, k, v, out, lse.astype(jnp.float32), g)
     dq = dqs.astype(jnp.float32) * sm_scale  # fwd consumed q*scale
 
     if G != H:

@@ -40,19 +40,6 @@ def _jd(d: dtypes.dtype):
     return dtypes.to_jax_dtype(d)
 
 
-def enable_x64(enabled: bool = True):
-    """Compat shim for the ``jax.enable_x64`` context manager: newer jax
-    releases moved it to ``jax.experimental.enable_x64`` and removed the
-    top-level alias. The Pallas kernels (pallasex/flashex) scope x64 OFF
-    around pallas_call (Mosaic rejects x64 iota), and the max-pool adjoint
-    scopes it ON for int64 index packing — both must work on every jax in
-    the support window."""
-    ctx = getattr(jax, "enable_x64", None)
-    if ctx is None:
-        from jax.experimental import enable_x64 as ctx
-    return ctx(enabled)
-
-
 def _reg(prim_id: PrimIDs, fn, checker=None):
     ex.register_implementation(prim_id, fn=fn, checker=checker)
 
@@ -486,7 +473,7 @@ def _pool_bwd_fn(g, a, kind, window, strides, padding):
     # single reduce_window max yields each window's first-argmax index. The
     # packing needs real int64 — enable x64 locally so the adjoint works even
     # when the caller never went through jit()'s _ensure_runtime.
-    with enable_x64():
+    with jax.enable_x64(True):
         return _max_pool_bwd_x64(g, a, window, strides, padding, lead, spatial)
 
 

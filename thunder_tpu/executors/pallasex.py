@@ -12,15 +12,19 @@ Claims ``torch.cross_entropy`` and the ``torch.cross_entropy_bwd``
 composite emitted by the autodiff rule. Falls back to the decomposition
 when shapes don't block-align (checker), exactly like the reference's
 executor checkers.
+
+Under a device mesh every ``pallas_call`` runs per batch shard inside
+``jax.shard_map`` (executors/kernel_mesh.py), its blocks sized on the
+shard's rows; reductions over rows (the loss sum, the norm weight grads)
+stay outside, where the partitioner turns them into collectives.
 """
 
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
 
-from thunder_tpu.core.proxies import TensorProxy, pyval
-from thunder_tpu.executors.jaxex import enable_x64 as jaxex_enable_x64
+from thunder_tpu.core.proxies import pyval
+from thunder_tpu.executors.kernel_mesh import batch_shards, per_batch_shard
 from thunder_tpu.extend import OperatorExecutor, add_default_executor, register_executor
 from thunder_tpu.resilience import chaos
 
@@ -56,7 +60,8 @@ def _ce_shapes_ok(input, target) -> bool:
     if len(getattr(input, "shape", ())) != 2:
         return False
     N, V = input.shape
-    return V % _LANE == 0 and _ce_block_n(int(N), int(V)) is not None
+    shards = batch_shards()
+    return V % _LANE == 0 and N % shards == 0 and _ce_block_n(int(N) // shards, int(V)) is not None
 
 
 def _ce_checker(input, target, weight=None, ignore_index=-100, reduction="mean", label_smoothing=0.0):
@@ -126,27 +131,30 @@ def _ce_call(kernel, out_lanes, out_dtype, logits, *extra):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    N, V = logits.shape
-    bn = _ce_block_n(int(N), int(V))
-    assert bn is not None, (
-        f"CE kernel called with unclaimable shape ({N}, {V}) — the checker "
-        "must gate this (a floored grid would leave tail rows unwritten)"
-    )
-    grid = (N // bn,)
-    in_specs = [pl.BlockSpec((bn, V), lambda i: (i, 0), memory_space=pltpu.VMEM)]
-    for _ in extra:
-        in_specs.append(pl.BlockSpec((bn, _LANE), lambda i: (i, 0), memory_space=pltpu.VMEM))
-    # Mosaic's index maths is 32-bit; scope out the runtime's x64 mode so the
-    # grid index maps don't trace to i64 (which fails to legalize).
-    with jaxex_enable_x64(False):
+    def rows(logits, *extra):  # one batch shard's rows
+        N, V = logits.shape
+        bn = _ce_block_n(int(N), int(V))
+        if bn is None:
+            raise ValueError(
+                f"CE kernel called with unclaimable shape ({N}, {V}) — the checker "
+                "must gate this (a floored grid would leave tail rows unwritten)"
+            )
+        in_specs = [pl.BlockSpec((bn, V), lambda i: (i, 0), memory_space=pltpu.VMEM)]
+        for _ in extra:
+            in_specs.append(pl.BlockSpec((bn, _LANE), lambda i: (i, 0), memory_space=pltpu.VMEM))
         return pl.pallas_call(
             kernel,
-            grid=grid,
+            grid=(N // bn,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((bn, out_lanes), lambda i: (i, 0), memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((N, out_lanes), out_dtype),
             interpret=_interpret(),
         )(logits, *extra)
+
+    # Mosaic's index maths is 32-bit; scope out the runtime's x64 mode so the
+    # grid index maps don't trace to i64 (which fails to legalize).
+    with jax.enable_x64(False):
+        return per_batch_shard(rows, logits, *extra)
 
 
 def _lanes(col):
@@ -214,7 +222,8 @@ def _rope_checker(x, cos, sin):
     if not (x.dtype == cos.dtype == sin.dtype):
         return False  # mixed dtypes promote in the decomposition; don't alter semantics
     # full-rotary only (partial decomposes); bt shrinks to a divisor of T
-    return x.shape[-2] == T and x.shape[-1] == n and n % 2 == 0 and T % 8 == 0
+    return (x.shape[-2] == T and x.shape[-1] == n and n % 2 == 0 and T % 8 == 0
+            and x.shape[0] % batch_shards() == 0)
 
 
 def _rope_kernel(x_ref, cos_ref, sin_ref, out_ref, *, half: int):
@@ -230,18 +239,14 @@ def _rope_kernel(x_ref, cos_ref, sin_ref, out_ref, *, half: int):
 def _rope_impl(x, cos, sin):
     chaos.kernel_seam("pallas", "apply_rope")
     import jax
-    import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, T, D = x.shape
-    bt = _ROPE_BT
-    while T % bt:
-        bt //= 2
-    xf = x.reshape(B * H, T, D)
-    cosx = cos.astype(x.dtype)
-    sinx = sin.astype(x.dtype)
-    with jaxex_enable_x64(False):
+    def shard(x, cos, sin):
+        B, H, T, D = x.shape
+        bt = _ROPE_BT
+        while T % bt:
+            bt //= 2
         out = pl.pallas_call(
             partial(_rope_kernel, half=D // 2),
             grid=(B * H, T // bt),
@@ -253,8 +258,11 @@ def _rope_impl(x, cos, sin):
             out_specs=pl.BlockSpec((1, bt, D), lambda i, j: (i, j, 0), memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((B * H, T, D), x.dtype),
             interpret=_interpret(),
-        )(xf, cosx, sinx)
-    return out.reshape(B, H, T, D)
+        )(x.reshape(B * H, T, D), cos, sin)
+        return out.reshape(B, H, T, D)
+
+    with jax.enable_x64(False):
+        return per_batch_shard(shard, x, cos.astype(x.dtype), sin.astype(x.dtype), replicated=(1, 2))
 
 
 ex.register_implementation("torch.apply_rope", fn=_rope_impl, checker=_rope_checker)
@@ -285,7 +293,7 @@ def _rms_shapes_ok(a, weight) -> bool:
     n_rows = 1
     for s in a.shape[:-1]:
         n_rows *= int(s)
-    return n_rows % 8 == 0 and weight is not None and tuple(weight.shape) == (D,)
+    return n_rows % (8 * batch_shards()) == 0 and weight is not None and tuple(weight.shape) == (D,)
 
 
 def _rms_fwd_checker(a, normalized_shape, weight=None, eps=None):
@@ -344,12 +352,11 @@ def _rms_impl(a, normalized_shape, weight=None, eps=None):
 
     e = 1e-6 if eps is None else float(eps)
     D = a.shape[-1]
-    xf = a.reshape(-1, D)
-    N = xf.shape[0]
-    bt = _norm_bt(N, D)
-    w2 = weight.reshape(1, D)
-    with jaxex_enable_x64(False):
-        out = pl.pallas_call(
+
+    def rows(xf, w2):
+        N = xf.shape[0]
+        bt = _norm_bt(N, D)
+        return pl.pallas_call(
             partial(_rms_fwd_kernel, eps=e),
             grid=(N // bt,),
             in_specs=[
@@ -360,6 +367,9 @@ def _rms_impl(a, normalized_shape, weight=None, eps=None):
             out_shape=jax.ShapeDtypeStruct((N, D), a.dtype),
             interpret=_interpret(),
         )(xf, w2)
+
+    with jax.enable_x64(False):
+        out = per_batch_shard(rows, a.reshape(-1, D), weight.reshape(1, D), replicated=(1,))
     return out.reshape(a.shape)
 
 
@@ -371,13 +381,11 @@ def _rms_bwd_impl(g, a, weight, eps):
 
     e = float(eps)
     D = a.shape[-1]
-    xf = a.reshape(-1, D)
-    gf = g.reshape(-1, D)
-    N = xf.shape[0]
-    bt = _norm_bt(N, D)
-    w2 = weight.reshape(1, D)
-    with jaxex_enable_x64(False):
-        dx, dwp = pl.pallas_call(
+
+    def rows(gf, xf, w2):
+        N = xf.shape[0]
+        bt = _norm_bt(N, D)
+        return pl.pallas_call(
             partial(_rms_bwd_kernel, eps=e),
             grid=(N // bt,),
             in_specs=[
@@ -395,6 +403,10 @@ def _rms_bwd_impl(g, a, weight, eps):
             ],
             interpret=_interpret(),
         )(gf, xf, w2)
+
+    with jax.enable_x64(False):
+        dx, dwp = per_batch_shard(
+            rows, g.reshape(-1, D), a.reshape(-1, D), weight.reshape(1, D), replicated=(2,))
     dw = jnp.sum(dwp, axis=0).astype(weight.dtype)
     return dx.reshape(a.shape), dw
 
@@ -451,14 +463,13 @@ def _ln_impl(a, normalized_shape, weight=None, bias=None, eps=1e-5):
 
     e = float(eps)
     D = a.shape[-1]
-    xf = a.reshape(-1, D)
-    N = xf.shape[0]
-    bt = _norm_bt(N, D)
-    w2 = weight.reshape(1, D)
     has_bias = bias is not None
     b2 = bias.reshape(1, D) if has_bias else jnp.zeros((1, D), dtype=a.dtype)
-    with jaxex_enable_x64(False):
-        out = pl.pallas_call(
+
+    def rows(xf, w2, b2):
+        N = xf.shape[0]
+        bt = _norm_bt(N, D)
+        return pl.pallas_call(
             partial(_ln_fwd_kernel, eps=e, has_bias=has_bias),
             grid=(N // bt,),
             in_specs=[
@@ -470,6 +481,9 @@ def _ln_impl(a, normalized_shape, weight=None, bias=None, eps=1e-5):
             out_shape=jax.ShapeDtypeStruct((N, D), a.dtype),
             interpret=_interpret(),
         )(xf, w2, b2)
+
+    with jax.enable_x64(False):
+        out = per_batch_shard(rows, a.reshape(-1, D), weight.reshape(1, D), b2, replicated=(1, 2))
     return out.reshape(a.shape)
 
 
@@ -481,13 +495,11 @@ def _ln_bwd_impl(g, a, weight, bias, eps):
 
     e = float(eps)
     D = a.shape[-1]
-    xf = a.reshape(-1, D)
-    gf = g.reshape(-1, D)
-    N = xf.shape[0]
-    bt = _norm_bt(N, D)
-    w2 = weight.reshape(1, D)
-    with jaxex_enable_x64(False):
-        dx, dwp, dbp = pl.pallas_call(
+
+    def rows(gf, xf, w2):
+        N = xf.shape[0]
+        bt = _norm_bt(N, D)
+        return pl.pallas_call(
             partial(_ln_bwd_kernel, eps=e),
             grid=(N // bt,),
             in_specs=[
@@ -507,6 +519,10 @@ def _ln_bwd_impl(g, a, weight, bias, eps):
             ],
             interpret=_interpret(),
         )(gf, xf, w2)
+
+    with jax.enable_x64(False):
+        dx, dwp, dbp = per_batch_shard(
+            rows, g.reshape(-1, D), a.reshape(-1, D), weight.reshape(1, D), replicated=(2,))
     dw = jnp.sum(dwp, axis=0).astype(weight.dtype)
     db = jnp.sum(dbp, axis=0).astype(weight.dtype) if bias is not None else None
     return dx.reshape(a.shape), dw, db

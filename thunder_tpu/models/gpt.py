@@ -181,7 +181,9 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
 
         def w(*shape, std=0.02):
             key_holder["k"], sub = jax.random.split(key_holder["k"])
-            return (jax.random.normal(sub, shape, dtype=jnp.float32) * std).astype(jdt)
+            # float(): a numpy float64 std is not weakly typed, and with x64 on
+            # would promote the whole product to float64, which a TPU emulates.
+            return (jax.random.normal(sub, shape, dtype=jnp.float32) * float(std)).astype(jdt)
 
     else:
 

@@ -176,11 +176,6 @@ def gpt_pp_loss_and_grads(config: GPTConfig, params: dict, idx, tgt, mesh,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer jax
-        from jax.shard_map import shard_map
-
     n_stages = mesh.shape["pp"]
     B, T = idx.shape
     mb = B // n_micro
@@ -245,18 +240,18 @@ def gpt_pp_loss_and_grads(config: GPTConfig, params: dict, idx, tgt, mesh,
         out_specs = (P(), {"blocks": block_in_spec, "wte": P(),
                            "ln_f": jax.tree_util.tree_map(lambda _: P(), stacked["ln_f"]),
                            "lm_head_w": P()})
-        loss, g = jax.jit(shard_map(
+        loss, g = jax.jit(jax.shard_map(
             local_1f1b, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         ))(stacked, streams)
         grads = merge_pp_grads(g, n_stages, config.n_layer)
         return loss, grads
 
     # GPipe: per-microbatch losses via pipeline_apply; grads via jax.grad.
     def mean_loss(stacked, streams):
-        losses = shard_map(
+        losses = jax.shard_map(
             local_gpipe_losses, mesh=mesh, in_specs=in_specs, out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(stacked, streams)
         return jnp.mean(losses)
 

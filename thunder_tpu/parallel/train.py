@@ -92,7 +92,10 @@ def _compile_loss_and_grads(config: GPTConfig, params, idx, targets, executors=N
     partitioner instead of dist_prims, so the pjit path keeps its exact
     program; trace-level FSDP/TP steps get their gathers prefetched. The
     mesh/param_specs (when given) divide sharded inputs so the scheduler's
-    liveness back-off prices per-device bytes."""
+    liveness back-off prices per-device bytes.
+
+    Claiming runs under the caller's ``kernel_mesh`` declaration, so the
+    kernel checkers size their blocks on one batch shard's rows."""
     from thunder_tpu.api import trace_program
     from thunder_tpu.executors.passes import transform_for_execution
     from thunder_tpu.extend import resolve_executors
@@ -151,19 +154,35 @@ def build_train_step(
     return tuple — the cost-model input for multichip MFU accounting
     (``scripts/bench_multichip.py`` prices its FLOPs/collectives against the
     device spec via ``analysis.cost.trace_cost``).
+
+    Under a ``mesh`` the step is one ``jax.jit`` with shardings, partitioned
+    by XLA; the claimed Mosaic kernels, which the partitioner cannot split,
+    run per batch shard inside ``jax.shard_map`` over the axes dim 0 of
+    ``batch_spec`` names (executors/kernel_mesh.py).
     """
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec
 
-    loss_and_grads, extrace = _compile_loss_and_grads(
-        config, params, idx, targets, executors=executors,
-        mesh=mesh, param_specs=param_specs,
-    )
+    from thunder_tpu.api import _ensure_runtime
+    from thunder_tpu.executors.kernel_mesh import kernel_mesh
+    from thunder_tpu.parallel.sharding import data_spec as _dspec
+
+    _ensure_runtime()  # x64 dtype semantics + the persistent compile cache
+    if mesh is not None and batch_spec is None:
+        batch_spec = _dspec(mesh)
+    batch_axes = batch_spec[0] if mesh is not None and len(batch_spec) else None
+
+    with kernel_mesh(mesh, batch_axes):
+        loss_and_grads, extrace = _compile_loss_and_grads(
+            config, params, idx, targets, executors=executors,
+            mesh=mesh, param_specs=param_specs,
+        )
 
     def step(params, opt_state, idx, targets):
         flat, _ = tree_flatten(((params, idx, targets), {}))
-        loss, grads = loss_and_grads(*flat)
+        with kernel_mesh(mesh, batch_axes):  # read while jax.jit traces the kernels
+            loss, grads = loss_and_grads(*flat)
         if grads_in_f32:
             grads = tuple(g.astype(jnp.float32) for g in grads)
         p_flat, p_spec = tree_flatten(params)
@@ -181,7 +200,10 @@ def build_train_step(
         )
         return new_params, new_state, loss
 
-    opt_state = adamw_init(params) if optimizer != "sgd" else {"step": 0}
+    # The state goes in as the step hands it back (an int32 array, and under a
+    # mesh laid out by ``opt_sh`` below): a first call whose arguments differ
+    # in type or layout from the second's traces and compiles the step twice.
+    opt_state = adamw_init(params) if optimizer != "sgd" else {"step": jnp.zeros((), dtype=jnp.int32)}
 
     # Donation metadata for the static planner suite (ISSUE 10): the param
     # leaves of the claimed trace are the donated buffers, so the liveness
@@ -213,9 +235,6 @@ def build_train_step(
         jfn = _stamp(jax.jit(step, donate_argnums=(0, 1) if donate else ()))
         return (jfn, opt_state, extrace) if return_extrace else (jfn, opt_state)
 
-    from thunder_tpu.parallel.sharding import data_spec as _dspec
-
-    batch_spec = batch_spec if batch_spec is not None else _dspec(mesh)
     ps = param_specs
 
     def ns(spec_tree):
@@ -224,6 +243,7 @@ def build_train_step(
 
     param_sh = ns(ps)
     opt_sh = ns(opt_state_specs(ps, optimizer))
+    opt_state = jax.device_put(opt_state, opt_sh)
     data_sh = NamedSharding(mesh, batch_spec)
     loss_sh = NamedSharding(mesh, PartitionSpec())
 

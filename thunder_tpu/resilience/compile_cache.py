@@ -11,7 +11,9 @@ defenses:
 - :func:`purge_on_error` — the recovery driver's last resort when a
   compile/first-run failure classifies as cache corruption (deserialization
   errors naming the persistent cache): clear the cache directory and let
-  the retry recompile from scratch.
+  the retry recompile from scratch. Only the checkout's own cache is ever
+  purged: a directory placed from outside (``JAX_COMPILATION_CACHE_DIR``)
+  is shared with whoever placed it, and the error propagates instead.
 
 Both emit ``cache_repair`` events so observability sees every repair.
 """
@@ -25,6 +27,15 @@ from typing import Optional
 from thunder_tpu.observability import events as obs_events
 
 logger = logging.getLogger("thunder_tpu")
+
+
+def default_cache_dir() -> str:
+    """``<checkout>/.jax_cache`` — where the compile cache (and, under
+    ``native/``, the built min-cut solver) live when nothing outside placed
+    them. Listed in ``.gitignore``; a fixed path, so entries written by one
+    process are found by the next."""
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    return os.path.join(checkout, ".jax_cache")
 
 
 def _entry_files(cache_dir: str) -> list[str]:
@@ -89,6 +100,13 @@ def purge_on_error(exc: BaseException) -> bool:
     cache_dir = configured_cache_dir()
     if not cache_dir or not os.path.isdir(cache_dir):
         return False
+    external = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if external and os.path.realpath(external) == os.path.realpath(cache_dir):
+        logger.warning(
+            "persistent XLA compile cache: %s came from JAX_COMPILATION_CACHE_DIR "
+            "and is not purged after %s: %s", cache_dir, type(exc).__name__, exc,
+        )
+        return False
     entries = _entry_files(cache_dir)
     for path in entries:
         try:
@@ -107,9 +125,6 @@ def purge_on_error(exc: BaseException) -> bool:
 
 
 def configured_cache_dir() -> Optional[str]:
-    try:
-        import jax
+    import jax
 
-        return jax.config.jax_compilation_cache_dir or None
-    except Exception:
-        return None
+    return jax.config.jax_compilation_cache_dir or None

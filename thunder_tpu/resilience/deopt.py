@@ -45,6 +45,7 @@ capped at 2s — set 0 in tests).
 
 from __future__ import annotations
 
+import logging
 import os
 import time
 from typing import Optional
@@ -52,6 +53,8 @@ from typing import Optional
 from thunder_tpu.observability import events as obs_events
 from thunder_tpu.observability import metrics as obsm
 from thunder_tpu.resilience import demotion
+
+logger = logging.getLogger("thunder_tpu")
 
 MAX_LEVEL = 3
 
@@ -238,19 +241,41 @@ def escalate(cd, reason: str, attempt: int, *, entry=None, cs=None) -> bool:
 # -- the recovery driver (called from api.fn_) ---------------------------------
 
 
+_RECOVERY = {
+    demotion.KERNEL: "demoting the claimed kernel executors and re-claiming on the next one down",
+    demotion.COMPILE: "recompiling one de-opt level down",
+    demotion.OOM: "recompiling one de-opt level down",
+    demotion.CACHE_CORRUPT: "purging the compile cache and recompiling",
+}
+
+
+def _recovered(kind: str, exc: BaseException, where: str) -> bool:
+    """Every recovery is said out loud, with the exception it recovered from:
+    a caller who did not ask for it must be able to see that the result came
+    from a demoted or de-optimized program (``cache_info(fn)`` and
+    ``demotion.quarantine_snapshot()`` say which)."""
+    logger.warning(
+        "thunder_tpu recovered from a %s failure at %s by %s: %s: %s",
+        kind, where, _RECOVERY[kind], type(exc).__name__, exc,
+    )
+    return True
+
+
 def handle_compile_failure(exc: BaseException, cd, cs, attempt: int) -> bool:
     """Recovery decision for an exception raised while *building* an entry
     (tracing/claiming/staging). True → the caller retries the compile."""
     kind = demotion.classify_failure(exc)
     if kind in (demotion.COMPILE, demotion.OOM):
-        return escalate(cd, f"compile failure: {kind}", attempt, cs=cs)
-    if kind == demotion.KERNEL:
+        ok = escalate(cd, f"compile failure: {kind}", attempt, cs=cs)
+    elif kind == demotion.KERNEL:
         # A kernel executor raised while staging its claimed op: demote and
         # re-claim (no ladder bump needed — the program itself is fine).
-        return _demote_from(exc, None, cs, attempt)
-    if kind == demotion.CACHE_CORRUPT:
-        return _purge_compile_cache(exc, attempt)
-    return False
+        ok = _demote_from(exc, None, cs, attempt)
+    elif kind == demotion.CACHE_CORRUPT:
+        ok = _purge_compile_cache(exc, attempt)
+    else:
+        return False
+    return ok and _recovered(kind, exc, "compile")
 
 
 def handle_run_failure(exc: BaseException, cd, cs, entry, attempt: int) -> bool:
@@ -263,12 +288,12 @@ def handle_run_failure(exc: BaseException, cd, cs, entry, attempt: int) -> bool:
     _evict(cs, entry)
     if kind == demotion.KERNEL:
         extrace = entry.computation_traces[-1] if entry.computation_traces else None
-        return _demote_from(exc, extrace, cs, attempt)
-    if kind in (demotion.COMPILE, demotion.OOM):
-        return escalate(cd, f"run failure: {kind}", attempt, entry=entry, cs=cs)
-    if kind == demotion.CACHE_CORRUPT:
-        return _purge_compile_cache(exc, attempt)
-    return False
+        ok = _demote_from(exc, extrace, cs, attempt)
+    elif kind in (demotion.COMPILE, demotion.OOM):
+        ok = escalate(cd, f"run failure: {kind}", attempt, entry=entry, cs=cs)
+    else:
+        ok = _purge_compile_cache(exc, attempt)
+    return ok and _recovered(kind, exc, "run")
 
 
 def _demote_from(exc, extrace, cs, attempt: int) -> bool:

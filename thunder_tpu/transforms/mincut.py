@@ -2,8 +2,10 @@
 
 Reference parity: thunder/core/rematerialization.py:245 (igraph max-flow).
 The native module (csrc/mincut.cpp) compiles lazily on first use with g++
-into the user cache dir; the pure-Python Dinic below is the fallback when no
-toolchain is available. Both implement the same interface:
+into the checkout's ignored cache directory (``.jax_cache/native``: built from
+what git commits, on the machine that runs it); the pure-Python Dinic below is
+the fallback when no toolchain is available. Which of the two is in use is
+logged once on the ``thunder_tpu`` logger. Both implement the same interface:
 
     min_cut(n_nodes, edges=[(u, v, cap)], s, t) -> (flow, source_side_set)
 
@@ -13,11 +15,13 @@ Capacities ≥ INF_CAP are treated as uncuttable.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
-import tempfile
 from collections import deque
-from typing import Optional, Sequence
+from typing import Sequence
+
+logger = logging.getLogger("thunder_tpu")
 
 INF_CAP = 1 << 60
 
@@ -30,16 +34,22 @@ def _load_native():
     if _lib_tried:
         return _lib
     _lib_tried = True
+    from thunder_tpu.resilience.compile_cache import default_cache_dir
+
     src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "csrc", "mincut.cpp")
-    cache_dir = os.path.join(tempfile.gettempdir(), "thunder_tpu_native")
-    os.makedirs(cache_dir, exist_ok=True)
+    cache_dir = os.path.join(default_cache_dir(), "native")
     so_path = os.path.join(cache_dir, "libttmincut.so")
     try:
+        os.makedirs(cache_dir, exist_ok=True)
         if not os.path.exists(so_path) or os.path.getmtime(so_path) < os.path.getmtime(src):
+            # Build to a private name, then rename: a second process never
+            # loads a half-written library.
+            tmp_path = f"{so_path}.{os.getpid()}.tmp"
             subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", src, "-o", so_path],
+                ["g++", "-O2", "-shared", "-fPIC", src, "-o", tmp_path],
                 check=True, capture_output=True, timeout=120,
             )
+            os.replace(tmp_path, so_path)
         lib = ctypes.CDLL(so_path)
         lib.tt_mincut.restype = ctypes.c_int64
         lib.tt_mincut.argtypes = [
@@ -49,8 +59,13 @@ def _load_native():
             ctypes.c_int32, ctypes.c_int32, ctypes.POINTER(ctypes.c_uint8),
         ]
         _lib = lib
-    except Exception:
+        logger.info("min-cut solver: native (%s)", so_path)
+    except (OSError, subprocess.SubprocessError) as e:
+        # No toolchain, a failed build or an unloadable library: the Python
+        # Dinic computes the same cut, slower. Said once, with the cause.
         _lib = None
+        logger.warning("min-cut solver: pure Python (native build unavailable: %s: %s)",
+                       type(e).__name__, e)
     return _lib
 
 
