@@ -31,6 +31,7 @@ from thunder_tpu.api import (  # noqa: F401
     cache_hits,
     cache_misses,
     cache_info,
+    compile_phases,
     set_execution_callback_file,
 )
 from thunder_tpu.common import (  # noqa: F401
@@ -52,7 +53,8 @@ __all__ = [
     "jit", "grad", "value_and_grad", "vmap", "jvp", "seed",
     "compile_data", "compile_stats", "last_traces", "last_prologue_traces",
     "last_backward_traces", "last_compile_options", "cache_hits",
-    "cache_misses", "cache_info", "set_execution_callback_file",
+    "cache_misses", "cache_info", "compile_phases",
+    "set_execution_callback_file",
     "CACHE_OPTIONS", "SHARP_EDGES_OPTIONS",
     "ThunderSharpEdgeError", "ThunderSharpEdgeWarning",
     "dtypes", "devices", "monitor", "profile", "resilience",

@@ -14,7 +14,9 @@ CUDA-graphs endgame, as the default).
 
 from __future__ import annotations
 
+import collections
 import functools
+import time
 from numbers import Number
 from typing import Any, Callable, Optional, Sequence
 
@@ -540,13 +542,33 @@ def _jax_cache_counts() -> dict:
             for k in ("hits", "misses", "backend_compile_s", "cache_get_s")}
 
 
+# Every compile-phase span of the process, both paths (``thunder_tpu.jit``
+# and ``parallel.build_train_step``), always on and bounded: the gated sinks
+# below need an operator to have asked before the compile ran; this is what
+# ``compile_phases()`` hands out after the fact.
+_compile_phase_records: collections.deque = collections.deque(maxlen=4096)
+
+
+def compile_phases() -> list:
+    """Every compile-phase span this process recorded, oldest first, as
+    copies: ``{"program": compile id, "phase": name, "s": seconds, "at":
+    time.perf_counter() when the span ended, ...extras}``. Holds the newest
+    4096; ``cache_info(fn)["compile_phase_seconds"]`` is the per-function
+    rollup (docs/observability.md, "Compile-phase spans")."""
+    return [dict(r) for r in _compile_phase_records]
+
+
 def _record_compile_phase(compile_id, phase: str, seconds: float, *,
                           log=None, **extra) -> None:
-    """One compile-pipeline span: a ``compile_phase`` event (correlated by
+    """One compile-pipeline span: a record in the process-wide list
+    ``compile_phases()`` reads, a ``compile_phase`` event (correlated by
     compile_id) + the ``thunder_tpu_compile_phase_s{phase=...}`` histogram.
     Together the spans decompose what ``thunder_tpu_xla_compile_s`` reports
     as one opaque number."""
     extra = {k: v for k, v in extra.items() if v is not None}
+    _compile_phase_records.append(
+        {"program": compile_id, "phase": phase, "s": seconds,
+         "at": time.perf_counter(), **extra})
     if obsm.enabled():
         labels = {"phase": phase}
         if extra.get("cache"):

@@ -15,6 +15,8 @@ inherits the param specs, giving ZeRO-sharded optimizer states for free.
 
 from __future__ import annotations
 
+import contextlib
+import time
 from functools import partial
 from typing import Any, Optional
 
@@ -81,6 +83,21 @@ def adamw_update(params, grads, state, *, lr=1e-3, b1=0.9, b2=0.95, eps=1e-8, we
 # =============================================================================
 
 
+@contextlib.contextmanager
+def _phase(program, name: str):
+    """One compile-phase span of the train path, through the recorder the
+    ``jit`` path has (``api._record_compile_phase``, read back with
+    ``thunder_tpu.compile_phases()``). The body may put extras into the dict
+    it is handed; a stage that raises records nothing, as on the ``jit``
+    path."""
+    from thunder_tpu.api import _record_compile_phase
+
+    extra: dict = {}
+    t0 = time.perf_counter()
+    yield extra
+    _record_compile_phase(program, name, time.perf_counter() - t0, **extra)
+
+
 def _compile_loss_and_grads(config: GPTConfig, params, idx, targets, executors=None,
                             *, mesh=None, param_specs=None, comm_schedule=True):
     """Trace loss_fn through the framework pipeline → a pure jax callable
@@ -99,32 +116,45 @@ def _compile_loss_and_grads(config: GPTConfig, params, idx, targets, executors=N
     from thunder_tpu.api import trace_program
     from thunder_tpu.executors.passes import transform_for_execution
     from thunder_tpu.extend import resolve_executors
+    from thunder_tpu.observability.events import current_compile_id
     from thunder_tpu.transforms.attention_residuals import save_sdpa_residuals_joint
     from thunder_tpu.transforms.autodiff import grad_transform
     from thunder_tpu.transforms.common import dce
 
+    # The phases carry the ``jit`` path's names where the stage is the
+    # ``jit`` path's (docs/observability.md, "Compile-phase spans").
+    program = current_compile_id()  # build_train_step's compile_scope
     ex_list = resolve_executors(executors)
     fn = lambda p, i, t: loss_fn(p, i, t, config)  # noqa: E731
-    _, comp = trace_program(fn, (params, idx, targets), {})
-    comp = dce(comp)
-    joint = grad_transform(comp, return_value=True)
-    joint = save_sdpa_residuals_joint(joint, ex_list)
-    divisors = None
-    if mesh is not None and param_specs is not None:
-        from thunder_tpu.analysis.liveness import arg_divisors_from_specs
+    with _phase(program, "trace"):
+        _, comp = trace_program(fn, (params, idx, targets), {})
+        comp = dce(comp)
+    with _phase(program, "transforms"):
+        joint = grad_transform(comp, return_value=True)
+        joint = save_sdpa_residuals_joint(joint, ex_list)
+        divisors = None
+        if mesh is not None and param_specs is not None:
+            from thunder_tpu.analysis.liveness import arg_divisors_from_specs
 
-        try:
-            # The joint trace shares its args with the claimed trace, so
-            # the divisors computed here hold for the scheduler's input.
-            divisors = arg_divisors_from_specs(joint, param_specs, mesh=mesh)
-        except Exception:  # noqa: BLE001 — divisors refine, never gate
-            divisors = None
-    extrace = transform_for_execution(
-        joint, ex_list,
-        comm_schedule=comm_schedule,
-        comm_schedule_opts={"arg_divisors": divisors} if divisors else None,
-    )
-    return extrace.python_callable(), extrace
+            try:
+                # The joint trace shares its args with the claimed trace, so
+                # the divisors computed here hold for the scheduler's input.
+                divisors = arg_divisors_from_specs(joint, param_specs, mesh=mesh)
+            except Exception:  # noqa: BLE001 — divisors refine, never gate
+                divisors = None
+    with _phase(program, "claim") as extra:  # holds the comm scheduler on this path
+        extrace = transform_for_execution(
+            joint, ex_list,
+            comm_schedule=comm_schedule,
+            comm_schedule_opts={"arg_divisors": divisors} if divisors else None,
+        )
+        comm_sched_tag = extrace.tags.get("comm_schedule")
+        if comm_sched_tag:  # by presence only, as static_analysis carries it on the jit path
+            extra["comm_schedule_moves"] = comm_sched_tag.get("moves")
+            extra["comm_schedule_exposed_pct"] = comm_sched_tag.get("exposed_pct_after")
+    with _phase(program, "codegen"):
+        run = extrace.python_callable()
+    return run, extrace
 
 
 def build_train_step(
@@ -166,6 +196,7 @@ def build_train_step(
 
     from thunder_tpu.api import _ensure_runtime
     from thunder_tpu.executors.kernel_mesh import kernel_mesh
+    from thunder_tpu.observability.events import compile_scope
     from thunder_tpu.parallel.sharding import data_spec as _dspec
 
     _ensure_runtime()  # x64 dtype semantics + the persistent compile cache
@@ -173,37 +204,53 @@ def build_train_step(
         batch_spec = _dspec(mesh)
     batch_axes = batch_spec[0] if mesh is not None and len(batch_spec) else None
 
-    with kernel_mesh(mesh, batch_axes):
-        loss_and_grads, extrace = _compile_loss_and_grads(
-            config, params, idx, targets, executors=executors,
-            mesh=mesh, param_specs=param_specs,
-        )
+    def ns(spec_tree):
+        return tree_map(lambda s: NamedSharding(mesh, s), spec_tree,
+                        is_leaf=lambda x: isinstance(x, PartitionSpec))
+
+    opt_sh = ns(opt_state_specs(param_specs, optimizer)) if mesh is not None else None
+
+    # One program id a step, from the sequence the jit path's compile ids
+    # come from: its compile-phase spans read like any other compile's.
+    with compile_scope() as program:
+        with kernel_mesh(mesh, batch_axes):
+            loss_and_grads, extrace = _compile_loss_and_grads(
+                config, params, idx, targets, executors=executors,
+                mesh=mesh, param_specs=param_specs,
+            )
+        # The state goes in as the step hands it back (an int32 array, and
+        # under a mesh laid out by ``opt_sh``): a first call whose arguments
+        # differ in type or layout from the second's traces and compiles the
+        # step twice.
+        with _phase(program, "optimizer_state") as extra:
+            opt_state = adamw_init(params) if optimizer != "sgd" else {"step": jnp.zeros((), dtype=jnp.int32)}
+            if mesh is not None:
+                opt_state = jax.device_put(opt_state, opt_sh)
+            extra["leaves"] = len(tree_flatten(opt_state)[0])
 
     def step(params, opt_state, idx, targets):
-        flat, _ = tree_flatten(((params, idx, targets), {}))
-        with kernel_mesh(mesh, batch_axes):  # read while jax.jit traces the kernels
-            loss, grads = loss_and_grads(*flat)
-        if grads_in_f32:
-            grads = tuple(g.astype(jnp.float32) for g in grads)
-        p_flat, p_spec = tree_flatten(params)
-        grads_tree = tree_unflatten(p_spec, list(grads))
-        if optimizer == "sgd":
-            # bf16-true SGD(wd) — no moment state; what lets multi-GB models
-            # train on one 16 GB chip (the bench.py protocol)
-            new_params = tree_map(
-                lambda p, g: (p - lr * (g.astype(p.dtype) + weight_decay * p)).astype(p.dtype),
-                params, grads_tree,
+        # This body runs only while jax traces it, so the span costs a step
+        # nothing and puts nothing in the jaxpr: one record a trace.
+        with _phase(program, "jax_trace"):
+            flat, _ = tree_flatten(((params, idx, targets), {}))
+            with kernel_mesh(mesh, batch_axes):  # read while jax.jit traces the kernels
+                loss, grads = loss_and_grads(*flat)
+            if grads_in_f32:
+                grads = tuple(g.astype(jnp.float32) for g in grads)
+            p_flat, p_spec = tree_flatten(params)
+            grads_tree = tree_unflatten(p_spec, list(grads))
+            if optimizer == "sgd":
+                # bf16-true SGD(wd) — no moment state; what lets multi-GB models
+                # train on one 16 GB chip (the bench.py protocol)
+                new_params = tree_map(
+                    lambda p, g: (p - lr * (g.astype(p.dtype) + weight_decay * p)).astype(p.dtype),
+                    params, grads_tree,
+                )
+                return new_params, opt_state, loss
+            new_params, new_state = adamw_update(
+                params, grads_tree, opt_state, lr=lr, b1=b1, b2=b2, weight_decay=weight_decay
             )
-            return new_params, opt_state, loss
-        new_params, new_state = adamw_update(
-            params, grads_tree, opt_state, lr=lr, b1=b1, b2=b2, weight_decay=weight_decay
-        )
-        return new_params, new_state, loss
-
-    # The state goes in as the step hands it back (an int32 array, and under a
-    # mesh laid out by ``opt_sh`` below): a first call whose arguments differ
-    # in type or layout from the second's traces and compiles the step twice.
-    opt_state = adamw_init(params) if optimizer != "sgd" else {"step": jnp.zeros((), dtype=jnp.int32)}
+            return new_params, new_state, loss
 
     # Donation metadata for the static planner suite (ISSUE 10): the param
     # leaves of the claimed trace are the donated buffers, so the liveness
@@ -235,15 +282,7 @@ def build_train_step(
         jfn = _stamp(jax.jit(step, donate_argnums=(0, 1) if donate else ()))
         return (jfn, opt_state, extrace) if return_extrace else (jfn, opt_state)
 
-    ps = param_specs
-
-    def ns(spec_tree):
-        return tree_map(lambda s: NamedSharding(mesh, s), spec_tree,
-                        is_leaf=lambda x: isinstance(x, PartitionSpec))
-
-    param_sh = ns(ps)
-    opt_sh = ns(opt_state_specs(ps, optimizer))
-    opt_state = jax.device_put(opt_state, opt_sh)
+    param_sh = ns(param_specs)
     data_sh = NamedSharding(mesh, batch_spec)
     loss_sh = NamedSharding(mesh, PartitionSpec())
 
