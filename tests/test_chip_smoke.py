@@ -144,9 +144,10 @@ class TestPeakLookup:
         assert resolve_device_spec("cpu").name == "cpu"
 
 
-@pytest.mark.parametrize("axes", [None, {"fsdp": 4}, {"dp": 4}, {"dp": 2, "fsdp": 2, "tp": 2}],
-                         ids=["one-chip", "fsdp4", "dp4", "dp2-fsdp2-tp2"])
-def test_train_step_lowers_for_tpu_with_mosaic_kernels(axes, monkeypatch):
+@pytest.mark.parametrize("axes,rotary", [(None, 1.0), ({"fsdp": 4}, 1.0), ({"dp": 4}, 1.0),
+                                         ({"dp": 2, "fsdp": 2, "tp": 2}, 1.0), ({"fsdp": 4}, 0.25)],
+                         ids=["one-chip", "fsdp4", "dp4", "dp2-fsdp2-tp2", "fsdp4-partial-rotary"])
+def test_train_step_lowers_for_tpu_with_mosaic_kernels(axes, rotary, monkeypatch):
     """``build_train_step`` cross-lowered for the TPU from the CPU host, the
     kernels as Mosaic calls rather than interpreted. Under a mesh this fails
     with "Mosaic kernels cannot be automatically partitioned" unless every
@@ -160,9 +161,9 @@ def test_train_step_lowers_for_tpu_with_mosaic_kernels(axes, monkeypatch):
     monkeypatch.setattr(flashex, "_interpret", lambda: False)
     monkeypatch.setattr(pallasex, "_interpret", lambda: False)
 
-    # pythia's shape at a tenth of the width, and full rope so that kernel lowers too
+    # pythia's shape at a tenth of the width; the rope kernel's full body, and its partial one in place
     cfg = dataclasses.replace(gpt.name_to_config("pythia-410m"), n_layer=2, n_embd=128,
-                              n_head=2, intermediate_size=512, rotary_percentage=1.0,
+                              n_head=2, intermediate_size=512, rotary_percentage=rotary,
                               vocab_size=512, padded_vocab_size=512)
     B, T = 8, 256
     params = gpt.init_params(cfg, dtype=dtypes.bfloat16, seed=0)
@@ -181,6 +182,51 @@ def test_train_step_lowers_for_tpu_with_mosaic_kernels(axes, monkeypatch):
     assert kernel_claims(extrace) == {
         "apply_rope": "pallas", "sdpa_fwd_res": "flash", "sdpa_bwd_res": "flash",
         "cross_entropy": "pallas", "cross_entropy_bwd": "pallas"}
+    text = step.trace(params, opt, idx, tgt).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+
+
+HEAD_SHAPES = {  # registry entry, what is cut, (head size, rotary features)
+    # pythia-410m's: 64 wide, 25% rotary, one key-value head a query head
+    "pythia-head": ("pythia-410m", dict(n_embd=128, n_head=2), (64, 16)),
+    # mistral-7b's: 128 wide, full rotary, grouped key-value heads
+    "mistral-head": ("mistral-7b", dict(n_embd=512, n_head=4, n_query_groups=1), (128, 128)),
+}
+
+
+@pytest.mark.parametrize("path", ["jit", "train"])
+@pytest.mark.parametrize("head", list(HEAD_SHAPES))
+def test_kernels_claimed_at_a_cell_head_shape(head, path, monkeypatch):
+    """Two layers at a benchmark configuration's head shape: the counter the
+    benchmark reports (``kernels_claimed``) reads a flash call and two rope
+    calls a layer and direction, plus the two cross-entropy calls of a train
+    step, whether the rotary share is 25% or all of the head; and the step
+    lowers for the TPU with its kernels as Mosaic calls."""
+    import thunder_tpu
+    from perfbench.jobs.gpt_model import kernels_claimed
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import flashex, pallasex
+    from thunder_tpu.models import gpt
+    from thunder_tpu.parallel import build_train_step
+
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    base, cut, head_shape = HEAD_SHAPES[head]
+    cfg = dataclasses.replace(gpt.name_to_config(base), n_layer=2, intermediate_size=512,
+                              vocab_size=512, padded_vocab_size=512, **cut)
+    assert (cfg.head_size, cfg.rope_n_elem) == head_shape
+    B, T = 2, 256
+    params = gpt.init_params(cfg, dtype=dtypes.bfloat16, seed=0)
+    idx = np.random.RandomState(0).randint(0, cfg.vocab_size, (B, T)).astype(np.int32)
+    if path == "jit":
+        jfn = thunder_tpu.jit(lambda p, i: gpt.forward(p, i, cfg))
+        assert np.isfinite(np.asarray(jfn(params, idx), dtype=np.float32)).all()
+        assert kernels_claimed(thunder_tpu.last_traces(jfn)[-1]) == 6  # 2 flash, 4 rope
+        return
+    tgt = np.roll(idx, -1, axis=1).astype(np.int32)
+    step, opt, extrace = build_train_step(cfg, params, idx, tgt, return_extrace=True)
+    assert kernels_claimed(extrace) == 14  # 4 flash, 8 rope, 2 cross-entropy
+    monkeypatch.setattr(flashex, "_interpret", lambda: False)
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
     text = step.trace(params, opt, idx, tgt).lower(lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text
 

@@ -420,32 +420,40 @@ class TestAttentionResiduals:
 
 class TestPallasRope:
     """Fused rotate-half ROPE kernel (pallasex): the decomposed form is
-    lane-misaligned at odd head sizes (e.g. 100); bwd is the same kernel
-    with -sin via the torch.apply_rope VJP rule."""
+    lane-misaligned at odd head sizes (e.g. 100) and with partial rotary
+    (pythia's 16 of 64); bwd is the same kernel with -sin via the
+    torch.apply_rope VJP rule."""
 
-    def _inputs(self, B=2, H=3, T=64, D=100):
+    # (hs, n): pythia, a half share, 50-lane halves beside 28 lanes that pass, phi-2, full rotary twice
+    SHARES = [(64, 16), (64, 32), (128, 100), (80, 32), (128, 128), (100, 100)]
+
+    def _inputs(self, D, n, B=2, H=3, T=64):
         import jax.numpy as jnp
 
         rng = np.random.RandomState(0)
         x = jnp.asarray(rng.randn(B, H, T, D).astype(np.float32), dtype=jnp.bfloat16)
-        theta = (10000.0 ** (np.arange(0, D // 2) * -2.0 / D)).astype(np.float32)
+        theta = (10000.0 ** (np.arange(0, n // 2) * -2.0 / n)).astype(np.float32)
         freqs = np.arange(T, dtype=np.float32)[:, None] * theta[None, :]
         emb = np.concatenate([freqs, freqs], 1)
         cos = jnp.asarray(np.cos(emb), dtype=jnp.bfloat16)
         sin = jnp.asarray(np.sin(emb), dtype=jnp.bfloat16)
         return x, cos, sin
 
-    def test_fwd_claims_and_matches(self):
-        x, cos, sin = self._inputs()
+    @pytest.mark.parametrize("hs,n", SHARES)
+    def test_fwd_claims_and_matches(self, hs, n):
+        x, cos, sin = self._inputs(D=hs, n=n)
         f = lambda x, c, s: ttorch.apply_rope(x, c, s)
         fast = thunder_tpu.jit(f)
         got = _f32(fast(x, cos, sin))
         assert "pallas_apply_rope" in thunder_tpu.last_traces(fast)[-1].python()
         want = _f32(thunder_tpu.jit(f, executors=jax_only)(x, cos, sin))
         np.testing.assert_allclose(got, want, rtol=3e-2, atol=4e-2)
+        # the features beyond n pass through untouched
+        np.testing.assert_array_equal(got[..., n:], _f32(x)[..., n:])
 
-    def test_bwd_same_kernel(self):
-        x, cos, sin = self._inputs()
+    @pytest.mark.parametrize("hs,n", SHARES)
+    def test_bwd_same_kernel(self, hs, n):
+        x, cos, sin = self._inputs(D=hs, n=n)
 
         def loss(x, c, s):
             o = ttorch.apply_rope(x, c, s)
@@ -455,20 +463,32 @@ class TestPallasRope:
         vgs = thunder_tpu.value_and_grad(loss, executors=jax_only)
         lf, gf = vgf(x, cos, sin)
         ls, gs = vgs(x, cos, sin)
+        # forward and backward, one kernel each
+        assert thunder_tpu.last_traces(vgf)[-1].python().count("= pallas_apply_rope(") == 2
         np.testing.assert_allclose(float(lf), float(ls), rtol=2e-2)
         np.testing.assert_allclose(_f32(gf[0]), _f32(gs[0]), rtol=5e-2, atol=8e-2)
+        np.testing.assert_array_equal(_f32(gf[0])[..., n:], _f32(gs[0])[..., n:])
 
-    def test_partial_rotary_decomposes(self):
+    @pytest.mark.parametrize("case", ["odd_n", "mixed_dtypes", "float16"])
+    def test_declined_decomposes(self, case):
+        """What Mosaic does not lower for partial rotary is declined at claim
+        time: there is no fallback at run time."""
         import jax.numpy as jnp
 
-        x, cos, sin = self._inputs(D=100)
-        x_wide = jnp.concatenate([x, x[..., :28]], axis=-1)  # hs=128 > n=100
+        x, cos, sin = self._inputs(D=64, n=16)
+        if case == "odd_n":
+            cos, sin = cos[:, :15], sin[:, :15]
+        elif case == "mixed_dtypes":
+            cos, sin = cos.astype(jnp.float32), sin.astype(jnp.float32)
+        else:  # no float16 matmul on the v5e
+            x, cos, sin = (a.astype(jnp.float16) for a in (x, cos, sin))
         f = lambda x, c, s: ttorch.apply_rope(x, c, s)
         jf = thunder_tpu.jit(f)
-        got = _f32(jf(x_wide, cos, sin))
+        got = jf(x, cos, sin)
         assert "pallas_apply_rope" not in thunder_tpu.last_traces(jf)[-1].python()
-        want = _f32(thunder_tpu.jit(f, executors=jax_only)(x_wide, cos, sin))
-        np.testing.assert_allclose(got, want, rtol=3e-2, atol=4e-2)
+        want = thunder_tpu.jit(f, executors=jax_only)(x, cos, sin)
+        assert got.dtype == want.dtype
+        np.testing.assert_allclose(_f32(got), _f32(want), rtol=3e-2, atol=4e-2)
 
 
 class TestNormExecutor:

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 from functools import partial
 
+from thunder_tpu.core import dtypes
 from thunder_tpu.core.proxies import pyval
 from thunder_tpu.executors.kernel_mesh import batch_shards, per_batch_shard
 from thunder_tpu.extend import OperatorExecutor, add_default_executor, register_executor
@@ -205,11 +206,14 @@ ex.register_implementation("torch.cross_entropy_bwd", fn=_ce_bwd_impl, checker=_
 # Fused rotary embedding (rotate-half ROPE)
 # =============================================================================
 #
-# The decomposed rotate-half at head sizes like 100 produces 50-lane slices
-# and a lane-dim concat — badly misaligned VPU work (r4 profile: ~14 ms/iter
-# of (.., 50)-shaped fusions plus associated relayouts on the 3B bench). The
-# kernel does the whole thing in one HBM pass per tensor; the backward is
-# the same kernel with -sin (see the torch.apply_rope VJP rule).
+# The decomposed rotate-half runs as slices and lane-dim concatenations of
+# pieces narrower than a vector register: 50-lane halves at head size 100 (r4
+# profile: ~14 ms/iter of (.., 50)-shaped fusions plus relayouts on the 3B
+# bench), and with partial rotary 8-, 16- and 48-lane pieces that cost
+# pythia-410m's forward more than its flash attention (PERF.md, PR 25). The
+# kernel does the whole thing in one HBM pass per tensor, whatever the rotary
+# share n <= hs; the backward is the same kernel with -sin (see the
+# torch.apply_rope VJP rule).
 
 
 _ROPE_BT = 2048  # sequence rows per block
@@ -221,9 +225,15 @@ def _rope_checker(x, cos, sin):
     T, n = cos.shape
     if not (x.dtype == cos.dtype == sin.dtype):
         return False  # mixed dtypes promote in the decomposition; don't alter semantics
-    # full-rotary only (partial decomposes); bt shrinks to a divisor of T
-    return (x.shape[-2] == T and x.shape[-1] == n and n % 2 == 0 and T % 8 == 0
-            and x.shape[0] % batch_shards() == 0)
+    D = x.shape[-1]
+    if n != D:
+        # partial rotary: what Mosaic was seen to compile for the v5e (float16
+        # has no matmul there; wider rows overflow scoped VMEM at bt=2048)
+        dt = dtypes.to_dtype(x.dtype)
+        if n > D or dt not in (dtypes.bfloat16, dtypes.float32) or D * dt.bytes > 512:
+            return False
+    # bt shrinks to a divisor of T
+    return x.shape[-2] == T and n % 2 == 0 and T % 8 == 0 and x.shape[0] % batch_shards() == 0
 
 
 def _rope_kernel(x_ref, cos_ref, sin_ref, out_ref, *, half: int):
@@ -236,19 +246,49 @@ def _rope_kernel(x_ref, cos_ref, sin_ref, out_ref, *, half: int):
     out_ref[0] = (x * cos_ref[...] + rotated * sin_ref[...]).astype(out_ref.dtype)
 
 
+def _rope_partial_kernel(x_ref, cos_ref, sin_ref, out_ref, *, n: int):
+    """Rotary on the first ``n`` of D lanes without a slice narrower than the
+    block: ``cos`` comes padded with ones and ``sin`` with zeros, and
+    rotate-half is a product with the D x D signed permutation (one nonzero a
+    column, f32 accumulation: exact), whose columns beyond ``n`` are zero. So
+    those lanes leave as ``x * 1 + 0 * 0``. A row that holds an inf or a NaN
+    comes out NaN in every lane, where the decomposition keeps it to its pair."""
+    import jax
+    import jax.numpy as jnp
+
+    x = x_ref[0]
+    D, half = x.shape[-1], n // 2
+    src = jax.lax.broadcasted_iota(jnp.int32, (D, D), 0)
+    dst = jax.lax.broadcasted_iota(jnp.int32, (D, D), 1)
+    perm = jnp.where((src == dst + half) & (dst < half), -1.0,
+                     jnp.where((src == dst - half) & (dst >= half) & (dst < n), 1.0, 0.0)).astype(x.dtype)
+    exact = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None  # bf16 operands are exact as they are
+    rotated = jnp.dot(x, perm, preferred_element_type=jnp.float32, precision=exact).astype(x.dtype)
+    out_ref[0] = (x * cos_ref[...] + rotated * sin_ref[...]).astype(out_ref.dtype)
+
+
 def _rope_impl(x, cos, sin):
     chaos.kernel_seam("pallas", "apply_rope")
     import jax
+    import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     def shard(x, cos, sin):
         B, H, T, D = x.shape
+        n = cos.shape[-1]
         bt = _ROPE_BT
         while T % bt:
             bt //= 2
+        kernel, in_place = partial(_rope_kernel, half=D // 2), {}
+        if n != D:
+            # Tables of full width keep the call's three operands; the VJP needs
+            # cos and sin only, never x, so x is dead after the call: in place.
+            kernel, in_place = partial(_rope_partial_kernel, n=n), {0: 0}
+            cos = jnp.concatenate([cos, jnp.ones((T, D - n), cos.dtype)], axis=-1)
+            sin = jnp.concatenate([sin, jnp.zeros((T, D - n), sin.dtype)], axis=-1)
         out = pl.pallas_call(
-            partial(_rope_kernel, half=D // 2),
+            kernel,
             grid=(B * H, T // bt),
             in_specs=[
                 pl.BlockSpec((1, bt, D), lambda i, j: (i, j, 0), memory_space=pltpu.VMEM),
@@ -257,6 +297,7 @@ def _rope_impl(x, cos, sin):
             ],
             out_specs=pl.BlockSpec((1, bt, D), lambda i, j: (i, j, 0), memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((B * H, T, D), x.dtype),
+            input_output_aliases=in_place,
             interpret=_interpret(),
         )(x.reshape(B * H, T, D), cos, sin)
         return out.reshape(B, H, T, D)

@@ -1360,10 +1360,12 @@ def apply_rope(x, cos, sin):
     convention; litgpt ``apply_rope``): x (..., T, hs), cos/sin (T, n) with
     n ≤ hs built as cat([freqs, freqs]) — features beyond n pass through.
 
-    Kept composite so the Pallas rope kernel (pallasex) claims it whole:
-    the decomposed rotate-half (two 50-lane slices + concat at hs=100) is
-    badly lane-misaligned on the VPU — the r4 profile showed ~14 ms/iter of
-    (.., 50)-shaped fusions on the 3B bench."""
+    Kept composite so the Pallas rope kernel (pallasex) claims it whole,
+    for n == hs and for n < hs: the decomposed rotate-half (two 50-lane
+    slices + concat at hs=100; 8-, 16- and 48-lane pieces at pythia's 16 of
+    64) is badly lane-misaligned on the VPU — the r4 profile showed
+    ~14 ms/iter of (.., 50)-shaped fusions on the 3B bench. This body stays
+    the definition, the ``jax_only`` path and what a declined call runs."""
     n = cos.shape[-1]
     half = n // 2
     rot = x[..., :n] if n != x.shape[-1] else x
