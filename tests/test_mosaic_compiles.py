@@ -43,6 +43,8 @@ ROPE_SHAPES = [
     ("float32", (2, 4, 2048, 128), 32),
     ("bfloat16", (2, 4, 8, 64), 16),        # the shortest block
     ("bfloat16", (1, 32, 4096, 128), 128),  # mistral-7b.train's q: full rotary
+    ("bfloat16", (2, 64, 4096, 192), 64),   # a.x-k1.fwd's q: the rope part first, 64 of 192 lanes
+    ("bfloat16", (2, 1, 4096, 64), 64),     # a.x-k1.fwd's one rope key for all heads
 ]
 
 
@@ -80,3 +82,72 @@ def test_rope_checker_declines_what_does_not_compile(one_chip, monkeypatch, dtyp
     assert not claimed
     with pytest.raises(Exception):  # noqa: B017, PT011 - Mosaic's own error, whatever its type
         lowered.compile()
+
+
+MLA_SHAPES = [
+    # (B, H, T), d_qk, d_v
+    ((2, 64, 4096), 192, 128),  # a.x-k1.fwd: latent attention's prefill call
+    ((1, 8, 2048), 192, 128),
+    ((1, 4, 1024), 128, 64),
+]
+
+
+@pytest.mark.parametrize("bht,d_qk,d_v", MLA_SHAPES, ids=[f"{d}-{v}-T{s[-1]}" for s, d, v in MLA_SHAPES])
+def test_attention_with_narrower_value_heads_compiles_for_v5e(one_chip, monkeypatch, bht, d_qk, d_v):
+    """The flash executor claims a call whose value heads are narrower than its
+    query and key heads, and splash compiles it for the v5e with the output at
+    the value width: no lane is padded to make the widths equal."""
+    import jax
+    import jax.numpy as jnp
+
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import flashex
+
+    monkeypatch.setattr(flashex, "_interpret", lambda: False)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    proxy = lambda d: SimpleNamespace(shape=(*bht, d), dtype=dtypes.bfloat16)
+    assert flashex._sdpa_checker(proxy(d_qk), proxy(d_qk), proxy(d_v), is_causal=True, scale=0.1)
+    sds = lambda d: jax.ShapeDtypeStruct((*bht, d), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v: flashex._sdpa_impl(q, k, v, is_causal=True, scale=0.1)).lower(
+        sds(d_qk), sds(d_qk), sds(d_v)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.out_info.shape == (*bht, d_v)
+
+
+def test_grouped_matmul_compiles_for_v5e_as_a_kernel_that_walks_the_groups(one_chip):
+    """The routed experts' grouped matmul at a.x-k1.fwd's worst-case buffer (8 rows
+    a token, 12 held experts): XLA's ragged dot is a Mosaic call on the v5e, with
+    the group sizes an operand, not a dense product masked afterwards."""
+    import jax
+    import jax.numpy as jnp
+
+    from thunder_tpu.executors import jaxex
+
+    sds = lambda s, d=jnp.bfloat16: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    for (k, n) in ((7168, 2048), (2048, 7168)):
+        text = jax.jit(jaxex._grouped_mm).lower(sds((65536, k)), sds((12, k, n)), sds((12,), jnp.int32)).compile().as_text()
+        assert "ragged-dot" in text and 'custom_call_target="tpu_custom_call"' in text
+
+
+def test_claimed_routed_experts_compile_for_v5e_with_the_short_buffer_and_the_worst_case(one_chip, monkeypatch):
+    """a.x-k1.fwd's expert layer as the pallas executor claims it: 8192 tokens, 8
+    choices among 192, 12 held experts of 7168 x 2048. Three megablox calls on
+    each of the two buffers (8192 rows, twice what an even router sends here,
+    and the worst case's 65536), chosen by one conditional on the count of rows
+    routed here."""
+    import jax
+    import jax.numpy as jnp
+
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import pallasex
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    shapes = {"x": ((8192, 7168), "bfloat16"), "top_i": ((8192, 8), "int32"), "top_w": ((8192, 8), "float32"),
+              "w_gate": ((12, 7168, 2048), "bfloat16"), "w_up": ((12, 7168, 2048), "bfloat16"),
+              "w_down": ((12, 2048, 7168), "bfloat16")}
+    assert pallasex._moe_experts_checker(*(SimpleNamespace(shape=s, dtype=getattr(dtypes, d)) for s, d in shapes.values()))
+    sds = [jax.ShapeDtypeStruct(s, getattr(jnp, d), sharding=one_chip) for s, d in shapes.values()]
+    compiled = jax.jit(lambda *a: pallasex._moe_experts_impl(*a, 0, 192)).lower(*sds).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6 and " conditional(" in text
+    assert all(f"bf16[{rows},7168]" in text for rows in (8192, 65536)) and "bf16[16384,7168]" not in text

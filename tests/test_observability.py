@@ -493,6 +493,36 @@ class TestAnnotatedCodegen:
         assert "__annotate_scope('L0.tanh#Delete_Last_Used')" in src
         assert "L2.sum#Delete_Last_Used" in src
 
+    def test_regions_of_the_traced_function_nest_and_reach_the_hlo(self):
+        """``core.trace.region`` around lines of the traced function: their
+        generated lines, and the ``del`` after their last use, stand under one
+        ``with`` a region, nested regions as ``a/b``, under annotation too; the
+        result does not change; outside a trace the context does nothing."""
+        import jax
+
+        from thunder_tpu.core.trace import region
+
+        def f(x):
+            with region("outer"):
+                y = clang.tanh(x)
+                with region("inner"):
+                    z = clang.mul(y, y)
+            return clang.sum(z)
+
+        with region("nowhere"):
+            pass
+        x = np.ones((2, 2), np.float32)
+        jf = ttpu.jit(f, executors=["jax"])
+        np.testing.assert_allclose(np.asarray(jf(x)), 4 * np.tanh(1.0) ** 2, rtol=1e-6)
+        final = ttpu.last_traces(jf)[-1]
+        assert [b.region for b in final.bound_symbols if b.sym.name in ("tanh", "mul", "sum")] == [
+            "outer", "outer/inner", None]
+        lines = [line for line in final.python().splitlines() if "__region" in line]
+        assert lines == ["  with __region('outer'):", "  with __region('outer/inner'):"]
+        assert final.python(annotate=True).count("with __region(") == 2
+        hlo = jax.jit(final.python_callable()).lower(x).as_text(debug_info=True)
+        assert "outer/inner/" in hlo
+
 
 # =============================================================================
 # Event replay / recompile-storm analysis

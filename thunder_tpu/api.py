@@ -115,9 +115,15 @@ def _build_prologue(
     for t in tensor_leaves:
         plg.add_name(t.name)
 
+    collections: list[CollectionProxy] = []
+
+    def collection(concrete: Any, name: Optional[str] = None) -> CollectionProxy:
+        collections.append(CollectionProxy(concrete, name=name))
+        return collections[-1]
+
     with tracectx(plg):
-        args_coll = CollectionProxy(args, name="args")
-        kwargs_coll = CollectionProxy(kwargs, name="kwargs")
+        args_coll = collection(args, "args")
+        kwargs_coll = collection(kwargs, "kwargs")
 
         from thunder_tpu.core.proxies import AnyProxy
 
@@ -176,7 +182,7 @@ def _build_prologue(
                 leaf_slots = []  # (slot, concrete) to guard
                 for c, p in zip(concrete, proxied):
                     if isinstance(c, (tuple, list, dict)):
-                        cp = CollectionProxy(c)
+                        cp = collection(c)
                         outs.append(cp)
                         sub.append((cp, c, p))
                     else:
@@ -194,7 +200,7 @@ def _build_prologue(
                 for k, c in concrete.items():
                     p = proxied[k]
                     if isinstance(c, (tuple, list, dict)):
-                        cp = CollectionProxy(c)
+                        cp = collection(c)
                         bsym = prims.unpack_key.bind(coll_proxy, k, output=cp)
                         plg.bound_symbols.append(bsym)
                         unpack_into(cp, c, p)
@@ -217,6 +223,11 @@ def _build_prologue(
 
         prims.python_return(tuple(tensor_leaves))
 
+    # The first call's containers were needed to lay the unpacking out, and no
+    # longer: a prologue that kept them would keep the caller's arrays alive (a
+    # model's weights, gigabytes of them) for as long as the cache entry lives.
+    for cp in collections:
+        cp.coll = None
     plg.output = tuple(tensor_leaves)
     return plg
 

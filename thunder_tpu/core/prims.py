@@ -208,6 +208,8 @@ class PrimIDs(enum.Enum):
     ARGMIN = enum.auto()
     # Linear algebra / NN
     MATMUL = enum.auto()
+    GROUPED_MM = enum.auto()
+    GROUPED_MM_DW = enum.auto()
     LINEAR = enum.auto()
     CONVOLUTION = enum.auto()
     CONVOLUTION_BWD = enum.auto()
@@ -1364,6 +1366,35 @@ def _matmul_meta(a: TensorProxy, b: TensorProxy) -> TensorProxy:
 
 
 matmul = make_prim(PrimIDs.MATMUL, "matmul", _matmul_meta, tags=(OpTags.MATMUL_OP,))
+
+
+def _grouped_mm_meta(a: TensorProxy, b: TensorProxy, group_sizes: TensorProxy) -> TensorProxy:
+    """Rows of ``a`` (M, K) lie grouped: the first ``group_sizes[0]`` rows are
+    multiplied by ``b[0]`` (K, N), the next ``group_sizes[1]`` by ``b[1]``, and
+    so on; what the rows beyond the groups' sum hold is the implementation's
+    (``jax.lax.ragged_dot`` zeroes them, a kernel may leave them unwritten)."""
+    check(a.ndim == 2 and b.ndim == 3, lambda: f"grouped_mm expects (M, K) and (G, K, N), got {a.shape}, {b.shape}")
+    check(a.dtype == b.dtype, lambda: f"grouped_mm dtype mismatch {a.dtype} vs {b.dtype}")
+    check(a.shape[1] == b.shape[1], lambda: f"grouped_mm contraction mismatch {a.shape} @ {b.shape}")
+    check(group_sizes.ndim == 1 and group_sizes.shape[0] == b.shape[0]
+          and dtypes.is_nonboolean_integer_dtype(group_sizes.dtype),
+          "grouped_mm needs one integer group size for each matrix of b")
+    return TensorProxy(like=a, shape=(a.shape[0], b.shape[2]))
+
+
+grouped_mm = make_prim(PrimIDs.GROUPED_MM, "grouped_mm", _grouped_mm_meta, tags=(OpTags.MATMUL_OP,))
+
+
+def _grouped_mm_dw_meta(a: TensorProxy, g: TensorProxy, group_sizes: TensorProxy) -> TensorProxy:
+    """The weight gradient of ``grouped_mm``: ``out[e] = a[rows of e].T @ g[rows
+    of e]`` for ``a`` (M, K) and ``g`` (M, N), so (G, K, N)."""
+    check(a.ndim == 2 and g.ndim == 2 and a.shape[0] == g.shape[0],
+          lambda: f"grouped_mm_dw expects (M, K) and (M, N), got {a.shape}, {g.shape}")
+    check(a.dtype == g.dtype, lambda: f"grouped_mm_dw dtype mismatch {a.dtype} vs {g.dtype}")
+    return TensorProxy(like=a, shape=(group_sizes.shape[0], a.shape[1], g.shape[1]))
+
+
+grouped_mm_dw = make_prim(PrimIDs.GROUPED_MM_DW, "grouped_mm_dw", _grouped_mm_dw_meta, tags=(OpTags.MATMUL_OP,))
 
 
 def _linear_meta(a: TensorProxy, w: TensorProxy, bias: Optional[TensorProxy]) -> TensorProxy:

@@ -188,6 +188,7 @@ class Symbol:
                 trace.pop_scope()
 
         bsym = self.bind(*args, output=result, subsymbols=tuple(subsymbols), **kwargs)
+        bsym.region = trace.region
         trace.add_bound_symbol(bsym)
 
         # torch.no_grad during acquisition (frontend/sharp.py toggles the
@@ -244,6 +245,8 @@ class BoundSymbol(baseutils.BoundSymbolInterface):
         # e.g. a compiled XLA region callable (reference: _call_ctx).
         self._call_ctx: dict[str, Any] = {}
         self.header: str = ""
+        # The region of the model's code it was recorded in (core/trace.py ``region``).
+        self.region: Optional[str] = None
 
     # -- tags ----------------------------------------------------------------
 
@@ -306,6 +309,7 @@ class BoundSymbol(baseutils.BoundSymbolInterface):
         )
         new._call_ctx = dict(self._call_ctx)
         new.header = self.header
+        new.region = self.region
         return new
 
     def from_bsym_swap_proxies(self, swap_map: dict, skip_output: bool = False) -> "BoundSymbol":

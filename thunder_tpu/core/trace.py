@@ -49,6 +49,8 @@ class TraceCtx:
         self._siginfo: Optional[SigInfo] = None
         # Free-form metadata transforms may attach (e.g. saved_for_backward).
         self.tags: dict[str, Any] = {}
+        # The region open while tracing (``region`` below), or None.
+        self.region: Optional[str] = None
 
     # -- naming --------------------------------------------------------------
 
@@ -147,13 +149,22 @@ class TraceCtx:
         lines.append(self.siginfo.prettyprint())
         body: list[str] = []
         tag = self._annotate_tag() if annotate else ""
+        open_region = None  # consecutive lines of one region share one ``with``
         for i, bsym in enumerate(self.bound_symbols):
+            depth = 1 if bsym.region is None else 2
             if annotate and bsym.flat_proxy_outs:
                 scope = f"L{i}.{bsym.sym.name}#{tag}"
-                body.append(f"{baseutils.indent(1)}with __annotate_scope({scope!r}):")
-                body.extend(bsym.python(indent=2, print_depth=print_depth))
+                lines_of = [f"{baseutils.indent(depth)}with __annotate_scope({scope!r}):"]
+                lines_of.extend(bsym.python(indent=depth + 1, print_depth=print_depth))
             else:
-                body.extend(bsym.python(indent=1, print_depth=print_depth))
+                lines_of = bsym.python(indent=depth, print_depth=print_depth)
+            if not lines_of:
+                continue
+            if bsym.region != open_region:
+                open_region = bsym.region
+                if open_region is not None:
+                    body.append(f"{baseutils.indent(1)}with __region({open_region!r}):")
+            body.extend(lines_of)
         if not body:
             body = [f"{baseutils.indent(1)}pass"]
         lines.extend(body)
@@ -198,6 +209,10 @@ class TraceCtx:
             import jax
 
             ctx["__annotate_scope"] = jax.named_scope
+        if any(bsym.region is not None for bsym in self.bound_symbols):
+            import jax
+
+            ctx["__region"] = jax.named_scope
         ctx.update(exec_ctx)
         fn = baseutils.compile_and_exec(self.siginfo.name, source, ctx)
         fn.__thunder_trace__ = self
@@ -251,6 +266,28 @@ def tracectx(trace: Optional[TraceCtx]):
         yield trace
     finally:
         _tracectx.reset(tok)
+
+
+@contextmanager
+def region(name: str):
+    """Name a region of the program being traced, from the model's own code:
+    every bound symbol recorded inside carries ``name`` (``BoundSymbol.region``,
+    its decomposition's symbols too), claiming and the passes that rewrite with
+    ``from_bsym`` keep it, and the generated program runs those lines under
+    ``jax.named_scope(name)``, so the name is in the HLO's metadata and on the
+    profile's rows whichever executor ran the line. Regions nest as ``a/b``.
+    Symbols a pass makes anew (a backward, a ``del``) carry none. Outside a
+    trace this does nothing."""
+    trace = get_tracectx()
+    if trace is None:
+        yield
+        return
+    outer = trace.region
+    trace.region = name if outer is None else f"{outer}/{name}"
+    try:
+        yield
+    finally:
+        trace.region = outer
 
 
 @contextmanager

@@ -154,13 +154,15 @@ def _dtype_ok(q, k, v) -> bool:
     return half(q) and half(k) and half(v)
 
 
-def _shapes_ok(q, k) -> bool:
-    if not (isinstance(q, TensorProxy) or hasattr(q, "shape")):
+def _shapes_ok(q, k, v) -> bool:
+    """The value heads may have a width of their own (latent attention: 192-wide
+    queries and keys, 128-wide values); splash takes it from v."""
+    if not all(isinstance(t, TensorProxy) or hasattr(t, "shape") for t in (q, k, v)):
         return False
-    if len(q.shape) != 4 or len(k.shape) != 4:
+    if len(q.shape) != 4 or len(k.shape) != 4 or len(v.shape) != 4:
         return False
     S, L, D = q.shape[-2], k.shape[-2], q.shape[-1]
-    if D > 256:
+    if D > 256 or v.shape[-1] > 256 or k.shape[-1] != D or v.shape[-2] != L:
         return False
     # Below half a block of real work, padding waste dominates any kernel
     # win — keep the cheap decomposition.
@@ -170,7 +172,7 @@ def _shapes_ok(q, k) -> bool:
 def _sdpa_checker(*args, **kwargs) -> bool:
     b = _sdpa_bound(args, kwargs)
     q, k = b["query"], b["key"]
-    if not (_on_tpu() and float(pyval(b["dropout_p"])) == 0.0 and _shapes_ok(q, k)
+    if not (_on_tpu() and float(pyval(b["dropout_p"])) == 0.0 and _shapes_ok(q, k, b["value"])
             and _dtype_ok(q, k, b["value"])):
         return False
     kind = _mask_kind(b["attn_mask"], q, k)
@@ -182,7 +184,7 @@ def _sdpa_checker(*args, **kwargs) -> bool:
 
 
 def _bwd_checker(g, query, key, value, attn_mask=None, is_causal=False, scale=None, enable_gqa=False) -> bool:
-    if not (_on_tpu() and _shapes_ok(query, key) and _dtype_ok(query, key, value)):
+    if not (_on_tpu() and _shapes_ok(query, key, value) and _dtype_ok(query, key, value)):
         return False
     return _mask_kind(attn_mask, query, key) != "no"
 
@@ -236,7 +238,8 @@ def _splash_kernel(H: int, Tq: int, Tkv: int, causal: bool, offset: int, interpr
 def _splash_sdpa(q, k, v, *, causal: bool, scale: float, kv_valid=None, q_valid=None):
     """Run splash attention with in-executor sequence padding.
 
-    q: (B, H, Tq, D); k/v: (B, H, Tkv, D) (already GQA-expanded).
+    q: (B, H, Tq, D); k: (B, H, Tkv, D); v: (B, H, Tkv, Dv), Dv its own
+    (already GQA-expanded). The output is (B, H, Tq, Dv).
     kv_valid/q_valid: optional bool (B, T) — False positions never attend /
     are never attended to (lowered to splash segment-ids). Output positions
     with an invalid query are finite garbage and are expected to be ignored
@@ -474,7 +477,7 @@ def residual_eligible(q, k, v) -> bool:
     if len(q.shape) != 4 or len(k.shape) != 4:
         return False
     S, L, D = q.shape[-2], k.shape[-2], q.shape[-1]
-    return S == L and S % _PAD == 0 and D <= 256
+    return S == L and S % _PAD == 0 and D <= 256 and v.shape[-1] <= 256
 
 
 def _fwd_res_checker(query, key, value, attn_mask=None, is_causal=False, scale=None, enable_gqa=False):

@@ -366,6 +366,25 @@ def _matmul(a, b):
 _reg(PrimIDs.MATMUL, _matmul)
 
 
+def _grouped_mm(a, b, group_sizes):
+    # XLA's own ragged dot: on the TPU a grouped-matmul kernel that walks the
+    # groups and skips the rows beyond their sum; on the CPU a masked dense form.
+    return lax.ragged_dot(a, b, group_sizes.astype(jnp.int32), precision=_dot_precision(a, b))
+
+
+_GROUPED_DW_DIMS = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())), lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _grouped_mm_dw(a, g, group_sizes):
+    return lax.ragged_dot_general(a, g, group_sizes.astype(jnp.int32), _GROUPED_DW_DIMS,
+                                  precision=_dot_precision(a, g))
+
+
+_reg(PrimIDs.GROUPED_MM, _grouped_mm)
+_reg(PrimIDs.GROUPED_MM_DW, _grouped_mm_dw)
+
+
 def _linear(a, w, bias):
     # x @ w.T via dot_general: contract a's last dim with w's dim 1 —
     # a single MXU-friendly contraction, no materialized transpose.

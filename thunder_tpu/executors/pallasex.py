@@ -310,6 +310,82 @@ ex.register_implementation("torch.apply_rope", fn=_rope_impl, checker=_rope_chec
 
 
 # =============================================================================
+# Routed experts: dispatch, jax's megablox grouped matmul, and a short buffer
+# =============================================================================
+#
+# The decomposition of torch.moe_experts (sort the pairs by held expert, gather,
+# one grouped matmul a projection, bring the rows back) runs under XLA with a
+# buffer of the router's worst case, min(k, held) * N rows. On the v5e that
+# buffer is what costs: at 12 of 192 experts held a sixteenth of its rows hold
+# work, and XLA's gather of 65,536 rows of 7168 takes 13.9 ms a layer where the
+# grouped matmuls take 5 to 6 (PERF.md, PR 27). Claimed here, the same steps run
+# on a short buffer when the rows routed here fit it, and on the worst case when
+# they do not: one lax.cond on a count the router just made, so no token is ever
+# dropped and no call fails. The short buffer is twice what an even router sends
+# here, 2 * k * N * held / n_expert rows; where that is no shorter than the
+# worst case (every expert held: mixtral) there is one buffer and no branch. The
+# grouped matmul is megablox's gmm (30.2 ms a call of a.x-k1.fwd against 37.4
+# for XLA's own ragged dot, same seed).
+
+_GMM_TILING = (512, 1024, 1024)
+_SHORT_BUFFER_OVER_EVEN_LOAD = 2
+
+
+def _moe_experts_checker(x, top_i, top_w, w_gate, w_up, w_down, expert_offset=0, n_expert=None):
+    if dtypes.to_dtype(x.dtype) is not dtypes.bfloat16 or dtypes.to_dtype(w_gate.dtype) is not dtypes.bfloat16:
+        return False
+    if len(x.shape) != 2 or batch_shards() != 1:  # a sort over all tokens is no per-shard kernel
+        return False
+    (N, C), k, (held, _, H) = x.shape, top_i.shape[1], w_gate.shape
+    return (min(k, held) * N) % _GMM_TILING[0] == 0 and C % _LANE == 0 and H % _LANE == 0
+
+
+def _moe_experts_impl(x, top_i, top_w, w_gate, w_up, w_down, expert_offset=0, n_expert=None):
+    chaos.kernel_seam("pallas", "moe_experts")
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    (N, C), k, held = x.shape, top_i.shape[1], w_gate.shape[0]
+    full = min(k, held) * N
+    tm, tk, tn = _GMM_TILING
+    even = -(-k * N * held // (n_expert or held))  # the rows an even router sends here
+    short = -(-_SHORT_BUFFER_OVER_EVEN_LOAD * even // tm) * tm
+
+    def grouped(a, b, sizes):
+        return gmm(a, b, sizes, preferred_element_type=a.dtype,
+                   tiling=(tm, min(tk, a.shape[1]), min(tn, b.shape[2])), interpret=_interpret())
+
+    with jax.enable_x64(False):
+        local = top_i.astype(jnp.int32) - int(expert_offset)
+        here = (local >= 0) & (local < held)
+        key = jnp.where(here, local, held).reshape(N * k)
+        order = jnp.argsort(key, stable=True)  # held pairs first, by expert
+        slot = jnp.zeros(N * k, jnp.int32).at[order].set(jnp.arange(N * k, dtype=jnp.int32))
+        sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32), axis=0, dtype=jnp.int32)
+
+        def on_buffer_of(rows):
+            def run():
+                xs = jnp.take(x, order[:rows] // k, axis=0)
+                gate = grouped(xs, w_gate, sizes).astype(jnp.float32)
+                h = (jax.nn.silu(gate) * grouped(xs, w_up, sizes).astype(jnp.float32)).astype(x.dtype)
+                ys = grouped(h, w_down, sizes)
+                # Rows beyond the groups are whatever gmm left there: masked, never multiplied.
+                back = jnp.take(ys, jnp.minimum(slot, rows - 1), axis=0).reshape(N, k, C)
+                back = jnp.where(here[..., None], back.astype(jnp.float32), 0.0)
+                return jnp.sum(back * top_w.astype(jnp.float32)[..., None], axis=1).astype(x.dtype)
+
+            return run
+
+        if short >= full:
+            return on_buffer_of(full)()
+        return jax.lax.cond(jnp.sum(sizes) <= short, on_buffer_of(short), on_buffer_of(full))
+
+
+ex.register_implementation("torch.moe_experts", fn=_moe_experts_impl, checker=_moe_experts_checker)
+
+
+# =============================================================================
 # Fused RMSNorm (fwd + bwd) — OPT-IN executor "norm"
 # =============================================================================
 #

@@ -180,6 +180,7 @@ def del_last_used(trace: TraceCtx, *, clear_mutable_collections: bool = False) -
             to_del.append(p)
         if to_del and bsym.sym.id not in (PrimIDs.RETURN,):
             rev.append(prims.python_del.bind(*to_del, output=None))
+            rev[-1].region = bsym.region  # beside the last use, so that a region's lines stay one block
         rev.append(bsym)
     new_bsyms = list(reversed(rev))
 

@@ -5,7 +5,10 @@ tests and benchmarks (thunder/tests/lit_gpt_model.py,
 thunder/benchmarks/benchmark_litgpt.py:41) — GPT-NeoX (pythia) and
 Llama/Mistral architectural variants: parallel vs sequential residual,
 LayerNorm vs RMSNorm, GptNeoxMLP vs SwiGLU, partial-rotary RoPE, and
-grouped-query attention.
+grouped-query attention. Beyond it: the DeepSeek-V3 family's block as A.X-K1
+publishes it: latent attention (MLA) under YaRN, a leading dense layer, and
+expert layers with a shared expert beside routed ones of which a chip may
+hold a share (``experts_held``, ``expert_offset``).
 
 TPU-first design: the model is a *pure function* ``forward(params, idx)``
 over a params pytree — no module object, no buffers, no in-place state. That
@@ -31,6 +34,8 @@ import numpy as np
 
 import thunder_tpu.torch as ttorch
 from thunder_tpu.core import dtypes
+from thunder_tpu.core.baseutils import check
+from thunder_tpu.core.trace import region
 
 
 @dataclass(frozen=True)
@@ -49,12 +54,40 @@ class GPTConfig:
     bias: bool = True
     norm_class: str = "LayerNorm"  # or "RMSNorm"
     norm_eps: float = 1e-5
-    mlp_class: str = "GptNeoxMLP"  # or "LLaMAMLP" / "MoEMLP"
+    mlp_class: str = "GptNeoxMLP"  # or "LLaMAMLP" / "MoEMLP" / "SharedRoutedMoE"
     intermediate_size: Optional[int] = None
     rope_base: int = 10000
-    # MoE (mlp_class="MoEMLP", mixtral-style SwiGLU experts):
+    # MoE (mlp_class="MoEMLP", mixtral-style SwiGLU experts: softmax over the
+    # top-k logits; "SharedRoutedMoE": sigmoid scores, group-limited top-k,
+    # shared experts beside the routed ones):
     n_expert: int = 0
     n_expert_per_token: int = 2
+    moe_intermediate_size: Optional[int] = None  # width of one expert; None -> mlp_hidden
+    n_shared_experts: int = 0
+    n_expert_groups: int = 1  # the router picks from the best n_limited_groups of these
+    n_limited_groups: int = 1
+    routed_scaling_factor: float = 1.0
+    # Layers [0, first_dense_layers) keep the dense LLaMAMLP at mlp_hidden; the
+    # rest have mlp_class. The per-layer setting the registry has.
+    first_dense_layers: int = 0
+    # This chip's share of each expert layer: experts expert_offset to
+    # expert_offset + experts_held are held (and computed) here; the router
+    # still scores all n_expert. None holds them all.
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    # Latent attention (attention_class="MLA"): low-rank q and kv projections
+    # with an RMSNorm on each latent, a rope part shared by all key heads beside
+    # a no-rope part, value heads of their own width.
+    attention_class: str = "MHA"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleaved: bool = False  # published pairs are (x0,x1),(x2,x3)..: de-interleaved, then rotate-half
+    # YaRN, or None for the plain rope: (factor, original_max_position_embeddings,
+    # beta_fast, beta_slow, mscale, mscale_all_dim)
+    yarn: Optional[tuple] = None
 
     @property
     def head_size(self) -> int:
@@ -75,6 +108,30 @@ class GPTConfig:
     @property
     def qkv_out(self) -> int:
         return (self.n_head + 2 * self.query_groups) * self.head_size
+
+    @property
+    def expert_hidden(self) -> int:
+        return self.moe_intermediate_size if self.moe_intermediate_size is not None else self.mlp_hidden
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held if self.experts_held is not None else self.n_expert
+
+    def layer_mlp_class(self, i: int) -> str:
+        return "LLaMAMLP" if i < self.first_dense_layers else self.mlp_class
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> Optional[float]:
+        """None is sdpa's own ``D**-0.5``. Latent attention under YaRN
+        multiplies it by ``m**2``, ``m = 0.1 * mscale_all_dim * ln(factor) + 1``."""
+        if self.attention_class != "MLA":
+            return None
+        m = _yarn_mscale(self.yarn[0], self.yarn[5]) if self.yarn else 1.0
+        return self.qk_head_dim ** -0.5 * m * m
 
 
 configs: dict[str, GPTConfig] = {}
@@ -127,6 +184,29 @@ _add(GPTConfig(name="mixtral-8x7b", block_size=4096, vocab_size=32000, padded_vo
                n_layer=32, n_head=32, n_embd=4096, n_query_groups=8, rotary_percentage=1.0,
                parallel_residual=False, bias=False, norm_class="RMSNorm", norm_eps=1e-5,
                mlp_class="MoEMLP", intermediate_size=14336, n_expert=8, n_expert_per_token=2))
+
+# A.X-K1 (huggingface.co/skt/A.X-K1, model_type axk1) at its published sizes:
+# latent attention with 192-wide query-key and 128-wide value heads under YaRN,
+# one dense layer, then 192 routed experts (8 a token, from the best 4 of 8
+# groups, sigmoid scores, scaled 2.5) beside one shared expert.
+_add(GPTConfig(name="A.X-K1", block_size=131072, vocab_size=163840, padded_vocab_size=163840,
+               n_layer=61, n_head=64, n_embd=7168, rotary_percentage=1.0, parallel_residual=False,
+               bias=False, norm_class="RMSNorm", norm_eps=1e-6, mlp_class="SharedRoutedMoE",
+               intermediate_size=18432, rope_base=10000, n_expert=192, n_expert_per_token=8,
+               moe_intermediate_size=2048, n_shared_experts=1, n_expert_groups=8, n_limited_groups=4,
+               routed_scaling_factor=2.5, first_dense_layers=1, attention_class="MLA",
+               q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+               v_head_dim=128, rope_interleaved=True, yarn=(32.0, 4096, 32.0, 1.0, 1.0, 1.0)))
+# The same blocks at test size: unequal head widths (24 and 16), groups, a held
+# subset of the experts.
+_add(GPTConfig(name="axk1-tiny", block_size=64, vocab_size=96, padded_vocab_size=96,
+               n_layer=3, n_head=2, n_embd=32, rotary_percentage=1.0, parallel_residual=False,
+               bias=False, norm_class="RMSNorm", norm_eps=1e-6, mlp_class="SharedRoutedMoE",
+               intermediate_size=64, n_expert=16, n_expert_per_token=4, moe_intermediate_size=16,
+               n_shared_experts=1, n_expert_groups=4, n_limited_groups=2, routed_scaling_factor=2.5,
+               first_dense_layers=1, experts_held=4, expert_offset=4, attention_class="MLA",
+               q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+               v_head_dim=16, rope_interleaved=True, yarn=(4.0, 16, 32.0, 1.0, 1.0, 1.0)))
 
 # Mistral — reference benchmark ladder step 5 (GQA).
 _add(GPTConfig(name="mistral-7b", block_size=4096, vocab_size=32000, padded_vocab_size=32000,
@@ -203,48 +283,78 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
             p["bias"] = zeros(C.n_embd)
         return p
 
-    def block_params(i):
-        p: dict[str, Any] = {
-            "norm_1": norm_params(),
-            "attn": {
-                "qkv_w": w(C.qkv_out, C.n_embd),
-                "proj_w": w(C.n_embd, C.n_head * C.head_size, std=0.02 / np.sqrt(2 * C.n_layer)),
-            },
-            "mlp": {},
+    def attn_params():
+        if C.attention_class == "MLA":
+            H, dqk = C.n_head, C.qk_head_dim
+            return {
+                "q_a_w": w(C.q_lora_rank, C.n_embd),
+                "q_a_norm": {"weight": ones(C.q_lora_rank)},
+                "q_b_w": w(H * dqk, C.q_lora_rank),
+                "kv_a_w": w(C.kv_lora_rank + C.qk_rope_head_dim, C.n_embd),
+                "kv_a_norm": {"weight": ones(C.kv_lora_rank)},
+                "kv_b_w": w(H * (C.qk_nope_head_dim + C.v_head_dim), C.kv_lora_rank),
+                "proj_w": w(C.n_embd, H * C.v_head_dim, std=0.02 / np.sqrt(2 * C.n_layer)),
+            }
+        p = {
+            "qkv_w": w(C.qkv_out, C.n_embd),
+            "proj_w": w(C.n_embd, C.n_head * C.head_size, std=0.02 / np.sqrt(2 * C.n_layer)),
         }
-        if not C.shared_attention_norm:
-            p["norm_2"] = norm_params()
         if C.bias:
-            p["attn"]["qkv_b"] = zeros(C.qkv_out)
-            p["attn"]["proj_b"] = zeros(C.n_embd)
-        if C.mlp_class == "MoEMLP":
-            E, H = C.n_expert, C.mlp_hidden
-            p["mlp"]["router_w"] = w(E, C.n_embd)
-            p["mlp"]["w1"] = w(E, H, C.n_embd)
-            p["mlp"]["w3"] = w(E, H, C.n_embd)
-            p["mlp"]["w2"] = w(E, C.n_embd, H, std=0.02 / np.sqrt(2 * C.n_layer))
-        elif C.mlp_class == "LLaMAMLP":
-            p["mlp"]["fc_1_w"] = w(C.mlp_hidden, C.n_embd)
-            p["mlp"]["fc_2_w"] = w(C.mlp_hidden, C.n_embd)
-            p["mlp"]["proj_w"] = w(C.n_embd, C.mlp_hidden, std=0.02 / np.sqrt(2 * C.n_layer))
-            if C.bias:
-                p["mlp"]["fc_1_b"] = zeros(C.mlp_hidden)
-                p["mlp"]["fc_2_b"] = zeros(C.mlp_hidden)
-                p["mlp"]["proj_b"] = zeros(C.n_embd)
-        else:
-            p["mlp"]["fc_w"] = w(C.mlp_hidden, C.n_embd)
-            p["mlp"]["proj_w"] = w(C.n_embd, C.mlp_hidden, std=0.02 / np.sqrt(2 * C.n_layer))
-            if C.bias:
-                p["mlp"]["fc_b"] = zeros(C.mlp_hidden)
-                p["mlp"]["proj_b"] = zeros(C.n_embd)
+            p["qkv_b"] = zeros(C.qkv_out)
+            p["proj_b"] = zeros(C.n_embd)
         return p
 
-    return {
-        "wte": w(C.padded_vocab_size, C.n_embd),
-        "blocks": [block_params(i) for i in range(C.n_layer)],
-        "ln_f": norm_params(),
-        "lm_head_w": w(C.padded_vocab_size, C.n_embd),
-    }
+    def swiglu_params(hidden):
+        p = {
+            "fc_1_w": w(hidden, C.n_embd),
+            "fc_2_w": w(hidden, C.n_embd),
+            "proj_w": w(C.n_embd, hidden, std=0.02 / np.sqrt(2 * C.n_layer)),
+        }
+        if C.bias:
+            p.update(fc_1_b=zeros(hidden), fc_2_b=zeros(hidden), proj_b=zeros(C.n_embd))
+        return p
+
+    def mlp_params(kind):
+        if kind == "MoEMLP":
+            E, H = C.n_expert, C.mlp_hidden
+            return {"router_w": w(E, C.n_embd), "w1": w(E, H, C.n_embd), "w3": w(E, H, C.n_embd),
+                    "w2": w(E, C.n_embd, H, std=0.02 / np.sqrt(2 * C.n_layer))}
+        if kind == "SharedRoutedMoE":
+            # Routed experts are stored (expert, in, out), the grouped matmul's
+            # layout, and only the held ones: the share is configuration.
+            E, H = C.held_experts, C.expert_hidden
+            p = {"router_w": w(C.n_expert, C.n_embd),
+                 "experts_gate": w(E, C.n_embd, H), "experts_up": w(E, C.n_embd, H),
+                 "experts_down": w(E, H, C.n_embd, std=0.02 / np.sqrt(2 * C.n_layer))}
+            if C.n_shared_experts:
+                p["shared"] = swiglu_params(C.n_shared_experts * H)
+            return p
+        if kind == "LLaMAMLP":
+            return swiglu_params(C.mlp_hidden)
+        p = {"fc_w": w(C.mlp_hidden, C.n_embd),
+             "proj_w": w(C.n_embd, C.mlp_hidden, std=0.02 / np.sqrt(2 * C.n_layer))}
+        if C.bias:
+            p.update(fc_b=zeros(C.mlp_hidden), proj_b=zeros(C.n_embd))
+        return p
+
+    def block_params(i):
+        p: dict[str, Any] = {"norm_1": norm_params(), "attn": attn_params(),
+                             "mlp": mlp_params(C.layer_mlp_class(i))}
+        if not C.shared_attention_norm:
+            p["norm_2"] = norm_params()
+        return p
+
+    params = {"wte": w(C.padded_vocab_size, C.n_embd)}
+    blocks = [block_params(i) for i in range(C.n_layer)]
+    if C.first_dense_layers:
+        # Two lists, so that each kind of leaf has as many layers as carry it.
+        params["dense_blocks"] = blocks[: C.first_dense_layers]
+        params["moe_blocks"] = blocks[C.first_dense_layers:]
+    else:
+        params["blocks"] = blocks
+    params["ln_f"] = norm_params()
+    params["lm_head_w"] = w(C.padded_vocab_size, C.n_embd)
+    return params
 
 
 # =============================================================================
@@ -258,15 +368,44 @@ def _norm(x, p, config: GPTConfig):
     return ttorch.layer_norm(x, (config.n_embd,), p["weight"], p.get("bias"), eps=config.norm_eps)
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * float(np.log(factor)) + 1.0
+
+
+def _yarn_inv_freq(n: int, base: float, yarn: tuple) -> np.ndarray:
+    """YaRN's inverse frequencies for a rope of ``n`` features: the plain
+    ``base**(-2i/n)`` where a feature turns more than ``beta_fast`` times over
+    the original context, that over ``factor`` where it turns fewer than
+    ``beta_slow`` times, and a linear blend between the two correction dims."""
+    factor, original, beta_fast, beta_slow = yarn[:4]
+    extra = base ** (-np.arange(0, n, 2, dtype=np.float64) / n)
+
+    def correction_dim(rotations):
+        return n * np.log(original / (rotations * 2 * np.pi)) / (2 * np.log(base))
+
+    low = max(np.floor(correction_dim(beta_fast)), 0)
+    high = min(np.ceil(correction_dim(beta_slow)), n - 1)
+    ramp = np.clip((np.arange(n // 2, dtype=np.float64) - low) / max(high - low, 1e-3), 0, 1)
+    return extra / factor * ramp + extra * (1 - ramp)
+
+
 def _rope_cache(T: int, config: GPTConfig, device, dtype):
     """cos/sin of shape (T, rope_n_elem) — built from iota, so XLA folds them
     into constants of the compiled executable."""
-    n = config.rope_n_elem
-    half = n // 2
     import thunder_tpu.clang as clang
 
-    theta = clang.pow(float(config.rope_base), clang.true_divide(
-        clang.mul(clang.arange(0, half, 1, device=device, dtype=dtypes.float32), -2.0), float(n)))
+    if config.attention_class == "MLA":
+        n = config.qk_rope_head_dim
+        inv = (_yarn_inv_freq(n, float(config.rope_base), config.yarn) if config.yarn
+               else float(config.rope_base) ** (-np.arange(0, n, 2, dtype=np.float64) / n))
+        theta = clang.tensor_from_sequence([float(v) for v in inv], device=device, dtype=dtypes.float32)
+        # The tables' own factor, mscale(factor, mscale) / mscale(factor, mscale_all_dim), is 1.
+        check(not config.yarn or config.yarn[4] == config.yarn[5],
+              lambda: f"YaRN tables with mscale {config.yarn[4]} != mscale_all_dim {config.yarn[5]} are not built")
+    else:
+        n = config.rope_n_elem
+        theta = clang.pow(float(config.rope_base), clang.true_divide(
+            clang.mul(clang.arange(0, n // 2, 1, device=device, dtype=dtypes.float32), -2.0), float(n)))
     pos = clang.arange(0, T, 1, device=device, dtype=dtypes.float32)
     freqs = clang.mul(clang.unsqueeze(pos, 1), clang.unsqueeze(theta, 0))  # (T, half)
     emb = clang.cat([freqs, freqs], dim=1)  # (T, n) rotate-half convention
@@ -301,59 +440,145 @@ def _attention(x, p, cos, sin, config: GPTConfig):
     return ttorch.linear(y, p["proj_w"], p.get("proj_b"))
 
 
-def _moe_mlp(x, p, config: GPTConfig):
-    """Mixtral-style MoE: top-k softmax routing over SwiGLU experts,
-    renormalized gate weights. Dense per-token formulation at the trace
-    level (every expert computed, top-k selected) — static shapes the MXU
-    tiles; the distributed execution path with real token dispatch over an
-    ``ep`` mesh axis is thunder_tpu.parallel.moe.moe_mlp."""
+def _deinterleave_rows(w):
+    """(G, n, c): each group's rows (x0, x1, x2, x3, ..) to (x0, x2, .., x1, x3, ..).
+    The published rope pairs neighbours, rotate-half pairs the two halves."""
+    g, n, c = w.shape
+    return ttorch.reshape(ttorch.permute(ttorch.reshape(w, (g, n // 2, 2, c)), (0, 2, 1, 3)), (g, n, c))
+
+
+def _mla_attention(x, p, cos, sin, config: GPTConfig):
+    """Latent attention in its expanded (prefill) form. A head's query and key
+    are [rope part, no-rope part] here, where the published order is [no-rope,
+    rope] with interleaved pairs: q.k is the same under one permutation of both,
+    and a permutation of a projection's outputs is a permutation of its
+    weight's rows, so it is applied to the rows of ``q_b_w`` and ``kv_a_w``
+    and the activations never take a lane shuffle. With the rope part first the
+    rope kernel's partial-rotary body rotates q in place."""
     B, T, C = x.shape
-    k = config.n_expert_per_token
-    xf = ttorch.reshape(x, (B * T, C))
-    gate_logits = ttorch.linear(xf, p["router_w"])            # (N, E)
-    top_logits, top_i = ttorch.topk(gate_logits, k, -1)       # (N, k)
-    gate = ttorch.softmax(top_logits, -1)                     # renormalized over the k chosen
-    h = ttorch.silu(ttorch.einsum("nd,ehd->neh", xf, p["w1"])) * ttorch.einsum(
-        "nd,ehd->neh", xf, p["w3"]
+    H, dn, dr, dv = config.n_head, config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
+    L, R = config.kv_lora_rank, config.q_lora_rank
+    pairs = _deinterleave_rows if config.rope_interleaved else (lambda w: w)
+
+    q_b = ttorch.reshape(p["q_b_w"], (H, dn + dr, R))
+    q_w = ttorch.reshape(ttorch.cat([pairs(q_b[:, dn:, :]), q_b[:, :dn, :]], 1), (H * (dr + dn), R))
+    k_pe_w = ttorch.reshape(pairs(ttorch.reshape(p["kv_a_w"][L:, :], (1, dr, C))), (dr, C))
+    kv_a_w = ttorch.cat([p["kv_a_w"][:L, :], k_pe_w], 0)
+
+    c_q = ttorch.rms_norm(ttorch.linear(x, p["q_a_w"]), (R,), p["q_a_norm"]["weight"], eps=config.norm_eps)
+    q = ttorch.permute(ttorch.reshape(ttorch.linear(c_q, q_w), (B, T, H, dr + dn)), (0, 2, 1, 3))
+    kv_a = ttorch.linear(x, kv_a_w)                                          # (B, T, L + dr)
+    c_kv = ttorch.rms_norm(kv_a[..., :L], (L,), p["kv_a_norm"]["weight"], eps=config.norm_eps)
+    k_pe = ttorch.reshape(kv_a[..., L:], (B, 1, T, dr))                      # one rope key for all heads
+    kv = ttorch.permute(ttorch.reshape(ttorch.linear(c_kv, p["kv_b_w"]), (B, T, H, dn + dv)), (0, 2, 1, 3))
+
+    q = ttorch.apply_rope(q, cos, sin)                                       # the first dr of dr + dn
+    k_pe = ttorch.apply_rope(k_pe, cos, sin)
+    k = ttorch.cat([ttorch.expand(k_pe, (B, H, T, dr)), kv[..., :dn]], -1)
+    y = ttorch.scaled_dot_product_attention(q, k, kv[..., dn:], is_causal=True, scale=config.softmax_scale)
+    y = ttorch.reshape(ttorch.permute(y, (0, 2, 1, 3)), (B, T, H * dv))
+    return ttorch.linear(y, p["proj_w"])
+
+
+def _swiglu(x, p):
+    h = ttorch.silu(ttorch.linear(x, p["fc_1_w"], p.get("fc_1_b"))) * ttorch.linear(
+        x, p["fc_2_w"], p.get("fc_2_b")
     )
-    all_out = ttorch.einsum("neh,edh->ned", h, p["w2"])       # (N, E, C)
-    idx3 = ttorch.expand(ttorch.unsqueeze(top_i, -1), (B * T, k, C))
-    sel = ttorch.take_along_dim(all_out, idx3, 1)             # (N, k, C)
-    out = ttorch.sum(sel * ttorch.unsqueeze(gate, -1), 1)
+    return ttorch.linear(h, p["proj_w"], p.get("proj_b"))
+
+
+def _moe_mlp(x, p, config: GPTConfig):
+    """Mixtral-style MoE: top-k of the router's logits, softmax over the k
+    chosen, SwiGLU experts, through the routed-expert operation (tokens
+    grouped by expert, one grouped matmul a projection)."""
+    B, T, C = x.shape
+    xf = ttorch.reshape(x, (B * T, C))
+    top_logits, top_i = ttorch.topk(ttorch.linear(xf, p["router_w"]), config.n_expert_per_token, -1)
+    gate = ttorch.softmax(top_logits, -1)                     # renormalized over the k chosen
+    # w1, w3 (E, H, C) and w2 (E, C, H) keep their checkpoint layout.
+    out = ttorch.moe_experts(xf, top_i, gate.float(), ttorch.permute(p["w1"], (0, 2, 1)),
+                             ttorch.permute(p["w3"], (0, 2, 1)), ttorch.permute(p["w2"], (0, 2, 1)), 0)
     return ttorch.reshape(out, (B, T, C))
 
 
-def _mlp(x, p, config: GPTConfig):
-    if config.mlp_class == "MoEMLP":
+def _shared_routed_moe(x, p, config: GPTConfig, routed_rows=None):
+    """``SwiGLU_shared(x) + sum_i w_i SwiGLU_i(x)`` over the chosen experts
+    held here (``experts_held`` from ``expert_offset``): the router scores all
+    ``n_expert``, normalises over all k chosen, and this chip adds its own
+    experts' part. ``routed_rows`` collects the rows each held expert got."""
+    B, T, C = x.shape
+    xf = ttorch.reshape(x, (B * T, C))
+    with region("moe.route"):
+        top_i, top_w = ttorch.moe_route(xf, p["router_w"], config.n_expert_per_token, config.n_expert_groups,
+                                        config.n_limited_groups, config.routed_scaling_factor)
+    if routed_rows is not None:
+        held = ttorch.arange(config.expert_offset, config.expert_offset + config.held_experts,
+                             device=x.device, dtype=top_i.dtype)
+        routed_rows.append(ttorch.sum((ttorch.unsqueeze(top_i, -1) == held).to(dtypes.int32), (0, 1)))
+    with region("moe.experts"):
+        out = ttorch.moe_experts(xf, top_i, top_w, p["experts_gate"], p["experts_up"], p["experts_down"],
+                                 config.expert_offset, config.n_expert)
+    if config.n_shared_experts:
+        with region("moe.shared"):
+            out = out + _swiglu(xf, p["shared"])
+    return ttorch.reshape(out, (B, T, C))
+
+
+def _mlp(x, p, kind: str, config: GPTConfig, routed_rows=None):
+    if kind == "MoEMLP":
         return _moe_mlp(x, p, config)
-    if config.mlp_class == "LLaMAMLP":
-        h = ttorch.silu(ttorch.linear(x, p["fc_1_w"], p.get("fc_1_b"))) * ttorch.linear(
-            x, p["fc_2_w"], p.get("fc_2_b")
-        )
-        return ttorch.linear(h, p["proj_w"], p.get("proj_b"))
+    if kind == "SharedRoutedMoE":
+        return _shared_routed_moe(x, p, config, routed_rows)
+    if kind == "LLaMAMLP":
+        return _swiglu(x, p)
     h = ttorch.gelu(ttorch.linear(x, p["fc_w"], p.get("fc_b")))
     return ttorch.linear(h, p["proj_w"], p.get("proj_b"))
 
 
-def _block(x, p, cos, sin, config: GPTConfig):
+def _attend(x, p, cos, sin, config: GPTConfig):
+    if config.attention_class == "MLA":
+        with region("mla"):
+            return _mla_attention(x, p, cos, sin, config)
+    return _attention(x, p, cos, sin, config)
+
+
+def _block(x, p, cos, sin, kind: str, config: GPTConfig, routed_rows=None):
     n1 = _norm(x, p["norm_1"], config)
-    attn_out = _attention(n1, p["attn"], cos, sin, config)
+    attn_out = _attend(n1, p["attn"], cos, sin, config)
     if config.parallel_residual:
         n2 = n1 if config.shared_attention_norm else _norm(x, p["norm_2"], config)
-        return x + attn_out + _mlp(n2, p["mlp"], config)
+        return x + attn_out + _mlp(n2, p["mlp"], kind, config, routed_rows)
     x = x + attn_out
-    return x + _mlp(_norm(x, p["norm_2"], config), p["mlp"], config)
+    return x + _mlp(_norm(x, p["norm_2"], config), p["mlp"], kind, config, routed_rows)
+
+
+def _layers(params: dict, config: GPTConfig):
+    """[(block's parameters, its MLP kind)] in layer order."""
+    blocks = params["blocks"] if "blocks" in params else params["dense_blocks"] + params["moe_blocks"]
+    return [(p, config.layer_mlp_class(i)) for i, p in enumerate(blocks)]
+
+
+def _hidden(params: dict, idx, config: GPTConfig, routed_rows=None):
+    B, T = idx.shape
+    x = ttorch.embedding(idx, params["wte"])  # (B, T, C)
+    cos, sin = _rope_cache(T, config, device=x.device, dtype=x.dtype)
+    for p, kind in _layers(params, config):
+        x = _block(x, p, cos, sin, kind, config, routed_rows)
+    return _norm(x, params["ln_f"], config)
 
 
 def forward(params: dict, idx, config: GPTConfig):
     """Token ids (B, T) int → logits (B, T, padded_vocab_size)."""
-    B, T = idx.shape
-    x = ttorch.embedding(idx, params["wte"])  # (B, T, C)
-    cos, sin = _rope_cache(T, config, device=x.device, dtype=x.dtype)
-    for p in params["blocks"]:
-        x = _block(x, p, cos, sin, config)
-    x = _norm(x, params["ln_f"], config)
-    return ttorch.linear(x, params["lm_head_w"])
+    return ttorch.linear(_hidden(params, idx, config), params["lm_head_w"])
+
+
+def routed_rows(params: dict, idx, config: GPTConfig):
+    """(expert layers, experts held) counts: the (token, choice) pairs that the
+    router of each expert layer sends to each expert held here for these ids,
+    which is the rows its grouped matmuls compute."""
+    rows: list = []
+    _hidden(params, idx, config, rows)
+    return ttorch.stack(rows, 0)
 
 
 def loss_fn(params: dict, idx, targets, config: GPTConfig):

@@ -1378,6 +1378,81 @@ def apply_rope(x, cos, sin):
     return cat([roped, x[..., n:]], dim=-1)
 
 
+@torchsymbol(id="torch.moe_route")
+def moe_route(x, router_w, top_k: int, n_group: int = 1, topk_group: int = 1,
+              routed_scaling_factor: float = 1.0):
+    """Sigmoid scores with group-limited top-k (the DeepSeek-V3 family's
+    router without a correction bias): x (N, C), router_w (E, C) ->
+    ``(top_i (N, k) int64, top_w (N, k) float32)``.
+
+    Scores are ``sigmoid(float32(x) float32(W)^T)``. The E experts lie in
+    ``n_group`` groups; a group's score is the sum of its two best scores; the
+    best ``topk_group`` groups stay, the others' scores are masked to 0, and
+    the top ``k`` of what is left are chosen. The weights are the unmasked
+    scores of the chosen, normalised over all k and multiplied by
+    ``routed_scaling_factor``. Float32 throughout, as published:
+    a bf16 score flips near-tied choices."""
+    N, E = x.shape[0], router_w.shape[0]
+    check(E % n_group == 0, lambda: f"{E} experts do not divide into {n_group} groups")
+    scores = sigmoid(linear(clang.maybe_convert_to_dtype(x, dtypes.float32),
+                            clang.maybe_convert_to_dtype(router_w, dtypes.float32)))
+    choose_from = scores
+    if n_group > 1:
+        grouped = reshape(scores, (N, n_group, E // n_group))
+        best_two, _ = topk(grouped, 2, -1)
+        _, kept = topk(sum(best_two, -1), topk_group, -1)                        # (N, topk_group)
+        groups = clang.arange(0, n_group, 1, device=x.device, dtype=dtypes.int64)
+        keep = sum(clang.maybe_convert_to_dtype(clang.eq(unsqueeze(kept, -1), groups), dtypes.float32), 1)
+        keep = expand(unsqueeze(clang.gt(keep, 0.0), -1), (N, n_group, E // n_group))
+        choose_from = reshape(where(keep, grouped, clang.full_like(grouped, 0.0)), (N, E))
+    _, top_i = topk(choose_from, top_k, -1)
+    top_w = take_along_dim(scores, top_i, 1)
+    return top_i, top_w / (sum(top_w, -1, True) + 1e-20) * routed_scaling_factor
+
+
+@torchsymbol(id="torch.moe_experts")
+def moe_experts(x, top_i, top_w, w_gate, w_up, w_down, expert_offset: int = 0, n_expert: Optional[int] = None):
+    """The routed experts *held here*, with real dispatch: x (N, C); top_i,
+    top_w (N, k) from the router over all experts; ``w_gate``, ``w_up``
+    (E_held, C, H) and ``w_down`` (E_held, H, C) are experts ``expert_offset``
+    to ``expert_offset + E_held``. Returns (N, C):
+    ``sum_i w_i SwiGLU_{e_i}(x)`` over a token's chosen experts held here.
+    What the other experts would add is their holder's to compute.
+
+    The (token, choice) pairs are sorted by local expert, pairs of experts
+    held elsewhere last; the tokens' rows are gathered in that order, each
+    projection is one grouped matmul over the held experts (rows beyond the
+    groups are not computed), and the rows go back to their tokens through
+    the inverse permutation, weighted and summed in float32. The buffer has
+    ``min(k, E_held) * N`` rows, the most a router that picks k distinct
+    experts can send here: no token is ever dropped. Work follows the rows
+    routed here, not the buffer. ``n_expert`` is how many experts the router
+    chose among (``None``: those held, all of them): nothing here needs it, an
+    implementation may size a shorter buffer by the ``k * N * E_held /
+    n_expert`` rows an even router sends here, as long as it keeps every row."""
+    N, C = x.shape
+    k, held = top_i.shape[1], w_gate.shape[0]
+    rows = builtins_min(k, held) * N
+    local = clang.sub(top_i, expert_offset)
+    here = clang.bitwise_and(clang.ge(local, 0), clang.lt(local, held))
+    key = reshape(where(here, local, clang.full_like(local, held)), (N * k,))
+    order = argsort(key)                                       # stable: (N*k,), held pairs first, by expert
+    slot = argsort(order)                                      # where each pair went
+    order = order[:rows]
+    experts = clang.arange(0, held, 1, device=x.device, dtype=key.dtype)
+    group_sizes = sum(clang.eq(unsqueeze(key, -1), experts), 0, dtype=dtypes.int32)
+    xs = clang.take(x, clang.floor_divide(order, k), 0)        # (rows, C)
+    h = silu(prims.grouped_mm(xs, w_gate, group_sizes)) * prims.grouped_mm(xs, w_up, group_sizes)
+    ys = prims.grouped_mm(h, w_down, group_sizes)              # (rows, C)
+    # Rows beyond the groups are whatever the grouped matmul left there, and the
+    # pairs that fell beyond the buffer are all held elsewhere: both are masked.
+    back = reshape(clang.take(ys, clang.minimum(slot, rows - 1), 0), (N, k, C))
+    back = where(expand(unsqueeze(here, -1), (N, k, C)), clang.maybe_convert_to_dtype(back, dtypes.float32),
+                 clang.full((N, k, C), 0.0, device=x.device, dtype=dtypes.float32))
+    out = sum(back * unsqueeze(top_w, -1), 1)
+    return clang.maybe_convert_to_dtype(out, x.dtype)
+
+
 @torchsymbol(id="torch.sdpa_fwd_res")
 def sdpa_fwd_res(query, key, value, attn_mask=None, is_causal: bool = False,
                  scale: Optional[float] = None, enable_gqa: bool = False):

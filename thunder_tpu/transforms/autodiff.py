@@ -662,6 +662,14 @@ def _matmul_vjp(bsym, g):
     return (ga, gb)
 
 
+@register_vjp(PrimIDs.GROUPED_MM)
+def _grouped_mm_vjp(bsym, g):
+    a, b, group_sizes = bsym.args
+    ga = prims.grouped_mm(g, clang.transpose(b, 1, 2), group_sizes) if _is_float_tensor(a) else None
+    gb = prims.grouped_mm_dw(a, g, group_sizes) if _is_float_tensor(b) else None
+    return (ga, gb, None)
+
+
 @register_vjp(PrimIDs.LINEAR)
 def _linear_vjp(bsym, g):
     a, w, bias = bsym.args
