@@ -310,6 +310,178 @@ class TestPallasCrossEntropy:
         src = thunder_tpu.last_traces(jf)[-1].python()
         assert "pallas_cross_entropy" not in src
 
+    @pytest.mark.parametrize("kind,mib,rows_bf16", [
+        ("TPU v5 lite", 64, 64), ("TPU v6 lite", 64, 64), ("TPU v5", 32, 32), ("TPU v4", 16, 16),
+        ("cpu", 16, 16), ("TPU v9", 16, 16),  # no generation of the table: the default scope
+    ])
+    def test_the_block_follows_the_vmem_of_the_devices_generation(self, monkeypatch, kind, mib, rows_bf16):
+        from thunder_tpu.executors import pallasex
+
+        monkeypatch.setattr(pallasex, "_device_kind", lambda: kind)
+        assert pallasex._ce_vmem_limit() == mib * 1024 * 1024
+        assert pallasex._ce_block_n(8192, 50304, 2) == rows_bf16  # pythia-410m.train's logits
+        # a vocabulary of 163840 in 16 rows of bfloat16: claimed where there is room, declined where not
+        assert (pallasex._ce_block_n(4096, 163840, 2) is None) == (mib == 16)
+
+
+class TestCrossEntropyUpcastFold:
+    """transforms/cross_entropy_upcast.py: where a program upcasts bf16 logits
+    for cross-entropy alone, the claimed pair reads the bf16 logits and writes a
+    bf16 gradient; loss and gradients are the ones of the program as written."""
+
+    N = 32
+
+    def _program(self, V, as_written_logits=lambda l: l.float(), **ce):
+        x, w = _bt(4, 8, 64), _bt(V, 64, seed=1)
+        target = np.random.RandomState(V).randint(0, V, (self.N,)).astype(np.int64)
+
+        def loss(x, w, t):
+            logits = as_written_logits(ttorch.linear(x, w))
+            return ttorch.cross_entropy(ttorch.reshape(logits, (self.N, V)), t, **ce)
+
+        return loss, (x, w, target)
+
+    @staticmethod
+    def _transforms_extra(fn):
+        program = thunder_tpu.compile_stats(fn).cache_entries[-1].compile_id
+        (record,) = [r for r in thunder_tpu.compile_phases()
+                     if r["program"] == program and r["phase"] == "transforms"]
+        return record
+
+    @staticmethod
+    def _as_written(monkeypatch):
+        from thunder_tpu.transforms import cross_entropy_upcast
+
+        monkeypatch.setattr(cross_entropy_upcast, "fold_cross_entropy_upcasts", lambda trc, executors: trc)
+
+    @pytest.mark.parametrize("case", ["several_chunks", "one_chunk", "ignored_rows", "sum"])
+    def test_folded_pair_equals_the_program_as_written_bit_for_bit(self, monkeypatch, case):
+        from thunder_tpu.executors import pallasex
+
+        V = 256
+        if case == "several_chunks":  # two whole chunks and a ragged one
+            V = 640
+            monkeypatch.setattr(pallasex, "_CE_CHUNK", 256)
+        loss, args = self._program(V, **({"reduction": "sum"} if case == "sum" else {}))
+        if case == "ignored_rows":
+            args[2][[0, 5, 31]] = -100
+
+        folded = thunder_tpu.value_and_grad(loss, argnums=(0, 1))
+        lf, gf = folded(*args)
+        src = thunder_tpu.last_traces(folded)[-1].python()
+        assert "pallas_cross_entropy(" in src and "pallas_cross_entropy_bwd(" in src
+        assert self._transforms_extra(folded)["cross_entropy_upcasts_folded"] == 1
+
+        with monkeypatch.context() as m:
+            self._as_written(m)
+            written = thunder_tpu.value_and_grad(loss, argnums=(0, 1))
+            lw, gw = written(*args)
+        assert "cross_entropy_upcasts_folded" not in self._transforms_extra(written)
+        assert lf.dtype == lw.dtype == np.float32
+        assert np.asarray(lf).tobytes() == np.asarray(lw).tobytes()
+        for a, b in zip(gf, gw):
+            assert a.dtype == b.dtype and np.array_equal(_f32(a), _f32(b))
+
+    def test_folded_joint_trace_keeps_bf16_logits_and_no_float32_copy(self):
+        from thunder_tpu.core import dtypes
+
+        V = 256
+        loss, args = self._program(V)
+        vg = thunder_tpu.value_and_grad(loss, argnums=(0, 1))
+        vg(*args)
+        bsyms = thunder_tpu.last_traces(vg)[-1].bound_symbols
+        at = {b.sym.name: i for i, b in enumerate(bsyms) if "cross_entropy" in b.sym.name}
+        fwd, bwd = at["cross_entropy"], at["cross_entropy_bwd"]
+        made = {p.name: p for b in bsyms[:fwd + 1] for p in b.flat_proxy_outs}
+        saved = {p.name: p for b in bsyms[bwd:] for p in b.flat_proxy_args if p.name in made}
+        wide = [p for p in saved.values() if p.dtype == dtypes.float32 and V in p.shape]
+        assert not wide
+        logits = bsyms[bwd].args[1]
+        assert logits.name in saved and logits.dtype == dtypes.bfloat16 and logits.shape == (self.N, V)
+        assert bsyms[bwd].output.dtype == dtypes.bfloat16
+        assert not any(b.sym.name == "convert_element_type" and V in getattr(b.output, "shape", ())
+                       for b in bsyms)
+
+    @pytest.mark.parametrize("case", ["second_reader", "float32_model", "label_smoothing", "vocabulary_off_the_lanes"])
+    def test_the_pass_leaves_alone(self, case):
+        V = 96 if case == "vocabulary_off_the_lanes" else 256
+        if case == "second_reader":
+            def loss(x, w, t):
+                logits = ttorch.linear(x, w).float()
+                return ttorch.cross_entropy(ttorch.reshape(logits, (self.N, V)), t) + 1e-4 * ttorch.sum(logits * logits)
+            args = self._program(V)[1]
+        elif case == "float32_model":
+            loss, (x, w, t) = self._program(V)
+            args = (_f32(x), _f32(w), t)
+        elif case == "label_smoothing":
+            loss, args = self._program(V, label_smoothing=0.1)
+        else:
+            loss, args = self._program(V)
+        vg = thunder_tpu.value_and_grad(loss, argnums=(0, 1))
+        lf, gf = vg(*args)
+        assert self._transforms_extra(vg)["cross_entropy_upcasts_folded"] == 0
+        src = thunder_tpu.last_traces(vg)[-1].python()
+        claimed = case in ("second_reader", "float32_model")  # on float32 logits, as before
+        assert ("pallas_cross_entropy(" in src) == claimed
+        slow = thunder_tpu.value_and_grad(loss, argnums=(0, 1), executors=jax_only)
+        ls, gs = slow(*args)
+        np.testing.assert_allclose(float(lf), float(ls), rtol=1e-5)
+        for a, b in zip(gf, gs):
+            assert a.dtype == b.dtype
+            np.testing.assert_allclose(_f32(a), _f32(b), rtol=5e-2, atol=1e-3)
+
+    def test_counter_is_one_for_loss_fn_and_zero_for_forward(self):
+        import time
+
+        from thunder_tpu.core import dtypes
+        from thunder_tpu.models import gpt as m
+        from thunder_tpu.parallel import build_train_step
+
+        cfg = m.GPTConfig(
+            name="fold-test", block_size=16, vocab_size=128, padded_vocab_size=128,
+            n_layer=1, n_head=2, n_embd=32, rotary_percentage=1.0, parallel_residual=False,
+            bias=False, norm_class="RMSNorm", mlp_class="LLaMAMLP", intermediate_size=64,
+        )
+        params = m.init_params(cfg, dtype=dtypes.bfloat16, seed=0)
+        idx = np.random.RandomState(0).randint(0, 128, (2, 16)).astype(np.int32)
+        tgt = np.roll(idx, -1, 1).astype(np.int32)
+
+        mark = time.perf_counter()
+        step, opt, extrace = build_train_step(cfg, params, idx, tgt, return_extrace=True)
+        (record,) = [r for r in thunder_tpu.compile_phases() if r["at"] >= mark and r["phase"] == "transforms"]
+        assert record["cross_entropy_upcasts_folded"] == 1
+        bwd = next(b for b in extrace.bound_symbols if b.sym.name == "cross_entropy_bwd")
+        assert bwd.sym.executor.name == "pallas" and bwd.args[1].dtype == dtypes.bfloat16
+        _, _, loss = step(params, opt, idx, tgt)
+        assert np.isfinite(float(loss))
+
+        jfn = thunder_tpu.jit(lambda p, i: m.forward(p, i, cfg))
+        jfn(m.init_params(cfg, dtype=dtypes.bfloat16, seed=0), idx)
+        assert self._transforms_extra(jfn)["cross_entropy_upcasts_folded"] == 0
+
+    def test_a_claim_that_fails_after_the_fold_computes_the_program_as_written(self, monkeypatch):
+        """The folded symbols decompose into the convert and the pair as written."""
+        V = 256
+        loss, args = self._program(V)
+        want_l, want_g = thunder_tpu.value_and_grad(loss, argnums=(0, 1), executors=jax_only)(*args)
+
+        pallas = get_executor("pallas")
+        vg = thunder_tpu.value_and_grad(loss, argnums=(0, 1))
+        asked = {"n": 0}
+        real = pallas.can_execute
+
+        def out_of_fuel(bsym):  # the pass asks the checkers; the claiming pass asks here
+            asked["n"] += "cross_entropy" in bsym.sym.name
+            return False if "cross_entropy" in bsym.sym.name else real(bsym)
+
+        monkeypatch.setattr(pallas, "can_execute", out_of_fuel)
+        got_l, got_g = vg(*args)
+        assert asked["n"] >= 2 and self._transforms_extra(vg)["cross_entropy_upcasts_folded"] == 1
+        assert "pallas_cross_entropy" not in thunder_tpu.last_traces(vg)[-1].python()
+        np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-6)
+        for a, b in zip(got_g, want_g):
+            assert np.array_equal(_f32(a), _f32(b))
+
 
 class TestEndToEndModel:
     def test_model_training_uses_kernels(self):

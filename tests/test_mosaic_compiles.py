@@ -34,6 +34,25 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
+@pytest.fixture(scope="module")
+def describe_chip(one_chip):
+    """Another generation's chip, by topology name (after ``one_chip``, which turned the persistent cache off)."""
+    import functools
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    @functools.cache
+    def describe(topology_name):
+        try:
+            topo = topologies.get_topology_desc(platform="tpu", topology_name=topology_name)
+        except Exception as e:  # noqa: BLE001
+            pytest.skip(f"no {topology_name} topology can be described here: {e}")
+        return SingleDeviceSharding(topo.devices[0])
+
+    return describe
+
+
 ROPE_SHAPES = [
     # dtype, (B, H, T, hs), n
     ("bfloat16", (8, 16, 2048, 64), 16),    # pythia-410m.fwd's q and k
@@ -81,6 +100,122 @@ def test_rope_checker_declines_what_does_not_compile(one_chip, monkeypatch, dtyp
     claimed, lowered = _rope_claim_and_lowering(monkeypatch, one_chip, dtype, (8, 16, 2048, hs), 16)
     assert not claimed
     with pytest.raises(Exception):  # noqa: B017, PT011 - Mosaic's own error, whatever its type
+        lowered.compile()
+
+
+CE_SHAPES = [
+    # rows, vocabulary, dtype
+    (8192, 50304, "bfloat16"),   # pythia-410m.train, the logits as the head wrote them
+    (4096, 32000, "bfloat16"),   # mistral-7b.train, and a chip of mistral-7b.fsdp4
+    (8192, 50304, "float32"),    # a program that computes its logits in float32
+    (4096, 32000, "float32"),
+    (4096, 163840, "bfloat16"),  # ROADMAP R3's vocabulary
+]
+
+
+def _ce_claim_and_lowering(monkeypatch, one_chip, backward, rows, vocab, dtype):
+    """(what the checker says of the shapes, the kernel's call lowered for the described chip)."""
+    import jax
+    import jax.numpy as jnp
+
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import pallasex
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    monkeypatch.setattr(pallasex, "_device_kind", lambda: next(iter(one_chip.device_set)).device_kind)
+    logits = SimpleNamespace(shape=(rows, vocab), dtype=getattr(dtypes, dtype))
+    target = SimpleNamespace(shape=(rows,), dtype=dtypes.int32)
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    args = (sds((rows, vocab), getattr(jnp, dtype)), sds((rows,), jnp.int32))
+    # A function of its own each time: jax keeps a traced call by function and shapes, and the block is the device's.
+    if backward:
+        return (pallasex._ce_bwd_checker(None, logits, target),
+                jax.jit(lambda *a: pallasex._ce_bwd_impl(*a)).lower(sds((), jnp.float32), *args))
+    return pallasex._ce_checker(logits, target), jax.jit(lambda *a: pallasex._ce_impl(*a)).lower(*args)
+
+
+CE_IDS = [f"{d}-{n}x{v}" for n, v, d in CE_SHAPES]
+
+
+@pytest.mark.parametrize("rows,vocab,dtype", CE_SHAPES, ids=CE_IDS)
+def test_cross_entropy_forward_kernel_compiles_for_v5e(one_chip, monkeypatch, rows, vocab, dtype):
+    claimed, lowered = _ce_claim_and_lowering(monkeypatch, one_chip, False, rows, vocab, dtype)
+    assert claimed
+    assert lowered.compile().as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("rows,vocab,dtype", CE_SHAPES, ids=CE_IDS)
+def test_cross_entropy_backward_kernel_compiles_for_v5e(one_chip, monkeypatch, rows, vocab, dtype):
+    """The gradient comes out in the logits' dtype, from one call."""
+    claimed, lowered = _ce_claim_and_lowering(monkeypatch, one_chip, True, rows, vocab, dtype)
+    assert claimed
+    compiled = lowered.compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert str(compiled.out_info.dtype) == dtype and compiled.out_info.shape == (rows, vocab)
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+def test_cross_entropy_checker_declines_float16_which_does_not_compile(one_chip, monkeypatch, backward):
+    """Mosaic loads no float16 vector on the v5e: the checkers say no, so the
+    upcast before the loss stays in a float16 program."""
+    claimed, lowered = _ce_claim_and_lowering(monkeypatch, one_chip, backward, 4096, 32000, "float16")
+    assert not claimed
+    with pytest.raises(Exception):  # noqa: B017, PT011 - Mosaic's own error, whatever its type
+        lowered.compile()
+
+
+OTHER_CHIPS = [
+    # topology, the VMEM a call asks for there in MiB, the row blocks at (8192, 50304) in bfloat16 and float32
+    ("v4:2x2x1", 16, (16, 8)),    # 16 MiB a core: the default scope, which the kernels lived in before
+    ("v5p:2x2x1", 32, (32, 16)),  # 64 MiB
+    ("v6e:2x2", 64, (64, 32)),    # 128 MiB, as the v5e
+]
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("topology,limit_mib,blocks", OTHER_CHIPS, ids=[t.split(":")[0] for t, _, _ in OTHER_CHIPS])
+def test_cross_entropy_kernels_compile_for_other_generations(describe_chip, monkeypatch, topology, limit_mib, blocks, dtype):
+    """The block follows the VMEM of the device's generation: what the checkers
+    claim at pythia's shapes compiles there, forward and backward."""
+    from thunder_tpu.executors import pallasex
+
+    chip = describe_chip(topology)
+    for backward in (False, True):
+        claimed, lowered = _ce_claim_and_lowering(monkeypatch, chip, backward, 8192, 50304, dtype)
+        assert claimed
+        assert lowered.compile().as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert pallasex._ce_vmem_limit() == limit_mib * 1024 * 1024
+    assert pallasex._ce_block_n(8192, 50304, 2 if dtype == "bfloat16" else 4) == blocks[dtype == "float32"]
+
+
+def test_cross_entropy_checker_declines_where_the_generation_has_no_room(describe_chip, monkeypatch):
+    """A vocabulary of 163840 in 16 rows of bfloat16 does not fit the v4's 16 MiB
+    twice over: the checkers say no there, and yes on the v5e (above)."""
+    for backward in (False, True):
+        with pytest.raises(ValueError, match="unclaimable"):
+            _ce_claim_and_lowering(monkeypatch, describe_chip("v4:2x2x1"), backward, 4096, 163840, "bfloat16")
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import pallasex
+
+    logits = SimpleNamespace(shape=(4096, 163840), dtype=dtypes.bfloat16)
+    target = SimpleNamespace(shape=(4096,), dtype=dtypes.int32)
+    assert not pallasex._ce_checker(logits, target) and not pallasex._ce_bwd_checker(None, logits, target)
+
+
+def test_the_v5e_block_does_not_compile_for_a_v4(describe_chip, monkeypatch):
+    """Why the budget is the device's: the backward on 64 rows of pythia's
+    vocabulary under a 64 MiB scope, right for the v5e, runs out of the v4's VMEM."""
+    import jax
+    import jax.numpy as jnp
+
+    from thunder_tpu.executors import pallasex
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    monkeypatch.setattr(pallasex, "_device_kind", lambda: "TPU v5 lite")
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=describe_chip("v4:2x2x1"))
+    lowered = jax.jit(lambda *a: pallasex._ce_bwd_impl(*a)).lower(
+        sds((), jnp.float32), sds((8192, 50304), jnp.bfloat16), sds((8192,), jnp.int32))
+    with pytest.raises(Exception, match="vmem"):  # noqa: PT011 - Mosaic's own error, whatever its type
         lowered.compile()
 
 

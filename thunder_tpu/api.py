@@ -680,6 +680,11 @@ def _compile_entry_impl(
         from thunder_tpu.transforms.attention_residuals import save_sdpa_residuals_joint
 
         comp_trc = save_sdpa_residuals_joint(comp_trc, cd.executors_list)
+        # and let cross-entropy read the 16-bit logits that a program upcast
+        # for it alone (transforms/cross_entropy_upcast.py)
+        from thunder_tpu.transforms.cross_entropy_upcast import fold_cross_entropy_upcasts
+
+        comp_trc = fold_cross_entropy_upcasts(comp_trc, cd.executors_list)
 
     comp_trc = functionalize_rng_ops(comp_trc)
     if comp_trc.tags.get(RNG_TAG):
@@ -849,9 +854,13 @@ def _compile_entry_impl(
     entry.schedule_certificate = static_cert
     cs.trace_seconds += entry.stats.trace_s
     comm_sched_tag = extrace.tags.get("comm_schedule")
+    from thunder_tpu.transforms.cross_entropy_upcast import FOLDED_TAG
+
     for phase in ("trace", "transforms", "claim", "static_analysis", "codegen",
                   "staging"):
         extra = {}
+        if phase == "transforms" and FOLDED_TAG in comp_trc.tags:  # by presence, as below: a de-optimized compile ran no such pass
+            extra[FOLDED_TAG] = comp_trc.tags[FOLDED_TAG]
         if phase == "static_analysis" and static_plan is not None:
             extra = dict(
                 predicted_peak_bytes=int(static_plan.peak_bytes),

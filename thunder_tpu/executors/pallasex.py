@@ -3,15 +3,22 @@
 Reference parity: the reference's only in-repo kernel-DSL code is its
 Triton cross-entropy (thunder/executors/triton_crossentropy.py:53-343, four
 @triton.jit kernels) plus the apex seat (apex_entropyex.py:38). This module
-is the TPU equivalent: Pallas/Mosaic kernels fusing max/logsumexp/pick into
-one HBM pass over the logits — the (N, V≈32-50k) logits matrix is the
-largest activation in LM training, so one fused read (fwd) and one fused
-write (bwd) replaces the ~5 passes of the decomposed path.
+is the TPU equivalent: Pallas/Mosaic kernels fusing max/logsumexp/pick. The
+(N, V≈32-160k) logits matrix is the largest activation in LM training; the
+forward reads it from HBM once and the backward reads it once and writes its
+gradient once, in the dtype the logits have (float32, or the bfloat16 the
+head wrote: transforms/cross_entropy_upcast.py folds a program's upcast
+before the loss into the claim), where the decomposed path makes ~5 passes.
+A call holds a block of rows in VMEM and walks it in chunks of lanes, upcast
+to float32 there, with a running maximum and sum: the float32 temporaries
+are (rows, chunk) whatever the vocabulary. ``_ce_block_n`` budgets the block
+and those against the VMEM of the device's generation (``_ce_vmem_limit``),
+so the checker declines on a chip where nothing would compile.
 
 Claims ``torch.cross_entropy`` and the ``torch.cross_entropy_bwd``
 composite emitted by the autodiff rule. Falls back to the decomposition
-when shapes don't block-align (checker), exactly like the reference's
-executor checkers.
+when shapes don't block-align or the dtype is neither (checker), exactly
+like the reference's executor checkers.
 
 Under a device mesh every ``pallas_call`` runs per batch shard inside
 ``jax.shard_map`` (executors/kernel_mesh.py), its blocks sized on the
@@ -33,7 +40,6 @@ ex = OperatorExecutor("pallas")
 register_executor(ex)
 add_default_executor(ex, front=True)
 
-_BLOCK_N = 16
 _LANE = 128
 
 
@@ -43,26 +49,61 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _ce_block_n(N: int, V: int):
-    """Row-block size for the CE kernels, or None when unclaimable.
+# Measured on the v5e at (8192, 50304) (PERF.md, PR 28): bf16 logits reach 1.14 ms forward and 2.56 backward
+# from blocks of 64 rows and chunks of 4096 lanes on; 32 rows and 2048 lanes read 1.86 and 2.68, 16 rows 2.79
+# and 4.14 (each chunk ends in reductions across lanes that the next one waits for). float32 logits sit on
+# their bytes (2.22 and 5.06 ms) from 16 rows on.
+_CE_CHUNK = 4096  # lanes a step of the walk over the vocabulary; not by dtype, so every dtype sums in one order
+_SCOPED_VMEM_DEFAULT = 16 * 1024 * 1024  # what Mosaic gives a call that asks for nothing, on every generation
+# A core's VMEM by generation (jax's pltpu.get_tpu_info has these numbers, for the default device alone).
+_VMEM_BYTES = {"v4": 16 * 1024 * 1024, "v5e": 128 * 1024 * 1024, "v5p": 64 * 1024 * 1024, "v6e": 128 * 1024 * 1024}
 
-    The bwd kernel live-holds ~6 f32 (block, V) temporaries (x, e, p, iota,
-    onehot, out) in scoped VMEM; budget them under the 16 MB scoped limit
-    with headroom (r5: pythia's V=50304 at the old fixed block of 16
-    overflowed by 724 KB on the real chip — 'Ran out of memory in memory
-    space vmem')."""
-    for bt in (32, 16, 8):
-        if N % bt == 0 and 6 * bt * V * 4 <= 12 * 1024 * 1024:
-            return bt
+
+def _device_kind() -> str:
+    import jax
+
+    return jax.devices()[0].device_kind
+
+
+def _ce_vmem_limit() -> int:
+    """The VMEM a cross-entropy call asks of Mosaic: half of what the device's
+    generation has, and never under the default scope. A device whose
+    generation the table lacks (the CPU's interpret mode, a newer chip) gets
+    the default scope: every TPU holds that, and the kernels lived in it
+    before they walked the vocabulary."""
+    from thunder_tpu.benchmarks import tpu_generation
+
+    try:
+        return max(_SCOPED_VMEM_DEFAULT, _VMEM_BYTES[tpu_generation(_device_kind())] // 2)
+    except ValueError:  # no TPU generation of the table
+        return _SCOPED_VMEM_DEFAULT
+
+
+def _ce_block_n(N: int, V: int, itemsize: int = 4):
+    """Row-block size for the CE kernels on this device, or None when unclaimable.
+
+    What a call holds in VMEM is the row block of the logits and (backward)
+    of their gradient, each twice for the pipeline, in the logits' dtype, and
+    some eight float32 temporaries of (block, _CE_CHUNK) whatever V is; three
+    quarters of ``_ce_vmem_limit()`` may go to them. 16-bit logits take
+    blocks of whole (16, 128) tiles, so 16 rows at least."""
+    budget = 3 * _ce_vmem_limit() // 4
+    for bn in (64, 32, 16) if itemsize < 4 else (64, 32, 16, 8):
+        if N % bn == 0 and 4 * bn * V * itemsize + 8 * bn * min(V, _CE_CHUNK) * 4 <= budget:
+            return bn
     return None
 
 
 def _ce_shapes_ok(input, target) -> bool:
     if len(getattr(input, "shape", ())) != 2:
         return False
+    dtype = dtypes.to_dtype(input.dtype)
+    if dtype not in (dtypes.float32, dtypes.bfloat16):  # Mosaic loads no float16 vector on the v5e
+        return False
     N, V = input.shape
     shards = batch_shards()
-    return V % _LANE == 0 and N % shards == 0 and _ce_block_n(int(N) // shards, int(V)) is not None
+    return (V % _LANE == 0 and N % shards == 0
+            and _ce_block_n(int(N) // shards, int(V), dtype.bytes) is not None)
 
 
 def _ce_checker(input, target, weight=None, ignore_index=-100, reduction="mean", label_smoothing=0.0):
@@ -86,40 +127,84 @@ def _ce_bwd_checker(g, input, target, ignore_index=-100, reduction="mean"):
 # Lane-width padding: Mosaic requires the last (lane) dim of every VMEM
 # block to be 128-aligned, so per-row scalars (targets, loss, row scales)
 # travel as (N, 128) with only lane 0 meaningful.
+#
+# A kernel has its row block of the logits in VMEM, in the dtype the program
+# wrote them, and walks it in chunks of _CE_CHUNK lanes: each chunk is upcast
+# to float32 there (exact for bfloat16), so the arithmetic is float32 whatever
+# came in and the temporaries are (block, chunk).
+
+
+def _ce_walk(V: int, step, carry):
+    """``carry = step(start, cols, carry)`` over the chunks of V lanes in turn:
+    the whole chunks in one loop, the ragged last one after it. ``cols`` is
+    the chunk's own lane index, (1, size)."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    lane = lambda size: jax.lax.broadcasted_iota(jax.numpy.int32, (1, size), 1)
+    whole, rest = divmod(V, _CE_CHUNK)
+    if whole:
+        cols = lane(_CE_CHUNK)
+        carry = jax.lax.fori_loop(
+            0, whole, lambda c, k: step(pl.multiple_of(c * _CE_CHUNK, _CE_CHUNK), cols, k), carry)
+    if rest:
+        carry = step(whole * _CE_CHUNK, lane(rest), carry)
+    return carry
+
+
+def _ce_chunk(logits_ref, start, cols):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    return logits_ref[:, pl.ds(start, cols.shape[1])].astype(jnp.float32)
+
+
+def _ce_row_stats(logits_ref, tgt=None):
+    """Per row of the block, kept running over the chunks and each (rows, 1):
+    the maximum m, sum(exp(x - m)) and, given the targets, the target's logit."""
+    import jax.numpy as jnp
+
+    n, V = logits_ref.shape
+
+    def step(start, cols, carry):
+        m, l, picked = carry
+        x = _ce_chunk(logits_ref, start, cols)
+        m_new = jnp.maximum(m, jnp.max(x, axis=1, keepdims=True))
+        at = jnp.where(m_new == -jnp.inf, 0.0, m_new)  # a chunk of -inf adds nothing
+        l = l * jnp.exp(m - at) + jnp.sum(jnp.exp(x - at), axis=1, keepdims=True)
+        if tgt is not None:
+            picked = picked + jnp.sum(jnp.where(cols == tgt - start, x, 0.0), axis=1, keepdims=True)
+        return m_new, l, picked
+
+    col = lambda v: jnp.full((n, 1), v, dtype=jnp.float32)
+    return _ce_walk(V, step, (col(-jnp.inf), col(0.0), col(0.0)))
 
 
 def _ce_fwd_kernel(logits_ref, tgt_ref, loss_ref, *, ignore_index: int):
-    import jax
     import jax.numpy as jnp
 
-    x = logits_ref[:].astype(jnp.float32)  # (BLOCK_N, V)
-    n, v = x.shape
-    m = jnp.max(x, axis=1, keepdims=True)
-    lse = jnp.log(jnp.sum(jnp.exp(x - m), axis=1, keepdims=True)) + m  # (BLOCK_N, 1)
-
-    tgt = tgt_ref[:, 0:1]  # (BLOCK_N, 1) int32
-    cols = jax.lax.broadcasted_iota(jnp.int32, (n, v), dimension=1)
-    picked = jnp.sum(jnp.where(cols == tgt, x, 0.0), axis=1, keepdims=True)
-
+    tgt = tgt_ref[:, 0:1]  # (rows, 1) int32
+    m, l, picked = _ce_row_stats(logits_ref, tgt)
     valid = (tgt != ignore_index).astype(jnp.float32)
-    loss_ref[:] = jnp.broadcast_to((lse - picked) * valid, loss_ref.shape)
+    loss_ref[:] = jnp.broadcast_to((jnp.log(l) + m - picked) * valid, loss_ref.shape)
 
 
 def _ce_bwd_kernel(logits_ref, tgt_ref, scale_ref, dlogits_ref, *, ignore_index: int):
-    import jax
     import jax.numpy as jnp
+    from jax.experimental import pallas as pl
 
-    x = logits_ref[:].astype(jnp.float32)
-    n, v = x.shape
-    m = jnp.max(x, axis=1, keepdims=True)
-    e = jnp.exp(x - m)
-    p = e / jnp.sum(e, axis=1, keepdims=True)
-
+    m, l, _ = _ce_row_stats(logits_ref)
+    inv_l = 1.0 / l
     tgt = tgt_ref[:, 0:1]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (n, v), dimension=1)
-    onehot = (cols == tgt).astype(jnp.float32)
+    scale = scale_ref[:, 0:1]
 
-    dlogits_ref[:] = ((p - onehot) * scale_ref[:, 0:1]).astype(dlogits_ref.dtype)
+    def write(start, cols, carry):  # the second walk, over the block still in VMEM
+        p = jnp.exp(_ce_chunk(logits_ref, start, cols) - m) * inv_l
+        onehot = (cols == tgt - start).astype(jnp.float32)
+        dlogits_ref[:, pl.ds(start, cols.shape[1])] = ((p - onehot) * scale).astype(dlogits_ref.dtype)
+        return carry
+
+    _ce_walk(logits_ref.shape[1], write, 0)
 
 
 # =============================================================================
@@ -134,10 +219,10 @@ def _ce_call(kernel, out_lanes, out_dtype, logits, *extra):
 
     def rows(logits, *extra):  # one batch shard's rows
         N, V = logits.shape
-        bn = _ce_block_n(int(N), int(V))
+        bn = _ce_block_n(int(N), int(V), logits.dtype.itemsize)
         if bn is None:
             raise ValueError(
-                f"CE kernel called with unclaimable shape ({N}, {V}) — the checker "
+                f"CE kernel called with unclaimable shape ({N}, {V}) {logits.dtype} — the checker "
                 "must gate this (a floored grid would leave tail rows unwritten)"
             )
         in_specs = [pl.BlockSpec((bn, V), lambda i: (i, 0), memory_space=pltpu.VMEM)]
@@ -149,6 +234,8 @@ def _ce_call(kernel, out_lanes, out_dtype, logits, *extra):
             in_specs=in_specs,
             out_specs=pl.BlockSpec((bn, out_lanes), lambda i: (i, 0), memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((N, out_lanes), out_dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",), vmem_limit_bytes=_ce_vmem_limit()),
             interpret=_interpret(),
         )(logits, *extra)
 

@@ -61,7 +61,9 @@ class Executor:
 
     # -- claiming ------------------------------------------------------------
 
-    def can_execute(self, bsym: BoundSymbol) -> bool:
+    def accepts(self, bsym: BoundSymbol) -> bool:
+        """What the checker says of ``bsym``; spends no fuel, so a transform
+        may ask before it rewrites for this executor."""
         info = self.implmap.get(bsym.sym.id)
         if info is None:
             return False
@@ -71,9 +73,12 @@ class Executor:
                     return False
             except Exception:
                 return False
+        return True
+
+    def can_execute(self, bsym: BoundSymbol) -> bool:
         # When fuel is set, each claim consumes one unit; exhausting fuel
         # makes this executor stop claiming (bisection knob).
-        return self.get_fuel(1)
+        return self.accepts(bsym) and self.get_fuel(1)
 
     def get_impl(self, sym_id: Any) -> Optional[Callable]:
         info = self.implmap.get(sym_id)

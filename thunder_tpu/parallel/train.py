@@ -120,6 +120,7 @@ def _compile_loss_and_grads(config: GPTConfig, params, idx, targets, executors=N
     from thunder_tpu.transforms.attention_residuals import save_sdpa_residuals_joint
     from thunder_tpu.transforms.autodiff import grad_transform
     from thunder_tpu.transforms.common import dce
+    from thunder_tpu.transforms.cross_entropy_upcast import FOLDED_TAG, fold_cross_entropy_upcasts
 
     # The phases carry the ``jit`` path's names where the stage is the
     # ``jit`` path's (docs/observability.md, "Compile-phase spans").
@@ -129,9 +130,11 @@ def _compile_loss_and_grads(config: GPTConfig, params, idx, targets, executors=N
     with _phase(program, "trace"):
         _, comp = trace_program(fn, (params, idx, targets), {})
         comp = dce(comp)
-    with _phase(program, "transforms"):
+    with _phase(program, "transforms") as extra:
         joint = grad_transform(comp, return_value=True)
         joint = save_sdpa_residuals_joint(joint, ex_list)
+        joint = fold_cross_entropy_upcasts(joint, ex_list)
+        extra[FOLDED_TAG] = joint.tags[FOLDED_TAG]
         divisors = None
         if mesh is not None and param_specs is not None:
             from thunder_tpu.analysis.liveness import arg_divisors_from_specs
