@@ -64,7 +64,7 @@ KERNEL_VS_JAX_RTOL_LATER = 8e-3
 # rows exactly as one chip would; only the order of the float32 sum differs.
 SHARDED_VS_ONE_CHIP_RTOL = 1e-3
 BALANCE = 0.25  # bytes_in_use on devices 1..3 against device 0
-MAX_STEP_S = 1.0  # pythia-410m B=2 T=2048 took 0.12 s/iter on a v5e on 2026-07-30 (BENCHMARKS.md)
+MAX_STEP_S = 1.0  # pythia-410m B=2 T=2048 took 0.12 s/iter on a v5e on 2026-07-30
 
 KERNEL_EXECUTORS = ("flash", "pallas")
 

@@ -56,8 +56,8 @@ def main(argv=None, *, config=None) -> dict:
     the place of ``--model``: ``chip_smoke.py --rehearse`` cuts widths so."""
     args = parse_args(argv)
 
-    from thunder_tpu.benchmarks import device_description
     from thunder_tpu.core import dtypes
+    from thunder_tpu.core.devices import device_description
     from thunder_tpu.models import gpt
     from thunder_tpu.parallel import build_train_step
 

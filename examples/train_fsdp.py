@@ -68,8 +68,8 @@ def main(argv=None, *, config=None) -> dict:
 
     import jax
 
-    from thunder_tpu.benchmarks import device_description
     from thunder_tpu.core import dtypes
+    from thunder_tpu.core.devices import device_description
     from thunder_tpu.models import gpt
     from thunder_tpu.parallel import (
         build_train_step,

@@ -10,14 +10,13 @@ autocast, RNG functionalization) is verified at the point it runs.
 
 Exit status is non-zero if any ERROR-severity diagnostic is found.
 
-The full run also executes the bench regression gate
+The full run also executes the regression gate
 (``scripts/perf_report.py --history --gate``) over the committed
-``BENCH_r*.json`` rounds, so a future bench regression beyond threshold
-fails this script loudly (known regressions are acknowledged in
-``BENCH_ACK.json``).
+``*_r*.json`` rounds, so a regression beyond threshold fails this script
+loudly.
 
 Usage:
-    python scripts/lint_traces.py            # all programs + bench gate
+    python scripts/lint_traces.py            # all programs + round gates
     python scripts/lint_traces.py gpt        # substring-filter by name
     python scripts/lint_traces.py --events LOG.jsonl [LOG2.jsonl ...]
         # replay observability event log(s) (THUNDER_TPU_EVENTS /
@@ -63,8 +62,7 @@ Usage:
         # /metrics must scrape mid-run with host labels AND the
         # always-export drop counter at 0, an injected hang must leave a
         # schema-valid flight-recorder dump, and the measured ops-plane
-        # overhead must stay under 1% of the step time (the same
-        # composition bench.py records as ops_overhead_pct)
+        # overhead must stay under 1% of the step time
     python scripts/lint_traces.py --hlo
         # HLO-auditor smoke (ISSUE 16; docs/trace_invariants.md "HLO
         # auditor"): the fsdp4·tp2 build_train_step executable's compiled
@@ -171,11 +169,9 @@ def _replay(paths: list, storm_threshold: int) -> int:
     return 1 if n_errors else 0
 
 
-def _bench_history_gate(glob_pat: str = "BENCH_r*.json",
-                        min_rounds: int = 2) -> int:
-    """Run the bench regression gate over one committed bench series
-    (``BENCH_r*.json`` single-host, ``MULTICHIP_BENCH_r*.json`` multichip —
-    scripts/perf_report.py). Returns the number of errors (0 when fewer
+def _bench_history_gate(glob_pat: str, min_rounds: int = 2) -> int:
+    """Run the regression gate over one committed series of rounds
+    (scripts/perf_report.py). Returns the number of errors (0 when fewer
     than ``min_rounds`` committed rounds exist; the SOAK_POD series passes
     ``min_rounds=1`` because its absolute federation invariants gate from
     the first committed round)."""
@@ -192,107 +188,6 @@ def _bench_history_gate(glob_pat: str = "BENCH_r*.json",
 
     print(f"--- bench regression gate (perf_report --history --gate) [{glob_pat}]")
     return run_history_gate(paths, gate=True)
-
-
-# The committed MULTICHIP_BENCH schema: what every round must carry for the
-# series to stay comparable (scripts/bench_multichip.py emits these; the
-# --multichip smoke and docs/performance.md "distributed telemetry" assert
-# them).
-_MULTICHIP_REQUIRED_KEYS = (
-    "metric", "value", "unit", "n_devices", "mesh", "model", "batch", "seq",
-    "train_iter_s", "train_iter_synced_s", "train_iter_strict_sync_s",
-    "train_tokens_per_sec", "train_mfu", "device_spec", "train_flops_per_step",
-    "multichip_trace_claim_s", "multichip_xla_compile_s", "compile_phases",
-)
-
-
-def _multichip_smoke() -> int:
-    """--multichip: the distributed-observatory smoke (ISSUE 8 satellite).
-    Runs a reduced-iteration ``scripts/bench_multichip.py`` on an 8-device
-    virtual CPU mesh, asserts the bench JSON schema (every key the committed
-    ``MULTICHIP_BENCH_r*.json`` series gates on), asserts collective rows are
-    present in the profiled attribution with the hidden/exposed overlap
-    split, and runs ``perf_report.py --gate`` over the committed multichip
-    series. Returns the error count."""
-    import json
-    import subprocess
-    import tempfile
-
-    scripts_dir = os.path.dirname(os.path.abspath(__file__))
-    out_path = os.path.join(tempfile.mkdtemp(prefix="ttpu_mc_smoke_"), "mc.json")
-    cmd = [sys.executable, os.path.join(scripts_dir, "bench_multichip.py"),
-           "--devices", "8", "--iters", "3", "--profile-steps", "2",
-           "--out", out_path]
-    print("--- multichip smoke: " + " ".join(cmd))
-    n_errors = 0
-    # The bench refuses to run with fewer devices than asked; the virtual CPU
-    # mesh is set up here, outside it.
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    r = subprocess.run(cmd, capture_output=True, text=True, timeout=1200, env=env)
-    tail = r.stderr.strip().splitlines()[-12:]
-    for line in tail:
-        print(f"    {line}")
-    if r.returncode != 0:
-        print(f"    FAILED: bench_multichip exited {r.returncode}")
-        return 1
-    with open(out_path) as f:
-        result = json.load(f)
-
-    missing = [k for k in _MULTICHIP_REQUIRED_KEYS if k not in result]
-    if missing:
-        n_errors += 1
-        print(f"    FAILED: bench JSON missing keys: {missing}")
-    else:
-        print(f"    schema OK ({len(_MULTICHIP_REQUIRED_KEYS)} required keys)")
-
-    # Collective attribution: the profiled run must classify wire ops into
-    # per-family rows carrying the hidden/exposed split.
-    colls = result.get("collectives") or {}
-    bad = [c for c, v in colls.items()
-           if not all(k in v for k in
-                      ("us_per_step", "hidden_us_per_step",
-                       "exposed_us_per_step", "calls"))]
-    if not colls:
-        n_errors += 1
-        print("    FAILED: no collective rows in the profiled attribution "
-              "(expected all-gather/all-reduce/... on the FSDP×TP step)")
-    elif bad:
-        n_errors += 1
-        print(f"    FAILED: collective rows missing overlap fields: {bad}")
-    else:
-        print(f"    collective rows OK: {sorted(colls)} "
-              f"({result.get('spmd_collective_exposed_pct')}% of device time "
-              "exposed, SPMD lanes)")
-
-    # The explicit-collective overlap workload (scheduler + static×measured
-    # join) is diagnostic: its absence is recorded, not fatal, but a
-    # recorded failure in the smoke IS an error — the seam must work in CI.
-    if result.get("overlap_error"):
-        n_errors += 1
-        print(f"    FAILED: overlap workload errored: {result['overlap_error']}")
-    elif result.get("overlap"):
-        shown = result.get("overlap_sites_shown")
-        total = result.get("overlap_sites_total")
-        moves = (result.get("comm_schedule") or {}).get("moves", 0)
-        exp = result.get("collective_exposed_pct")
-        exp_raw = result.get("collective_exposed_pct_unscheduled")
-        if total is None or shown is None:
-            n_errors += 1
-            print("    FAILED: overlap table lacks the no-silent-caps "
-                  "site counts (overlap_sites_total/shown)")
-        elif moves < 1 or exp is None or exp_raw is None or exp >= exp_raw:
-            n_errors += 1
-            print(f"    FAILED: scheduler must move sites and cut the static "
-                  f"exposed pct (moves={moves}, {exp_raw} -> {exp})")
-        else:
-            print(f"    overlap table OK: {shown}/{total} site(s), "
-                  f"{moves} scheduler move(s), static exposed "
-                  f"{exp_raw}% -> {exp}%")
-
-    n_errors += _bench_history_gate("MULTICHIP_BENCH_r*.json")
-    print(f"\nlint_traces --multichip: {n_errors} error(s)")
-    return n_errors
 
 
 def _hlo_smoke() -> int:
@@ -1535,8 +1430,8 @@ def _ops_smoke() -> int:
 
     # Overhead: the ops plane's per-step cost is one tap per emitted event
     # (steady state: one step_time event per step). Composed against the
-    # measured step time like bench.py's obs-overhead protocol — an A/B
-    # wall-clock diff at <1% would drown in host noise.
+    # measured step time — an A/B wall-clock diff at <1% would drown in
+    # host noise.
     N = 20_000
     t0 = time.perf_counter()
     for _ in range(N):
@@ -1682,7 +1577,7 @@ def _roofline_smoke() -> int:
 
     # Overhead: the armed-but-not-due per-step cost is tick()'s counter
     # bump + modulo (maybe_sample then dispatches fn unchanged). Composed
-    # against the measured step like bench.py's obs-overhead protocol.
+    # against the measured step.
     N = 50_000
     armed = RooflineSampler(jf, every=10**9)
     t0 = time.perf_counter()
@@ -2128,7 +2023,7 @@ def _chaos_multihost_inner() -> int:
 
 
 _USAGE = ("usage: lint_traces.py [pattern] | --static | --schedule | --chaos | "
-          "--chaos-multihost | --multichip | --soak | --federation | --hlo | "
+          "--chaos-multihost | --soak | --federation | --hlo | "
           "--roofline | --critpath | --events <log.jsonl> [...] "
           "[--storm-threshold N]")
 
@@ -2172,9 +2067,6 @@ def main(argv=None) -> int:
 
     if "--chaos" in argv:
         return 1 if _chaos_smoke() else 0
-
-    if "--multichip" in argv:
-        return 1 if _multichip_smoke() else 0
 
     if "--events" in argv:
         i = argv.index("--events")
@@ -2233,12 +2125,9 @@ def main(argv=None) -> int:
             n_errors += 1
             print(f"    FAILED: {e}")
 
-    # CI half of the perf observatory (ISSUE 5/8): a committed bench round
-    # regressing beyond threshold — single-host or multichip series — fails
-    # the lint run, not just a human's eye.
+    # CI half of the perf observatory (ISSUE 5/8): a committed round
+    # regressing beyond threshold fails the lint run, not just a human's eye.
     if not pattern:
-        n_errors += _bench_history_gate()
-        n_errors += _bench_history_gate("MULTICHIP_BENCH_r*.json")
         n_errors += _bench_history_gate("SOAK_r*.json")
         n_errors += _bench_history_gate("SOAK_POD_r*.json", min_rounds=1)
         n_errors += _bench_history_gate("ROOFLINE_r*.json", min_rounds=1)

@@ -6,18 +6,17 @@ Two modes:
 **History / regression gate** — build the perf trajectory across committed
 bench rounds and flag per-metric deltas beyond thresholds::
 
-    python scripts/perf_report.py --history BENCH_r0*.json
-    python scripts/perf_report.py --history BENCH_r0*.json --gate   # CI: exit 1
-                                                                    # on un-acked regressions
-    python scripts/perf_report.py --history MULTICHIP_BENCH_r*.json --gate
+    python scripts/perf_report.py --history ROOFLINE_r0*.json
+    python scripts/perf_report.py --history ROOFLINE_r0*.json --gate   # CI: exit 1
+                                                                       # on un-acked regressions
     python scripts/perf_report.py --history SOAK_r*.json --gate
 
-The single-host (``BENCH_r*.json``, from ``bench.py``), multichip
-(``MULTICHIP_BENCH_r*.json``, from ``scripts/bench_multichip.py``), and
-soak (``SOAK_r*.json``, from ``scripts/soak_fleet.py`` — headline
-``value`` is goodput tokens/sec, gated UP-good) series are gated
+Each series (``SOAK_r*.json`` from ``scripts/soak_fleet.py`` — headline
+``value`` is goodput tokens/sec, gated UP-good — or ``SOAK_POD_r*.json``
+and ``CRITPATH_r*.json`` from ``scripts/soak_pod.py``) is gated
 separately — one invocation per glob — with the same direction-aware
-deltas, noise floors, and ack semantics.
+deltas, noise floors, and ack semantics. The driver's ``PERF_LEDGER.jsonl``
+is the record of speed on the chip; this script does not read it.
 
 Metric direction is inferred from the name (times/counts: lower is better;
 MFU/throughput/ratios-vs-baseline: higher is better); sub-noise-floor
@@ -137,9 +136,8 @@ _SOAK_POD_NOISE_FLOORS = (
 )
 
 
-# ROOFLINE_r* rounds (headline metric "roofline_*", from bench.py's
-# roofline path — ISSUE 19): the per-op ``op_<line>_<sym>_us`` /
-# ``_achieved_frac`` series. Per-op microsecond timings are the noisiest
+# ROOFLINE_r* rounds (headline metric "roofline_*" — ISSUE 19): the per-op
+# ``op_<line>_<sym>_us`` / ``_achieved_frac`` series. Per-op microsecond timings are the noisiest
 # numbers the gate sees (single-op, single-probe, tens of µs on the CPU
 # round) — the floors absorb scheduler jitter while still catching an op
 # that genuinely doubled; achieved fraction is a ratio of the same
@@ -236,7 +234,7 @@ _HEADLINE_KEYS = {"value", "vs_baseline", "tokens_per_sec", "mfu", "baseline_mfu
 def load_round(path: str) -> tuple[str, dict[str, float]]:
     """(round label, numeric metrics) from one committed bench JSON — the
     driver's ``{"n", "cmd", "rc", "tail", "parsed": {...}}`` wrapper or a
-    bare ``bench.py`` JSON line. The round's headline ``metric`` name is kept
+    bare JSON line. The round's headline ``metric`` name is kept
     under ``_metric_name`` for the comparability check."""
     with open(path) as f:
         doc = json.load(f)
@@ -332,8 +330,7 @@ def analyze_history(
 def compare_rounds(
     prev: dict[str, float], cur: dict[str, float], *, threshold: float = 0.10,
 ) -> tuple[dict[str, float], list[str]]:
-    """One-transition comparison used by ``bench.py`` against the newest
-    committed round: ``(deltas, regressions)`` where ``deltas`` maps each
+    """One-transition comparison against the newest committed round: ``(deltas, regressions)`` where ``deltas`` maps each
     gated metric to its signed relative change and ``regressions`` holds
     human-readable strings for changes beyond ``threshold`` in the bad
     direction (noise floors applied)."""

@@ -15,8 +15,8 @@ correlation rules), and its headline is **goodput**:
 where ``useful_tokens`` counts each of the N steps once (re-executed steps
 after a restore are waste, paid in ``wall_s``), ``wall_s`` is the whole
 soak wall clock including every recovery/rebuild/restore, and the overhead
-pct is the directly-measured steady-state cost of the watchdog + SDC guard
-(the ``bench_multichip --resilience-overhead`` protocol). One number that
+pct is the directly-measured steady-state cost of the watchdog + SDC
+guard. One number that
 only improves if speed AND resilience hold simultaneously.
 
 Output: one JSON line (the committed ``SOAK_r*.json`` series), gated by
@@ -341,8 +341,8 @@ def _build_workload(args):
 
 
 def _measure_overheads(step_fn, state, mesh, n: int = 6):
-    """(ideal tokens-per-step denominator, resilience_overhead_pct): the
-    bench_multichip --resilience-overhead protocol — median clean step,
+    """(ideal tokens-per-step denominator, resilience_overhead_pct):
+    median clean step,
     median SDC checksum, median watchdog spawn, overhead measured directly
     (loop-vs-loop deltas drown in CPU-mesh jitter)."""
     from thunder_tpu.resilience.watchdog import SDCGuard, guard_call
@@ -802,7 +802,7 @@ def main(argv=None) -> int:
 
     if len(jax.devices()) < args.devices and not args._subprocess:
         # Backend already initialized with fewer devices: re-exec on the
-        # virtual CPU mesh (the bench_multichip pattern).
+        # virtual CPU mesh.
         import subprocess
 
         env = {

@@ -119,7 +119,7 @@ class TestCompileCachePlacement:
 
 class TestPeakLookup:
     def test_known_kinds(self):
-        from thunder_tpu.benchmarks import peak_tflops, tpu_generation
+        from thunder_tpu.core.devices import peak_tflops, tpu_generation
 
         assert tpu_generation("TPU v5 lite") == "v5e"
         assert tpu_generation("TPU v5e") == "v5e"
@@ -130,7 +130,7 @@ class TestPeakLookup:
 
     @pytest.mark.parametrize("kind", ["cpu", "TPU v9", "NVIDIA A100", ""])
     def test_unknown_kind_raises(self, kind):
-        from thunder_tpu.benchmarks import peak_tflops, tpu_generation
+        from thunder_tpu.core.devices import peak_tflops, tpu_generation
 
         with pytest.raises(ValueError, match="no peak is recorded"):
             tpu_generation(kind)
@@ -142,6 +142,46 @@ class TestPeakLookup:
 
         assert resolve_device_spec(None).name == "cpu"
         assert resolve_device_spec("cpu").name == "cpu"
+
+    @pytest.mark.parametrize("kind,gen,vmem_mib", [("TPU v4", "v4", 16), ("TPU v5 lite", "v5e", 128),
+                                                    ("TPU v5p", "v5p", 64), ("TPU v6 lite", "v6e", 128)])
+    def test_one_row_a_generation(self, monkeypatch, kind, gen, vmem_mib):
+        """core/devices.py is the program's one table: the executor sizes its
+        blocks by the row's VMEM, and the cost model prices by its peak."""
+        from thunder_tpu.analysis.cost import DEVICE_SPECS
+        from thunder_tpu.core.devices import TPU_SPECS, peak_tflops
+        from thunder_tpu.executors import pallasex
+
+        assert TPU_SPECS[gen].vmem_bytes == vmem_mib * 1024 * 1024
+        monkeypatch.setattr(pallasex, "_device_kind", lambda: kind)
+        assert pallasex._ce_vmem_limit() == max(16, vmem_mib // 2) * 1024 * 1024
+        assert peak_tflops(kind) == DEVICE_SPECS[gen].peak_flops["bf16"] / 1e12
+
+    def test_the_table_covers_the_cost_models_tpus(self):
+        from thunder_tpu.analysis.cost import DEVICE_SPECS
+        from thunder_tpu.core.devices import TPU_SPECS
+
+        assert set(TPU_SPECS) == set(DEVICE_SPECS) - {"a100", "cpu"}
+
+    def test_the_benchmarks_table_agrees(self):
+        """perfbench/peaks.json is the benchmark's own table (the ledger's
+        ``mfu``); every device_kind it has reads the same peak here."""
+        from thunder_tpu.core.devices import peak_tflops
+
+        with open(os.path.join(REPO, "perfbench", "peaks.json")) as f:
+            peaks = {k: v for k, v in json.load(f).items() if not k.startswith("_")}
+        assert peaks
+        for kind, row in peaks.items():
+            assert peak_tflops(kind) == row["bf16_flops_per_s"] / 1e12
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v9"])
+    def test_an_unknown_kind_gets_the_default_scope_and_no_benchmark_module(self, monkeypatch, kind):
+        from thunder_tpu.executors import pallasex
+
+        monkeypatch.setattr(pallasex, "_device_kind", lambda: kind)
+        assert pallasex._ce_vmem_limit() == 16 * 1024 * 1024
+        # the executors sit below anything that measures them
+        assert not [m for m in sys.modules if "benchmarks" in m and m.startswith("thunder_tpu")]
 
 
 @pytest.mark.parametrize("axes,rotary", [(None, 1.0), ({"fsdp": 4}, 1.0), ({"dp": 4}, 1.0),

@@ -661,69 +661,3 @@ class TestPallasRope:
         want = thunder_tpu.jit(f, executors=jax_only)(x, cos, sin)
         assert got.dtype == want.dtype
         np.testing.assert_allclose(_f32(got), _f32(want), rtol=3e-2, atol=4e-2)
-
-
-class TestNormExecutor:
-    """Opt-in fused RMSNorm executor (reference seat: cudnn_layernormex.py:134).
-    Registered but NOT default: on TPU, XLA's fused decomposition measured
-    FASTER than the pallas kernel on the 3B bench (see pallasex.py) — the
-    seat exists for parity and for workloads where the tradeoff differs."""
-
-    def test_opt_in_claims_and_matches(self):
-        import jax.numpy as jnp
-
-        rng = np.random.RandomState(0)
-        x = jnp.asarray(rng.randn(16, 256).astype(np.float32), dtype=jnp.bfloat16)
-        w = jnp.asarray((rng.randn(256) * 0.1 + 1.0).astype(np.float32), dtype=jnp.bfloat16)
-
-        f = lambda x, w: ttorch.rms_norm(x, (256,), w, eps=1e-6)
-        fast = thunder_tpu.jit(f, executors=["norm", "jax"])
-        got = _f32(fast(x, w))
-        assert "norm_rms_norm" in thunder_tpu.last_traces(fast)[-1].python()
-        want = _f32(thunder_tpu.jit(f, executors=jax_only)(x, w))
-        np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
-
-        # default executors do NOT claim (measured regression)
-        dflt = thunder_tpu.jit(f)
-        dflt(x, w)
-        assert "norm_rms_norm" not in thunder_tpu.last_traces(dflt)[-1].python()
-
-    def test_bwd_claims_and_matches(self):
-        import jax.numpy as jnp
-
-        rng = np.random.RandomState(1)
-        x = jnp.asarray(rng.randn(16, 256).astype(np.float32), dtype=jnp.bfloat16)
-        w = jnp.asarray((rng.randn(256) * 0.1 + 1.0).astype(np.float32), dtype=jnp.bfloat16)
-
-        def loss(x, w):
-            return ttorch.sum(ttorch.rms_norm(x, (256,), w, eps=1e-6).float() ** 2)
-
-        vgf = thunder_tpu.value_and_grad(loss, executors=["norm", "jax"])
-        vgs = thunder_tpu.value_and_grad(loss, executors=jax_only)
-        lf, gf = vgf(x, w)
-        ls, gs = vgs(x, w)
-        assert "norm_rms_norm_bwd" in thunder_tpu.last_traces(vgf)[-1].python()
-        np.testing.assert_allclose(float(lf), float(ls), rtol=2e-2)
-        np.testing.assert_allclose(_f32(gf[0]), _f32(gs[0]), rtol=5e-2, atol=5e-2)
-        np.testing.assert_allclose(_f32(gf[1]), _f32(gs[1]), rtol=5e-2, atol=5e-1)
-
-    def test_layer_norm_opt_in(self):
-        import jax.numpy as jnp
-
-        rng = np.random.RandomState(2)
-        x = jnp.asarray(rng.randn(16, 256).astype(np.float32), dtype=jnp.bfloat16)
-        w = jnp.asarray((rng.randn(256) * 0.1 + 1.0).astype(np.float32), dtype=jnp.bfloat16)
-        b = jnp.asarray((rng.randn(256) * 0.1).astype(np.float32), dtype=jnp.bfloat16)
-
-        def loss(x, w, b):
-            return ttorch.sum(ttorch.layer_norm(x, (256,), w, b, eps=1e-5).float() ** 2)
-
-        vgf = thunder_tpu.value_and_grad(loss, executors=["norm", "jax"])
-        vgs = thunder_tpu.value_and_grad(loss, executors=jax_only)
-        lf, gf = vgf(x, w, b)
-        ls, gs = vgs(x, w, b)
-        src = thunder_tpu.last_traces(vgf)[-1].python()
-        assert "norm_layer_norm" in src and "norm_layer_norm_bwd" in src
-        np.testing.assert_allclose(float(lf), float(ls), rtol=2e-2)
-        for n, a, bb in zip(["dx", "dw", "db"], gf, gs):
-            np.testing.assert_allclose(_f32(a), _f32(bb), rtol=5e-2, atol=5e-1, err_msg=n)

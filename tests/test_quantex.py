@@ -129,11 +129,7 @@ class TestQuantTraining:
 
 class TestQuantizedTraining:
     """TE-seat capability evidence (reference: transformer_engineex.py:398-423
-    actually trains): int8-forward training converges on a small model, and
-    the r4 bench CLI records the 3B datapoint (open_llama_3b, 10 iters, v5e:
-    bf16 0.774 s/iter MFU 0.552 loss→6.62; quant 0.709 s/iter MFU 0.603
-    loss→7.23 — `python -m thunder_tpu.benchmarks.litgpt --model
-    open_llama_3b --optimizer sgd --executors quant,flash,pallas,jax`)."""
+    actually trains): int8-forward training converges on a small model."""
 
     def test_small_model_converges(self):
         import jax.numpy as jnp

@@ -1,10 +1,5 @@
-"""Tooling: examine, memory estimator, benchmark harness, checkpointing,
-trace dump (reference: thunder/examine tests + benchmark harness usage)."""
-
-import json
-import os
-import subprocess
-import sys
+"""Tooling: examine, memory estimator, checkpointing, trace dump
+(reference: thunder/examine tests)."""
 
 import numpy as np
 import pytest
@@ -59,34 +54,6 @@ class TestExamine:
         inputs_bytes = x.nbytes + w.nbytes
         assert peak >= inputs_bytes + 128 * 256 * 4
         assert peak < inputs_bytes + 2 * 128 * 256 * 4 + 4096
-
-
-class TestBenchmarkHarness:
-    def test_run_benchmark(self):
-        import jax.numpy as jnp
-
-        from thunder_tpu.benchmarks import run_benchmark
-
-        x = jnp.ones((128, 128))
-        r = run_benchmark("matmul", lambda: x @ x, warmup=1, iters=3,
-                          tokens_per_iter=128, flops_per_iter=2 * 128**3)
-        s = r.summary()
-        assert s["iters"] == 3 and s["median_iter_time_s"] > 0
-        assert "tokens_per_sec" in s and "mfu" in s
-
-    def test_litgpt_cli(self):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        r = subprocess.run(
-            [sys.executable, "-m", "thunder_tpu.benchmarks.litgpt",
-             "--model", "gpt-tiny", "--micro-batch", "2", "--seq", "32",
-             "--iters", "2", "--warmup", "1"],
-            capture_output=True, text=True, timeout=420, env=env,
-        )
-        assert r.returncode == 0, r.stderr[-2000:]
-        summary = json.loads(r.stdout.strip().splitlines()[-1])
-        assert summary["tokens_per_sec"] > 0
-        assert summary["n_params"] > 0
 
 
 class TestCheckpoint:
@@ -268,22 +235,3 @@ class TestExamineFullReport:
         m = nn.Sequential(nn.Linear(8, 8), nn.GELU())
         r = examine(m, torch.randn(2, 8))
         assert r["supported"] and r["unsupported_ops"] == []
-
-
-class TestExecutorMatrix:
-    def test_litgpt_matrix_markdown(self):
-        """VERDICT r4 missing #1: executor-matrix comparison mode — the
-        analogue of the reference's eager/inductor/thunder columns."""
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        r = subprocess.run(
-            [sys.executable, "-m", "thunder_tpu.benchmarks.litgpt",
-             "--model", "gpt-tiny", "--micro-batch", "2", "--seq", "32",
-             "--iters", "2", "--warmup", "1", "--matrix", "--markdown"],
-            capture_output=True, text=True, timeout=540, env=env,
-        )
-        assert r.returncode == 0, r.stderr[-2000:]
-        table = r.stdout
-        assert "| executors |" in table and "| jax |" in table
-        # at least the jax baseline and the default stack must have run
-        assert "+pallas (default)" in table, table

@@ -45,6 +45,7 @@ import os
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence
 
+from thunder_tpu.core.devices import TPU_SPECS, tpu_generation
 from thunder_tpu.core.prims import OpTags, PrimIDs
 from thunder_tpu.core.proxies import TensorProxy, pyval
 from thunder_tpu.core.trace import TraceCtx
@@ -116,6 +117,10 @@ def _dtype_class(dtype: Any) -> str:
     return "bf16" if nbytes <= 2 else "f32"
 
 
+def _tpu_bf16(gen: str) -> float:
+    return TPU_SPECS[gen].peak_bf16_tflops * 1e12  # the bf16 peak has one home: core/devices.py
+
+
 # Datasheet peaks. f32 on TPU runs through the MXU at roughly half bf16
 # throughput (XLA splits f32 matmuls); "cpu" is a deliberately small spec so
 # host-platform tests still classify sensibly.
@@ -124,13 +129,13 @@ def _dtype_class(dtype: Any) -> str:
 # below ICI everywhere. These drive the federated-mesh roofline (ISSUE 18),
 # not any single-slice number.
 DEVICE_SPECS: dict[str, DeviceSpec] = {
-    "v5e": DeviceSpec("v5e", {"bf16": 197e12, "f32": 98.5e12, "int8": 394e12},
+    "v5e": DeviceSpec("v5e", {"bf16": _tpu_bf16("v5e"), "f32": 98.5e12, "int8": 394e12},
                       hbm_bw=819e9, ici_bw=186e9, dcn_bw=6.25e9, hbm_bytes=16e9),
-    "v5p": DeviceSpec("v5p", {"bf16": 459e12, "f32": 229.5e12, "int8": 918e12},
+    "v5p": DeviceSpec("v5p", {"bf16": _tpu_bf16("v5p"), "f32": 229.5e12, "int8": 918e12},
                       hbm_bw=2765e9, ici_bw=600e9, dcn_bw=25e9, hbm_bytes=95e9),
-    "v4": DeviceSpec("v4", {"bf16": 275e12, "f32": 137.5e12, "int8": 275e12},
+    "v4": DeviceSpec("v4", {"bf16": _tpu_bf16("v4"), "f32": 137.5e12, "int8": 275e12},
                      hbm_bw=1228e9, ici_bw=300e9, dcn_bw=6.25e9, hbm_bytes=32e9),
-    "v6e": DeviceSpec("v6e", {"bf16": 918e12, "f32": 459e12, "int8": 1836e12},
+    "v6e": DeviceSpec("v6e", {"bf16": _tpu_bf16("v6e"), "f32": 459e12, "int8": 1836e12},
                       hbm_bw=1640e9, ici_bw=448e9, dcn_bw=12.5e9, hbm_bytes=32e9),
     "a100": DeviceSpec("a100", {"bf16": 312e12, "f32": 19.5e12, "int8": 624e12},
                        hbm_bw=1555e9, ici_bw=600e9, dcn_bw=25e9, hbm_bytes=80e9),
@@ -155,8 +160,7 @@ def calibrate_ici(spec: DeviceSpec, samples: Sequence[tuple]) -> DeviceSpec:
 
     ``samples``: ``(cls, comm_bytes, measured_s)`` rows — the cost model's
     ring-factor wire bytes for a collective joined with its measured device
-    time (``scripts/bench_multichip.py`` feeds the lane-segmentation table).
-    The fit is the aggregate rate per family, ``Σ bytes / Σ seconds``,
+    time. The fit is the aggregate rate per family, ``Σ bytes / Σ seconds``,
     clamped to the datasheet ``ici_bw`` from above (a measurement can only
     reveal the wire to be *slower* than the link rate). Returns a new spec
     whose :meth:`DeviceSpec.ici_bw_for` prices each family at its fitted
@@ -186,8 +190,8 @@ def resolve_device_spec(device: Any = None) -> DeviceSpec:
     """A :class:`DeviceSpec` from a spec object, a table name, or None
     (autodetect: the ``cpu`` spec, by name, when the local platform is cpu —
     the analysis tests price traces there — else the chip from
-    ``thunder_tpu.benchmarks.tpu_generation()``, the one ``device_kind``
-    lookup the bench uses too). A device that is in neither table raises,
+    ``core.devices.tpu_generation()``, the one ``device_kind`` lookup of
+    the program). A device that is in neither table raises,
     named or autodetected: a guessed peak makes every bound wrong."""
     if isinstance(device, DeviceSpec):
         return device
@@ -200,8 +204,6 @@ def resolve_device_spec(device: Any = None) -> DeviceSpec:
             )
         return spec
     import jax
-
-    from thunder_tpu.benchmarks import tpu_generation
 
     if jax.devices()[0].platform == "cpu":
         return DEVICE_SPECS["cpu"]

@@ -33,9 +33,7 @@ Pipeline:
    the roofline compute between the site and its first consumer is the
    overlap window; windows share a per-op budget so two sites never claim
    the same fusion. The resulting :class:`HloScheduleReport` carries
-   ``exposed_pct`` — committed by ``scripts/bench_multichip.py`` as
-   ``spmd_collective_exposed_pct_static``, the baseline number ROADMAP
-   item 3's scheduling-hints work is measured against.
+   ``exposed_pct``.
 
 Advisory by construction: the ``hlo.*`` verifier rules report INFO/WARNING
 only, and the ``api.py`` compile phase wraps the whole audit in a

@@ -516,7 +516,7 @@ def _compile_entry_checked(
 # compile_phase span for an entry's first run says whether the seconds went
 # to a real backend compile (cache miss) or a cache-entry deserialize (hit)
 # — the distinction that explains 2x swings in xla-compile totals between
-# otherwise identical rounds (BENCHMARKS.md, r4→r5 diagnosis).
+# otherwise identical runs.
 _jax_cache_events = {
     "hits": 0, "misses": 0, "backend_compile_s": 0.0, "cache_get_s": 0.0,
     "installed": False,
@@ -1829,7 +1829,7 @@ def jit(
                 cs.last_trace_host_stop = timer_ns()
                 if obsm.enabled():
                     # Single flag check on the warm path when metrics are off
-                    # (BENCHMARKS.md budgets: <1% off, <5% on).
+                    # (budgets: <1% off, <5% on).
                     obsm.CACHE_HITS.inc(kind=hit_kind)
                     obsm.CACHE_LOOKUP_US.observe(
                         (cs.last_trace_cache_stop - cs.last_trace_cache_start) / 1e3

@@ -11,7 +11,7 @@ meshes.
 from __future__ import annotations
 
 import enum
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 
 class DeviceType(enum.Enum):
@@ -111,3 +111,60 @@ def to_device(x: Any) -> Optional[Device]:
 
 
 cpu = Device("cpu")
+
+
+# -- what the chip has -------------------------------------------------------
+
+
+class TpuSpec(NamedTuple):
+    peak_bf16_tflops: float  # per chip
+    vmem_bytes: int  # per core
+
+
+# The one table of the program, a row per TPU generation. Peaks: Google Cloud
+# TPU documentation, the "TPU v4", "TPU v5e", "TPU v5p" and "TPU v6e"
+# system-architecture pages. VMEM: what jax's pltpu.get_tpu_info reports (for
+# the default device alone). perfbench/peaks.json is the benchmark's own table;
+# tests/test_chip_smoke.py holds the two equal.
+TPU_SPECS = {
+    "v4": TpuSpec(275.0, 16 * 1024 * 1024),
+    "v5e": TpuSpec(197.0, 128 * 1024 * 1024),
+    "v5p": TpuSpec(459.0, 64 * 1024 * 1024),
+    "v6e": TpuSpec(918.0, 128 * 1024 * 1024),
+}
+
+# jax's ``device_kind`` (lower-cased, spaces dropped) -> generation; the
+# first match wins, so "v5lite" is tested before "v5".
+_DEVICE_KINDS = (("v5lite", "v5e"), ("v5e", "v5e"), ("v6lite", "v6e"), ("v6e", "v6e"),
+                 ("v5p", "v5p"), ("v5", "v5p"), ("v4", "v4"))
+
+
+def tpu_generation(device_kind: Optional[str] = None) -> str:
+    """Generation name ("v5e", ...) of ``device_kind`` (default: the first
+    device jax reports). A device that is not in the table raises: a peak
+    that is guessed makes every utilization wrong."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    kind = device_kind.lower().replace(" ", "")
+    if "tpu" in kind:
+        for needle, gen in _DEVICE_KINDS:
+            if needle in kind:
+                return gen
+    raise ValueError(
+        f"no peak is recorded for device_kind {device_kind!r}; known TPU generations: "
+        f"{sorted(TPU_SPECS)} (add the chip to thunder_tpu/core/devices.py)"
+    )
+
+
+def peak_tflops(device_kind: Optional[str] = None) -> float:
+    return TPU_SPECS[tpu_generation(device_kind)].peak_bf16_tflops
+
+
+def device_description() -> dict:
+    """The device as jax reports it — what every result line names."""
+    import jax
+
+    devices = jax.devices()
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind, "count": len(devices)}

@@ -42,11 +42,8 @@ Under a device mesh (``parallel.build_train_step(mesh=...)``) every kernel
 call runs per batch shard inside ``jax.shard_map`` (executors/kernel_mesh.py):
 the SPMD partitioner cannot split a Mosaic custom call.
 
-Tuning knobs (env): THUNDER_FLASH_BQ/BKV/BQ_DKV/BKV_DKV,
-THUNDER_FLASH_FUSED_BWD=1|0.
-Block-size defaults (1024) were measured end-to-end on v5e: open_llama_3b
-train iter 0.6979 (512) -> 0.6950 s (1024); fwd 1.1647 -> 1.1546 s (r4
-ablations; 2048 regressed to 0.7080).
+Every block is ``_BLOCK`` (1024) and the backward is splash's fused kernel:
+the one sweep on the chip found nothing at 512 (PERF.md section 7).
 """
 
 from __future__ import annotations
@@ -68,14 +65,7 @@ add_default_executor(ex, front=True)
 
 _PAD = 128  # sequence alignment quantum (Mosaic lane width)
 _NEG_BIG = -1e9  # additive-mask entries at or below this count as "masked"
-
-
-def _blk(name: str, dflt: int) -> int:
-    return int(os.environ.get(name, dflt))
-
-
-def _fused_bwd() -> bool:
-    return os.environ.get("THUNDER_FLASH_FUSED_BWD", "1") == "1"
+_BLOCK = 1024  # every splash block, forward and backward, fitted to the sequence
 
 
 def _interpret() -> bool:
@@ -194,8 +184,8 @@ def _bwd_checker(g, query, key, value, attn_mask=None, is_causal=False, scale=No
 # =============================================================================
 
 
-def _fit_block(pref: int, t: int) -> int:
-    b = min(pref, t)
+def _fit_block(t: int) -> int:
+    b = min(_BLOCK, t)
     b -= b % _PAD
     b = max(b, _PAD)
     while t % b:
@@ -205,19 +195,17 @@ def _fit_block(pref: int, t: int) -> int:
 
 @lru_cache(maxsize=64)
 def _splash_kernel(H: int, Tq: int, Tkv: int, causal: bool, offset: int, interpret: bool,
-                   bq: int, bkv: int, bqd: int, bkd: int, fused: bool, downcast: bool,
-                   save_res: bool = False):
+                   downcast: bool, save_res: bool = False):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
         splash_attention_mask as sm,
     )
 
+    bq, bkv = _fit_block(Tq), _fit_block(Tkv)
     block_sizes = sk.BlockSizes(
         block_q=bq, block_kv=bkv, block_kv_compute=bkv,
-        block_q_dkv=bqd, block_kv_dkv=bkd, block_kv_dkv_compute=bkd,
-        block_q_dq=None if fused else bqd,
-        block_kv_dq=None if fused else bkd,
-        use_fused_bwd_kernel=fused,
+        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv,
+        use_fused_bwd_kernel=True,
     )
     if causal:
         head_mask = sm.CausalMask((Tq, Tkv), offset=offset)
@@ -265,11 +253,6 @@ def _splash_sdpa(q, k, v, *, causal: bool, scale: float, kv_valid=None, q_valid=
     Tqp, Tkvp = Tq + pq, Tkv + pkv
     kernel = _splash_kernel(
         H, Tqp, Tkvp, causal, off, _interpret(),
-        _fit_block(_blk("THUNDER_FLASH_BQ", 1024), Tqp),
-        _fit_block(_blk("THUNDER_FLASH_BKV", 1024), Tkvp),
-        _fit_block(_blk("THUNDER_FLASH_BQ_DKV", 1024), Tqp),
-        _fit_block(_blk("THUNDER_FLASH_BKV_DKV", 1024), Tkvp),
-        _fused_bwd(),
         # bf16 data is already narrow; keep f32 inputs at full precision in
         # SMEM (the downcast costs ~1e-3 abs error on f32 workloads).
         q.dtype == jnp.bfloat16,
@@ -497,11 +480,6 @@ def _splash_fwd_res(q, k, v, *, causal: bool, scale: float):
     Tkv = k.shape[-2]
     kernel = _splash_kernel(
         H, Tq, Tkv, causal, Tkv - Tq, _interpret(),
-        _fit_block(_blk("THUNDER_FLASH_BQ", 1024), Tq),
-        _fit_block(_blk("THUNDER_FLASH_BKV", 1024), Tkv),
-        _fit_block(_blk("THUNDER_FLASH_BQ_DKV", 1024), Tq),
-        _fit_block(_blk("THUNDER_FLASH_BKV_DKV", 1024), Tkv),
-        _fused_bwd(),
         q.dtype == jnp.bfloat16,
         True,
     )
@@ -535,11 +513,6 @@ def _sdpa_bwd_res_impl(g, query, key, value, out, lse, attn_mask=None, is_causal
 
     kernel = _splash_kernel(
         H, Tq, Tkv, bool(is_causal), Tkv - Tq, _interpret(),
-        _fit_block(_blk("THUNDER_FLASH_BQ", 1024), Tq),
-        _fit_block(_blk("THUNDER_FLASH_BKV", 1024), Tkv),
-        _fit_block(_blk("THUNDER_FLASH_BQ_DKV", 1024), Tq),
-        _fit_block(_blk("THUNDER_FLASH_BKV_DKV", 1024), Tkv),
-        _fused_bwd(),
         query.dtype == jnp.bfloat16,
         False,
     )

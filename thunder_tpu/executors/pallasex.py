@@ -22,8 +22,8 @@ like the reference's executor checkers.
 
 Under a device mesh every ``pallas_call`` runs per batch shard inside
 ``jax.shard_map`` (executors/kernel_mesh.py), its blocks sized on the
-shard's rows; reductions over rows (the loss sum, the norm weight grads)
-stay outside, where the partitioner turns them into collectives.
+shard's rows; reductions over rows (the loss sum) stay outside, where the
+partitioner turns them into collectives.
 """
 
 from __future__ import annotations
@@ -31,6 +31,7 @@ from __future__ import annotations
 from functools import partial
 
 from thunder_tpu.core import dtypes
+from thunder_tpu.core.devices import TPU_SPECS, tpu_generation
 from thunder_tpu.core.proxies import pyval
 from thunder_tpu.executors.kernel_mesh import batch_shards, per_batch_shard
 from thunder_tpu.extend import OperatorExecutor, add_default_executor, register_executor
@@ -55,8 +56,6 @@ def _interpret() -> bool:
 # their bytes (2.22 and 5.06 ms) from 16 rows on.
 _CE_CHUNK = 4096  # lanes a step of the walk over the vocabulary; not by dtype, so every dtype sums in one order
 _SCOPED_VMEM_DEFAULT = 16 * 1024 * 1024  # what Mosaic gives a call that asks for nothing, on every generation
-# A core's VMEM by generation (jax's pltpu.get_tpu_info has these numbers, for the default device alone).
-_VMEM_BYTES = {"v4": 16 * 1024 * 1024, "v5e": 128 * 1024 * 1024, "v5p": 64 * 1024 * 1024, "v6e": 128 * 1024 * 1024}
 
 
 def _device_kind() -> str:
@@ -71,10 +70,8 @@ def _ce_vmem_limit() -> int:
     generation the table lacks (the CPU's interpret mode, a newer chip) gets
     the default scope: every TPU holds that, and the kernels lived in it
     before they walked the vocabulary."""
-    from thunder_tpu.benchmarks import tpu_generation
-
     try:
-        return max(_SCOPED_VMEM_DEFAULT, _VMEM_BYTES[tpu_generation(_device_kind())] // 2)
+        return max(_SCOPED_VMEM_DEFAULT, TPU_SPECS[tpu_generation(_device_kind())].vmem_bytes // 2)
     except ValueError:  # no TPU generation of the table
         return _SCOPED_VMEM_DEFAULT
 
@@ -470,271 +467,3 @@ def _moe_experts_impl(x, top_i, top_w, w_gate, w_up, w_down, expert_offset=0, n_
 
 
 ex.register_implementation("torch.moe_experts", fn=_moe_experts_impl, checker=_moe_experts_checker)
-
-
-# =============================================================================
-# Fused RMSNorm (fwd + bwd) — OPT-IN executor "norm"
-# =============================================================================
-#
-# Reference seat: the cudnn fused-norm executor (cudnn_layernormex.py:134).
-# MEASURED (r4, open_llama_3b on v5e): claiming these by default REGRESSES
-# the bench — fwd 1.1197→1.1398 s, train 0.6808→0.6900 s/iter — because XLA
-# fuses the decomposed norm into its matmul neighbors, which a pallas_call
-# boundary forbids. The seat therefore exists as an opt-in executor
-# (``executors=["norm", ...]``), mirroring quantex's registered-not-default
-# posture, with this measurement as the justification.
-
-
-_NORM_BT = 256
-
-
-def _rms_shapes_ok(a, weight) -> bool:
-    if len(getattr(a, "shape", ())) < 2:
-        return False
-    D = a.shape[-1]
-    if D % _LANE != 0:
-        return False
-    n_rows = 1
-    for s in a.shape[:-1]:
-        n_rows *= int(s)
-    return n_rows % (8 * batch_shards()) == 0 and weight is not None and tuple(weight.shape) == (D,)
-
-
-def _rms_fwd_checker(a, normalized_shape, weight=None, eps=None):
-    return len(tuple(normalized_shape)) == 1 and _rms_shapes_ok(a, weight)
-
-
-def _rms_bwd_checker(g, a, weight, eps):
-    return _rms_shapes_ok(a, weight)
-
-
-def _rms_fwd_kernel(x_ref, w_ref, out_ref, *, eps: float):
-    import jax
-    import jax.numpy as jnp
-
-    x = x_ref[...].astype(jnp.float32)
-    ms = jnp.mean(x * x, axis=-1, keepdims=True)
-    y = x * jax.lax.rsqrt(ms + eps) * w_ref[...].astype(jnp.float32)
-    out_ref[...] = y.astype(out_ref.dtype)
-
-
-def _rms_bwd_kernel(g_ref, x_ref, w_ref, dx_ref, dwp_ref, *, eps: float):
-    import jax
-    import jax.numpy as jnp
-
-    x = x_ref[...].astype(jnp.float32)
-    g = g_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
-    ms = jnp.mean(x * x, axis=-1, keepdims=True)
-    rstd = jax.lax.rsqrt(ms + eps)
-    xhat = x * rstd
-    wg = g * w
-    dot = jnp.mean(wg * xhat, axis=-1, keepdims=True)
-    dx_ref[...] = (rstd * (wg - xhat * dot)).astype(dx_ref.dtype)
-    # dw partial: (8, D) block (TPU sublane tiling); the sum lands in row 0
-    part = jnp.sum(g * xhat, axis=0, keepdims=True)
-    rows = jax.lax.broadcasted_iota(jnp.int32, dwp_ref.shape, dimension=0)
-    dwp_ref[...] = jnp.where(rows == 0, part, 0.0)
-
-
-def _norm_bt(n_rows: int, d: int) -> int:
-    bt = _NORM_BT
-    # VMEM budget: ~3 row-blocks live in f32 plus outputs; stay well under
-    # the 16 MB scoped limit (measured OOM at bt=256, D=3200).
-    while bt > 8 and bt * d * 4 * 5 > 10_000_000:
-        bt //= 2
-    while n_rows % bt:
-        bt //= 2
-    return max(bt, 1)
-
-
-def _rms_impl(a, normalized_shape, weight=None, eps=None):
-    chaos.kernel_seam("norm", "rms_norm")
-    import jax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    e = 1e-6 if eps is None else float(eps)
-    D = a.shape[-1]
-
-    def rows(xf, w2):
-        N = xf.shape[0]
-        bt = _norm_bt(N, D)
-        return pl.pallas_call(
-            partial(_rms_fwd_kernel, eps=e),
-            grid=(N // bt,),
-            in_specs=[
-                pl.BlockSpec((bt, D), lambda i: (i, 0), memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, D), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec((bt, D), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((N, D), a.dtype),
-            interpret=_interpret(),
-        )(xf, w2)
-
-    with jax.enable_x64(False):
-        out = per_batch_shard(rows, a.reshape(-1, D), weight.reshape(1, D), replicated=(1,))
-    return out.reshape(a.shape)
-
-
-def _rms_bwd_impl(g, a, weight, eps):
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    e = float(eps)
-    D = a.shape[-1]
-
-    def rows(gf, xf, w2):
-        N = xf.shape[0]
-        bt = _norm_bt(N, D)
-        return pl.pallas_call(
-            partial(_rms_bwd_kernel, eps=e),
-            grid=(N // bt,),
-            in_specs=[
-                pl.BlockSpec((bt, D), lambda i: (i, 0), memory_space=pltpu.VMEM),
-                pl.BlockSpec((bt, D), lambda i: (i, 0), memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, D), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            ],
-            out_specs=[
-                pl.BlockSpec((bt, D), lambda i: (i, 0), memory_space=pltpu.VMEM),
-                pl.BlockSpec((8, D), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((N, D), a.dtype),
-                jax.ShapeDtypeStruct((8 * (N // bt), D), jnp.float32),
-            ],
-            interpret=_interpret(),
-        )(gf, xf, w2)
-
-    with jax.enable_x64(False):
-        dx, dwp = per_batch_shard(
-            rows, g.reshape(-1, D), a.reshape(-1, D), weight.reshape(1, D), replicated=(2,))
-    dw = jnp.sum(dwp, axis=0).astype(weight.dtype)
-    return dx.reshape(a.shape), dw
-
-
-def _ln_fwd_checker(a, normalized_shape, weight=None, bias=None, eps=1e-5):
-    return len(tuple(normalized_shape)) == 1 and _rms_shapes_ok(a, weight)
-
-
-def _ln_bwd_checker(g, a, weight, bias, eps):
-    return _rms_shapes_ok(a, weight)
-
-
-def _ln_fwd_kernel(x_ref, w_ref, b_ref, out_ref, *, eps: float, has_bias: bool):
-    import jax
-    import jax.numpy as jnp
-
-    x = x_ref[...].astype(jnp.float32)
-    mu = jnp.mean(x, axis=-1, keepdims=True)
-    xc = x - mu
-    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
-    y = xc * jax.lax.rsqrt(var + eps) * w_ref[...].astype(jnp.float32)
-    if has_bias:
-        y = y + b_ref[...].astype(jnp.float32)
-    out_ref[...] = y.astype(out_ref.dtype)
-
-
-def _ln_bwd_kernel(g_ref, x_ref, w_ref, dx_ref, dwp_ref, dbp_ref, *, eps: float):
-    import jax
-    import jax.numpy as jnp
-
-    x = x_ref[...].astype(jnp.float32)
-    g = g_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
-    mu = jnp.mean(x, axis=-1, keepdims=True)
-    xc = x - mu
-    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
-    rstd = jax.lax.rsqrt(var + eps)
-    xhat = xc * rstd
-    wg = g * w
-    m1 = jnp.mean(wg, axis=-1, keepdims=True)
-    m2 = jnp.mean(wg * xhat, axis=-1, keepdims=True)
-    dx_ref[...] = (rstd * (wg - m1 - xhat * m2)).astype(dx_ref.dtype)
-    rows = jax.lax.broadcasted_iota(jnp.int32, dwp_ref.shape, dimension=0)
-    dwp_ref[...] = jnp.where(rows == 0, jnp.sum(g * xhat, axis=0, keepdims=True), 0.0)
-    dbp_ref[...] = jnp.where(rows == 0, jnp.sum(g, axis=0, keepdims=True), 0.0)
-
-
-def _ln_impl(a, normalized_shape, weight=None, bias=None, eps=1e-5):
-    chaos.kernel_seam("norm", "layer_norm")
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    e = float(eps)
-    D = a.shape[-1]
-    has_bias = bias is not None
-    b2 = bias.reshape(1, D) if has_bias else jnp.zeros((1, D), dtype=a.dtype)
-
-    def rows(xf, w2, b2):
-        N = xf.shape[0]
-        bt = _norm_bt(N, D)
-        return pl.pallas_call(
-            partial(_ln_fwd_kernel, eps=e, has_bias=has_bias),
-            grid=(N // bt,),
-            in_specs=[
-                pl.BlockSpec((bt, D), lambda i: (i, 0), memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, D), lambda i: (0, 0), memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, D), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec((bt, D), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((N, D), a.dtype),
-            interpret=_interpret(),
-        )(xf, w2, b2)
-
-    with jax.enable_x64(False):
-        out = per_batch_shard(rows, a.reshape(-1, D), weight.reshape(1, D), b2, replicated=(1, 2))
-    return out.reshape(a.shape)
-
-
-def _ln_bwd_impl(g, a, weight, bias, eps):
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    e = float(eps)
-    D = a.shape[-1]
-
-    def rows(gf, xf, w2):
-        N = xf.shape[0]
-        bt = _norm_bt(N, D)
-        return pl.pallas_call(
-            partial(_ln_bwd_kernel, eps=e),
-            grid=(N // bt,),
-            in_specs=[
-                pl.BlockSpec((bt, D), lambda i: (i, 0), memory_space=pltpu.VMEM),
-                pl.BlockSpec((bt, D), lambda i: (i, 0), memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, D), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            ],
-            out_specs=[
-                pl.BlockSpec((bt, D), lambda i: (i, 0), memory_space=pltpu.VMEM),
-                pl.BlockSpec((8, D), lambda i: (i, 0), memory_space=pltpu.VMEM),
-                pl.BlockSpec((8, D), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((N, D), a.dtype),
-                jax.ShapeDtypeStruct((8 * (N // bt), D), jnp.float32),
-                jax.ShapeDtypeStruct((8 * (N // bt), D), jnp.float32),
-            ],
-            interpret=_interpret(),
-        )(gf, xf, w2)
-
-    with jax.enable_x64(False):
-        dx, dwp, dbp = per_batch_shard(
-            rows, g.reshape(-1, D), a.reshape(-1, D), weight.reshape(1, D), replicated=(2,))
-    dw = jnp.sum(dwp, axis=0).astype(weight.dtype)
-    db = jnp.sum(dbp, axis=0).astype(weight.dtype) if bias is not None else None
-    return dx.reshape(a.shape), dw, db
-
-
-norm_ex = OperatorExecutor("norm")
-register_executor(norm_ex)
-norm_ex.register_implementation("torch.rms_norm", fn=_rms_impl, checker=_rms_fwd_checker)
-norm_ex.register_implementation("torch.rms_norm_bwd", fn=_rms_bwd_impl, checker=_rms_bwd_checker)
-norm_ex.register_implementation("torch.layer_norm", fn=_ln_impl, checker=_ln_fwd_checker)
-norm_ex.register_implementation("torch.layer_norm_bwd", fn=_ln_bwd_impl, checker=_ln_bwd_checker)

@@ -63,13 +63,6 @@ def ops_active() -> bool:
     return bool(_ops["taps"])
 
 
-def ops_taps() -> tuple:
-    """(taps, recorder) snapshot — for callers that need to restore the
-    installed taps around an A/B measurement (bench.py) without tearing
-    down a live ops plane's server."""
-    return _ops["taps"], _ops["recorder"]
-
-
 def _tap(kind: str, fields: dict) -> None:
     for tap in _ops["taps"]:
         try:

@@ -9,8 +9,8 @@ Prometheus text exposition format.
 Design constraints (the reason this is not a prometheus_client dependency):
 
 - **Disabled must be free.** Every mutate method checks one module-level
-  flag and returns; the GPT-block dispatch bench budget is <1% overhead with
-  observability off and <5% with metrics on (BENCHMARKS.md).
+  flag and returns; the GPT-block dispatch budget is <1% overhead with
+  observability off and <5% with metrics on.
 - **No locks on the hot path.** CPython dict ops are atomic enough for
   monotonic counters; a torn read in ``report()`` costs one sample, never a
   crash. (Compile-side metrics are effectively single-threaded anyway.)
@@ -254,8 +254,7 @@ class MetricsRegistry:
         return out
 
     def report_compact(self) -> dict:
-        """Flat {name+labels: value} snapshot with empty series dropped —
-        what ``bench.py`` embeds in its JSON line."""
+        """Flat {name+labels: value} snapshot with empty series dropped."""
         out: dict[str, Any] = {}
         for name, m in sorted(self._metrics.items()):
             for k in list(m._values):
@@ -345,8 +344,7 @@ COMPILE_MS = REGISTRY.histogram(
 )
 # The metric that doubled r4→r5 without anyone noticing: the TOTAL seconds a
 # compile class spends in XLA (staging + backend compile), not just the
-# trace-side per-pass ms. Labelled cls=exact|bucketed (dispatch first runs) or
-# cls=bench_forward|bench_train_step (bench.py's measured compiles).
+# trace-side per-pass ms. Labelled cls=exact|bucketed (dispatch first runs).
 XLA_COMPILE_S = REGISTRY.histogram(
     "thunder_tpu_xla_compile_s",
     "End-to-end XLA compile+first-run seconds, labelled by compile class",

@@ -1,9 +1,8 @@
 """Profiler bracketing: ``thunder_tpu.profile(fn, *args)``.
 
 Runs a (compiled or plain) callable under ``jax.profiler.trace`` with one
-``StepTraceAnnotation`` per step, producing an xprof-ready trace directory —
-the consolidated home of the recipe that used to live only in
-``scripts/profile_train.py``. Combined with annotated codegen
+``StepTraceAnnotation`` per step, producing an xprof-ready trace directory.
+Combined with annotated codegen
 (``THUNDER_TPU_ANNOTATE_TRACES=1``; see ``core/trace.py``), every HLO row in
 the profile carries the originating trace line + pass provenance, so
 profiler time attributes back to BoundSymbols.

@@ -19,9 +19,8 @@ plane's :class:`~thunder_tpu.observability.detect.DetectorBank`
 ``cost_model_drift`` anomaly — and a regressed executor-claimed kernel a
 ``kernel_regression`` — in-run, not at the next manual profile. The live
 ledger is served at ``/debug/roofline`` and printable via
-``thunder_tpu.monitor.roofline_report()``; ``bench.py`` commits it as the
-``ROOFLINE_r*.json`` per-op series that ``scripts/perf_report.py --gate``
-enforces. docs/performance.md ("continuous roofline ledger") walks the
+``thunder_tpu.monitor.roofline_report()``; ``ROOFLINE_r*.json`` is the
+per-op series of it that ``scripts/perf_report.py --gate`` enforces. docs/performance.md ("continuous roofline ledger") walks the
 workflow.
 
 Off-path cost: when no probe is due, :meth:`RooflineSampler.maybe_sample`

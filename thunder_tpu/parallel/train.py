@@ -184,9 +184,7 @@ def build_train_step(
     ``step_fn(params, opt_state, idx, targets) -> (params, opt_state, loss)``.
 
     ``return_extrace=True`` appends the claimed joint execution trace to the
-    return tuple — the cost-model input for multichip MFU accounting
-    (``scripts/bench_multichip.py`` prices its FLOPs/collectives against the
-    device spec via ``analysis.cost.trace_cost``).
+    return tuple (``perfbench/jobs/train.py`` counts the claimed kernels in it).
 
     Under a ``mesh`` the step is one ``jax.jit`` with shardings, partitioned
     by XLA; the claimed Mosaic kernels, which the partitioner cannot split,
@@ -244,7 +242,7 @@ def build_train_step(
             grads_tree = tree_unflatten(p_spec, list(grads))
             if optimizer == "sgd":
                 # bf16-true SGD(wd) — no moment state; what lets multi-GB models
-                # train on one 16 GB chip (the bench.py protocol)
+                # train on one 16 GB chip
                 new_params = tree_map(
                     lambda p, g: (p - lr * (g.astype(p.dtype) + weight_decay * p)).astype(p.dtype),
                     params, grads_tree,

@@ -27,8 +27,7 @@ the *one* pass licensed to re-bless a collective order — and verified by
 the PR 1 lint rules; the ``sched.exposed-collective`` advisory rule reports
 the per-site predicted hidden/exposed µs the pass leaves behind (the
 compile-time twin of the measured lane segmentation in
-``observability/attribution.py``, which ``scripts/bench_multichip.py``
-joins against this pass's report).
+``observability/attribution.py``).
 
 The pass is **advisory-safe**: any internal failure — including a chaos
 ``sched_bad`` seam corrupting a placement, which the interval validation
