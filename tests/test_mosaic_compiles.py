@@ -6,6 +6,7 @@ other test of the kernels runs in, cannot show that.
 One process at a time may load the TPU's library, so the topology is described
 inside a fixture (never at import) and every such test lives in this file."""
 
+import re
 from types import SimpleNamespace
 
 import pytest
@@ -286,3 +287,127 @@ def test_claimed_routed_experts_compile_for_v5e_with_the_short_buffer_and_the_wo
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 6 and " conditional(" in text
     assert all(f"bf16[{rows},7168]" in text for rows in (8192, 65536)) and "bf16[16384,7168]" not in text
+
+
+ROPE_HEADS_SHAPES = [
+    # (B, lane groups of the array, T, lanes), n, first, heads read, scale, heads a lane group
+    ((8, 24, 2048, 128), 16, 0, 16, 0.125, 2),   # pythia-410m.fwd's q out of the packed projection: two heads of 64 a group
+    ((8, 24, 2048, 128), 16, 16, 16, 1.0, 2),    # and its k
+    ((8, 24, 2048, 128), 64, 0, 16, 0.125, 2),   # full rotary on heads of 64 (tinyllama's)
+    ((8, 48, 2048, 64), 16, 0, 16, 0.125, 1),    # a head of 64 a group, where the counts of heads are odd
+    ((8, 48, 2048, 64), 16, 16, 16, 1.0, 1),
+    ((1, 48, 4096, 128), 128, 0, 32, 128 ** -0.5, 1),  # 32 query and 8 key heads of 128, full rotary
+    ((1, 48, 4096, 128), 128, 32, 8, 1.0, 1),
+    ((2, 64, 4096, 192), 64, 0, 64, 192 ** -0.5 * 1.3466 ** 2, 1),  # a.x-k1.fwd's q: every head, so in place
+]
+
+
+def _heads_call_compiled(monkeypatch, one_chip, call, checker, shape, tables, donate):
+    import jax
+    import jax.numpy as jnp
+
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import pallasex
+
+    proxy = lambda s: SimpleNamespace(shape=s, dtype=dtypes.bfloat16)
+    sds = lambda s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    assert checker(proxy(shape), *(proxy(t) for t in tables))
+    compiled = jax.jit(call, donate_argnums=(0,) if donate else ()).lower(sds(shape), *(sds(t) for t in tables)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and " slice(" not in text  # found by the block index
+    assert ("output_to_operand_aliasing" in text) == donate
+    return compiled
+
+
+@pytest.mark.parametrize("shape,n,first,heads,scale,split", ROPE_HEADS_SHAPES,
+                         ids=[f"{s[-1] // k}-{n}-heads{f}to{f + h}of{s[1] * k}" for s, n, f, h, _, k in ROPE_HEADS_SHAPES])
+def test_rope_on_some_heads_of_a_head_major_array_compiles_for_v5e(one_chip, monkeypatch, shape, n, first, heads, scale, split):
+    """``apply_rope_heads`` as transforms/attention_layout.py writes it: one call
+    that reads its heads by the block index and writes them scaled, each head
+    to a (T, hs) of its own where a group's lanes hold several."""
+    from thunder_tpu.executors import pallasex
+
+    tables = [(shape[-2], n)] * 2
+    compiled = _heads_call_compiled(
+        monkeypatch, one_chip, lambda x, c, s: pallasex._rope_heads_impl(x, c, s, first, heads, scale, split),
+        lambda x, c, s: pallasex._rope_heads_checker(x, c, s, first, heads, scale, split), shape, tables,
+        donate=(heads, split) == (shape[1], 1))
+    assert compiled.out_info.shape == (shape[0], heads, shape[2], shape[3] // split)
+
+
+def test_split_heads_compiles_for_v5e(one_chip, monkeypatch):
+    """pythia-410m.fwd's v: heads 32 to 48 of the 24 groups of two."""
+    from thunder_tpu.executors import pallasex
+
+    compiled = _heads_call_compiled(
+        monkeypatch, one_chip, lambda x: pallasex._split_heads_impl(x, 32, 16, 2),
+        lambda x: pallasex._split_heads_checker(x, 32, 16, 2), (8, 24, 2048, 128), [], donate=False)
+    assert compiled.out_info.shape == (8, 16, 2048, 64)
+
+
+def test_heads_checkers_decline_what_would_not_compile_or_is_not_there():
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import pallasex
+
+    proxy = lambda s, d=dtypes.bfloat16: SimpleNamespace(shape=s, dtype=d)
+    x, t = proxy((8, 24, 2048, 128)), proxy((2048, 16))
+    assert pallasex._rope_heads_checker(x, t, t, 16, 16, 1.0, 2) and pallasex._split_heads_checker(x, 32, 16, 2)
+    assert not pallasex._split_heads_checker(x, 33, 15, 2)         # a group is read whole
+    assert not pallasex._split_heads_checker(x, 32, 18, 2)         # beyond the array's 48 heads
+    assert not pallasex._rope_heads_checker(proxy((8, 24, 2048, 128), dtypes.float16), t, t, 0, 16, 1.0, 2)
+    assert not pallasex._rope_heads_checker(x, proxy((2048, 16), dtypes.float32), proxy((2048, 16), dtypes.float32), 0, 16, 1.0, 2)
+    assert pallasex.heads_per_lane_group(64, 16, 16) == 2 and pallasex.heads_per_lane_group(32, 8, 4) == 4
+    assert pallasex.heads_per_lane_group(64, 15, 5) == pallasex.heads_per_lane_group(128, 32, 8) == 1
+    assert pallasex.heads_per_lane_group(96, 8, 8) == pallasex.heads_per_lane_group(192, 64) == 1
+
+
+def test_forward_of_pythia_has_no_layout_copy_in_front_of_attention_on_the_v5e(one_chip, monkeypatch):
+    """What the gain of transforms/attention_layout.py rests on is the TPU
+    compiler's: a dot whose own output has the head dimension is written
+    head-major with no copy. pythia-410m.fwd's program at depth 2, as the cell
+    runs it, compiled for the described chip: no copy or scaling of q, k or v
+    is left inside a layer, and the executable needs no more than as written."""
+    import jax
+    import jax.numpy as jnp
+
+    from perfbench import manifest
+    from perfbench.jobs import gpt_model
+    from perfbench.run import executable_needs
+    from thunder_tpu.api import trace_program
+    from thunder_tpu.executors import flashex, pallasex
+    from thunder_tpu.executors.passes import transform_for_execution
+    from thunder_tpu.extend import resolve_executors
+    from thunder_tpu.models import gpt
+    from thunder_tpu.transforms.attention_layout import FOLDED_TAG, fold_attention_layouts
+    from thunder_tpu.transforms.common import dce
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    monkeypatch.setattr(flashex, "_interpret", lambda: False)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    cell = manifest.load_cell("pythia-410m.fwd")
+    keys = manifest.published(cell)
+    keys.update(num_hidden_layers=2, reduced=[*keys["reduced"], "num_hidden_layers"])
+    cfg = gpt_model.gpt_config(keys)
+    shapes = gpt_model.param_shapes(cfg)
+    tokens = jax.ShapeDtypeStruct((cell.traffic["batch"], cell.traffic["seq"]), jnp.int32)
+    flat = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip) for a in jax.tree_util.tree_leaves((shapes, tokens))]
+
+    def compiled(folded: bool):
+        _, trc = trace_program(lambda p, i: gpt.forward(p, i, cfg), (shapes, tokens), {})
+        trc = dce(trc)
+        if folded:
+            trc = fold_attention_layouts(trc, resolve_executors(None))
+            assert trc.tags[FOLDED_TAG] == 2
+        return jax.jit(transform_for_execution(trc, resolve_executors(None)).python_callable()).lower(*flat).compile()
+
+    def layout_instructions(text):
+        """Copies, slices, and scalings by a broadcast constant, that write an array of q's, k's or v's size."""
+        made = re.findall(r"^\s*%((?:copy|slice|broadcast_multiply_fusion)[.\d]*) = (bf16\[[\d,]+\])", text[text.index("ENTRY"):], re.M)
+        return [name for name, shape in made if shape in ("bf16[8,2048,1024]", "bf16[8,16,2048,64]", "bf16[128,2048,64]")]
+
+    written, folded = compiled(False), compiled(True)
+    # as written: 3 slices, 2 head transposes of q and k, q's scaling, a layer; one copy of the embedding outside
+    assert len(layout_instructions(written.as_text())) == 2 * 6 + 1
+    assert len(layout_instructions(folded.as_text())) == 1
+    assert executable_needs(folded)[0] <= executable_needs(written)[0]

@@ -191,6 +191,7 @@ class TestEventLog:
         phase_optional = {
             "cache", "predicted_peak_bytes", "collective_sites",
             "cross_entropy_upcasts_folded",  # on transforms (ISSUE 28)
+            "attention_layouts_folded",  # on transforms (ISSUE 30)
             # hlo_audit (ISSUE 16)
             "hlo_ops", "hlo_acquire_s", "hlo_analyze_s", "hlo_collectives",
             "hlo_inserted_collectives", "hlo_exposed_pct", "hlo_host_transfers",

@@ -685,6 +685,13 @@ def _compile_entry_impl(
         from thunder_tpu.transforms.cross_entropy_upcast import fold_cross_entropy_upcasts
 
         comp_trc = fold_cross_entropy_upcasts(comp_trc, cd.executors_list)
+        # and, in a forward program, let q, k and v leave their projection
+        # head-major (transforms/attention_layout.py)
+        from thunder_tpu.transforms.attention_layout import fold_attention_layouts
+
+        folded = fold_attention_layouts(comp_trc, cd.executors_list)
+        if folded is not comp_trc:
+            computation_traces.append(comp_trc := folded)
 
     comp_trc = functionalize_rng_ops(comp_trc)
     if comp_trc.tags.get(RNG_TAG):
@@ -854,13 +861,14 @@ def _compile_entry_impl(
     entry.schedule_certificate = static_cert
     cs.trace_seconds += entry.stats.trace_s
     comm_sched_tag = extrace.tags.get("comm_schedule")
+    from thunder_tpu.transforms.attention_layout import FOLDED_TAG as LAYOUTS_FOLDED_TAG
     from thunder_tpu.transforms.cross_entropy_upcast import FOLDED_TAG
 
     for phase in ("trace", "transforms", "claim", "static_analysis", "codegen",
                   "staging"):
         extra = {}
-        if phase == "transforms" and FOLDED_TAG in comp_trc.tags:  # by presence, as below: a de-optimized compile ran no such pass
-            extra[FOLDED_TAG] = comp_trc.tags[FOLDED_TAG]
+        if phase == "transforms":  # by presence, as below: a de-optimized compile ran no such pass
+            extra = {tag: comp_trc.tags[tag] for tag in (FOLDED_TAG, LAYOUTS_FOLDED_TAG) if tag in comp_trc.tags}
         if phase == "static_analysis" and static_plan is not None:
             extra = dict(
                 predicted_peak_bytes=int(static_plan.peak_bytes),

@@ -223,6 +223,15 @@ def _splash_kernel(H: int, Tq: int, Tkv: int, causal: bool, offset: int, interpr
         )
 
 
+def _scaled(q, scale: float):
+    """q times the softmax scale, which splash leaves to its caller. At 1.0 q
+    came scaled (transforms/attention_layout.py folds the scale into the rope
+    call that writes q) and a pass over it would multiply nothing."""
+    import jax.numpy as jnp
+
+    return q if scale == 1.0 else (q * jnp.asarray(scale, dtype=q.dtype)).astype(q.dtype)
+
+
 def _splash_sdpa(q, k, v, *, causal: bool, scale: float, kv_valid=None, q_valid=None):
     """Run splash attention with in-executor sequence padding.
 
@@ -257,7 +266,7 @@ def _splash_sdpa(q, k, v, *, causal: bool, scale: float, kv_valid=None, q_valid=
         # SMEM (the downcast costs ~1e-3 abs error on f32 workloads).
         q.dtype == jnp.bfloat16,
     )
-    qs = (q * jnp.asarray(scale, dtype=q.dtype)).astype(q.dtype)
+    qs = _scaled(q, scale)
 
     # The kernel's index maths is 32-bit; scope out the runtime's x64 mode
     # while it traces.
@@ -483,7 +492,7 @@ def _splash_fwd_res(q, k, v, *, causal: bool, scale: float):
         q.dtype == jnp.bfloat16,
         True,
     )
-    qs = (q * jnp.asarray(scale, dtype=q.dtype)).astype(q.dtype)
+    qs = _scaled(q, scale)
     with jax.enable_x64(False):
         out, (lse,) = per_batch_shard(jax.vmap(kernel), qs, k, v)
     return out, lse[..., :Tq].astype(jnp.float32)

@@ -397,6 +397,25 @@ def _linear(a, w, bias):
 _reg(PrimIDs.LINEAR, _linear)
 
 
+# A projection with the head dimension in the dot's own output
+# (transforms/attention_layout.py): XLA writes a head-major array with no
+# transpose only where the dot itself has the heads; ``linear -> reshape ->
+# permute`` leaves it a copy. Heads narrower than the lanes cost the dot twice
+# its time (29.5 ms against 14.6 in pythia-410m.fwd), so the caller asks for
+# them side by side: 24 of 128 for 48 of 64 read 15.8 (PERF.md, PR 30).
+
+
+def _linear_heads(a, w, bias=None, heads=1):
+    hs = w.shape[0] // heads
+    out = jnp.einsum("btc,hdc->bhtd", a, w.reshape(heads, hs, w.shape[1]), precision=_dot_precision(a, w))
+    if bias is not None:
+        out = out + bias.reshape(heads, 1, hs)
+    return out
+
+
+ex.register_implementation("torch.linear_heads", fn=_linear_heads)
+
+
 def _convolution(a, weight, bias, stride, padding, dilation, groups):
     spatial = a.ndim - 2
     stride = tuple(stride[i] if i < len(stride) else stride[-1] for i in range(spatial))

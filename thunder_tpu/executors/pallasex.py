@@ -29,6 +29,7 @@ partitioner turns them into collectives.
 from __future__ import annotations
 
 from functools import partial
+from types import SimpleNamespace
 
 from thunder_tpu.core import dtypes
 from thunder_tpu.core.devices import TPU_SPECS, tpu_generation
@@ -320,57 +321,102 @@ def _rope_checker(x, cos, sin):
     return x.shape[-2] == T and n % 2 == 0 and T % 8 == 0 and x.shape[0] % batch_shards() == 0
 
 
-def _rope_kernel(x_ref, cos_ref, sin_ref, out_ref, *, half: int):
+def _rope_rows(ref):
+    """The index of a block's (bt, D) rows: ``_rope_impl``'s blocks are
+    (1, bt, D), ``_heads_call``'s input blocks (1, 1, bt, D)."""
+    return (0,) * (len(ref.shape) - 2)
+
+
+def _rope_write(out_ref, rows, scale: float = 1.0):
+    """``rows * scale`` in float32 and then the one rounding to the output's
+    dtype; at ``scale`` 1.0 the kernel is what it was before there was one.
+    Where the output block is (1, split, bt, hs), ``rows`` hold ``split`` heads
+    side by side in their lanes, and each goes to its own (bt, hs)."""
     import jax.numpy as jnp
 
-    x = x_ref[0]
+    if scale != 1.0:
+        rows = rows.astype(jnp.float32) * scale
+    hs = out_ref.shape[-1]
+    if rows.shape[-1] == hs:
+        out_ref[_rope_rows(out_ref)] = rows.astype(out_ref.dtype)
+        return
+    for i in range(rows.shape[-1] // hs):
+        out_ref[0, i] = rows[:, i * hs:(i + 1) * hs].astype(out_ref.dtype)
+
+
+def _rope_kernel(x_ref, cos_ref, sin_ref, out_ref, *, half: int, scale: float = 1.0):
+    import jax.numpy as jnp
+
+    x = x_ref[_rope_rows(x_ref)]
     x1 = x[..., :half]
     x2 = x[..., half:]
     rotated = jnp.concatenate([-x2, x1], axis=-1)
-    out_ref[0] = (x * cos_ref[...] + rotated * sin_ref[...]).astype(out_ref.dtype)
+    _rope_write(out_ref, x * cos_ref[...] + rotated * sin_ref[...], scale)
 
 
-def _rope_partial_kernel(x_ref, cos_ref, sin_ref, out_ref, *, n: int):
-    """Rotary on the first ``n`` of D lanes without a slice narrower than the
-    block: ``cos`` comes padded with ones and ``sin`` with zeros, and
-    rotate-half is a product with the D x D signed permutation (one nonzero a
-    column, f32 accumulation: exact), whose columns beyond ``n`` are zero. So
-    those lanes leave as ``x * 1 + 0 * 0``. A row that holds an inf or a NaN
-    comes out NaN in every lane, where the decomposition keeps it to its pair."""
+def _rope_partial_kernel(x_ref, cos_ref, sin_ref, out_ref, *, n: int, hs: int, scale: float = 1.0):
+    """Rotary on the first ``n`` of every ``hs`` of D lanes (D // hs heads side
+    by side) without a slice narrower than the block: ``cos`` comes padded with
+    ones and ``sin`` with zeros, and rotate-half is a product with the D x D
+    signed permutation (one nonzero a column, f32 accumulation: exact), whose
+    columns beyond a head's ``n`` are zero. So those lanes leave as
+    ``x * 1 + 0 * 0``. A row that holds an inf or a NaN comes out NaN in every
+    lane, where the decomposition keeps it to its pair."""
     import jax
     import jax.numpy as jnp
 
-    x = x_ref[0]
+    x = x_ref[_rope_rows(x_ref)]
     D, half = x.shape[-1], n // 2
     src = jax.lax.broadcasted_iota(jnp.int32, (D, D), 0)
     dst = jax.lax.broadcasted_iota(jnp.int32, (D, D), 1)
-    perm = jnp.where((src == dst + half) & (dst < half), -1.0,
-                     jnp.where((src == dst - half) & (dst >= half) & (dst < n), 1.0, 0.0)).astype(x.dtype)
+    at = dst  # a column's place in its head
+    if hs != D:
+        at = dst % hs
+    perm = jnp.where((src == dst + half) & (at < half), -1.0,
+                     jnp.where((src == dst - half) & (at >= half) & (at < n), 1.0, 0.0)).astype(x.dtype)
     exact = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None  # bf16 operands are exact as they are
     rotated = jnp.dot(x, perm, preferred_element_type=jnp.float32, precision=exact).astype(x.dtype)
-    out_ref[0] = (x * cos_ref[...] + rotated * sin_ref[...]).astype(out_ref.dtype)
+    _rope_write(out_ref, x * cos_ref[...] + rotated * sin_ref[...], scale)
+
+
+def _rope_block(T: int) -> int:
+    """Sequence rows a block: ``_ROPE_BT``, shrunk to a divisor of T."""
+    bt = _ROPE_BT
+    while T % bt:
+        bt //= 2
+    return bt
+
+
+def _rope_kernel_and_tables(T: int, D: int, cos, sin, scale: float = 1.0, split: int = 1):
+    """(kernel body, sequence rows a block, cos, sin) for rows of D lanes that
+    hold ``split`` heads side by side, under (T, n) tables. All but one head of
+    full rotary take tables of full width, ones and zeros beyond a head's n,
+    which keeps the call's three operands."""
+    import jax.numpy as jnp
+
+    n, hs, bt = cos.shape[-1], D // split, _rope_block(T)
+    if n == D:
+        return partial(_rope_kernel, half=D // 2, scale=scale), bt, cos, sin
+    if n != hs:
+        cos = jnp.concatenate([cos, jnp.ones((T, hs - n), cos.dtype)], axis=-1)
+        sin = jnp.concatenate([sin, jnp.zeros((T, hs - n), sin.dtype)], axis=-1)
+    if split != 1:
+        cos, sin = jnp.tile(cos, (1, split)), jnp.tile(sin, (1, split))
+    return partial(_rope_partial_kernel, n=n, hs=hs, scale=scale), bt, cos, sin
 
 
 def _rope_impl(x, cos, sin):
     chaos.kernel_seam("pallas", "apply_rope")
     import jax
-    import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     def shard(x, cos, sin):
         B, H, T, D = x.shape
-        n = cos.shape[-1]
-        bt = _ROPE_BT
-        while T % bt:
-            bt //= 2
-        kernel, in_place = partial(_rope_kernel, half=D // 2), {}
-        if n != D:
-            # Tables of full width keep the call's three operands; the VJP needs
-            # cos and sin only, never x, so x is dead after the call: in place.
-            kernel, in_place = partial(_rope_partial_kernel, n=n), {0: 0}
-            cos = jnp.concatenate([cos, jnp.ones((T, D - n), cos.dtype)], axis=-1)
-            sin = jnp.concatenate([sin, jnp.zeros((T, D - n), sin.dtype)], axis=-1)
+        # The VJP needs cos and sin only, never x, so where the rotary is
+        # partial x is dead after the call: in place.
+        in_place = {0: 0} if cos.shape[-1] != D else {}
+        kernel, bt, cos, sin = _rope_kernel_and_tables(T, D, cos, sin)
         out = pl.pallas_call(
             kernel,
             grid=(B * H, T // bt),
@@ -391,6 +437,94 @@ def _rope_impl(x, cos, sin):
 
 
 ex.register_implementation("torch.apply_rope", fn=_rope_impl, checker=_rope_checker)
+
+
+# Heads out of a head-major array, for transforms/attention_layout.py: the fused
+# qkv projection writes (B, P, T, L) in one dot, and q, k and v are read out of
+# it by the block index, so no slice of it is ever made. Heads narrower than the
+# 128 lanes lie ``split`` side by side in L = split * hs lanes (two of 64), so
+# the projection writes whole tiles and the calls read them whole; each head
+# leaves to a (T, hs) of its own, which is what the attention kernel takes. q
+# leaves times the softmax scale, which costs attention a pass over q otherwise.
+
+
+def heads_per_lane_group(hs: int, *head_counts: int) -> int:
+    """How many heads of ``hs`` lanes the projection should lay side by side:
+    as many as fill the lane width, where every count of heads divides by it."""
+    split = _LANE // hs if hs < _LANE and _LANE % hs == 0 else 1
+    return split if all(h % split == 0 for h in head_counts) else 1
+
+
+def _heads_checker(x, first, heads, split) -> bool:
+    if len(getattr(x, "shape", ())) != 4 or x.shape[0] % batch_shards():
+        return False
+    _, P, T, L = x.shape
+    dt = dtypes.to_dtype(x.dtype)
+    return (0 <= first and 0 < heads and first + heads <= P * split and T % 8 == 0
+            and L % split == first % split == heads % split == 0
+            # what Mosaic was seen to compile for the v5e where lanes are split on the way out
+            and (split == 1 or (dt in (dtypes.bfloat16, dtypes.float32) and L * dt.bytes <= 512)))
+
+
+def _rope_heads_checker(x, cos, sin, first, heads, scale=1.0, split=1):
+    """``_rope_checker``'s word on the heads that are read."""
+    first, heads, split = int(pyval(first)), int(pyval(heads)), int(pyval(split))
+    if not _heads_checker(x, first, heads, split):
+        return False
+    B, _, T, L = x.shape
+    return _rope_checker(SimpleNamespace(shape=(B, heads, T, L // split), dtype=x.dtype), cos, sin)
+
+
+def _split_heads_checker(x, first, heads, split):
+    return _heads_checker(x, int(pyval(first)), int(pyval(heads)), int(pyval(split)))
+
+
+def _heads_call(kernel_and_tables, x, tables, first: int, heads: int, split: int):
+    """One call over (batch, lane groups read, blocks of the sequence): group
+    ``first // split + g`` of x (B, P, T, L) in, heads ``split * g`` and on of
+    (B, heads, T, L // split) out."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def shard(x, *tables):
+        B, P, T, L = x.shape
+        kernel, bt, *tables = kernel_and_tables(T, L, *tables)
+        table = pl.BlockSpec((bt, L), lambda b, g, j: (j, 0), memory_space=pltpu.VMEM)
+        return pl.pallas_call(
+            kernel,
+            grid=(B, heads // split, T // bt),
+            in_specs=[pl.BlockSpec((1, 1, bt, L), lambda b, g, j: (b, first // split + g, j, 0),
+                                   memory_space=pltpu.VMEM), *(table for _ in tables)],
+            out_specs=pl.BlockSpec((1, split, bt, L // split), lambda b, g, j: (b, g, j, 0), memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((B, heads, T, L // split), x.dtype),
+            input_output_aliases={0: 0} if (heads, split) == (P, 1) else {},  # every head: x's own buffer, as _rope_impl's
+            interpret=_interpret(),
+        )(x, *tables)
+
+    with jax.enable_x64(False):
+        return per_batch_shard(shard, x, *(t.astype(x.dtype) for t in tables), replicated=tuple(range(1, 1 + len(tables))))
+
+
+def _rope_heads_impl(x, cos, sin, first, heads, scale=1.0, split=1):
+    chaos.kernel_seam("pallas", "apply_rope_heads")
+    split = int(split)
+    return _heads_call(partial(_rope_kernel_and_tables, scale=float(scale), split=split), x, (cos, sin),
+                       int(first), int(heads), split)
+
+
+def _split_kernel(x_ref, out_ref):
+    _rope_write(out_ref, x_ref[_rope_rows(x_ref)])
+
+
+def _split_heads_impl(x, first, heads, split):
+    chaos.kernel_seam("pallas", "split_heads")
+
+    return _heads_call(lambda T, L: (_split_kernel, _rope_block(T)), x, (), int(first), int(heads), int(split))
+
+
+ex.register_implementation("torch.apply_rope_heads", fn=_rope_heads_impl, checker=_rope_heads_checker)
+ex.register_implementation("torch.split_heads", fn=_split_heads_impl, checker=_split_heads_checker)
 
 
 # =============================================================================

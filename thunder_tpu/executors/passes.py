@@ -43,6 +43,18 @@ def _claimed(sym: Symbol, ex: Executor) -> Symbol:
     return new
 
 
+def would_claim(bsym: BoundSymbol, executors: Sequence[Executor]):
+    """The name of the executor the claiming pass would give ``bsym`` whole
+    to, or None: asked in its order, of the checkers alone (no fuel is spent),
+    so a transform may ask before it rewrites for an executor."""
+    from thunder_tpu.resilience.demotion import is_quarantined
+
+    for ex in executors:
+        if not is_quarantined(bsym.sym.id, ex.name) and ex.accepts(bsym):
+            return ex.name
+    return None
+
+
 def transform_for_execution(
     trace: TraceCtx,
     executors_list: Sequence[Executor],

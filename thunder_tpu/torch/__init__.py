@@ -1378,6 +1378,45 @@ def apply_rope(x, cos, sin):
     return cat([roped, x[..., n:]], dim=-1)
 
 
+# Attention's operands head-major from the projection that makes them
+# (transforms/attention_layout.py writes these; each body is the program
+# as written, which is what runs where no executor claims the symbol).
+
+
+@torchsymbol(id="torch.linear_heads")
+def linear_heads(a, w, bias=None, heads: int = 1):
+    """``linear`` whose outputs are ``heads`` heads, written head-major:
+    a (B, T, C), w (heads * hs, C) -> (B, heads, T, hs). A caller that wants
+    narrow heads side by side in the lanes asks for fewer, wider ones."""
+    B, T = a.shape[0], a.shape[1]
+    return permute(reshape(linear(a, w, bias), (B, T, heads, w.shape[0] // heads)), (0, 2, 1, 3))
+
+
+def _heads_side_by_side(x, split: int):
+    """x (B, P, T, split * hs), whose lanes hold ``split`` heads side by side,
+    as (B, P * split, T, hs)."""
+    if split == 1:
+        return x
+    B, P, T, L = x.shape
+    return reshape(permute(reshape(x, (B, P, T, split, L // split)), (0, 1, 3, 2, 4)), (B, P * split, T, L // split))
+
+
+@torchsymbol(id="torch.split_heads")
+def split_heads(x, first: int, heads: int, split: int = 1):
+    """Heads ``[first, first + heads)`` of a head-major x (B, P, T, split * hs)
+    whose lanes hold ``split`` heads side by side: (B, heads, T, hs)."""
+    return _heads_side_by_side(x, split)[:, first:first + heads]
+
+
+@torchsymbol(id="torch.apply_rope_heads")
+def apply_rope_heads(x, cos, sin, first: int, heads: int, scale: float = 1.0, split: int = 1):
+    """``apply_rope`` on ``split_heads(x, first, heads, split)``, times
+    ``scale``. Attention's softmax scale rides here on q, so that
+    ``scaled_dot_product_attention(scale=1.0)`` multiplies nothing."""
+    roped = apply_rope(split_heads(x, first, heads, split), cos, sin)
+    return roped if scale == 1.0 else mul(roped, scale)
+
+
 @torchsymbol(id="torch.moe_route")
 def moe_route(x, router_w, top_k: int, n_group: int = 1, topk_group: int = 1,
               routed_scaling_factor: float = 1.0):

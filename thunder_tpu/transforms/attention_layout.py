@@ -1,0 +1,218 @@
+"""Attention's operands head-major from the projection that makes them.
+
+Programs write attention token-major (``models/gpt.py::_attention`` does, as
+litgpt- and HF-style programs do): one ``linear`` for q, k and v, three slices
+of its last dimension, a ``reshape`` to (B, T, heads, hs) and a ``permute`` to
+(B, heads, T, hs) each, ``apply_rope`` on q and k, the attention call, and a
+``permute`` and ``reshape`` back before the output ``linear``. On the chip each
+slice and permute is a copy of an activation, and the softmax scale is a pass
+over q of its own: 168 copies and 24 scalings in a forward call of pythia-410m,
+a sixth of its time, with no arithmetic in them (PERF.md, PR 30).
+
+XLA writes a projection head-major with no copy, but only where the dot's own
+output has the head dimension. So this pass rewrites the idiom to the packed
+form: ``linear_heads`` writes (B, H + 2G, T, hs) in one dot on the weight as
+it lies; ``apply_rope_heads`` reads q's and k's heads out of that array by its
+block index, and writes q times the softmax scale; the attention call takes
+``scale=1.0``. Heads narrower than the 128 lanes (pythia's 64) would cost the
+dot twice its time in half-empty tiles, so there the projection lays two side
+by side, (B, (H + 2G) / 2, T, 128), and the kernels that read it give each
+head a (T, hs) of its own: ``split_heads`` does that for v, which is a plain
+slice where the heads fill the lanes. Where only q comes through ``linear ->
+reshape -> permute -> apply_rope`` (latent attention) it does that part.
+
+Forward programs only: in a trace that holds a backward q, k and v have a
+second reader and nothing matches. It asks the checkers first: where ``pallas``
+would not take the rope call or ``flash`` the attention call (a CPU run
+without the kernels), the program stays as written. Each new symbol keeps the
+program as written as its decomposition, so a claim that fails later still
+computes it.
+"""
+
+from __future__ import annotations
+
+import math
+import time
+
+from thunder_tpu.core.proxies import Proxy, TensorProxy, pyval, variableify
+from thunder_tpu.core.pytree import tree_flatten
+from thunder_tpu.core.trace import TraceCtx, from_trace, tracectx, wrap_in_trace_provenance
+from thunder_tpu.executors.pallasex import heads_per_lane_group
+from thunder_tpu.executors.passes import would_claim
+from thunder_tpu.transforms.attention_residuals import _bound_sdpa
+
+FOLDED_TAG = "attention_layouts_folded"  # how many attention sites the pass rewrote
+
+_SDPA = "torch.scaled_dot_product_attention"
+_HEADS_FIRST = (0, 2, 1, 3)
+# who has to take each new line for the rewrite to pay: asked of the checkers before anything is changed
+_CLAIMED_BY = {"torch.apply_rope_heads": "pallas", "torch.split_heads": "pallas", _SDPA: "flash"}
+
+
+class _Uses:
+    """Who writes and who reads each proxy of a trace, by index."""
+
+    def __init__(self, trc: TraceCtx):
+        self.bsyms = trc.bound_symbols
+        self.writer: dict[str, int] = {}
+        self.readers: dict[str, list[int]] = {}
+        for i, b in enumerate(self.bsyms):
+            for p in b.flat_proxy_outs:
+                self.writer[p.name] = i
+            for name in dict.fromkeys(p.name for p in b.flat_proxy_args):
+                self.readers.setdefault(name, []).append(i)
+        self.returned = {p.name for p in tree_flatten(trc.output)[0] if isinstance(p, Proxy)}
+
+    def made_by(self, p, sym_id: str, readers: int = 1):
+        """The index of the ``sym_id`` that wrote ``p`` as its one output, if
+        ``p`` has just ``readers`` readers and does not leave the trace."""
+        if not isinstance(p, TensorProxy) or p.name in self.returned:
+            return None
+        i = self.writer.get(p.name)
+        if i is None or self.bsyms[i].sym.id != sym_id or self.bsyms[i].output is not p:
+            return None
+        return i if len(self.readers.get(p.name, ())) == readers else None
+
+
+def _token_major(uses: _Uses, p):
+    """``p`` (B, h, T, hs) as ``permute(reshape(s, (B, T, h, hs)), (0, 2, 1, 3))``:
+    (s, the two indices), or None."""
+    perm = uses.made_by(p, "torch.permute")
+    if perm is None or tuple(_dims(uses.bsyms[perm].args[1:])) != _HEADS_FIRST:
+        return None
+    mid = uses.bsyms[perm].args[0]
+    resh = uses.made_by(mid, "torch.reshape")
+    if resh is None:
+        return None
+    s = uses.bsyms[resh].args[0]
+    B, h, T, hs = p.shape
+    if tuple(mid.shape) != (B, T, h, hs) or tuple(getattr(s, "shape", ())) != (B, T, h * hs):
+        return None
+    return s, [perm, resh]
+
+
+def _dims(rest):
+    return rest[0] if len(rest) == 1 and isinstance(rest[0], (tuple, list)) else rest
+
+
+def _last_dim_slice(uses: _Uses, s):
+    """``s`` as ``lin[..., a:b]``: (lin, a, b, the index), or None."""
+    i = uses.made_by(s, "torch.getitem")
+    if i is None:
+        return None
+    lin, key = uses.bsyms[i].args
+    key = key if isinstance(key, tuple) else (key,)
+    *lead, last = key
+    whole = lambda k: k is Ellipsis or (isinstance(k, slice) and k == slice(None))
+    if not (all(whole(k) for k in lead) and isinstance(last, slice) and last.step in (None, 1)
+            and (Ellipsis in lead or len(key) == len(lin.shape))):
+        return None
+    a, b, _ = last.indices(lin.shape[-1])
+    return lin, a, b, i
+
+
+def _roped(uses: _Uses, p):
+    """``p`` as ``apply_rope(x, cos, sin)`` read by the attention call alone:
+    (x, cos, sin, the index), or None."""
+    i = uses.made_by(p, "torch.apply_rope")
+    return None if i is None else (*uses.bsyms[i].args, i)
+
+
+def _match(uses: _Uses, b: dict):
+    """What of the idiom stands in front of an attention call: the operands of
+    the rewrite and the indices that go, or None.
+
+    Packed: q, k and v are the three slices that tile one ``linear``'s output.
+    Else q alone, straight from a ``linear`` of its own."""
+    q, k, v = b["query"], b["key"], b["value"]
+    rq = _roped(uses, q)
+    if rq is None or (tq := _token_major(uses, rq[0])) is None:
+        return None
+    gone = [rq[3], *tq[1]]
+    lin_at = uses.made_by(tq[0], "torch.linear")
+    if lin_at is not None:
+        return dict(lin=lin_at, q_tables=rq[1:3], gone=[*gone, lin_at])
+
+    rk = _roped(uses, k)
+    if rk is None or (tk := _token_major(uses, rk[0])) is None or (tv := _token_major(uses, v)) is None:
+        return None
+    slices = [_last_dim_slice(uses, s) for s in (tq[0], tk[0], tv[0])]
+    if any(s is None for s in slices) or len({s[0].name for s in slices}) != 1:
+        return None
+    lin = slices[0][0]
+    lin_at = uses.made_by(lin, "torch.linear", readers=3)
+    (H, hs), G = (q.shape[1], q.shape[3]), k.shape[1]
+    tiles = [(s[1], s[2]) for s in slices] == [(0, H * hs), (H * hs, (H + G) * hs), ((H + G) * hs, lin.shape[-1])]
+    if lin_at is None or not tiles or tuple(v.shape) != tuple(k.shape):
+        return None
+    gone += [rk[3], *tk[1], *tv[1], *(s[3] for s in slices), lin_at]
+    return dict(lin=lin_at, q_tables=rq[1:3], k_tables=rk[1:3], gone=gone)
+
+
+def _rewritten(trc: TraceCtx, uses: _Uses, at: int, b: dict, m: dict) -> list:
+    """The lines that take the attention call's place."""
+    import thunder_tpu.torch as ltorch
+
+    sdpa = uses.bsyms[at]
+    q, k, v = b["query"], b["key"], b["value"]
+    x, w, *bias = uses.bsyms[m["lin"]].args
+    H, G = q.shape[1], k.shape[1]
+    scale = float(pyval(b["scale"])) if b["scale"] is not None else 1.0 / math.sqrt(q.shape[-1])
+    with tracectx(trc):
+        region, trc.region = trc.region, sdpa.region
+        trc.push_scope(lines := [])
+        try:
+            split = 1
+            if "k_tables" in m:  # packed: k and v come out of the same projection
+                split = heads_per_lane_group(q.shape[-1], H, G)
+                packed = ltorch.linear_heads(x, w, *bias, heads=(H + 2 * G) // split)
+                k = ltorch.apply_rope_heads(packed, *m["k_tables"], H, G, 1.0, split)
+                v = ltorch.split_heads(packed, H + G, G, split) if split > 1 else packed[:, H + G:]
+            else:
+                packed = ltorch.linear_heads(x, w, *bias, heads=H)
+            q = ltorch.apply_rope_heads(packed, *m["q_tables"], 0, H, scale, split)
+            y = ltorch.scaled_dot_product_attention(q, k, v, is_causal=b["is_causal"], scale=1.0,
+                                                    enable_gqa=b["enable_gqa"])
+        finally:
+            trc.pop_scope()
+            trc.region = region
+    # the result under the name its readers know
+    lines[-1] = lines[-1].from_bsym_swap_proxies({variableify(y): sdpa.output})
+    return lines
+
+
+def fold_attention_layouts(trc: TraceCtx, executors) -> TraceCtx:
+    """Forward-trace pass. Counts the sites it rewrote under ``trc.tags[FOLDED_TAG]``."""
+    trc.tags[FOLDED_TAG] = 0
+    executors = tuple(executors or ())
+    names = {getattr(e, "name", None) for e in executors}
+    ids = {str(b.sym.id) for b in trc.bound_symbols}
+    if not {"pallas", "flash"} <= names or _SDPA not in ids or any("_bwd" in i for i in ids):
+        return trc
+    start = time.perf_counter_ns()
+    uses = _Uses(trc)
+    put: dict[int, list] = {}
+    gone: set[int] = set()
+    for at, sdpa in enumerate(uses.bsyms):
+        if sdpa.sym.id != _SDPA:
+            continue
+        b = _bound_sdpa(sdpa.args, sdpa.kwargs)
+        if b["attn_mask"] is not None or float(pyval(b["dropout_p"])) != 0.0:
+            continue
+        m = _match(uses, b)
+        if m is None:
+            continue
+        lines = _rewritten(trc, uses, at, b, m)
+        if any(would_claim(line, executors) != _CLAIMED_BY[line.sym.id] for line in lines if line.sym.id in _CLAIMED_BY):
+            continue
+        put[at] = lines
+        gone.update(m["gone"])
+
+    if not put:
+        return trc
+    new = from_trace(trc)
+    for i, bsym in enumerate(uses.bsyms):
+        if i not in gone:
+            new.bound_symbols.extend(put.get(i, (bsym,)))
+    new.tags[FOLDED_TAG] = len(put)
+    return wrap_in_trace_provenance(new, "Attention layout folding", start)

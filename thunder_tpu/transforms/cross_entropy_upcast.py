@@ -29,6 +29,7 @@ from thunder_tpu.core.prims import PrimIDs
 from thunder_tpu.core.proxies import Proxy, TensorProxy
 from thunder_tpu.core.pytree import tree_flatten
 from thunder_tpu.core.trace import TraceCtx, tracectx, wrap_in_trace_provenance
+from thunder_tpu.executors.passes import would_claim
 
 FOLDED_TAG = "cross_entropy_upcasts_folded"  # how many (forward, backward) pairs the pass folded
 
@@ -40,17 +41,6 @@ _NARROW = (dtypes.bfloat16,)
 
 def _is_convert(bsym, to) -> bool:
     return bsym.sym.id is PrimIDs.CONVERT_ELEMENT_TYPE and dtypes.to_dtype(bsym.args[1]) in to
-
-
-def _claimed_by_pallas(bsym, executors) -> bool:
-    """Whether the claiming pass would give ``bsym`` whole to ``pallas``:
-    asked in its order, of the checkers alone (no fuel is spent)."""
-    from thunder_tpu.resilience.demotion import is_quarantined
-
-    for ex in executors:
-        if not is_quarantined(bsym.sym.id, ex.name) and ex.accepts(bsym):
-            return ex.name == "pallas"
-    return False
 
 
 def _match(bsyms, readers, returned, up: int):
@@ -145,7 +135,7 @@ def fold_cross_entropy_upcasts(trc: TraceCtx, executors) -> TraceCtx:
             grad = bsyms[down].output if not bw_chain else TensorProxy(like=bsyms[bwd].output, dtype=logits.dtype)
         new_ce = bsyms[ce].from_bsym(args=(logits, *bsyms[ce].args[1:]))
         new_bwd = bsyms[bwd].from_bsym(args=(bsyms[bwd].args[0], logits, *bsyms[bwd].args[2:]), output=grad)
-        if not (_claimed_by_pallas(new_ce, executors) and _claimed_by_pallas(new_bwd, executors)):
+        if not (would_claim(new_ce, executors) == would_claim(new_bwd, executors) == "pallas"):
             continue
         # What each decomposes into, should its claim fail later: the program as written.
         wide = bsyms[ce].args[0]
