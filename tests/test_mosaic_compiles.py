@@ -65,6 +65,8 @@ ROPE_SHAPES = [
     ("bfloat16", (1, 32, 4096, 128), 128),  # mistral-7b.train's q: full rotary
     ("bfloat16", (2, 64, 4096, 192), 64),   # a.x-k1.fwd's q: the rope part first, 64 of 192 lanes
     ("bfloat16", (2, 1, 4096, 64), 64),     # a.x-k1.fwd's one rope key for all heads
+    ("bfloat16", (2, 32, 4096, 64), 64),    # lfm2-8b-a1b.fwd's normed q: full rotary on heads of 64
+    ("bfloat16", (2, 8, 4096, 64), 64),     # and its k, a head for four query heads
 ]
 
 
@@ -250,6 +252,27 @@ def test_attention_with_narrower_value_heads_compiles_for_v5e(one_chip, monkeypa
     assert compiled.out_info.shape == (*bht, d_v)
 
 
+def test_grouped_query_attention_at_head_64_compiles_for_v5e(one_chip, monkeypatch):
+    """lfm2-8b-a1b.fwd's attention call: 32 query heads of 64 on 8 key-value heads
+    at T=4096, which no other cell asks of splash (pythia has heads of 64 and a
+    key head each, mistral four query heads a key head at 128)."""
+    import jax
+    import jax.numpy as jnp
+
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import flashex
+
+    monkeypatch.setattr(flashex, "_interpret", lambda: False)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    proxy = lambda h: SimpleNamespace(shape=(2, h, 4096, 64), dtype=dtypes.bfloat16)
+    assert flashex._sdpa_checker(proxy(32), proxy(8), proxy(8), is_causal=True, enable_gqa=True)
+    sds = lambda h: jax.ShapeDtypeStruct((2, h, 4096, 64), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v: flashex._sdpa_impl(q, k, v, is_causal=True, enable_gqa=True)).lower(
+        sds(32), sds(8), sds(8)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.out_info.shape == (2, 32, 4096, 64)
+
+
 def test_grouped_matmul_compiles_for_v5e_as_a_kernel_that_walks_the_groups(one_chip):
     """The routed experts' grouped matmul at a.x-k1.fwd's worst-case buffer (8 rows
     a token, 12 held experts): XLA's ragged dot is a Mosaic call on the v5e, with
@@ -287,6 +310,30 @@ def test_claimed_routed_experts_compile_for_v5e_with_the_short_buffer_and_the_wo
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 6 and " conditional(" in text
     assert all(f"bf16[{rows},7168]" in text for rows in (8192, 65536)) and "bf16[16384,7168]" not in text
+
+
+def test_claimed_routed_experts_compile_for_v5e_on_one_buffer_where_every_expert_is_held(one_chip, monkeypatch):
+    """lfm2-8b-a1b.fwd's expert layer as the pallas executor claims it: 8192
+    tokens, 4 choices among 32 experts of 2048 x 1792, all held: one buffer of
+    32,768 rows, three megablox calls, no conditional; the tiles along the
+    experts' width are 896, which divides 1792."""
+    import jax
+    import jax.numpy as jnp
+
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import pallasex
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    shapes = {"x": ((8192, 2048), "bfloat16"), "top_i": ((8192, 4), "int32"), "top_w": ((8192, 4), "float32"),
+              "w_gate": ((32, 2048, 1792), "bfloat16"), "w_up": ((32, 2048, 1792), "bfloat16"),
+              "w_down": ((32, 1792, 2048), "bfloat16")}
+    assert pallasex._moe_experts_checker(*(SimpleNamespace(shape=s, dtype=getattr(dtypes, d)) for s, d in shapes.values()))
+    assert (pallasex._gmm_tile(1792), pallasex._gmm_tile(2048)) == (896, 1024)
+    sds = [jax.ShapeDtypeStruct(s, getattr(jnp, d), sharding=one_chip) for s, d in shapes.values()]
+    compiled = jax.jit(lambda *a: pallasex._moe_experts_impl(*a, 0, 32)).lower(*sds).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3 and " conditional(" not in text
+    assert "bf16[32768,2048]" in text and "bf16[32768,1792]" in text
 
 
 ROPE_HEADS_SHAPES = [

@@ -525,6 +525,29 @@ class TestAnnotatedCodegen:
         hlo = jax.jit(final.python_callable()).lower(x).as_text(debug_info=True)
         assert "outer/inner/" in hlo
 
+    @pytest.mark.parametrize("name,layers", [("conv", 3), ("attn.qk_norm", 1)])
+    def test_a_layers_mixer_has_a_region_of_its_own_in_the_program_and_the_hlo(self, name, layers):
+        """The model opens ``conv`` around each gated short convolution with its
+        two projections and ``attn.qk_norm`` around the norms of the query and
+        key heads (``models/gpt.py``, ISSUE 31): one ``with`` a layer that has
+        the mixer, the lines inside carry the region, and jax's HLO names it."""
+        import jax
+
+        from thunder_tpu.core import dtypes
+        from thunder_tpu.models import gpt
+
+        cfg = gpt.name_to_config("lfm2-tiny")
+        params = gpt.init_params(cfg, dtype=dtypes.float32, seed=0)
+        idx = np.arange(32, dtype=np.int32).reshape(2, 16) % cfg.padded_vocab_size
+        jf = ttpu.jit(lambda p, i: gpt.forward(p, i, cfg), executors=["jax"])
+        jf(params, idx)
+        final = ttpu.last_traces(jf)[-1]
+        assert final.python().count(f"with __region('{name}'):") == layers
+        inside = [b.sym.name for b in final.bound_symbols if b.region == name]
+        assert inside and ("linear" in inside) == (name == "conv") and ("rsqrt" in inside) == (name == "attn.qk_norm")
+        hlo = jax.jit(final.python_callable()).lower(*jax.tree_util.tree_leaves((params, idx))).as_text(debug_info=True)
+        assert f"/{name}/" in hlo
+
 
 # =============================================================================
 # Event replay / recompile-storm analysis

@@ -549,6 +549,18 @@ _GMM_TILING = (512, 1024, 1024)
 _SHORT_BUFFER_OVER_EVEN_LOAD = 2
 
 
+def _gmm_tile(width: int, most: int = _GMM_TILING[1]) -> int:
+    """The grouped matmul's tile along a dimension of ``width``: the largest
+    multiple of the lanes between half of ``most`` and ``most`` that divides it
+    (896 for experts 1792 wide, where a tile of 1024 leaves the second a quarter
+    empty and masks the down projection's contraction), else ``most`` or the
+    width, as before: a last tile partly empty costs less than many small ones."""
+    for tile in range(min(most, width) // _LANE * _LANE, most // 2 - 1, -_LANE):
+        if width % tile == 0:
+            return tile
+    return min(most, width)
+
+
 def _moe_experts_checker(x, top_i, top_w, w_gate, w_up, w_down, expert_offset=0, n_expert=None):
     if dtypes.to_dtype(x.dtype) is not dtypes.bfloat16 or dtypes.to_dtype(w_gate.dtype) is not dtypes.bfloat16:
         return False
@@ -566,13 +578,13 @@ def _moe_experts_impl(x, top_i, top_w, w_gate, w_up, w_down, expert_offset=0, n_
 
     (N, C), k, held = x.shape, top_i.shape[1], w_gate.shape[0]
     full = min(k, held) * N
-    tm, tk, tn = _GMM_TILING
+    tm = _GMM_TILING[0]
     even = -(-k * N * held // (n_expert or held))  # the rows an even router sends here
     short = -(-_SHORT_BUFFER_OVER_EVEN_LOAD * even // tm) * tm
 
     def grouped(a, b, sizes):
         return gmm(a, b, sizes, preferred_element_type=a.dtype,
-                   tiling=(tm, min(tk, a.shape[1]), min(tn, b.shape[2])), interpret=_interpret())
+                   tiling=(tm, _gmm_tile(a.shape[1]), _gmm_tile(b.shape[2])), interpret=_interpret())
 
     with jax.enable_x64(False):
         local = top_i.astype(jnp.int32) - int(expert_offset)

@@ -8,7 +8,10 @@ LayerNorm vs RMSNorm, GptNeoxMLP vs SwiGLU, partial-rotary RoPE, and
 grouped-query attention. Beyond it: the DeepSeek-V3 family's block as A.X-K1
 publishes it: latent attention (MLA) under YaRN, a leading dense layer, and
 expert layers with a shared expert beside routed ones of which a chip may
-hold a share (``experts_held``, ``expert_offset``).
+hold a share (``experts_held``, ``expert_offset``); and LFM2-8B-A1B's: a mixer
+kind per layer (``layer_types``: a gated short convolution or grouped-query
+attention with an RMSNorm on every query and key head), a router whose bias
+takes part in the choice and not in the weight, a head that is the embedding.
 
 TPU-first design: the model is a *pure function* ``forward(params, idx)``
 over a params pytree — no module object, no buffers, no in-place state. That
@@ -88,6 +91,17 @@ class GPTConfig:
     # YaRN, or None for the plain rope: (factor, original_max_position_embeddings,
     # beta_fast, beta_slow, mscale, mscale_all_dim)
     yarn: Optional[tuple] = None
+    # The mixer of each layer, as published: "full_attention" or "conv" (a gated
+    # short convolution of conv_kernel taps). Empty is attention everywhere; a
+    # model cut in depth runs the first n_layer of them.
+    layer_types: tuple = ()
+    conv_kernel: int = 3
+    qk_norm: bool = False  # an RMSNorm over head_size on every query and key head, before the rope
+    tie_embeddings: bool = False  # the head reads wte: no lm_head_w leaf
+    # The router's bias (a buffer, float32, one an expert) is added to the scores
+    # for the choice and left out of the weights; router_norm_eps is the weights' normaliser's.
+    router_bias: bool = False
+    router_norm_eps: float = 1e-20
 
     @property
     def head_size(self) -> int:
@@ -119,6 +133,9 @@ class GPTConfig:
 
     def layer_mlp_class(self, i: int) -> str:
         return "LLaMAMLP" if i < self.first_dense_layers else self.mlp_class
+
+    def layer_mixer(self, i: int) -> str:
+        return self.layer_types[i] if self.layer_types else "full_attention"
 
     @property
     def qk_head_dim(self) -> int:
@@ -207,6 +224,27 @@ _add(GPTConfig(name="axk1-tiny", block_size=64, vocab_size=96, padded_vocab_size
                first_dense_layers=1, experts_held=4, expert_offset=4, attention_class="MLA",
                q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
                v_head_dim=16, rope_interleaved=True, yarn=(4.0, 16, 32.0, 1.0, 1.0, 1.0)))
+
+# LFM2-8B-A1B (huggingface.co/LiquidAI/LFM2-8B-A1B, model_type lfm2_moe) at its
+# published sizes: 18 gated short convolutions (3 taps) and 6 grouped-query
+# attention layers with normed heads of 64, two dense layers of 7168, then 32
+# experts of 1792, 4 a token by sigmoid scores plus a bias, no shared expert.
+_LFM2_LAYERS = tuple("full_attention" if i in (2, 6, 10, 14, 18, 21) else "conv" for i in range(24))
+_add(GPTConfig(name="LFM2-8B-A1B", block_size=128000, vocab_size=65536, padded_vocab_size=65536,
+               n_layer=24, n_head=32, n_embd=2048, n_query_groups=8, rotary_percentage=1.0,
+               parallel_residual=False, bias=False, norm_class="RMSNorm", norm_eps=1e-5,
+               mlp_class="SharedRoutedMoE", intermediate_size=7168, rope_base=1000000, n_expert=32,
+               n_expert_per_token=4, moe_intermediate_size=1792, first_dense_layers=2,
+               layer_types=_LFM2_LAYERS, conv_kernel=3, qk_norm=True, tie_embeddings=True,
+               router_bias=True, router_norm_eps=1e-6))
+# The same blocks at test size: both mixers, a dense conv layer first.
+_add(GPTConfig(name="lfm2-tiny", block_size=64, vocab_size=96, padded_vocab_size=96,
+               n_layer=4, n_head=4, n_embd=32, n_query_groups=1, rotary_percentage=1.0,
+               parallel_residual=False, bias=False, norm_class="RMSNorm", norm_eps=1e-5,
+               mlp_class="SharedRoutedMoE", intermediate_size=64, rope_base=1000000, n_expert=8,
+               n_expert_per_token=2, moe_intermediate_size=16, first_dense_layers=1,
+               layer_types=("conv", "full_attention", "conv", "conv"), conv_kernel=3, qk_norm=True,
+               tie_embeddings=True, router_bias=True, router_norm_eps=1e-6))
 
 # Mistral — reference benchmark ladder step 5 (GQA).
 _add(GPTConfig(name="mistral-7b", block_size=4096, vocab_size=32000, padded_vocab_size=32000,
@@ -302,7 +340,15 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
         if C.bias:
             p["qkv_b"] = zeros(C.qkv_out)
             p["proj_b"] = zeros(C.n_embd)
+        if C.qk_norm:  # one weight of head_size for all heads
+            p["q_norm"] = {"weight": ones(C.head_size)}
+            p["k_norm"] = {"weight": ones(C.head_size)}
         return p
+
+    def conv_params():
+        # conv_w is the depthwise Conv1d's (channels, 1, taps) without the 1, oldest tap first.
+        return {"in_proj_w": w(3 * C.n_embd, C.n_embd), "conv_w": w(C.n_embd, C.conv_kernel),
+                "out_proj_w": w(C.n_embd, C.n_embd, std=0.02 / np.sqrt(2 * C.n_layer))}
 
     def swiglu_params(hidden):
         p = {
@@ -326,6 +372,8 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
             p = {"router_w": w(C.n_expert, C.n_embd),
                  "experts_gate": w(E, C.n_embd, H), "experts_up": w(E, C.n_embd, H),
                  "experts_down": w(E, H, C.n_embd, std=0.02 / np.sqrt(2 * C.n_layer))}
+            if C.router_bias:  # a buffer: float32 whatever the weights are, and no gradient reaches it
+                p["router_bias"] = jnp.zeros((C.n_expert,), dtype=jnp.float32)
             if C.n_shared_experts:
                 p["shared"] = swiglu_params(C.n_shared_experts * H)
             return p
@@ -338,8 +386,11 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
         return p
 
     def block_params(i):
-        p: dict[str, Any] = {"norm_1": norm_params(), "attn": attn_params(),
-                             "mlp": mlp_params(C.layer_mlp_class(i))}
+        p: dict[str, Any] = {"norm_1": norm_params(), "mlp": mlp_params(C.layer_mlp_class(i))}
+        if C.layer_mixer(i) == "conv":
+            p["conv"] = conv_params()
+        else:
+            p["attn"] = attn_params()
         if not C.shared_attention_norm:
             p["norm_2"] = norm_params()
         return p
@@ -353,7 +404,8 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
     else:
         params["blocks"] = blocks
     params["ln_f"] = norm_params()
-    params["lm_head_w"] = w(C.padded_vocab_size, C.n_embd)
+    if not C.tie_embeddings:
+        params["lm_head_w"] = w(C.padded_vocab_size, C.n_embd)
     return params
 
 
@@ -432,12 +484,24 @@ def _attention(x, p, cos, sin, config: GPTConfig):
     k = ttorch.permute(ttorch.reshape(k, (B, T, G, hs)), (0, 2, 1, 3))
     v = ttorch.permute(ttorch.reshape(v, (B, T, G, hs)), (0, 2, 1, 3))
 
+    if config.qk_norm:
+        with region("attn.qk_norm"):
+            q = ttorch.rms_norm(q, (hs,), p["q_norm"]["weight"], eps=config.norm_eps)
+            k = ttorch.rms_norm(k, (hs,), p["k_norm"]["weight"], eps=config.norm_eps)
     q = _apply_rope(q, cos, sin, config)
     k = _apply_rope(k, cos, sin, config)
 
     y = ttorch.scaled_dot_product_attention(q, k, v, is_causal=True, enable_gqa=(G != H))
     y = ttorch.reshape(ttorch.permute(y, (0, 2, 1, 3)), (B, T, H * hs))
     return ttorch.linear(y, p["proj_w"], p.get("proj_b"))
+
+
+def _short_conv(x, p, config: GPTConfig):
+    """The gated short convolution: ``[B | C | u] = in_proj(x)``, a causal
+    depthwise convolution of ``conv_kernel`` taps over ``B * u``, times ``C``,
+    then ``out_proj``."""
+    with region("conv"):
+        return ttorch.linear(ttorch.short_conv(ttorch.linear(x, p["in_proj_w"]), p["conv_w"]), p["out_proj_w"])
 
 
 def _deinterleave_rows(w):
@@ -501,20 +565,29 @@ def _moe_mlp(x, p, config: GPTConfig):
     return ttorch.reshape(out, (B, T, C))
 
 
-def _shared_routed_moe(x, p, config: GPTConfig, routed_rows=None):
+def _shared_routed_moe(x, p, config: GPTConfig, counts=None):
     """``SwiGLU_shared(x) + sum_i w_i SwiGLU_i(x)`` over the chosen experts
     held here (``experts_held`` from ``expert_offset``): the router scores all
     ``n_expert``, normalises over all k chosen, and this chip adds its own
-    experts' part. ``routed_rows`` collects the rows each held expert got."""
+    experts' part. ``counts`` collects, a layer, the rows each held expert got
+    (``"rows"``) and, where the router has a bias, the (token, choice) pairs
+    whose expert the same router without its bias does not choose (``"changed"``)."""
     B, T, C = x.shape
     xf = ttorch.reshape(x, (B * T, C))
+
+    def route(bias):
+        return ttorch.moe_route(xf, p["router_w"], config.n_expert_per_token, config.n_expert_groups,
+                                config.n_limited_groups, config.routed_scaling_factor, bias, config.router_norm_eps)
+
     with region("moe.route"):
-        top_i, top_w = ttorch.moe_route(xf, p["router_w"], config.n_expert_per_token, config.n_expert_groups,
-                                        config.n_limited_groups, config.routed_scaling_factor)
-    if routed_rows is not None:
+        top_i, top_w = route(p.get("router_bias"))
+    if counts is not None:
         held = ttorch.arange(config.expert_offset, config.expert_offset + config.held_experts,
                              device=x.device, dtype=top_i.dtype)
-        routed_rows.append(ttorch.sum((ttorch.unsqueeze(top_i, -1) == held).to(dtypes.int32), (0, 1)))
+        counts["rows"].append(ttorch.sum((ttorch.unsqueeze(top_i, -1) == held).to(dtypes.int32), (0, 1)))
+        if "router_bias" in p:
+            kept = ttorch.unsqueeze(top_i, -1) == ttorch.unsqueeze(route(None)[0], 1)        # (N, k, k)
+            counts["changed"].append(top_i.shape[0] * top_i.shape[1] - ttorch.sum(kept.to(dtypes.int32), (0, 1, 2)))
     with region("moe.experts"):
         out = ttorch.moe_experts(xf, top_i, top_w, p["experts_gate"], p["experts_up"], p["experts_down"],
                                  config.expert_offset, config.n_expert)
@@ -524,32 +597,35 @@ def _shared_routed_moe(x, p, config: GPTConfig, routed_rows=None):
     return ttorch.reshape(out, (B, T, C))
 
 
-def _mlp(x, p, kind: str, config: GPTConfig, routed_rows=None):
+def _mlp(x, p, kind: str, config: GPTConfig, counts=None):
     if kind == "MoEMLP":
         return _moe_mlp(x, p, config)
     if kind == "SharedRoutedMoE":
-        return _shared_routed_moe(x, p, config, routed_rows)
+        return _shared_routed_moe(x, p, config, counts)
     if kind == "LLaMAMLP":
         return _swiglu(x, p)
     h = ttorch.gelu(ttorch.linear(x, p["fc_w"], p.get("fc_b")))
     return ttorch.linear(h, p["proj_w"], p.get("proj_b"))
 
 
-def _attend(x, p, cos, sin, config: GPTConfig):
+def _mix(x, p, cos, sin, config: GPTConfig):
+    """The layer's mixer, by the parameters it was given: the tree was built from ``layer_mixer(i)``."""
+    if "conv" in p:
+        return _short_conv(x, p["conv"], config)
     if config.attention_class == "MLA":
         with region("mla"):
-            return _mla_attention(x, p, cos, sin, config)
-    return _attention(x, p, cos, sin, config)
+            return _mla_attention(x, p["attn"], cos, sin, config)
+    return _attention(x, p["attn"], cos, sin, config)
 
 
-def _block(x, p, cos, sin, kind: str, config: GPTConfig, routed_rows=None):
+def _block(x, p, cos, sin, kind: str, config: GPTConfig, counts=None):
     n1 = _norm(x, p["norm_1"], config)
-    attn_out = _attend(n1, p["attn"], cos, sin, config)
+    attn_out = _mix(n1, p, cos, sin, config)
     if config.parallel_residual:
         n2 = n1 if config.shared_attention_norm else _norm(x, p["norm_2"], config)
-        return x + attn_out + _mlp(n2, p["mlp"], kind, config, routed_rows)
+        return x + attn_out + _mlp(n2, p["mlp"], kind, config, counts)
     x = x + attn_out
-    return x + _mlp(_norm(x, p["norm_2"], config), p["mlp"], kind, config, routed_rows)
+    return x + _mlp(_norm(x, p["norm_2"], config), p["mlp"], kind, config, counts)
 
 
 def _layers(params: dict, config: GPTConfig):
@@ -558,27 +634,34 @@ def _layers(params: dict, config: GPTConfig):
     return [(p, config.layer_mlp_class(i)) for i, p in enumerate(blocks)]
 
 
-def _hidden(params: dict, idx, config: GPTConfig, routed_rows=None):
+def _hidden(params: dict, idx, config: GPTConfig, counts=None):
     B, T = idx.shape
     x = ttorch.embedding(idx, params["wte"])  # (B, T, C)
     cos, sin = _rope_cache(T, config, device=x.device, dtype=x.dtype)
     for p, kind in _layers(params, config):
-        x = _block(x, p, cos, sin, kind, config, routed_rows)
+        x = _block(x, p, cos, sin, kind, config, counts)
     return _norm(x, params["ln_f"], config)
 
 
 def forward(params: dict, idx, config: GPTConfig):
     """Token ids (B, T) int → logits (B, T, padded_vocab_size)."""
-    return ttorch.linear(_hidden(params, idx, config), params["lm_head_w"])
+    return ttorch.linear(_hidden(params, idx, config), params["wte" if config.tie_embeddings else "lm_head_w"])
+
+
+def router_counts(params: dict, idx, config: GPTConfig):
+    """What the routers of the expert layers do with these ids, by the program's
+    own count: ``(rows (expert layers, experts held), changed (expert layers,)
+    or None)``. ``rows`` are the (token, choice) pairs sent to each expert held
+    here, which is what its grouped matmuls compute; ``changed``, where the
+    router has a bias, the pairs whose expert the router without it leaves out."""
+    counts: dict = {"rows": [], "changed": []}
+    _hidden(params, idx, config, counts)
+    return ttorch.stack(counts["rows"], 0), (ttorch.stack(counts["changed"], 0) if counts["changed"] else None)
 
 
 def routed_rows(params: dict, idx, config: GPTConfig):
-    """(expert layers, experts held) counts: the (token, choice) pairs that the
-    router of each expert layer sends to each expert held here for these ids,
-    which is the rows its grouped matmuls compute."""
-    rows: list = []
-    _hidden(params, idx, config, rows)
-    return ttorch.stack(rows, 0)
+    """``router_counts``'s rows."""
+    return router_counts(params, idx, config)[0]
 
 
 def loss_fn(params: dict, idx, targets, config: GPTConfig):

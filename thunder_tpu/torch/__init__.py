@@ -1417,27 +1417,51 @@ def apply_rope_heads(x, cos, sin, first: int, heads: int, scale: float = 1.0, sp
     return roped if scale == 1.0 else mul(roped, scale)
 
 
+@torchsymbol(id="torch.short_conv")
+def short_conv(bcu, w):
+    """The gated short convolution between its two projections (LFM2's conv
+    mixer): bcu (B, T, 3C) is ``[B | C | u]``, w (C, K) a depthwise causal
+    filter, oldest tap first. ``z = B * u``;
+    ``c[t] = sum_j w[:, j] * z[t - (K - 1 - j)]`` with ``z[<0] = 0``; returns
+    ``C * c``, (B, T, C).
+
+    The taps are K shifted products of one left-padded ``z`` (pad, slices,
+    multiplies and adds, which the backward has rules for and XLA fuses into
+    one pass over bcu), not ``conv1d(groups=C)``, whose channel-major layout
+    costs two transposes of the activation (CHANGES.md, PR 31, has both times)."""
+    C, K = w.shape
+    check(bcu.ndim == 3 and bcu.shape[-1] == 3 * C, lambda: f"short_conv: {tuple(bcu.shape)} is not (B, T, 3 * {C})")
+    T = bcu.shape[1]
+    z = pad(bcu[..., :C] * bcu[..., 2 * C:], (0, 0, K - 1, 0))
+    c = z[:, 0:T] * w[:, 0]
+    for j in range(1, K):
+        c = c + z[:, j:j + T] * w[:, j]
+    return bcu[..., C:2 * C] * c
+
+
 @torchsymbol(id="torch.moe_route")
 def moe_route(x, router_w, top_k: int, n_group: int = 1, topk_group: int = 1,
-              routed_scaling_factor: float = 1.0):
+              routed_scaling_factor: float = 1.0, bias=None, norm_eps: float = 1e-20):
     """Sigmoid scores with group-limited top-k (the DeepSeek-V3 family's
-    router without a correction bias): x (N, C), router_w (E, C) ->
+    router; LFM2's with ``bias``): x (N, C), router_w (E, C) ->
     ``(top_i (N, k) int64, top_w (N, k) float32)``.
 
     Scores are ``sigmoid(float32(x) float32(W)^T)``. The E experts lie in
     ``n_group`` groups; a group's score is the sum of its two best scores; the
     best ``topk_group`` groups stay, the others' scores are masked to 0, and
-    the top ``k`` of what is left are chosen. The weights are the unmasked
-    scores of the chosen, normalised over all k and multiplied by
+    the top ``k`` of what is left are chosen. ``bias`` (E,) float32, where
+    given, is added to the scores for the choice, groups' and experts' alike,
+    and takes no part in the weights. The weights are the unmasked, unbiased
+    scores of the chosen, over their sum plus ``norm_eps`` and multiplied by
     ``routed_scaling_factor``. Float32 throughout, as published:
     a bf16 score flips near-tied choices."""
     N, E = x.shape[0], router_w.shape[0]
     check(E % n_group == 0, lambda: f"{E} experts do not divide into {n_group} groups")
     scores = sigmoid(linear(clang.maybe_convert_to_dtype(x, dtypes.float32),
                             clang.maybe_convert_to_dtype(router_w, dtypes.float32)))
-    choose_from = scores
+    choose_from = scores if bias is None else scores + bias
     if n_group > 1:
-        grouped = reshape(scores, (N, n_group, E // n_group))
+        grouped = reshape(choose_from, (N, n_group, E // n_group))
         best_two, _ = topk(grouped, 2, -1)
         _, kept = topk(sum(best_two, -1), topk_group, -1)                        # (N, topk_group)
         groups = clang.arange(0, n_group, 1, device=x.device, dtype=dtypes.int64)
@@ -1446,7 +1470,7 @@ def moe_route(x, router_w, top_k: int, n_group: int = 1, topk_group: int = 1,
         choose_from = reshape(where(keep, grouped, clang.full_like(grouped, 0.0)), (N, E))
     _, top_i = topk(choose_from, top_k, -1)
     top_w = take_along_dim(scores, top_i, 1)
-    return top_i, top_w / (sum(top_w, -1, True) + 1e-20) * routed_scaling_factor
+    return top_i, top_w / (sum(top_w, -1, True) + norm_eps) * routed_scaling_factor
 
 
 @torchsymbol(id="torch.moe_experts")
