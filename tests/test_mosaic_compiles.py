@@ -273,6 +273,17 @@ def test_grouped_query_attention_at_head_64_compiles_for_v5e(one_chip, monkeypat
     assert compiled.out_info.shape == (2, 32, 4096, 64)
 
 
+def _arrays_written(text):
+    """(instruction, shape) of what the entry computation and a conditional's
+    branches write: the instructions of every computation that is not the body
+    of a fusion, which never reaches memory."""
+    made = []
+    for body in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \([^\n]*\) -> [^\n]*\{\n)", text):
+        if not body.lstrip().startswith("%fused_computation"):
+            made += re.findall(r"^\s+(?:ROOT )?%([\w\-]+?)[.\d]* = \(?(\w+\[[\d,]*\])", body, re.M)
+    return made
+
+
 def test_grouped_matmul_compiles_for_v5e_as_a_kernel_that_walks_the_groups(one_chip):
     """The routed experts' grouped matmul at a.x-k1.fwd's worst-case buffer (8 rows
     a token, 12 held experts): XLA's ragged dot is a Mosaic call on the v5e, with
@@ -310,13 +321,24 @@ def test_claimed_routed_experts_compile_for_v5e_with_the_short_buffer_and_the_wo
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 6 and " conditional(" in text
     assert all(f"bf16[{rows},7168]" in text for rows in (8192, 65536)) and "bf16[16384,7168]" not in text
+    # The way back: 8 gathers of (8192, 7168) that one fusion masks (a select, on bf16), weighs and sums, in either
+    # branch; no array of a buffer's size in float32 or stacked, and no fill after a gather.
+    written = _arrays_written(text)
+    assert not [made for made in written if made[1].startswith("f32[") and made[1].endswith(",7168]")]
+    assert not [made for made in written if made[1] in ("bf16[8,8192,7168]", "bf16[8192,8,7168]")]
+    assert "broadcast_select_fusion" not in dict(written)
+    assert written.count(("fusion", "bf16[8192,7168]")) == 1 + 8 + 8  # into the short buffer, and back from either
+    assert written.count(("add_convert_fusion", "bf16[8192,7168]")) == 2
 
 
 def test_claimed_routed_experts_compile_for_v5e_on_one_buffer_where_every_expert_is_held(one_chip, monkeypatch):
     """lfm2-8b-a1b.fwd's expert layer as the pallas executor claims it: 8192
     tokens, 4 choices among 32 experts of 2048 x 1792, all held: one buffer of
     32,768 rows, three megablox calls, no conditional; the tiles along the
-    experts' width are 896, which divides 1792."""
+    experts' width are 896, which divides 1792. Called as a layer calls it,
+    (B, T, C) around it and the residual and the next norm's statistics behind:
+    there a sum left open to the caller's fusions kept four float32 converts of
+    (8192, 2048) standing alone."""
     import jax
     import jax.numpy as jnp
 
@@ -330,10 +352,24 @@ def test_claimed_routed_experts_compile_for_v5e_on_one_buffer_where_every_expert
     assert pallasex._moe_experts_checker(*(SimpleNamespace(shape=s, dtype=getattr(dtypes, d)) for s, d in shapes.values()))
     assert (pallasex._gmm_tile(1792), pallasex._gmm_tile(2048)) == (896, 1024)
     sds = [jax.ShapeDtypeStruct(s, getattr(jnp, d), sharding=one_chip) for s, d in shapes.values()]
-    compiled = jax.jit(lambda *a: pallasex._moe_experts_impl(*a, 0, 32)).lower(*sds).compile()
+
+    def layer(x, *routing_and_weights):
+        stream = x.reshape(2, 4096, 2048)
+        stream = stream + pallasex._moe_experts_impl(x, *routing_and_weights, 0, 32).reshape(stream.shape)
+        return stream, jnp.mean(jnp.square(stream.astype(jnp.float32)), -1)
+
+    compiled = jax.jit(layer).lower(*sds).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3 and " conditional(" not in text
     assert "bf16[32768,2048]" in text and "bf16[32768,1792]" in text
+    # Each row moves once each way: no float32 copy of the buffer, stacked or not, no relayout to (N, k, C), no fill
+    # after a gather; four gathers of (8192, 2048) come back and one fusion reads them.
+    written = _arrays_written(text)
+    assert not [made for made in written if made[1].startswith("f32[") and made[1].endswith(",2048]")]
+    assert not [made for made in written if made[1].endswith("[8192,4,2048]")]
+    assert "broadcast_select_fusion" not in dict(written)
+    assert written.count(("fusion", "bf16[8192,2048]")) == 4 and written.count(("fusion", "bf16[32768,2048]")) == 1
+    assert written.count(("add_convert_fusion", "bf16[8192,2048]")) == 1
 
 
 ROPE_HEADS_SHAPES = [

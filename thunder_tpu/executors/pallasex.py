@@ -544,6 +544,22 @@ ex.register_implementation("torch.split_heads", fn=_split_heads_impl, checker=_s
 # worst case (every expert held: mixtral) there is one buffer and no branch. The
 # grouped matmul is megablox's gmm (30.2 ms a call of a.x-k1.fwd against 37.4
 # for XLA's own ragged dot, same seed).
+#
+# The dispatch moves each row once each way (PR 32). The pairs lie choice-major
+# (pair j * N + n), so the way back is k gathers of (N, C) and never a (rows, C)
+# array relaid as (N, k, C), which puts k in the sublanes and costs a copy. No
+# gather asks for jnp.take's fill: order is a permutation and slot is clamped,
+# and the fill is a pass of its own over the buffer. One fusion reads the k
+# gathers: it selects, converts, weighs in float32 and sums over k in order, so
+# no float32 copy of the buffer is written. The sum is written term by term
+# behind a barrier because of what XLA makes of the other forms in the cells'
+# programs: open to the caller's fusions, lfm2-8b-a1b.fwd keeps k converts of
+# (N, C) standing alone; as a reduce over the gathers stacked (k, N, C),
+# a.x-k1.fwd writes the stack, 940 MB a layer.
+# Where some experts are held elsewhere the rows beyond the groups are whatever
+# gmm left there and may be NaN: the mask is a select, on bf16, inside that
+# fusion, and never a multiply by 0. Where every expert is held every pair has
+# a computed row and there is no mask.
 
 _GMM_TILING = (512, 1024, 1024)
 _SHORT_BUFFER_OVER_EVEN_LOAD = 2
@@ -586,24 +602,35 @@ def _moe_experts_impl(x, top_i, top_w, w_gate, w_up, w_down, expert_offset=0, n_
         return gmm(a, b, sizes, preferred_element_type=a.dtype,
                    tiling=(tm, _gmm_tile(a.shape[1]), _gmm_tile(b.shape[2])), interpret=_interpret())
 
+    # Every expert held: every pair has a computed row, and the way back needs no mask.
+    masked = not (int(expert_offset) == 0 and n_expert in (None, held) and k <= held)
+    in_bounds = "promise_in_bounds"  # order is a permutation and slot is clamped: no gather needs a fill
+
     with jax.enable_x64(False):
         local = top_i.astype(jnp.int32) - int(expert_offset)
         here = (local >= 0) & (local < held)
-        key = jnp.where(here, local, held).reshape(N * k)
+        key = jnp.where(here, local, held).T.reshape(k * N)  # choice-major: pair j * N + n
         order = jnp.argsort(key, stable=True)  # held pairs first, by expert
-        slot = jnp.zeros(N * k, jnp.int32).at[order].set(jnp.arange(N * k, dtype=jnp.int32))
+        slot = jnp.zeros(k * N, jnp.int32).at[order].set(jnp.arange(k * N, dtype=jnp.int32))
         sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32), axis=0, dtype=jnp.int32)
+        weight = top_w.astype(jnp.float32)
 
         def on_buffer_of(rows):
             def run():
-                xs = jnp.take(x, order[:rows] // k, axis=0)
+                xs = x.at[order[:rows] % N].get(mode=in_bounds)
                 gate = grouped(xs, w_gate, sizes).astype(jnp.float32)
                 h = (jax.nn.silu(gate) * grouped(xs, w_up, sizes).astype(jnp.float32)).astype(x.dtype)
                 ys = grouped(h, w_down, sizes)
-                # Rows beyond the groups are whatever gmm left there: masked, never multiplied.
-                back = jnp.take(ys, jnp.minimum(slot, rows - 1), axis=0).reshape(N, k, C)
-                back = jnp.where(here[..., None], back.astype(jnp.float32), 0.0)
-                return jnp.sum(back * top_w.astype(jnp.float32)[..., None], axis=1).astype(x.dtype)
+                came = jnp.minimum(slot, rows - 1).reshape(k, N)
+                out = 0.0
+                for j in range(k):
+                    back = ys.at[came[j]].get(mode=in_bounds)
+                    if masked:  # selected out, never multiplied: such a row may be NaN
+                        back = jnp.where(here[:, j, None], back, jnp.zeros((), back.dtype))
+                    out = out + back.astype(jnp.float32) * weight[:, j, None]
+                # The barrier keeps the k converts in the sum's fusion: a reshape after the call draws the sum into
+                # the caller's next fusion otherwise and leaves each behind, a float32 (N, C) written and read.
+                return jax.lax.optimization_barrier(out.astype(x.dtype))
 
             return run
 
