@@ -1,0 +1,419 @@
+"""MiniCPM-SALA's blocks at test size on the CPU, float32, seeded weights:
+block-sparse attention whose blocks each query chooses by scoring pooled keys,
+linear attention with a decay a head, each with its own head layout and rope
+setting, output gates and an output norm, the MiniCPM family's scalings, and a
+head on the last positions. Against the plain reference
+(``perfbench/reference/minicpm_sala.py``), which knows nothing of the program,
+and against loops over single queries written here."""
+
+import dataclasses
+import json
+import os
+
+import numpy as np
+import pytest
+
+import thunder_tpu
+import thunder_tpu.torch as ttorch
+from thunder_tpu.core import dtypes
+from thunder_tpu.models import gpt
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+with open(os.path.join(REPO, "perfbench", "configs", "minicpm-sala.json"), encoding="utf-8") as _f:
+    _FILE = json.load(_f)
+# The stand-in (``--rehearse``'s sizes): sparse, linear, linear, linear; 256 wide, heads of 64, 4 query heads on
+# one key-value head in the sparse layer; blocks of 16 keys, 6 a query, 3 of them forced; dense under 64 positions.
+KEYS = {**_FILE, **_FILE["stand_in"]}
+SPARSE = dict(kernel_size=8, kernel_stride=4, block_size=16, topk=6, init_blocks=1, local_blocks=2)
+HP = {**SPARSE, "matmul_inputs": None}
+T = 256
+
+
+def built(keys=KEYS, seed=5):
+    """(the program's config, its parameters, the same numbers stacked for the reference)."""
+    import jax
+
+    from perfbench import weights
+    from perfbench.jobs import gpt_model
+
+    cfg = gpt_model.gpt_config(keys, rehearse=True)
+    shapes = jax.eval_shape(lambda: gpt.init_params(cfg, dtype=dtypes.float32, device_init=True))
+    return cfg, weights.make_system_weights(shapes, seed), weights.make_reference_weights(shapes, seed)
+
+
+def batch(t=T, seed=0, b=1):
+    return np.random.RandomState(seed).randint(0, KEYS["vocab_size"], (b, t)).astype(np.int32)
+
+
+def qkv(t, heads=4, groups=1, d=16, seed=0):
+    rng = np.random.RandomState(seed)
+    return (rng.randn(1, heads, t, d).astype(np.float32), rng.randn(1, groups, t, d).astype(np.float32),
+            rng.randn(1, groups, t, d).astype(np.float32))
+
+
+def rel(got, want):
+    return float(np.linalg.norm(np.asarray(got, np.float64) - want) / np.linalg.norm(want))
+
+
+# -----------------------------------------------------------------------------
+# The model
+# -----------------------------------------------------------------------------
+
+
+def test_the_registry_lists_the_model_at_its_published_sizes():
+    """Every published key of the configuration file is the registry's: the
+    benchmark lays only the cut in depth over the entry, the layer pattern it
+    runs is the first 10 of the published list, and the decay and the residual
+    scale keep the published depth."""
+    from perfbench import manifest
+    from perfbench.jobs import forward_sparse_linear, gpt_model
+
+    cell = manifest.load_cell("minicpm-sala.fwd-t32k")
+    cfg = gpt_model.gpt_config(manifest.published(cell))
+    listed = gpt.name_to_config("MiniCPM-SALA")
+    assert cfg == dataclasses.replace(listed, n_layer=10)
+    assert (listed.n_layer, listed.n_embd, listed.n_head, listed.query_groups, listed.head_size) == (32, 4096, 32, 2, 128)
+    assert (listed.linear_heads, listed.linear_groups, listed.intermediate_size) == (32, 32, 16384)
+    assert (listed.padded_vocab_size, listed.block_size, listed.tie_embeddings) == (73448, 524288, False)
+    assert listed.layer_types == tuple(forward_sparse_linear.MIXERS[m] for m in _FILE["mixer_types"])
+    assert [listed.layer_types.count(k) for k in ("sparse_attention", "linear_attention")] == [8, 24]
+    assert [i for i, k in enumerate(listed.layer_types) if k == "sparse_attention"] == [0, 9, 16, 17, 22, 29, 30, 31]
+    assert [cfg.layer_mixer(i) for i in range(10)] == ["sparse_attention"] + ["linear_attention"] * 8 + ["sparse_attention"]
+    assert (listed.embedding_scale, listed.logit_divisor) == (_FILE["scale_emb"], 4096 / _FILE["dim_model_base"])
+    assert listed.residual_scale == pytest.approx(_FILE["scale_depth"] / 32 ** 0.5)
+    assert (listed.attn_rope, listed.linear_rope, listed.qk_norm) == (False, True, True)
+    sparse = {f: getattr(listed, g) for f, g in forward_sparse_linear.SPARSE_FIELDS.items()}
+    assert sparse == _FILE["sparse_config"]
+    # the decay of the cut model is the published model's: layer 9 of 32, not of 10
+    assert cfg.linear_decay(1) == listed.linear_decay(1) and len(cfg.linear_decay(1)) == 32
+    assert cfg.linear_decay(1)[0] == pytest.approx(2 ** (-8 / 32) * (1 - 1 / 31 + 1e-5))
+    assert cfg.linear_decay(8)[31] == pytest.approx(2 ** -8 * (1 - 8 / 31 + 1e-5))
+    # every default is yesterday's program: no scaling, roped attention, no gate
+    plain = gpt.name_to_config("mistral-7b")
+    assert (plain.embedding_scale, plain.residual_scale, plain.logit_divisor, plain.attn_rope, plain.attn_output_gate) \
+        == (1.0, 1.0, 1.0, True, False)
+
+
+def test_the_parameter_tree_has_each_kinds_leaves_at_each_kinds_head_layout():
+    import jax
+
+    cfg = dataclasses.replace(gpt.name_to_config("MiniCPM-SALA"), n_layer=2)
+    shapes = jax.eval_shape(lambda: gpt.init_params(cfg, device_init=True))
+    sparse, linear = shapes["blocks"][0]["sparse_attn"], shapes["blocks"][1]["linear_attn"]
+    assert sparse["qkv_w"].shape == ((32 + 2 * 2) * 128, 4096) and linear["qkv_w"].shape == (3 * 32 * 128, 4096)
+    assert sorted(sparse) == ["gate_w", "k_norm", "proj_w", "q_norm", "qkv_w"]
+    assert sorted(linear) == ["gate_w", "k_norm", "out_norm", "proj_w", "q_norm", "qkv_w"]
+    assert linear["out_norm"]["weight"].shape == (4096,) and sparse["q_norm"]["weight"].shape == (128,)
+    assert shapes["lm_head_w"].shape == shapes["wte"].shape == (73448, 4096)
+    count = lambda tree: sum(int(np.prod(leaf.shape)) for leaf in jax.tree_util.tree_leaves(tree))
+    assert count(shapes["blocks"][0]) == pytest.approx(253.8e6, rel=1e-3)   # ISSUE 33's reckoning
+    assert count(shapes["blocks"][1]) == pytest.approx(285.2e6, rel=1e-3)
+
+
+@pytest.mark.parametrize("t", [T, 200, 48], ids=["sparse-256", "sparse-200", "dense-under-64"])
+def test_forward_through_jit_agrees_with_the_reference(t):
+    import jax.numpy as jnp
+
+    from perfbench.reference import minicpm_sala
+
+    cfg, params, stacked = built()
+    idx = batch(t, b=2)
+    jfn = thunder_tpu.jit(lambda p, i: gpt.forward(p, i, cfg))
+    got, want = np.asarray(jfn(params, idx)), np.asarray(minicpm_sala.forward(stacked, jnp.asarray(idx), KEYS))
+    assert got.shape == (2, t, KEYS["vocab_size"])
+    assert rel(got, want) < 2e-5
+    names = [b.sym.name for b in thunder_tpu.last_traces(jfn)[-1].bound_symbols]
+    assert ("topk" in names) == (t >= 64)  # under dense_len no block is chosen: plain causal attention
+
+
+def test_forward_last_is_the_last_rows_of_forward():
+    cfg, params, _ = built()
+    idx = batch(b=2)
+    whole = np.asarray(thunder_tpu.jit(lambda p, i: gpt.forward(p, i, cfg))(params, idx))
+    last = np.asarray(thunder_tpu.jit(lambda p, i: gpt.forward(p, i, cfg, last=24))(params, idx))
+    assert last.shape == (2, 24, KEYS["vocab_size"])
+    np.testing.assert_allclose(last, whole[:, -24:], rtol=1e-5, atol=1e-6)
+    tiny = gpt.name_to_config("llama-tiny")  # and on a model without any of this
+    p = gpt.init_params(tiny, dtype=dtypes.float32)
+    ids = np.random.RandomState(1).randint(0, 96, (2, 32)).astype(np.int32)
+    np.testing.assert_allclose(np.asarray(thunder_tpu.jit(lambda p, i: gpt.forward(p, i, tiny, last=5))(p, ids)),
+                               np.asarray(thunder_tpu.jit(lambda p, i: gpt.forward(p, i, tiny))(p, ids))[:, -5:],
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_the_models_regions_are_named_in_the_generated_program_and_in_the_hlo():
+    import jax
+
+    from perfbench.layer_metrics import _regions
+
+    cfg, params, _ = built()
+    idx = batch()
+    jfn = thunder_tpu.jit(lambda p, i: gpt.forward(p, i, cfg))
+    jfn(params, idx)
+    run = thunder_tpu.last_traces(jfn)[-1]
+    opened = [line.strip() for line in run.python().splitlines() if line.strip().startswith("with __region(")]
+    norm = ["with __region('attn.qk_norm'):"]
+    assert opened == norm + ["with __region('attn.sparse.select'):", "with __region('attn.sparse.attend'):"] \
+        + (norm + ["with __region('attn.linear'):"]) * 3
+    compiled = jax.jit(run.python_callable()).lower(*jax.tree_util.tree_leaves((params, idx))).compile()
+    found = _regions.of_instructions(compiled.as_text())
+    assert set(found.values()) == set(_regions.REGIONS)
+
+
+def test_sparse_selection_counts_are_the_blocks_each_tile_of_queries_chose():
+    cfg, params, _ = built()
+    idx = batch(b=2)
+    counts = np.asarray(thunder_tpu.jit(lambda p, i: gpt.sparse_selection_counts(p, i, cfg))(params, idx))
+    assert counts.shape == (1, 2, 1, T // gpt.SPARSE_TILE)  # sparse layers, batch, key-value heads, tiles
+    # a tile of 128 queries spans 8 blocks of 16: its queries' own, and of the 8 before them what they chose
+    assert (counts[..., 0] == 8).all() and (counts[..., 1] > 8 + 1).all() and (counts <= T // 16).all()
+    q, k, _ = qkv(T, d=64)
+    ids = np.asarray(thunder_tpu.jit(lambda q, k: ttorch.sparse_block_select(q, k, **SPARSE))(q, k))
+    want = [len(set(ids[0, 0, t0:t0 + 128].ravel()) - {-1}) for t0 in (0, 128)]
+    got = np.asarray(thunder_tpu.jit(lambda i: gpt._tile_union(i, T // 16))(ids))
+    assert got[0, 0].tolist() == want
+
+
+# -----------------------------------------------------------------------------
+# The composites against loops over single queries
+# -----------------------------------------------------------------------------
+
+
+def select_by_loops(q, k, kernel_size, kernel_stride, block_size, topk, init_blocks, local_blocks):
+    """Steps 1 to 5 a query at a time, numpy float64: q (H, T, d), k (G, T, d) -> ids (G, T, topk), -1 padded."""
+    H, T_, d = q.shape
+    G = k.shape[0]
+    q, k = q.astype(np.float64), k.astype(np.float64)
+    pools = (T_ - kernel_size) // kernel_stride + 1
+    pooled = np.stack([k[:, kernel_stride * j:kernel_stride * j + kernel_size].mean(1) for j in range(pools)], 1)
+    out = -np.ones((G, T_, topk), np.int64)
+    for g in range(G):
+        for t in range(T_):
+            past = [j for j in range(pools) if kernel_stride * j + kernel_size <= t + 1]
+            P = np.zeros(pools)
+            for h in range(g * H // G, (g + 1) * H // G):
+                if past:
+                    s = pooled[g, past] @ q[h, t] / np.sqrt(d)
+                    e = np.exp(s - s.max())
+                    P[past] += e / e.sum()
+            own = t // block_size
+            score = []
+            for b in range(own + 1):
+                over = [j for j in range(pools) if kernel_stride * j < block_size * (b + 1)
+                        and kernel_stride * j + kernel_size > block_size * b]
+                forced = b < init_blocks or b > own - local_blocks
+                score.append(np.inf if forced else max((P[j] for j in over), default=0.0))
+            order = sorted(range(own + 1), key=lambda b: (-score[b], b))[:topk]  # the lower block on a tie
+            out[g, t, :len(order)] = order
+    return out
+
+
+def attend_by_loops(q, k, v, ids, block_size):
+    H, T_, d = q.shape
+    G = k.shape[0]
+    out = np.zeros((H, T_, d))
+    for h in range(H):
+        g = h * G // H
+        for t in range(T_):
+            keys = [s for s in range(t + 1) if s // block_size in set(ids[g, t].tolist())]
+            s = k[g, keys].astype(np.float64) @ q[h, t] / np.sqrt(d)
+            e = np.exp(s - s.max())
+            out[h, t] = (e / e.sum()) @ v[g, keys]
+    return out
+
+
+@pytest.mark.parametrize("t", [40, 100, 131], ids=lambda t: f"T{t}")
+@pytest.mark.parametrize("chunks", [(1024, 256), (48, 24)], ids=["one-chunk", "chunks-of-48-and-24"])
+def test_sparse_block_attention_against_loops_over_single_queries(t, chunks):
+    """Under and over ``topk`` blocks, at a T no chunk divides, at two chunk sizes: the same ids, the same output."""
+    q, k, v = qkv(t, heads=4, groups=2)
+    select, attend = chunks
+    ids = np.asarray(thunder_tpu.jit(lambda q, k: ttorch.sparse_block_select(q, k, query_chunk=select, **SPARSE))(q, k))
+    want = select_by_loops(q[0], k[0], **SPARSE)
+    assert ids.shape == (1, 2, t, 6) and ids.dtype == np.int32
+    chosen = lambda a: [[sorted(set(row.tolist()) - {-1}) for row in head] for head in a]
+    assert chosen(ids[0]) == chosen(want)
+    np.testing.assert_array_equal(ids[0], want)  # and in the same order: best first, the lower block on a tie
+    out = np.asarray(thunder_tpu.jit(lambda q, k, v, i: ttorch.sparse_block_attend(
+        q, k, v, i, block_size=16, query_chunk=attend))(q, k, v, ids))
+    np.testing.assert_allclose(out[0], attend_by_loops(q[0], k[0], v[0], want, 16), rtol=2e-4, atol=2e-5)
+    whole = np.asarray(thunder_tpu.jit(lambda q, k, v: ttorch.sparse_block_attention(q, k, v, **SPARSE))(q, k, v))
+    np.testing.assert_allclose(whole, out, rtol=1e-5, atol=1e-6)
+
+
+def test_the_reference_chooses_and_attends_as_the_loops_do():
+    import jax.numpy as jnp
+
+    from perfbench.reference import minicpm_sala
+
+    q, k, v = qkv(131, heads=4, groups=2)
+    out, ids = minicpm_sala.sparse_attention(jnp.asarray(q[0]), jnp.asarray(k[0]), jnp.asarray(v[0]), HP)
+    want = select_by_loops(q[0], k[0], **SPARSE)
+    np.testing.assert_array_equal(np.asarray(ids), want)
+    np.testing.assert_allclose(np.asarray(out), attend_by_loops(q[0], k[0], v[0], want, 16), rtol=2e-4, atol=2e-5)
+
+
+def test_on_whole_spans_the_xla_executor_runs_the_same_passes_as_loops(monkeypatch):
+    """``jaxex`` claims both halves where the sequence is two or more whole spans
+    and the caller leaves the chunking open, and gives the decomposition's ids
+    and output; anything else is the decomposition's."""
+    from thunder_tpu.executors import jaxex
+
+    q, k, v = qkv(T, heads=4, groups=2)
+    for name, value in (("SPARSE_LOOP_SPAN", 64), ("SPARSE_LOOP_SELECT", 32), ("SPARSE_LOOP_ATTEND", 16)):
+        monkeypatch.setattr(jaxex, name, value)
+    whole = thunder_tpu.jit(lambda q, k, v: ttorch.sparse_block_attention(q, k, v, **SPARSE))
+    out = np.asarray(whole(q, k, v))
+    owners = {b.sym.name: b.sym.executor.name for b in thunder_tpu.last_traces(whole)[-1].bound_symbols
+              if b.sym.name.startswith("sparse_block")}
+    assert owners == {"sparse_block_select": "jax", "sparse_block_attend": "jax"}
+    ids = np.asarray(thunder_tpu.jit(lambda q, k: ttorch.sparse_block_select(q, k, **SPARSE))(q, k))
+    unrolled = np.asarray(thunder_tpu.jit(lambda q, k: ttorch.sparse_block_select(q, k, query_chunk=48, **SPARSE))(q, k))
+    np.testing.assert_array_equal(ids, unrolled)
+    want = np.asarray(thunder_tpu.jit(lambda q, k, v, i: ttorch.sparse_block_attend(
+        q, k, v, i, block_size=16, query_chunk=48))(q, k, v, unrolled))
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-6)
+    # not whole spans, a single span, or a chunking the caller chose: the decomposition
+    assert not jaxex._sparse_loop_checker(q[:, :, :200], k[:, :, :200])
+    assert not jaxex._sparse_loop_checker(q[:, :, :64], k[:, :, :64])
+    assert jaxex._sparse_loop_checker(q, k) and not jaxex._sparse_loop_checker(q, k, query_chunk=64)
+    short = thunder_tpu.jit(lambda q, k, v: ttorch.sparse_block_attention(q, k, v, **SPARSE))
+    short(q[:, :, :200], k[:, :, :200], v[:, :, :200])
+    assert "sparse_block_select" not in [b.sym.name for b in thunder_tpu.last_traces(short)[-1].bound_symbols]
+
+
+def test_the_forced_blocks_are_always_chosen_and_ties_go_to_the_lower_block():
+    q, k, _ = qkv(T, heads=4, groups=1)
+    ids = np.asarray(thunder_tpu.jit(lambda q, k: ttorch.sparse_block_select(q, k, **SPARSE))(q, k))[0, 0]
+    for t in (0, 15, 16, 47, 48, 100, 255):
+        own = t // 16
+        assert {0, own, max(own - 1, 0)} <= set(ids[t].tolist())
+        assert ids[t].max() <= own and (ids[t] >= 0).sum() == min(6, own + 1)
+    # queries of zeros score every pooled key alike: every free block ties, and the lowest are taken
+    ids = np.asarray(thunder_tpu.jit(lambda q, k: ttorch.sparse_block_select(q, k, **SPARSE))(np.zeros_like(q), k))[0, 0]
+    assert ids[255].tolist() == [0, 14, 15, 1, 2, 3] and ids[100].tolist() == [0, 5, 6, 1, 2, 3]
+
+
+def test_both_mixers_are_causal():
+    q, k, v = qkv(T, heads=4, groups=1)
+    q2, k2, v2 = (a.copy() for a in (q, k, v))
+    for a in (q2, k2, v2):
+        a[:, :, 150:] += 1.0
+    sparse = thunder_tpu.jit(lambda q, k, v: ttorch.sparse_block_attention(q, k, v, **SPARSE))
+    np.testing.assert_array_equal(np.asarray(sparse(q, k, v))[:, :, :150], np.asarray(sparse(q2, k2, v2))[:, :, :150])
+    decay = np.asarray([0.5, 0.1, 0.01, 0.0], np.float32)
+    kk, vv, kk2, vv2 = (np.repeat(a, 4, 1) for a in (k, v, k2, v2))
+    linear = thunder_tpu.jit(lambda q, k, v, g: ttorch.linear_attention(q, k, v, g, chunk=64))
+    np.testing.assert_allclose(np.asarray(linear(q, kk, vv, decay))[:, :, :150],
+                               np.asarray(linear(q2, kk2, vv2, decay))[:, :, :150], rtol=1e-5, atol=1e-5)
+
+
+def linear_by_recurrence(q, k, v, g):
+    """``S_t = exp(-g) S_{t-1} + k_t^T v_t``, ``o_t = q_t S_t / sqrt(d)``, float64: (H, T, d)."""
+    H, T_, d = q.shape
+    out = np.zeros((H, T_, d))
+    for h in range(H):
+        S = np.zeros((d, d))
+        for t in range(T_):
+            S = np.exp(-float(g[h])) * S + np.outer(k[h, t], v[h, t]).astype(np.float64)
+            out[h, t] = q[h, t].astype(np.float64) @ S / np.sqrt(d)
+    return out
+
+
+@pytest.mark.parametrize("t,chunk", [(100, 256), (100, 32), (131, 48), (256, 64)], ids=lambda x: str(x))
+def test_linear_attention_against_the_recurrence(t, chunk):
+    """One chunk, whole chunks, and a T no chunk divides; a fast, a slow and no decay."""
+    import jax.numpy as jnp
+
+    from perfbench.reference import minicpm_sala
+
+    q, k, v = qkv(t, heads=4, groups=4)
+    decay = np.asarray([0.8, 0.1, 0.004, 0.0], np.float32)
+    want = linear_by_recurrence(q[0], k[0], v[0], decay)
+    got = np.asarray(thunder_tpu.jit(lambda q, k, v, g: ttorch.linear_attention(q, k, v, g, chunk=chunk))(q, k, v, decay))
+    assert rel(got[0], want) < 1e-5
+    quadratic = minicpm_sala.linear_attention(jnp.asarray(q[0]), jnp.asarray(k[0]), jnp.asarray(v[0]),
+                                              jnp.asarray(decay), HP)
+    assert rel(np.asarray(quadratic), want) < 1e-5
+
+
+def test_linear_attention_refuses_grouped_keys():
+    q, k, v = qkv(32, heads=4, groups=2)
+    with pytest.raises(Exception, match="key heads"):
+        thunder_tpu.jit(lambda q, k, v, g: ttorch.linear_attention(q, k, v, g))(q, k, v, np.zeros(4, np.float32))
+
+
+# -----------------------------------------------------------------------------
+# The comparison that decides ``correct``
+# -----------------------------------------------------------------------------
+
+
+def _only_the_forced_blocks(monkeypatch, cfg):
+    return dataclasses.replace(cfg, sparse_topk=cfg.sparse_init_blocks + cfg.sparse_window_size // cfg.sparse_block_size)
+
+
+def _dense_in_place_of_the_chosen_blocks(monkeypatch, cfg):
+    return dataclasses.replace(cfg, sparse_dense_len=10 ** 9)
+
+
+def _the_decay_dropped(monkeypatch, cfg):
+    monkeypatch.setattr(gpt.GPTConfig, "linear_decay", lambda self, layer: (0.0,) * self.linear_heads)
+    return cfg
+
+
+def _the_pooling_over_a_blocks_keys_dropped(monkeypatch, cfg):
+    """Step 4 without its max over the overlapping pooled keys: a block scores by its first pooled key alone."""
+    per = cfg.sparse_block_size // cfg.sparse_kernel_stride
+    monkeypatch.setattr(ttorch, "_pooled_to_blocks", lambda P, per_, r, nb: ttorch.pad(
+        P, (0, per * nb - P.shape[-1]))[..., ::per] if per * nb >= P.shape[-1] else P[..., :per * nb:per])
+    return cfg
+
+
+def _a_gate_dropped(which):
+    def mutate(monkeypatch, cfg):
+        return dataclasses.replace(cfg, **{which: False})
+    return mutate
+
+
+def _the_output_norm_dropped(monkeypatch, cfg):
+    return dataclasses.replace(cfg, linear_output_norm=False)
+
+
+def _the_residual_scale_dropped(monkeypatch, cfg):
+    return dataclasses.replace(cfg, residual_scale=1.0)
+
+
+MUTATIONS = {"only-the-forced-blocks": _only_the_forced_blocks,
+             "dense-in-place-of-step-6": _dense_in_place_of_the_chosen_blocks,
+             "the-decay-dropped": _the_decay_dropped,
+             "step-4s-pooling-dropped": _the_pooling_over_a_blocks_keys_dropped,
+             "the-sparse-gate-dropped": _a_gate_dropped("attn_output_gate"),
+             "the-linear-gate-dropped": _a_gate_dropped("linear_output_gate"),
+             "the-output-norm-dropped": _the_output_norm_dropped,
+             "the-residual-scale-dropped": _the_residual_scale_dropped}
+
+
+@pytest.mark.parametrize("name", sorted(MUTATIONS))
+def test_a_mutated_system_fails_the_cells_comparison_at_rehearsal_size(monkeypatch, name):
+    """Each departure from the equations fails the comparison the cell's check
+    makes (``perfbench/checks_sparse_linear.py``) at the stand-in's sizes,
+    where the unmutated system is within a hundredth of the limits."""
+    import jax.numpy as jnp
+
+    from perfbench import checks_sparse_linear
+    from perfbench.jobs import forward_sparse_linear
+    from perfbench.reference import minicpm_sala
+
+    cfg, params, stacked = built()
+    params, stacked = forward_sparse_linear.with_mixers_heard(params), forward_sparse_linear.with_mixers_heard(stacked)
+    idx = batch()
+    last = 64
+    want = np.asarray(minicpm_sala.forward(stacked, jnp.asarray(idx), KEYS, last=last))
+    clean = np.asarray(thunder_tpu.jit(lambda p, i: gpt.forward(p, i, cfg, last=last))(params, idx))
+    sound = checks_sparse_linear.compare_logits(clean, want)
+    assert sound["ok"] and sound["logits_rel_l2"] < 1e-2 * sound["logits_rtol"], sound
+    mutated = MUTATIONS[name](monkeypatch, cfg)
+    got = np.asarray(thunder_tpu.jit(lambda p, i: gpt.forward(p, i, mutated, last=last))(params, idx))
+    verdict = checks_sparse_linear.compare_logits(got, want)
+    assert not verdict["ok"], verdict

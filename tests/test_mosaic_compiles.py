@@ -67,6 +67,7 @@ ROPE_SHAPES = [
     ("bfloat16", (2, 1, 4096, 64), 64),     # a.x-k1.fwd's one rope key for all heads
     ("bfloat16", (2, 32, 4096, 64), 64),    # lfm2-8b-a1b.fwd's normed q: full rotary on heads of 64
     ("bfloat16", (2, 8, 4096, 64), 64),     # and its k, a head for four query heads
+    ("bfloat16", (1, 32, 32768, 128), 128), # minicpm-sala.fwd-t32k's linear layers' q and k: 32,768 positions
 ]
 
 
@@ -494,3 +495,30 @@ def test_forward_of_pythia_has_no_layout_copy_in_front_of_attention_on_the_v5e(o
     assert len(layout_instructions(written.as_text())) == 2 * 6 + 1
     assert len(layout_instructions(folded.as_text())) == 1
     assert executable_needs(folded)[0] <= executable_needs(written)[0]
+
+
+def test_block_sparse_attention_over_three_spans_compiles_for_v5e_as_loops_with_no_square_of_the_sequence(one_chip):
+    """``minicpm-sala.fwd-t32k``'s sparse layer at three eighths of its length (12,288 positions, three spans; 32 query
+    heads on 2 key-value heads of 128): both halves are ``jaxex``'s, a ``while`` a span and half, and nothing the
+    compiled program holds has the sequence twice among its dimensions or the sequence beside its 767 pooled keys."""
+    import jax
+    import jax.numpy as jnp
+
+    import thunder_tpu.torch as ttorch
+    from thunder_tpu.api import trace_program
+    from thunder_tpu.executors.passes import transform_for_execution
+    from thunder_tpu.extend import resolve_executors
+    from thunder_tpu.transforms.common import dce
+
+    T = 12288
+    shapes = [jax.ShapeDtypeStruct((1, heads, T, 128), jnp.bfloat16, sharding=one_chip) for heads in (32, 2, 2)]
+    _, comp = trace_program(lambda q, k, v: ttorch.sparse_block_attention(
+        q, k, v, kernel_size=32, kernel_stride=16, block_size=64, topk=64, init_blocks=1, local_blocks=32), shapes, {})
+    claimed = transform_for_execution(dce(comp), resolve_executors(None))
+    owners = [(b.sym.name, b.sym.executor.name) for b in claimed.bound_symbols if b.sym.name.startswith("sparse_block")]
+    assert owners == [("sparse_block_select", "jax"), ("sparse_block_attend", "jax")]
+    text = jax.jit(claimed.python_callable()).lower(*shapes).compile().as_text()
+    assert text.count(" while(") == 6
+    dims = [[int(d) for d in m.split(",") if d] for m in re.findall(r"(?:pred|[subf]\d+|bf16)\[([\d,]*)\]", text)]
+    assert not [d for d in dims if d.count(T) >= 2 or (T in d and 767 in d)]
+    assert [d for d in dims if d == [2, 4096, T]]  # the last span's scores: 16 query heads x 256 queries against every key

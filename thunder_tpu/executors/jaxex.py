@@ -416,6 +416,107 @@ def _linear_heads(a, w, bias=None, heads=1):
 ex.register_implementation("torch.linear_heads", fn=_linear_heads)
 
 
+# Block-sparse attention whose blocks the data chooses, over a long sequence
+# (``torch.sparse_block_select`` and ``torch.sparse_block_attend``). Their
+# decompositions go through the queries in chunks unrolled into the program,
+# each against the keys up to its own end: at 32,768 positions that is 160
+# passes a layer, every one of a shape of its own, which the TPU's compiler
+# takes six minutes over and schedules so that several passes' scores are alive
+# at once (15.4 GB for ten layers; no chip, PR 33). Here the same passes are
+# iterations of ``lax.map``: the queries of a span of ``SPARSE_LOOP_SPAN`` share
+# one key length (the span's end), so a layer compiles a loop body a span, and a
+# loop keeps one pass's temporaries alive. The arithmetic of a pass is the
+# decomposition's, operation for operation.
+
+SPARSE_LOOP_SPAN = 4096     # queries whose passes share a key length, a compiled body and a loop
+SPARSE_LOOP_SELECT = 512    # queries a pass
+SPARSE_LOOP_ATTEND = 256
+
+
+def _sparse_loop_checker(q, *args, query_chunk=None, **kwargs):
+    """Sequences of whole spans, two or more, where the caller leaves the chunking open."""
+    T = q.shape[2]
+    return query_chunk is None and T % SPARSE_LOOP_SPAN == 0 and T >= 2 * SPARSE_LOOP_SPAN
+
+
+def _by_spans(T: int, n: int, one_pass, query_axis: int):
+    """``one_pass(t0, keys_end)`` for every ``n`` queries from ``t0``, those of a span
+    under one ``lax.map``; each output has its ``n`` queries on ``query_axis``."""
+    spans = []
+    for s0 in range(0, T, SPARSE_LOOP_SPAN):
+        out = lax.map(lambda i: one_pass(s0 + i * n, s0 + SPARSE_LOOP_SPAN), jnp.arange(SPARSE_LOOP_SPAN // n, dtype=jnp.int32))
+        out = jnp.moveaxis(out, 0, query_axis)                       # (.., passes, n, ..)
+        spans.append(out.reshape(*out.shape[:query_axis], -1, *out.shape[query_axis + 2:]))
+    return jnp.concatenate(spans, query_axis)
+
+
+def _sparse_block_select_loops(q, k, *, kernel_size, kernel_stride, block_size, topk, init_blocks, local_blocks,
+                               scale=None, query_chunk=None):
+    B, H, T, d = q.shape
+    G = k.shape[1]
+    R, r, per = H // G, kernel_size // kernel_stride, block_size // kernel_stride
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    n_pool = (T - kernel_size) // kernel_stride + 1
+    strides = n_pool + r - 1
+    parts = k[:, :, :strides * kernel_stride].astype(jnp.float32).reshape(B, G, strides, kernel_stride, d).sum(3)
+    pooled = sum(parts[:, :, i:i + n_pool] for i in range(r))
+    pooled = jnp.swapaxes(pooled * (1.0 / kernel_size), -2, -1)                        # (B, G, d, n_pool)
+    qg = q.reshape(B, G, R, T, d)
+    n = SPARSE_LOOP_SELECT
+
+    def one_pass(t0, keys_end):
+        nb, pools = -(-keys_end // block_size), (keys_end - kernel_size) // kernel_stride + 1
+        t = t0 + jnp.arange(n, dtype=jnp.int32)
+        qc = (lax.dynamic_slice_in_dim(qg, t0, n, 3).astype(jnp.float32) * scale).reshape(B, G, R * n, d)
+        s = _matmul(qc, pooled[..., :pools]).reshape(B, G, R, n, pools)
+        past = (kernel_size + kernel_stride * jnp.arange(pools, dtype=jnp.int32))[None, :] <= (t + 1)[:, None]
+        s = jnp.where(past, s, -jnp.inf)
+        top = s.max(-1, keepdims=True)
+        e = jnp.exp(s - top)
+        p = jnp.where(top == -jnp.inf, 0.0, e / e.sum(-1, keepdims=True))               # a query with no pooled key in its past: zeros
+        P = jnp.pad(p.sum(2), ((0, 0), (0, 0), (0, 0), (r - 1, per * (nb + 1) - (r - 1) - pools)))
+        score = P[..., :per * nb].reshape(B, G, n, nb, per).max(-1)
+        after = P[..., per:].reshape(B, G, n, nb, per)
+        for i in range(r - 1):
+            score = jnp.maximum(score, after[..., i])
+        b, own = jnp.arange(nb, dtype=jnp.int32)[None, :], (t // block_size)[:, None]
+        forced = (b < init_blocks) | (b > own - local_blocks)
+        score = jnp.where(b > own, -jnp.inf, jnp.where(forced, jnp.inf, score))
+        best, ids = lax.top_k(score, min(topk, nb))
+        ids = jnp.where(best > -jnp.inf, ids.astype(jnp.int32), -1)
+        return jnp.pad(ids, ((0, 0), (0, 0), (0, 0), (0, topk - ids.shape[-1])), constant_values=-1)
+
+    return _by_spans(T, n, one_pass, 2)
+
+
+def _sparse_block_attend_loops(q, k, v, block_ids, *, block_size, scale=None, query_chunk=None):
+    B, H, T, d = q.shape
+    G = k.shape[1]
+    R = H // G
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    qg, kT = q.reshape(B, G, R, T, d), jnp.swapaxes(k, -2, -1)
+    n = SPARSE_LOOP_ATTEND
+
+    def one_pass(t0, keys_end):
+        nb = -(-keys_end // block_size)
+        t = t0 + jnp.arange(n, dtype=jnp.int32)
+        ids = lax.dynamic_slice_in_dim(block_ids, t0, n, 2)
+        chosen = (ids[..., None] == jnp.arange(nb, dtype=ids.dtype)).any(-2)            # (B, G, n, nb)
+        keys = jnp.broadcast_to(chosen[..., None], (B, G, n, nb, block_size)).reshape(B, G, n, nb * block_size)
+        mask = (keys[..., :keys_end] & (jnp.arange(keys_end, dtype=jnp.int32)[None, :] <= t[:, None]))[:, :, None]
+        s = _matmul(lax.dynamic_slice_in_dim(qg, t0, n, 3).reshape(B, G, R * n, d), kT[..., :keys_end])
+        s = jnp.where(mask, (s.astype(jnp.float32) * scale).reshape(B, G, R, n, keys_end), -jnp.inf)
+        e = jnp.exp(s - s.max(-1, keepdims=True))
+        o = _matmul(e.astype(v.dtype).reshape(B, G, R * n, keys_end), v[:, :, :keys_end])
+        return (o.reshape(B, G, R, n, d).astype(jnp.float32) / e.sum(-1, keepdims=True)).astype(q.dtype)
+
+    return _by_spans(T, n, one_pass, 3).reshape(B, H, T, d)
+
+
+ex.register_implementation("torch.sparse_block_select", fn=_sparse_block_select_loops, checker=_sparse_loop_checker)
+ex.register_implementation("torch.sparse_block_attend", fn=_sparse_block_attend_loops, checker=_sparse_loop_checker)
+
+
 def _convolution(a, weight, bias, stride, padding, dilation, groups):
     spatial = a.ndim - 2
     stride = tuple(stride[i] if i < len(stride) else stride[-1] for i in range(spatial))

@@ -11,7 +11,12 @@ expert layers with a shared expert beside routed ones of which a chip may
 hold a share (``experts_held``, ``expert_offset``); and LFM2-8B-A1B's: a mixer
 kind per layer (``layer_types``: a gated short convolution or grouped-query
 attention with an RMSNorm on every query and key head), a router whose bias
-takes part in the choice and not in the weight, a head that is the embedding.
+takes part in the choice and not in the weight, a head that is the embedding;
+and MiniCPM-SALA's: block-sparse attention whose blocks each query chooses by
+scoring pooled keys (``"sparse_attention"``) beside linear attention with a
+decay per head (``"linear_attention"``), each with its own head layout and rope
+setting, an output gate and an output norm on a mixer, and the MiniCPM
+family's scalings of the embedding, the residual and the head's input.
 
 TPU-first design: the model is a *pure function* ``forward(params, idx)``
 over a params pytree — no module object, no buffers, no in-place state. That
@@ -102,6 +107,38 @@ class GPTConfig:
     # for the choice and left out of the weights; router_norm_eps is the weights' normaliser's.
     router_bias: bool = False
     router_norm_eps: float = 1e-20
+    # The MiniCPM family's scalings: the embedding times embedding_scale, each
+    # sublayer's output times residual_scale before it joins the residual, the
+    # final norm's output over logit_divisor before the head.
+    embedding_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
+    # Softmax attention layers ("full_attention", "sparse_attention"): rope or none,
+    # and y * sigmoid(gate(x)) before the output projection.
+    attn_rope: bool = True
+    attn_output_gate: bool = False
+    # "sparse_attention" (InfLLM-V2): keys mean-pooled over sparse_kernel_size at
+    # sparse_kernel_stride score blocks of sparse_block_size; a query attends to
+    # its sparse_topk best, the first sparse_init_blocks and those of the last
+    # sparse_window_size keys always among them; a sequence shorter than
+    # sparse_dense_len attends causally to everything.
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2048
+    sparse_dense_len: int = 8192
+    # "linear_attention" (Lightning Attention): its own head counts (None: n_head
+    # of each), rope, an RMSNorm over all heads' outputs, an output gate; head h
+    # of layer l decays by exp(-g) a step, g = 2**(-8 (h + 1) / H) * (1 - l /
+    # (decay_depth - 1) + 1e-5), decay_depth the published depth (None: n_layer).
+    linear_n_head: Optional[int] = None
+    linear_query_groups: Optional[int] = None
+    linear_rope: bool = True
+    linear_output_norm: bool = False
+    linear_output_gate: bool = False
+    decay_depth: Optional[int] = None
 
     @property
     def head_size(self) -> int:
@@ -136,6 +173,19 @@ class GPTConfig:
 
     def layer_mixer(self, i: int) -> str:
         return self.layer_types[i] if self.layer_types else "full_attention"
+
+    @property
+    def linear_heads(self) -> int:
+        return self.linear_n_head if self.linear_n_head is not None else self.n_head
+
+    @property
+    def linear_groups(self) -> int:
+        return self.linear_query_groups if self.linear_query_groups is not None else self.linear_heads
+
+    def linear_decay(self, layer: int) -> tuple:
+        """g of each head of linear-attention layer ``layer`` (0-based, of ``decay_depth``)."""
+        H, L = self.linear_heads, self.decay_depth if self.decay_depth is not None else self.n_layer
+        return tuple(2.0 ** (-8.0 * (h + 1) / H) * (1.0 - layer / max(L - 1, 1) + 1e-5) for h in range(H))
 
     @property
     def qk_head_dim(self) -> int:
@@ -246,6 +296,34 @@ _add(GPTConfig(name="lfm2-tiny", block_size=64, vocab_size=96, padded_vocab_size
                layer_types=("conv", "full_attention", "conv", "conv"), conv_kernel=3, qk_norm=True,
                tie_embeddings=True, router_bias=True, router_norm_eps=1e-6))
 
+# MiniCPM-SALA (huggingface.co/openbmb/MiniCPM-SALA, model_type minicpm_sala) at
+# its published sizes: 8 block-sparse attention layers (32 query heads on 2
+# key-value heads of 128, no rope, normed heads, an output gate; MiniCPM4's
+# sparse constants are the fields' defaults) among 24 linear-attention layers
+# (32 heads of 128, rope, normed heads, a decay per head, an output norm and
+# gate), SwiGLU of 16384, the family's scalings, an untied head.
+_SALA_LAYERS = tuple("sparse_attention" if i in (0, 9, 16, 17, 22, 29, 30, 31) else "linear_attention"
+                     for i in range(32))
+_add(GPTConfig(name="MiniCPM-SALA", block_size=524288, vocab_size=73448, padded_vocab_size=73448,
+               n_layer=32, n_head=32, n_embd=4096, n_query_groups=2, rotary_percentage=1.0,
+               parallel_residual=False, bias=False, norm_class="RMSNorm", norm_eps=1e-6,
+               mlp_class="LLaMAMLP", intermediate_size=16384, rope_base=10000, layer_types=_SALA_LAYERS,
+               qk_norm=True, embedding_scale=12.0, residual_scale=1.4 / 32 ** 0.5, logit_divisor=4096 / 256,
+               attn_rope=False, attn_output_gate=True, linear_n_head=32, linear_query_groups=32,
+               linear_rope=True, linear_output_norm=True, linear_output_gate=True, decay_depth=32))
+# The same blocks at test size: at T = 256 a query has 16 blocks of 16 keys and
+# attends to 6, three of them chosen by score; T < 64 attends densely.
+_add(GPTConfig(name="minicpm-sala-tiny", block_size=256, vocab_size=96, padded_vocab_size=96,
+               n_layer=4, n_head=4, n_embd=256, n_query_groups=1, rotary_percentage=1.0,
+               parallel_residual=False, bias=False, norm_class="RMSNorm", norm_eps=1e-6,
+               mlp_class="LLaMAMLP", intermediate_size=512, rope_base=10000,
+               layer_types=("sparse_attention", "linear_attention", "linear_attention", "linear_attention"),
+               qk_norm=True, embedding_scale=12.0, residual_scale=1.4 / 4 ** 0.5, logit_divisor=256 / 64,
+               attn_rope=False, attn_output_gate=True, sparse_kernel_size=8, sparse_kernel_stride=4,
+               sparse_block_size=16, sparse_topk=6, sparse_init_blocks=1, sparse_window_size=32,
+               sparse_dense_len=64, linear_n_head=4, linear_query_groups=4, linear_rope=True,
+               linear_output_norm=True, linear_output_gate=True))
+
 # Mistral — reference benchmark ladder step 5 (GQA).
 _add(GPTConfig(name="mistral-7b", block_size=4096, vocab_size=32000, padded_vocab_size=32000,
                n_layer=32, n_head=32, n_embd=4096, n_query_groups=8, rotary_percentage=1.0,
@@ -321,7 +399,7 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
             p["bias"] = zeros(C.n_embd)
         return p
 
-    def attn_params():
+    def attn_params(heads, groups, gate, out_norm=False):
         if C.attention_class == "MLA":
             H, dqk = C.n_head, C.qk_head_dim
             return {
@@ -334,15 +412,19 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
                 "proj_w": w(C.n_embd, H * C.v_head_dim, std=0.02 / np.sqrt(2 * C.n_layer)),
             }
         p = {
-            "qkv_w": w(C.qkv_out, C.n_embd),
-            "proj_w": w(C.n_embd, C.n_head * C.head_size, std=0.02 / np.sqrt(2 * C.n_layer)),
+            "qkv_w": w((heads + 2 * groups) * C.head_size, C.n_embd),
+            "proj_w": w(C.n_embd, heads * C.head_size, std=0.02 / np.sqrt(2 * C.n_layer)),
         }
         if C.bias:
-            p["qkv_b"] = zeros(C.qkv_out)
+            p["qkv_b"] = zeros((heads + 2 * groups) * C.head_size)
             p["proj_b"] = zeros(C.n_embd)
         if C.qk_norm:  # one weight of head_size for all heads
             p["q_norm"] = {"weight": ones(C.head_size)}
             p["k_norm"] = {"weight": ones(C.head_size)}
+        if gate:  # y * sigmoid(gate(x)) before the output projection
+            p["gate_w"] = w(heads * C.head_size, C.n_embd)
+        if out_norm:  # an RMSNorm over all heads' outputs
+            p["out_norm"] = {"weight": ones(heads * C.head_size)}
         return p
 
     def conv_params():
@@ -387,10 +469,16 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
 
     def block_params(i):
         p: dict[str, Any] = {"norm_1": norm_params(), "mlp": mlp_params(C.layer_mlp_class(i))}
-        if C.layer_mixer(i) == "conv":
+        mixer = C.layer_mixer(i)
+        if mixer == "conv":
             p["conv"] = conv_params()
+        elif mixer == "sparse_attention":
+            p["sparse_attn"] = attn_params(C.n_head, C.query_groups, C.attn_output_gate)
+        elif mixer == "linear_attention":
+            p["linear_attn"] = attn_params(C.linear_heads, C.linear_groups, C.linear_output_gate,
+                                           C.linear_output_norm)
         else:
-            p["attn"] = attn_params()
+            p["attn"] = attn_params(C.n_head, C.query_groups, C.attn_output_gate)
         if not C.shared_attention_norm:
             p["norm_2"] = norm_params()
         return p
@@ -471,9 +559,11 @@ def _apply_rope(x, cos, sin, config: GPTConfig):
     return ttorch.apply_rope(x, cos, sin)
 
 
-def _attention(x, p, cos, sin, config: GPTConfig):
+def _qkv_heads(x, p, H: int, G: int, cos, sin, config: GPTConfig, rope: bool = True):
+    """The packed projection's q (B, H, T, hs), k and v (B, G, T, hs), the heads
+    normed where the model norms them, q and k roped where the layer ropes."""
     B, T, C = x.shape
-    H, G, hs = config.n_head, config.query_groups, config.head_size
+    hs = config.head_size
 
     qkv = ttorch.linear(x, p["qkv_w"], p.get("qkv_b"))  # (B, T, (H+2G)*hs)
     q = qkv[..., : H * hs]
@@ -488,12 +578,82 @@ def _attention(x, p, cos, sin, config: GPTConfig):
         with region("attn.qk_norm"):
             q = ttorch.rms_norm(q, (hs,), p["q_norm"]["weight"], eps=config.norm_eps)
             k = ttorch.rms_norm(k, (hs,), p["k_norm"]["weight"], eps=config.norm_eps)
-    q = _apply_rope(q, cos, sin, config)
-    k = _apply_rope(k, cos, sin, config)
+    if rope:
+        q = _apply_rope(q, cos, sin, config)
+        k = _apply_rope(k, cos, sin, config)
+    return q, k, v
 
-    y = ttorch.scaled_dot_product_attention(q, k, v, is_causal=True, enable_gqa=(G != H))
-    y = ttorch.reshape(ttorch.permute(y, (0, 2, 1, 3)), (B, T, H * hs))
+
+def _merge_heads(y):
+    """(B, H, T, hs) -> (B, T, H * hs)."""
+    B, H, T, hs = y.shape
+    return ttorch.reshape(ttorch.permute(y, (0, 2, 1, 3)), (B, T, H * hs))
+
+
+SPARSE_TILE = 128  # consecutive queries whose chosen blocks ``sparse_selection_counts`` unites
+
+
+def _attention(x, p, cos, sin, config: GPTConfig, sparse: bool = False, counts=None):
+    """Causal softmax attention between its projections. ``sparse``: InfLLM-V2's
+    layer, where every query attends to the ``sparse_topk`` blocks of
+    ``sparse_block_size`` keys that its key-value head's queries score highest
+    through mean-pooled keys, the first blocks and its own window always among
+    them; a sequence shorter than ``sparse_dense_len`` attends to everything.
+    ``counts`` collects, a sparse layer, the distinct blocks that each tile of
+    ``SPARSE_TILE`` consecutive queries chose (``"tile_union"``)."""
+    C, T = config, x.shape[1]
+    H, G = C.n_head, C.query_groups
+    q, k, v = _qkv_heads(x, p, H, G, cos, sin, C, C.attn_rope)
+    if not sparse or T < C.sparse_dense_len:
+        y = ttorch.scaled_dot_product_attention(q, k, v, is_causal=True, enable_gqa=(G != H))
+    else:
+        how = dict(kernel_size=C.sparse_kernel_size, kernel_stride=C.sparse_kernel_stride,
+                   block_size=C.sparse_block_size, topk=C.sparse_topk, init_blocks=C.sparse_init_blocks,
+                   local_blocks=C.sparse_window_size // C.sparse_block_size)
+        if counts is None:
+            y = ttorch.sparse_block_attention(q, k, v, **how)
+        else:  # the composite's two halves, the chosen blocks counted between them
+            ids = ttorch.sparse_block_select(q, k, **how)
+            counts["tile_union"].append(_tile_union(ids, -(-T // C.sparse_block_size)))
+            y = ttorch.sparse_block_attend(q, k, v, ids, block_size=C.sparse_block_size)
+    y = _merge_heads(y)
+    if C.attn_output_gate:
+        y = y * ttorch.sigmoid(ttorch.linear(x, p["gate_w"]))
     return ttorch.linear(y, p["proj_w"], p.get("proj_b"))
+
+
+def _tile_union(ids, n_blocks: int, queries_a_pass: int = 4096):
+    """ids (B, G, T, k), a query's chosen blocks (-1: none) -> (B, G, T // SPARSE_TILE)
+    int32, the distinct blocks the queries of each whole tile chose between them."""
+    B, G, T, k = ids.shape
+    blocks = ttorch.arange(0, n_blocks, device=ids.device, dtype=ids.dtype)
+    tiles = []
+    for t0 in range(0, T - T % SPARSE_TILE, queries_a_pass):  # a pass: (.., 4096 * k, n_blocks) compares, reduced at once
+        t1 = min(t0 + queries_a_pass, T - T % SPARSE_TILE)
+        of_tile = ttorch.reshape(ids[:, :, t0:t1], (B, G, (t1 - t0) // SPARSE_TILE, SPARSE_TILE * k, 1))
+        chosen = ttorch.amax((of_tile == blocks).to(dtypes.int32), 3)           # (B, G, tiles, n_blocks)
+        tiles.append(ttorch.sum(chosen, -1))
+    return ttorch.cat(tiles, 2)
+
+
+def _linear_attention(x, p, cos, sin, config: GPTConfig, layer: int):
+    """Lightning Attention's layer: ``o_t = sum_{s<=t} exp(-g (t-s)) (q_t . k_s /
+    sqrt(d)) v_s`` with one ``g`` a head (``GPTConfig.linear_decay``), no softmax
+    and no normaliser; then the norm over all heads' outputs and the gate."""
+    import thunder_tpu.clang as clang
+
+    C = config
+    H = C.linear_heads
+    q, k, v = _qkv_heads(x, p, H, C.linear_groups, cos, sin, C, C.linear_rope)
+    decay = clang.tensor_from_sequence(list(C.linear_decay(layer)), device=x.device, dtype=dtypes.float32)
+    with region("attn.linear"):
+        y = ttorch.linear_attention(q, k, v, decay)
+    y = _merge_heads(y)
+    if C.linear_output_norm:
+        y = ttorch.rms_norm(y, (H * C.head_size,), p["out_norm"]["weight"], eps=C.norm_eps)
+    if C.linear_output_gate:
+        y = y * ttorch.sigmoid(ttorch.linear(x, p["gate_w"]))
+    return ttorch.linear(y, p["proj_w"])
 
 
 def _short_conv(x, p, config: GPTConfig):
@@ -608,24 +768,29 @@ def _mlp(x, p, kind: str, config: GPTConfig, counts=None):
     return ttorch.linear(h, p["proj_w"], p.get("proj_b"))
 
 
-def _mix(x, p, cos, sin, config: GPTConfig):
+def _mix(x, p, cos, sin, config: GPTConfig, layer: int = 0, counts=None):
     """The layer's mixer, by the parameters it was given: the tree was built from ``layer_mixer(i)``."""
     if "conv" in p:
         return _short_conv(x, p["conv"], config)
+    if "sparse_attn" in p:
+        return _attention(x, p["sparse_attn"], cos, sin, config, sparse=True, counts=counts)
+    if "linear_attn" in p:
+        return _linear_attention(x, p["linear_attn"], cos, sin, config, layer)
     if config.attention_class == "MLA":
         with region("mla"):
             return _mla_attention(x, p["attn"], cos, sin, config)
     return _attention(x, p["attn"], cos, sin, config)
 
 
-def _block(x, p, cos, sin, kind: str, config: GPTConfig, counts=None):
+def _block(x, p, cos, sin, kind: str, config: GPTConfig, counts=None, layer: int = 0):
+    scaled = (lambda y: y) if config.residual_scale == 1.0 else (lambda y: y * config.residual_scale)
     n1 = _norm(x, p["norm_1"], config)
-    attn_out = _mix(n1, p, cos, sin, config)
+    attn_out = scaled(_mix(n1, p, cos, sin, config, layer, counts))
     if config.parallel_residual:
         n2 = n1 if config.shared_attention_norm else _norm(x, p["norm_2"], config)
-        return x + attn_out + _mlp(n2, p["mlp"], kind, config, counts)
+        return x + attn_out + scaled(_mlp(n2, p["mlp"], kind, config, counts))
     x = x + attn_out
-    return x + _mlp(_norm(x, p["norm_2"], config), p["mlp"], kind, config, counts)
+    return x + scaled(_mlp(_norm(x, p["norm_2"], config), p["mlp"], kind, config, counts))
 
 
 def _layers(params: dict, config: GPTConfig):
@@ -634,18 +799,26 @@ def _layers(params: dict, config: GPTConfig):
     return [(p, config.layer_mlp_class(i)) for i, p in enumerate(blocks)]
 
 
-def _hidden(params: dict, idx, config: GPTConfig, counts=None):
+def _hidden(params: dict, idx, config: GPTConfig, counts=None, last: Optional[int] = None):
     B, T = idx.shape
     x = ttorch.embedding(idx, params["wte"])  # (B, T, C)
+    if config.embedding_scale != 1.0:
+        x = x * config.embedding_scale
     cos, sin = _rope_cache(T, config, device=x.device, dtype=x.dtype)
-    for p, kind in _layers(params, config):
-        x = _block(x, p, cos, sin, kind, config, counts)
-    return _norm(x, params["ln_f"], config)
+    for layer, (p, kind) in enumerate(_layers(params, config)):
+        x = _block(x, p, cos, sin, kind, config, counts, layer)
+    if last is not None:
+        x = x[:, T - last:]
+    x = _norm(x, params["ln_f"], config)
+    return x if config.logit_divisor == 1.0 else x / config.logit_divisor
 
 
-def forward(params: dict, idx, config: GPTConfig):
-    """Token ids (B, T) int → logits (B, T, padded_vocab_size)."""
-    return ttorch.linear(_hidden(params, idx, config), params["wte" if config.tie_embeddings else "lm_head_w"])
+def forward(params: dict, idx, config: GPTConfig, last: Optional[int] = None):
+    """Token ids (B, T) int → logits (B, T, padded_vocab_size); with ``last``
+    the final norm and the head run on the last ``last`` positions only, what
+    a prefill reads: (B, last, padded_vocab_size)."""
+    return ttorch.linear(_hidden(params, idx, config, last=last),
+                         params["wte" if config.tie_embeddings else "lm_head_w"])
 
 
 def router_counts(params: dict, idx, config: GPTConfig):
@@ -657,6 +830,16 @@ def router_counts(params: dict, idx, config: GPTConfig):
     counts: dict = {"rows": [], "changed": []}
     _hidden(params, idx, config, counts)
     return ttorch.stack(counts["rows"], 0), (ttorch.stack(counts["changed"], 0) if counts["changed"] else None)
+
+
+def sparse_selection_counts(params: dict, idx, config: GPTConfig):
+    """What the sparse layers' selection does with these ids, by the program's own
+    count: (sparse layers, B, key-value heads, T // SPARSE_TILE) int32, the distinct
+    blocks the queries of each tile of ``SPARSE_TILE`` consecutive positions
+    chose between them (forced blocks included; ``sparse_topk`` if they all agree)."""
+    counts: dict = {"rows": [], "changed": [], "tile_union": []}
+    _hidden(params, idx, config, counts)
+    return ttorch.stack(counts["tile_union"], 0)
 
 
 def routed_rows(params: dict, idx, config: GPTConfig):

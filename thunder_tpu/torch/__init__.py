@@ -1516,6 +1516,203 @@ def moe_experts(x, top_i, top_w, w_gate, w_up, w_down, expert_offset: int = 0, n
     return clang.maybe_convert_to_dtype(out, x.dtype)
 
 
+# Block-sparse attention whose blocks the data chooses, and linear attention
+# with a decay a head (MiniCPM-SALA's two mixers). Composites, so that an
+# executor can claim each whole; the bodies below are the definition and what
+# XLA runs where none does, written so that nothing of shape (heads, T, T) or
+# (heads, T, pooled keys) is ever whole: queries go in chunks, each against the
+# keys up to its own end. (On sequences of whole spans ``jaxex`` runs the sparse
+# halves' passes as loops; the chunks here are unrolled into the program.)
+
+SPARSE_SELECT_CHUNK = 2048  # queries a pass of the selection: (H, chunk, pooled keys) float32 scores
+SPARSE_ATTEND_CHUNK = 512   # queries a pass of the attention: (H, chunk, keys so far) scores
+LINEAR_ATTENTION_CHUNK = 256
+
+
+def _query_chunks(T: int, size: int):
+    return [(t0, builtins_min(t0 + size, T)) for t0 in range(0, T, size)]
+
+
+def _pooled_to_blocks(P, per: int, r: int, nb: int):
+    """Step 4: P (.., pooled keys) -> (.., nb), a block's score the largest P
+    among the ``per + r - 1`` pooled keys that overlap it: its own ``per``, the
+    ``r - 1`` before them that reach in, none where the sequence has none (P >= 0)."""
+    lead, pools = tuple(P.shape[:-1]), P.shape[-1]
+    P = pad(P, (r - 1, per * (nb + 1) - (r - 1) - pools))                  # P[j] at j + r - 1: block b's window starts at per * b
+    score = amax(reshape(P[..., :per * nb], lead + (nb, per)), -1)
+    after = reshape(P[..., per:], lead + (nb, per))
+    for e in range(r - 1):
+        score = clang.maximum(score, after[..., e])
+    return score
+
+
+@torchsymbol(id="torch.sparse_block_select")
+def sparse_block_select(q, k, *, kernel_size: int, kernel_stride: int, block_size: int, topk: int,
+                        init_blocks: int, local_blocks: int, scale: Optional[float] = None,
+                        query_chunk: Optional[int] = None):
+    """Which blocks of keys each query attends to (InfLLM-V2's selection): q
+    (B, H, T, d), k (B, G, T, d), ``H / G`` query heads a key-value head ->
+    (B, G, T, topk) int32 block ids, best first, -1 where a query has fewer
+    than ``topk`` blocks in its past. All query heads of a key-value head share
+    one choice.
+
+    1. Pooled keys ``Kc[j] = mean(k[stride j : stride j + kernel_size])``.
+    2. ``p[h, t, :] = softmax_j(scale q[h, t] . Kc[j])`` over the pooled keys
+       wholly in the query's past (``stride j + kernel_size <= t + 1``), zeros
+       where there is none; float32.
+    3. ``P[g, t, j]``: the sum of ``p`` over the group's query heads.
+    4. Block ``b`` (keys ``[block_size b, block_size (b + 1))``) scores the
+       largest ``P[g, t, j]`` among the pooled keys that overlap it.
+    5. The first ``init_blocks`` blocks and the ``local_blocks`` ending at the
+       query's own score +inf, blocks after its own -inf; the ``topk`` best are
+       chosen, the lower block on a tie."""
+    B, H, T, d = q.shape
+    G = k.shape[1]
+    R, r, per = H // G, kernel_size // kernel_stride, block_size // kernel_stride
+    check(H % G == 0 and kernel_size % kernel_stride == 0 and block_size % kernel_stride == 0 and r - 1 <= per,
+          lambda: f"sparse_block_select: {H} on {G} heads, pooling {kernel_size}/{kernel_stride}, blocks of {block_size}")
+    check(local_blocks >= 1 and init_blocks + local_blocks <= topk,
+          lambda: f"sparse_block_select: {init_blocks} + {local_blocks} forced blocks do not fit top-{topk}")
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    n_pool = (T - kernel_size) // kernel_stride + 1 if T >= kernel_size else 0
+    neg_inf, i32 = -float("inf"), dtypes.int32
+
+    pooled = None
+    if n_pool:  # sums over each stride once, then r neighbours: the mean over kernel_size keys
+        strides = n_pool + r - 1
+        parts = sum(reshape(clang.maybe_convert_to_dtype(k[:, :, :strides * kernel_stride], dtypes.float32),
+                            (B, G, strides, kernel_stride, d)), 3)
+        pooled = parts[:, :, 0:n_pool]
+        for i in range(1, r):
+            pooled = pooled + parts[:, :, i:i + n_pool]
+        pooled = transpose(pooled * (1.0 / kernel_size), -2, -1)                      # (B, G, d, n_pool)
+    qg = reshape(q, (B, G, R, T, d))
+
+    chosen = []
+    for t0, t1 in _query_chunks(T, query_chunk or SPARSE_SELECT_CHUNK):
+        n, nb = t1 - t0, -(-t1 // block_size)                 # this pass's queries; blocks up to its last query's
+        pools = builtins_max(0, (t1 - kernel_size) // kernel_stride + 1)  # pooled keys wholly before t1
+        t = arange(t0, t1, device=q.device, dtype=i32)
+        if pools:
+            qc = reshape(clang.maybe_convert_to_dtype(qg[:, :, :, t0:t1], dtypes.float32) * scale, (B, G, R * n, d))
+            s = reshape(matmul(qc, pooled[:, :, :, :pools]), (B, G, R, n, pools))
+            ends = arange(kernel_size, kernel_size + pools * kernel_stride, kernel_stride, device=q.device, dtype=i32)
+            past = unsqueeze(ends, 0) <= unsqueeze(t + 1, 1)                           # (n, pools)
+            p = _safe_softmax(where(past, s, clang.full_like(s, neg_inf)))
+            score = _pooled_to_blocks(sum(p, 2), per, r, nb)
+        else:
+            score = zeros(B, G, n, nb, device=q.device, dtype=dtypes.float32)
+        b = unsqueeze(arange(0, nb, device=q.device, dtype=i32), 0)                    # (1, nb)
+        own = unsqueeze(clang.floor_divide(t, block_size), 1)                          # (n, 1)
+        forced = (b < init_blocks) | (b > own - local_blocks)
+        score = where(b > own, clang.full_like(score, neg_inf), where(forced, clang.full_like(score, float("inf")), score))
+        best, ids = clang.topk(score, builtins_min(topk, nb), -1)  # the lower index first on a tie
+        ids = clang.maybe_convert_to_dtype(ids, i32)
+        ids = where(best > neg_inf, ids, clang.full_like(ids, -1))
+        if nb < topk:
+            ids = pad(ids, (0, topk - nb), value=-1)
+        chosen.append(ids)
+    return cat(chosen, 2)
+
+
+@torchsymbol(id="torch.sparse_block_attend")
+def sparse_block_attend(q, k, v, block_ids, *, block_size: int, scale: Optional[float] = None,
+                        query_chunk: Optional[int] = None):
+    """Causal softmax attention of each query over the keys of its chosen
+    blocks: q (B, H, T, d), k, v (B, G, T, d), ``block_ids`` (B, G, T, n) as
+    ``sparse_block_select`` gives them (shared by a key-value head's query
+    heads, -1: none) -> (B, H, T, d). A query's own block has to be among its
+    blocks (no row is empty).
+
+    Dense scores under a mask built from the ids, a chunk of queries against
+    the keys up to the chunk's end: work follows ``T * T / 2``, not the chosen
+    blocks; gathering every query's blocks would move ``n * block_size`` keys
+    and values a query."""
+    B, H, T, d = q.shape
+    G = k.shape[1]
+    R = H // G
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    qg = reshape(q, (B, G, R, T, d))
+    kT = transpose(k, -2, -1)
+    out = []
+    for t0, t1 in _query_chunks(T, query_chunk or SPARSE_ATTEND_CHUNK):
+        n, nb = t1 - t0, -(-t1 // block_size)
+        blocks = arange(0, nb, device=q.device, dtype=block_ids.dtype)
+        chosen = amax(clang.maybe_convert_to_dtype(unsqueeze(block_ids[:, :, t0:t1], -1) == blocks, dtypes.int32), 3)
+        keys = reshape(expand(unsqueeze(chosen, -1), (B, G, n, nb, block_size)), (B, G, n, nb * block_size))[..., :t1]
+        causal = clang.diagonal_mask(n, t1, offset=t0, upper=False, device=q.device)   # key <= t0 + row
+        mask = unsqueeze((keys > 0) & causal, 2)                                       # (B, G, 1, n, t1)
+        s = matmul(reshape(qg[:, :, :, t0:t1], (B, G, R * n, d)), kT[..., :t1])
+        s = reshape(clang.maybe_convert_to_dtype(s, dtypes.float32) * scale, (B, G, R, n, t1))
+        s = where(mask, s, clang.full_like(s, -float("inf")))
+        e = exp(s - amax(s, -1, True))
+        o = matmul(reshape(clang.maybe_convert_to_dtype(e, v.dtype), (B, G, R * n, t1)), v[:, :, :t1])
+        o = clang.maybe_convert_to_dtype(reshape(o, (B, G, R, n, d)), dtypes.float32) / sum(e, -1, True)
+        out.append(clang.maybe_convert_to_dtype(o, q.dtype))
+    return reshape(cat(out, 3), (B, H, T, d))
+
+
+@torchsymbol(id="torch.sparse_block_attention")
+def sparse_block_attention(q, k, v, *, kernel_size: int, kernel_stride: int, block_size: int, topk: int,
+                           init_blocks: int, local_blocks: int, scale: Optional[float] = None):
+    """``sparse_block_attend`` over the blocks ``sparse_block_select`` chooses:
+    InfLLM-V2's attention between its projections, q (B, H, T, d), k, v
+    (B, G, T, d) -> (B, H, T, d). The two halves are composites of their own
+    and run in the regions ``attn.sparse.select`` and ``attn.sparse.attend``."""
+    from thunder_tpu.core.trace import region
+
+    with region("attn.sparse.select"):
+        ids = sparse_block_select(q, k, kernel_size=kernel_size, kernel_stride=kernel_stride, block_size=block_size,
+                                  topk=topk, init_blocks=init_blocks, local_blocks=local_blocks, scale=scale)
+    with region("attn.sparse.attend"):
+        return sparse_block_attend(q, k, v, ids, block_size=block_size, scale=scale)
+
+
+@torchsymbol(id="torch.linear_attention")
+def linear_attention(q, k, v, decay, scale: Optional[float] = None, chunk: Optional[int] = None):
+    """Causal linear attention with a constant decay a head: q, k, v
+    (B, H, T, d), ``decay`` (H,) float32, g >= 0 -> (B, H, T, d),
+
+        S_t = exp(-g) S_{t-1} + k_t^T v_t,    o_t = scale q_t S_t
+            = scale sum_{s<=t} exp(-g (t - s)) (q_t . k_s) v_s
+
+    no softmax, no normaliser. With g constant this needs no scan: within a
+    chunk of ``chunk`` positions the masked quadratic form; a chunk's summary
+    ``A_c = sum_j exp(-g (chunk - 1 - j)) k_j^T v_j``; the state entering chunk
+    c is ``sum_{c' < c} exp(-g chunk (c - 1 - c')) A_c'``, one lower-triangular
+    product over the summaries. Every exponent is <= 0: nothing overflows."""
+    B, H, T, d = q.shape
+    check(k.shape[1] == H and v.shape[1] == H, lambda: f"linear_attention: {k.shape[1]} key heads for {H} query heads")
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    C = builtins_min(chunk or LINEAR_ATTENTION_CHUNK, T)
+    n = -(-T // C)
+    if n * C != T:  # zero keys and values add nothing; the padded queries' rows are dropped
+        q, k, v = (pad(a, (0, 0, 0, n * C - T)) for a in (q, k, v))
+    f32 = dtypes.float32
+    g = reshape(clang.maybe_convert_to_dtype(decay, f32), (H, 1, 1))
+    pos = arange(0, C, device=q.device, dtype=f32)
+    ahead = unsqueeze(pos, 1) - unsqueeze(pos, 0)                                      # (C, C): i - j
+    within = where(ahead >= 0, exp(-g * clang.maximum(ahead, 0.0)), clang.full((H, C, C), 0.0, device=q.device, dtype=f32))
+    to_end = exp(-g * reshape(C - 1 - pos, (1, C, 1)))                                 # (H, C, 1): from j to the chunk's last position
+    from_start = exp(-g * reshape(pos + 1, (1, C, 1)))                                 # from the state before the chunk to i
+    steps = arange(0, n, device=q.device, dtype=f32)
+    between = unsqueeze(steps, 1) - unsqueeze(steps, 0) - 1                            # (n, n): c - 1 - c'
+    carry = where(between >= 0, exp(-g * C * clang.maximum(between, 0.0)), clang.full((H, n, n), 0.0, device=q.device, dtype=f32))
+
+    qc, kc, vc = (reshape(a, (B, H, n, C, d)) for a in (q, k, v))
+    lowp = lambda a: clang.maybe_convert_to_dtype(a, q.dtype)
+    s = clang.maybe_convert_to_dtype(matmul(qc, transpose(kc, -2, -1)), f32) * unsqueeze(within, 1)   # (B, H, n, C, C)
+    o = clang.maybe_convert_to_dtype(matmul(lowp(s), vc), f32)
+    if n > 1:
+        k_end = lowp(clang.maybe_convert_to_dtype(kc, f32) * unsqueeze(to_end, 1))
+        summaries = clang.maybe_convert_to_dtype(matmul(transpose(k_end, -2, -1), vc), f32)  # (B, H, n, d, d)
+        entering = reshape(matmul(carry, reshape(summaries, (B, H, n, d * d))), (B, H, n, d, d))
+        q_in = lowp(clang.maybe_convert_to_dtype(qc, f32) * unsqueeze(from_start, 1))
+        o = o + clang.maybe_convert_to_dtype(matmul(q_in, lowp(entering)), f32)
+    o = reshape(lowp(o * scale), (B, H, n * C, d))
+    return o if n * C == T else o[:, :, :T]
+
+
 @torchsymbol(id="torch.sdpa_fwd_res")
 def sdpa_fwd_res(query, key, value, attn_mask=None, is_causal: bool = False,
                  scale: Optional[float] = None, enable_gqa: bool = False):
