@@ -9,6 +9,7 @@ and against loops over single queries written here."""
 import dataclasses
 import json
 import os
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -281,6 +282,167 @@ def test_on_whole_spans_the_xla_executor_runs_the_same_passes_as_loops(monkeypat
     short = thunder_tpu.jit(lambda q, k, v: ttorch.sparse_block_attention(q, k, v, **SPARSE))
     short(q[:, :, :200], k[:, :, :200], v[:, :, :200])
     assert "sparse_block_select" not in [b.sym.name for b in thunder_tpu.last_traces(short)[-1].bound_symbols]
+
+
+# -----------------------------------------------------------------------------
+# The attention over the chosen blocks as a Pallas kernel (interpret mode here)
+# -----------------------------------------------------------------------------
+
+KERNEL_TILES = (32, 64)  # queries and keys a tile at test size: 8 query tiles and 4 key tiles of 4 blocks in 256 positions
+
+
+@pytest.fixture
+def pallasex(monkeypatch):
+    """The executor's module with the kernel's tiles at test size."""
+    from thunder_tpu.executors import pallasex
+
+    monkeypatch.setattr(pallasex, "_SPARSE_ATTEND_TILES", KERNEL_TILES)
+    return pallasex
+
+
+def _attend(**how):
+    return thunder_tpu.jit(lambda q, k, v, i: ttorch.sparse_block_attend(q, k, v, i, block_size=16, **how))
+
+
+def _proxies(*arrays):
+    """What a checker sees of float32 arrays and int32 ids."""
+    return [SimpleNamespace(shape=a.shape, dtype=dtypes.int32 if a.dtype == np.int32 else dtypes.float32) for a in arrays]
+
+
+def _ids_selected(q, k, **over):
+    """What the selection gives: fewer than ``topk`` blocks (-1) for the first 80 queries."""
+    return np.asarray(thunder_tpu.jit(lambda q, k: ttorch.sparse_block_select(q, k, **{**SPARSE, **over}))(q, k))
+
+
+def _ids_forced_only(q, k):
+    return _ids_selected(q, k, topk=SPARSE["init_blocks"] + SPARSE["local_blocks"])
+
+
+def _ids_disjoint_then_all(q, k):
+    """Every query its own block and the one before; the queries of tile 6 (192 to 223) their own and block 1 or block
+    9 by turns, so that between them they leave key tile 1 (blocks 4 to 7) out; those of tile 7 every block."""
+    G, T_ = k.shape[1], q.shape[2]
+    ids = -np.ones((1, G, T_, 16), np.int32)
+    for t in range(T_):
+        own = t // 16
+        chosen = [own, own - 1] if own else [own]
+        if 192 <= t < 224:
+            chosen = [own, 1 if t % 2 else 9]
+        elif t >= 224:
+            chosen = list(range(own, -1, -1))
+        ids[:, :, t, :len(chosen)] = chosen
+    return ids
+
+
+KERNEL_CASES = [
+    # heads, key-value heads, dtype, the ids
+    (32, 2, "float32", _ids_selected),          # R = 16: MiniCPM-SALA's grouping
+    (32, 2, "bfloat16", _ids_selected),
+    (32, 2, "bfloat16", _ids_disjoint_then_all),
+    (2, 2, "float32", _ids_selected),           # R = 1
+    (2, 2, "bfloat16", _ids_forced_only),
+    (2, 2, "float32", _ids_forced_only),
+    (2, 2, "float32", _ids_disjoint_then_all),
+    (4, 1, "bfloat16", _ids_selected),
+]
+
+
+@pytest.mark.parametrize("heads,groups,dtype,make_ids", KERNEL_CASES,
+                         ids=[f"{h}on{g}-{d}-{m.__name__[5:]}" for h, g, d, m in KERNEL_CASES])
+def test_the_attend_kernel_against_the_decomposition_and_loops_over_single_queries(pallasex, heads, groups, dtype, make_ids):
+    """``pallas`` claims ``sparse_block_attend`` at heads of 128 on whole tiles and gives what the decomposition and
+    the loops give, whatever the ids: with -1 among them, the forced blocks alone, a key tile that no query of a query
+    tile chose (skipped whole) and a query tile that chose every block."""
+    import jax.numpy as jnp
+
+    q, k, v = qkv(T, heads=heads, groups=groups, d=128, seed=heads)
+    ids = make_ids(q, k)
+    if dtype == "bfloat16":  # the loops read what the kernel reads
+        q, k, v = (np.asarray(jnp.asarray(a, jnp.bfloat16)) for a in (q, k, v))
+    kernel = _attend()
+    got = np.asarray(kernel(q, k, v, ids))
+    owners = {b.sym.name: b.sym.executor.name for b in thunder_tpu.last_traces(kernel)[-1].bound_symbols
+              if b.sym.name.startswith("sparse_block")}
+    assert owners == {"sparse_block_attend": "pallas"}
+    assert got.shape == q.shape and got.dtype == q.dtype
+    unrolled = np.asarray(_attend(query_chunk=48)(q, k, v, ids)).astype(np.float32)
+    loops = attend_by_loops(*(np.asarray(a[0], np.float32) for a in (q, k, v)), ids[0], 16)
+    got = got.astype(np.float32)
+    if dtype == "float32":
+        np.testing.assert_allclose(got[0], loops, rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(got, unrolled, rtol=1e-5, atol=2e-6)
+    else:  # the output's own rounding is 2**-9 of values up to 3; the decomposition rounds its scores to bf16 besides
+        np.testing.assert_allclose(got[0], loops, rtol=0, atol=2e-2)
+        np.testing.assert_allclose(got, unrolled, rtol=0, atol=5e-2)
+        assert rel(got[0], loops) <= 1.1 * rel(unrolled[0], loops) + 1e-3  # float32 scores: no further from the loops
+
+
+def test_visited_key_tiles_against_a_count_by_hand():
+    """Of the 20 pairs of a query tile of 32 and a key tile of 64 at or before the causal frontier in 256 positions
+    (1 + 1 + 2 + 2 + 3 + 3 + 4 + 4), a key-value head: with every query's own block and the one before, 1, 1, 2, 1, 2, 1
+    for the first six query tiles; tile 6 leaves key tile 1 out (3 of 4); tile 7 chose everything (4)."""
+    from thunder_tpu.executors import pallasex
+
+    q, k, _ = qkv(T, heads=2, groups=2, d=128)
+    ids = _ids_disjoint_then_all(q, k)
+    visited, causal = pallasex.visited_key_tiles(ids, 16, *KERNEL_TILES)
+    assert (int(visited), int(causal)) == (2 * 15, 2 * 20)
+    flags = np.asarray(pallasex._sparse_pair_flags(ids, 16, *KERNEL_TILES))[0, 0]
+    assert flags[6].tolist() == [True, False, True, True] and flags[7].all() and flags[0].tolist() == [True, False, False, False]
+    # every block up to its own chosen: every causal pair, here at the kernel's own tiles (the default)
+    tq, tk = pallasex._SPARSE_ATTEND_TILES
+    own = (np.arange(4 * tk, dtype=np.int32) // 64)[None, None, :, None]
+    everything = np.minimum(np.arange(4 * tk // 64, dtype=np.int32)[None, None, None, :], own)
+    pairs = sum(-(-(i + 1) * tq // tk) for i in range(4 * tk // tq))
+    assert [int(x) for x in pallasex.visited_key_tiles(everything, 64)] == [pairs, pairs]
+    assert int(pallasex.visited_key_tiles(np.zeros_like(everything), 64)[0]) == 4 * tk // tq  # block 0 alone: the first key tile
+
+
+def test_the_attend_kernel_is_causal_to_the_bit(pallasex):
+    q, k, v = qkv(T, heads=4, groups=2, d=128)
+    ids = _ids_selected(q, k)
+    k2, v2 = k.copy(), v.copy()
+    k2[:, :, 150:] += 1.0
+    v2[:, :, 150:] -= 1.0
+    kernel = _attend()
+    first, second = np.asarray(kernel(q, k, v, ids)), np.asarray(kernel(q, k2, v2, ids))
+    np.testing.assert_array_equal(first[:, :, :150], second[:, :, :150])
+    assert np.abs(first[:, :, 150:] - second[:, :, 150:]).max() > 0.1
+
+
+def test_under_a_mesh_the_attend_kernel_runs_a_batch_shard_a_device(pallasex):
+    """As the other kernels (``executors/kernel_mesh.py``): two sequences over two devices, each shard a call of its
+    own on its own ids, the values those of one call on both; a batch the mesh does not divide is declined."""
+    import jax
+    from jax.sharding import Mesh
+
+    from thunder_tpu.executors.kernel_mesh import kernel_mesh
+
+    q, k, v = (np.concatenate([a, b]) for a, b in zip(qkv(T, heads=4, groups=2, d=128), qkv(T, heads=4, groups=2, d=128, seed=1)))
+    ids = _ids_selected(q, k)
+    want = np.asarray(pallasex._sparse_attend_impl(q, k, v, ids, block_size=16))
+    with kernel_mesh(Mesh(np.asarray(jax.devices()[:2]), ("dp",)), "dp"):
+        assert pallasex._sparse_attend_checker(*_proxies(q, k, v, ids), block_size=16)
+        assert not pallasex._sparse_attend_checker(*_proxies(*(a[:1] for a in (q, k, v, ids))), block_size=16)
+        got = jax.jit(lambda *a: pallasex._sparse_attend_impl(*a, block_size=16))(q, k, v, ids)
+    assert len(got.sharding.device_set) == 2
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("d,t,how", [(64, T, {}), (128, 200, {}), (128, T, {"query_chunk": 64})],
+                         ids=["heads-of-64", "a-ragged-length", "a-chunking-the-caller-chose"])
+def test_what_the_attend_kernels_checker_declines_is_the_decompositions(pallasex, d, t, how):
+    """Heads that are not whole lanes, a length the tiles do not divide, a chunking the caller chose: no executor owns
+    the symbol (``jaxex``'s loops want whole spans of 4,096), and the values are the decomposition's."""
+    q, k, v = qkv(t, heads=4, groups=2, d=d)
+    ids = _ids_selected(q, k)
+    assert not pallasex._sparse_attend_checker(*_proxies(q, k, v, ids), block_size=16, **how)
+    jfn = _attend(**how)
+    got = np.asarray(jfn(q, k, v, ids))
+    assert "sparse_block_attend" not in [b.sym.name for b in thunder_tpu.last_traces(jfn)[-1].bound_symbols]
+    np.testing.assert_allclose(got[0], attend_by_loops(q[0], k[0], v[0], ids[0], 16), rtol=2e-4, atol=2e-5)
+    # and what it takes: heads of 128 on whole tiles, the chunking left open
+    assert pallasex._sparse_attend_checker(*_proxies(*qkv(T, heads=4, groups=2, d=128), np.zeros((1, 2, T, 6), np.int32)), block_size=16)
 
 
 def test_the_forced_blocks_are_always_chosen_and_ties_go_to_the_lower_block():

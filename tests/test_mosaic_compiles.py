@@ -497,28 +497,121 @@ def test_forward_of_pythia_has_no_layout_copy_in_front_of_attention_on_the_v5e(o
     assert executable_needs(folded)[0] <= executable_needs(written)[0]
 
 
-def test_block_sparse_attention_over_three_spans_compiles_for_v5e_as_loops_with_no_square_of_the_sequence(one_chip):
+def _as_the_trace_names_it(text, name_prefix):
+    """The instruction whose name starts with ``name_prefix``, its operands with their shapes: the compiled text
+    leaves an operand's shape to its own line, the device trace's event names (``kernel_families.match``'s input) do not."""
+    shapes = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ", text, re.M))
+    line = next(l.strip() for l in text.splitlines() if l.strip().startswith(name_prefix))
+    head, operands, tail = re.match(r"(.*? custom-call\()([^)]*)(\).*)", line).groups()
+    return head + ", ".join(f"{shapes[o]} {o}" for o in operands.split(", ")) + tail
+
+
+def _sparse_attend_compiled(monkeypatch, one_chip, shape, groups, ids, dtype="bfloat16"):
+    import jax
+    import jax.numpy as jnp
+
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import pallasex
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    monkeypatch.setattr(pallasex, "_device_kind", lambda: next(iter(one_chip.device_set)).device_kind)
+    B, H, T, d = shape
+    shapes = [(shape, dtype), ((B, groups, T, d), dtype), ((B, groups, T, d), dtype), ((B, groups, T, ids), "int32")]
+    claimed = pallasex._sparse_attend_checker(*(SimpleNamespace(shape=s, dtype=getattr(dtypes, d_)) for s, d_ in shapes), block_size=64)
+    sds = [jax.ShapeDtypeStruct(s, getattr(jnp, d_), sharding=one_chip) for s, d_ in shapes]
+    return claimed, jax.jit(lambda *a: pallasex._sparse_attend_impl(*a, block_size=64)).lower(*sds)
+
+
+def test_the_attention_over_the_chosen_blocks_compiles_for_v5e_at_the_cells_shapes(one_chip, monkeypatch):
+    """``minicpm-sala.fwd-t32k``'s sparse layer: 32 query heads on 2 key-value heads of 128 at 32,768 positions, 64
+    ids a query. One Mosaic call within the VMEM it asks for (k and v of a key-value head whole, 8 MB each), which no
+    family of the benchmark's takes for its own (the rope family tells a call by its shapes)."""
+    from perfbench import kernel_families
+    from thunder_tpu.executors import pallasex
+
+    claimed, lowered = _sparse_attend_compiled(monkeypatch, one_chip, (1, 32, 32768, 128), 2, 64)
+    assert claimed
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "%sparse_attend_fwd" in text
+    assert compiled.out_info.shape == (1, 32, 32768, 128)
+    call = next(line for line in text.splitlines() if line.strip().startswith("%sparse_attend_fwd"))
+    scoped = int(re.search(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"', call).group(1))
+    assert scoped == pallasex._ce_vmem_limit() == 64 * 1024 * 1024
+    assert pallasex._sparse_attend_vmem(32768, 16, 128, 2) <= 3 * scoped // 4
+    named = _as_the_trace_names_it(text, "%sparse_attend_fwd")
+    operands = re.findall(r"(\w+)\[[\d,]*\]\S* %", named.split("custom-call(")[1].split("), custom_call_target")[0])
+    assert operands == ["s32", "bf16", "bf16", "bf16", "s32"]  # the flags lead: no (x, cos, sin) of the rope family
+    assert kernel_families.match(named) is None
+
+
+SPARSE_ATTEND_SHAPES = {
+    # (B, H, T, d), key-value heads, dtype, whether the checker claims it
+    "float32": ((1, 32, 8192, 128), 2, "float32", True),
+    "a-key-head-a-query-head": ((2, 4, 4096, 128), 4, "bfloat16", True),
+    "heads-of-256": ((1, 8, 4096, 256), 2, "bfloat16", True),
+    "too-long-for-the-VMEM": ((1, 32, 131072, 128), 2, "bfloat16", False),
+    "float32-too-long-for-the-VMEM": ((1, 32, 32768, 128), 2, "float32", False),
+    "eight-sequences": ((8, 32, 32768, 128), 2, "bfloat16", True),
+    "more-flags-than-the-SMEM-holds": ((4, 64, 16384, 128), 64, "bfloat16", False),  # 262,144: out of SMEM by 1.1 K
+}
+
+
+@pytest.mark.parametrize("shape,groups,dtype,claims", SPARSE_ATTEND_SHAPES.values(), ids=SPARSE_ATTEND_SHAPES)
+def test_the_attend_kernels_checker_claims_what_compiles_for_v5e(one_chip, monkeypatch, shape, groups, dtype, claims):
+    """float32, a key-value head a query head and wider heads compile; keys and values that the v5e's VMEM does not
+    hold twice over, and more flags than a call may keep in SMEM, are ``jaxex``'s loops'."""
+    claimed, lowered = _sparse_attend_compiled(monkeypatch, one_chip, shape, groups, 64, dtype)
+    assert claimed == claims
+    if claims:
+        assert lowered.compile().as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_the_attend_kernels_checker_declines_where_the_generation_has_no_room(describe_chip, monkeypatch):
+    """A v4 core has 16 MiB: the cell's keys and values do not fit it, 2,048 positions do and compile there."""
+    from thunder_tpu.executors import pallasex
+
+    chip = describe_chip("v4:2x2x1")
+    assert not _sparse_attend_compiled(monkeypatch, chip, (1, 32, 32768, 128), 2, 64)[0]
+    claimed, lowered = _sparse_attend_compiled(monkeypatch, chip, (1, 32, 2048, 128), 2, 64)
+    assert claimed and pallasex._ce_vmem_limit() == 16 * 1024 * 1024
+    assert lowered.compile().as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_block_sparse_attention_over_three_spans_compiles_for_v5e_as_loops_and_a_kernel_with_no_square_of_the_sequence(one_chip, monkeypatch):
     """``minicpm-sala.fwd-t32k``'s sparse layer at three eighths of its length (12,288 positions, three spans; 32 query
-    heads on 2 key-value heads of 128): both halves are ``jaxex``'s, a ``while`` a span and half, and nothing the
-    compiled program holds has the sequence twice among its dimensions or the sequence beside its 767 pooled keys."""
+    heads on 2 key-value heads of 128): the selection is ``jaxex``'s, a ``while`` a span, the attention over the chosen
+    blocks one Mosaic call of ``pallas``, and nothing the compiled program holds has the sequence twice among its
+    dimensions or the sequence beside its 767 pooled keys: no score reaches HBM. The benchmark's readers find the
+    call in the region it was written in and in no kernel family."""
     import jax
     import jax.numpy as jnp
 
     import thunder_tpu.torch as ttorch
+    from perfbench import kernel_families
+    from perfbench.layer_metrics import _regions
     from thunder_tpu.api import trace_program
+    from thunder_tpu.executors import pallasex
     from thunder_tpu.executors.passes import transform_for_execution
     from thunder_tpu.extend import resolve_executors
     from thunder_tpu.transforms.common import dce
 
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    monkeypatch.setattr(pallasex, "_device_kind", lambda: next(iter(one_chip.device_set)).device_kind)
     T = 12288
     shapes = [jax.ShapeDtypeStruct((1, heads, T, 128), jnp.bfloat16, sharding=one_chip) for heads in (32, 2, 2)]
     _, comp = trace_program(lambda q, k, v: ttorch.sparse_block_attention(
         q, k, v, kernel_size=32, kernel_stride=16, block_size=64, topk=64, init_blocks=1, local_blocks=32), shapes, {})
     claimed = transform_for_execution(dce(comp), resolve_executors(None))
     owners = [(b.sym.name, b.sym.executor.name) for b in claimed.bound_symbols if b.sym.name.startswith("sparse_block")]
-    assert owners == [("sparse_block_select", "jax"), ("sparse_block_attend", "jax")]
+    assert owners == [("sparse_block_select", "jax"), ("sparse_block_attend", "pallas")]
     text = jax.jit(claimed.python_callable()).lower(*shapes).compile().as_text()
-    assert text.count(" while(") == 6
+    assert text.count(" while(") == 3 and text.count('custom_call_target="tpu_custom_call"') == 1
     dims = [[int(d) for d in m.split(",") if d] for m in re.findall(r"(?:pred|[subf]\d+|bf16)\[([\d,]*)\]", text)]
     assert not [d for d in dims if d.count(T) >= 2 or (T in d and 767 in d)]
-    assert [d for d in dims if d == [2, 4096, T]]  # the last span's scores: 16 query heads x 256 queries against every key
+    assert not [d for d in dims if d[:2] == [2, 4096] and d[-1] >= 4096]  # the spans' scores, (2, 4096, keys), are gone
+    call = next(l.split(" = ")[0].strip().lstrip("%") for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l)
+    regions = _regions.of_instructions(text)
+    assert call.startswith("sparse_attend_fwd") and regions[call] == "attn.sparse.attend"
+    assert "attn.sparse.select" in regions.values()
+    assert kernel_families.match(_as_the_trace_names_it(text, "%" + call)) is None

@@ -426,7 +426,12 @@ ex.register_implementation("torch.linear_heads", fn=_linear_heads)
 # iterations of ``lax.map``: the queries of a span of ``SPARSE_LOOP_SPAN`` share
 # one key length (the span's end), so a layer compiles a loop body a span, and a
 # loop keeps one pass's temporaries alive. The arithmetic of a pass is the
-# decomposition's, operation for operation.
+# decomposition's, operation for operation. The selection runs here; the
+# attention over the chosen blocks runs here only where ``pallas`` declines it
+# (``pallasex._sparse_attend_checker``: heads that are not whole lanes, keys and
+# values the device's VMEM does not hold): its kernel, ahead in the executors'
+# order, keeps the scores these passes write to HBM once and read twice on the
+# chip (PERF.md, PR 34).
 
 SPARSE_LOOP_SPAN = 4096     # queries whose passes share a key length, a compiled body and a loop
 SPARSE_LOOP_SELECT = 512    # queries a pass

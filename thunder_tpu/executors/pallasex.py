@@ -640,3 +640,177 @@ def _moe_experts_impl(x, top_i, top_w, w_gate, w_up, w_down, expert_offset=0, n_
 
 
 ex.register_implementation("torch.moe_experts", fn=_moe_experts_impl, checker=_moe_experts_checker)
+
+
+# =============================================================================
+# Block-sparse attention over the blocks the data chose (torch.sparse_block_attend)
+# =============================================================================
+#
+# A flash-style forward over a mask that block ids give: no score, no exp and no
+# mask element reaches HBM, where jaxex's lax.map passes write every bf16 score
+# once and read it twice (PERF.md, PR 33: some 500 of minicpm-sala.fwd-t32k's
+# 2,106 ms). All R = H / G query heads of a key-value head share one selection,
+# so a tile of tq consecutive queries is R tq rows against one mask.
+#
+# A call holds the keys and values of one key-value head whole in VMEM (8 MB
+# each at 32,768 positions of 128) and walks, for each tile of tq queries, the
+# tiles of tk keys up to the tile's causal frontier, with a running maximum and
+# sum a row. Scores are held keys-major, (tk, tq) a head: the reductions over
+# keys are then sums of whole registers, the running maximum and sum are rows of
+# lanes, and the mask of a pair of tiles is built from the tile's (n, tq) ids by
+# nbt = tk / block_size comparisons and broadcasts along sublanes, once for the
+# R heads. The values come transposed, (d, tk) a tile, so that the accumulator
+# is (d, tq); it is transposed once, when a query tile is done.
+#
+# It adapts to the ids: a key tile none of whose blocks any query of the query
+# tile chose is skipped whole, by a flag a pair that XLA makes from the ids
+# (``_sparse_pair_flags``) and the call reads in SMEM.
+
+# Measured on the v5e at minicpm-sala.fwd-t32k's shapes, a layer (PERF.md, PR 34): 256 x 1024 reads 63.9 ms, 256 x 2048
+# 63.2, 256 x 512 66.5, 256 x 256 67.6, 512 x 1024 67.2, 512 x 512 69.6, 128 x 256 72.7, 128 x 1024 and 128 x 2048 80.6,
+# 128 x 512 84.0, 512 x 2048 78.3: 3.7 ps a causal score where the MXU's least is 2.6.
+_SPARSE_ATTEND_TILES = (256, 1024)  # queries and keys a tile
+# The flags a call reads in SMEM, of which the v5e's compiler gives a call 1 MiB (no chip: 262,144 flags ran out by 1.1 K).
+_SPARSE_ATTEND_FLAGS_MOST = 64 * 1024
+
+
+def _sparse_pair_flags(block_ids, block_size: int, tq: int, tk: int):
+    """(B, G, T // tq, T // tk) bool: whether any query of a query tile chose a
+    block of a key tile. ``block_ids`` (B, G, T, n), -1 for none."""
+    import jax.numpy as jnp
+
+    B, G, T, n = block_ids.shape
+    tile_of = jnp.where(block_ids >= 0, block_ids * block_size // tk, -1).reshape(B, G, T // tq, tq * n)
+    return (tile_of[..., None] == jnp.arange(T // tk, dtype=block_ids.dtype)).any(-2)
+
+
+def visited_key_tiles(block_ids, block_size: int, tq: int | None = None, tk: int | None = None):
+    """(visited, causal): of the pairs of a tile of ``tq`` queries and a tile of
+    ``tk`` keys at or before its causal frontier, summed over batch and
+    key-value heads, how many the kernel computes (some query of the tile chose
+    a block of the key tile) and how many there are. A pure function of the
+    ids and the two tile sizes (the kernel's own by default)."""
+    import jax.numpy as jnp
+
+    tq, tk = tq or _SPARSE_ATTEND_TILES[0], tk or _SPARSE_ATTEND_TILES[1]
+    B, G, T, _ = block_ids.shape
+    frontier = -(-(jnp.arange(1, T // tq + 1) * tq) // tk)                       # key tiles a query tile can see
+    causal = jnp.arange(T // tk)[None, :] < frontier[:, None]
+    visited = _sparse_pair_flags(block_ids, block_size, tq, tk) & causal
+    return jnp.sum(visited), B * G * jnp.sum(causal)
+
+
+def _sparse_attend_vmem(T: int, R: int, d: int, itemsize: int) -> int:
+    """What a call holds in VMEM: k and v whole and the blocks of q and the
+    output, each twice for the pipeline; the accumulator; and a head's float32
+    scores, their exp and its copy in v's dtype, some four (tk, tq) at once.
+    Three quarters of ``_ce_vmem_limit()``, which this call asks for too, may
+    go to them."""
+    tq, tk = _SPARSE_ATTEND_TILES
+    return 4 * T * d * itemsize + 4 * R * tq * d * itemsize + R * d * tq * 4 + 4 * tk * tq * 4
+
+
+def _sparse_attend_checker(q, k, v, block_ids, *, block_size, scale=None, query_chunk=None):
+    if query_chunk is not None or any(len(getattr(a, "shape", ())) != 4 for a in (q, k, v, block_ids)):
+        return False
+    dtype = dtypes.to_dtype(q.dtype)
+    if dtype not in (dtypes.bfloat16, dtypes.float32) or any(dtypes.to_dtype(a.dtype) is not dtype for a in (k, v)):
+        return False
+    (B, H, T, d), G = q.shape, k.shape[1]
+    tq, tk = _SPARSE_ATTEND_TILES
+    block_size = int(pyval(block_size))
+    return (d % _LANE == 0 and H % G == 0 and T % tq == 0 and T % tk == 0 and tk % block_size == 0
+            and block_size % 8 == 0 and tuple(k.shape) == tuple(v.shape) == (B, G, T, d) and B % batch_shards() == 0
+            and B // batch_shards() * G * (T // tq) * (T // tk) <= _SPARSE_ATTEND_FLAGS_MOST
+            and _sparse_attend_vmem(int(T), H // G, int(d), dtype.bytes) <= 3 * _ce_vmem_limit() // 4)
+
+
+def _sparse_attend_kernel(flags_ref, q_ref, k_ref, vt_ref, ids_ref, out_ref, m_scr, l_scr, acc_scr, *,
+                          scale: float, block_size: int):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    R, tq = q_ref.shape[2], q_ref.shape[3]
+    nk, tk = k_ref.shape[2], k_ref.shape[3]
+    b, g, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    first_flag = ((b * pl.num_programs(1) + g) * pl.num_programs(2) + qi) * nk
+    f32 = jnp.float32
+
+    m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, f32)
+    l_scr[...] = jnp.zeros(l_scr.shape, f32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, f32)
+    ids = ids_ref[0, 0]                                                          # (n, tq): a query's ids down its lane
+    ahead = (qi * tq + jax.lax.broadcasted_iota(jnp.int32, (tk, tq), 1)
+             - jax.lax.broadcasted_iota(jnp.int32, (tk, tq), 0))                 # query's position - key's place in its tile
+
+    def pair(kt, carry):
+        @pl.when(flags_ref[first_flag + kt] != 0)
+        def _():
+            # The mask of the pair, once for the R heads: a key is kept if its block is among its query's ids and it
+            # is not after the query.
+            first_block = kt * (tk // block_size)
+            chosen = [jnp.max(jnp.where(ids == first_block + i, 1.0, 0.0), axis=0, keepdims=True)
+                      for i in range(tk // block_size)]
+            chosen = jnp.concatenate([jnp.broadcast_to(row, (block_size, tq)) for row in chosen], axis=0)
+            bias = jnp.where((chosen > 0.0) & (ahead >= kt * tk), 0.0, -jnp.inf)
+            keys, values = k_ref[0, 0, kt], vt_ref[0, 0, kt]                      # (tk, d), (d, tk)
+            for r in range(R):
+                s = jax.lax.dot_general(keys, q_ref[0, 0, r], (((1,), (1,)), ((), ())), preferred_element_type=f32)
+                s = s * scale + bias                                             # (tk, tq)
+                m_was = m_scr[r]
+                m = jnp.maximum(m_was, jnp.max(s, axis=0, keepdims=True))
+                at = jnp.where(m == -jnp.inf, 0.0, m)                            # a row with no key yet adds nothing
+                shrink = jnp.exp(m_was - at)
+                p = jnp.exp(s - at)
+                l_scr[r] = l_scr[r] * shrink + jnp.sum(p, axis=0, keepdims=True)
+                acc_scr[r] = acc_scr[r] * shrink + jnp.dot(values, p.astype(values.dtype), preferred_element_type=f32)
+                m_scr[r] = m
+        return carry
+
+    jax.lax.fori_loop(0, ((qi + 1) * tq + tk - 1) // tk, pair, 0)
+    for r in range(R):
+        out_ref[0, 0, r] = (acc_scr[r] / l_scr[r]).T.astype(out_ref.dtype)
+
+
+def _sparse_attend_impl(q, k, v, block_ids, *, block_size, scale=None, query_chunk=None):
+    chaos.kernel_seam("pallas", "sparse_block_attend")
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    block_size = int(block_size)
+    scale = float(scale) if scale is not None else q.shape[-1] ** -0.5
+    tq, tk = _SPARSE_ATTEND_TILES
+
+    def shard(q, k, v, block_ids):
+        (B, H, T, d), G, n = q.shape, k.shape[1], block_ids.shape[-1]
+        R, nq, nk = H // G, T // tq, T // tk
+        flags = _sparse_pair_flags(block_ids, block_size, tq, tk).astype(jnp.int32).reshape(-1)
+        whole = lambda *block: pl.BlockSpec((1, 1, *block), lambda b, g, i, flags: (b, g, 0, 0, 0), memory_space=pltpu.VMEM)
+        rows = pl.BlockSpec((1, 1, R, tq, d), lambda b, g, i, flags: (b, g, 0, i, 0), memory_space=pltpu.VMEM)
+        out = pl.pallas_call(
+            partial(_sparse_attend_kernel, scale=scale, block_size=block_size),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(B, G, nq),
+                in_specs=[rows, whole(nk, tk, d), whole(nk, d, tk),
+                          pl.BlockSpec((1, 1, n, tq), lambda b, g, i, flags: (b, g, 0, i), memory_space=pltpu.VMEM)],
+                out_specs=rows,
+                scratch_shapes=[pltpu.VMEM((R, 1, tq), jnp.float32), pltpu.VMEM((R, 1, tq), jnp.float32),
+                                pltpu.VMEM((R, d, tq), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((B, G, R, T, d), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel"), vmem_limit_bytes=_ce_vmem_limit()),
+            name="sparse_attend_fwd",
+            interpret=_interpret(),
+        )(flags, q.reshape(B, G, R, T, d), k.reshape(B, G, nk, tk, d),
+          jnp.swapaxes(v.reshape(B, G, nk, tk, d), -2, -1), jnp.swapaxes(block_ids, -2, -1))
+        return out.reshape(B, H, T, d)
+
+    with jax.enable_x64(False):
+        return per_batch_shard(shard, q, k, v, block_ids.astype(jnp.int32))
+
+
+ex.register_implementation("torch.sparse_block_attend", fn=_sparse_attend_impl, checker=_sparse_attend_checker)

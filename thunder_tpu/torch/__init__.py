@@ -1522,7 +1522,8 @@ def moe_experts(x, top_i, top_w, w_gate, w_up, w_down, expert_offset: int = 0, n
 # XLA runs where none does, written so that nothing of shape (heads, T, T) or
 # (heads, T, pooled keys) is ever whole: queries go in chunks, each against the
 # keys up to its own end. (On sequences of whole spans ``jaxex`` runs the sparse
-# halves' passes as loops; the chunks here are unrolled into the program.)
+# halves' passes as loops, and the attention over the chosen blocks is ``pallas``'s
+# kernel where it claims it; the chunks here are unrolled into the program.)
 
 SPARSE_SELECT_CHUNK = 2048  # queries a pass of the selection: (H, chunk, pooled keys) float32 scores
 SPARSE_ATTEND_CHUNK = 512   # queries a pass of the attention: (H, chunk, keys so far) scores
@@ -1627,7 +1628,18 @@ def sparse_block_attend(q, k, v, block_ids, *, block_size: int, scale: Optional[
     Dense scores under a mask built from the ids, a chunk of queries against
     the keys up to the chunk's end: work follows ``T * T / 2``, not the chosen
     blocks; gathering every query's blocks would move ``n * block_size`` keys
-    and values a query."""
+    and values a query.
+
+    Which form runs where: this body is the definition, the CPU's path and the
+    tests' reference, and what XLA runs where no executor claims the symbol
+    (its chunks unrolled into the program). ``pallas`` claims it where the
+    caller leaves ``query_chunk`` open, heads are whole lanes, the length whole
+    tiles and the device's VMEM holds a key-value head's keys and values
+    (``executors/pallasex.py``: one Mosaic call a layer, an online softmax, no
+    score in HBM; it skips a key tile that no query of a query tile chose).
+    What that declines on two or more whole spans of 4,096 positions, ``jax``
+    runs as ``lax.map`` loops (``executors/jaxex.py``): the same passes as
+    here, their scores through HBM."""
     B, H, T, d = q.shape
     G = k.shape[1]
     R = H // G
