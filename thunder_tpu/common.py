@@ -94,29 +94,35 @@ def suppress_sharp_edges():
 def sharp_edge(msg: str) -> None:
     """Report a tracing-unsafe construct per the active policy. ALLOW is
     silent (the reference's default); WARN emits ThunderSharpEdgeWarning;
-    ERROR raises ThunderSharpEdgeError."""
+    ERROR raises ThunderSharpEdgeError.
+
+    The body runs with reporting suppressed: while a trace is acquired the
+    clocks and ``os.environ`` are the interceptors' (frontend/sharp.py), and
+    what the reporter reads for itself (the log's configuration, an event's
+    timestamp, ``warnings``' filters) is not a sharp edge of the user's."""
     if _sharp_edges_suppressed.get():
         return
-    policy = _sharp_edges_policy.get()
-    # Observability tap (before the ALLOW early-return: the event log wants
-    # every sharp edge, the policy only governs warn/raise behavior).
-    from thunder_tpu.observability import events, metrics as obsm
+    with suppress_sharp_edges():
+        policy = _sharp_edges_policy.get()
+        # Observability tap (before the ALLOW early-return: the event log wants
+        # every sharp edge, the policy only governs warn/raise behavior).
+        from thunder_tpu.observability import events, metrics as obsm
 
-    if obsm.enabled():
-        obsm.SHARP_EDGES.inc()
-    if events.active_log() is not None:
-        events.emit_event("sharp_edge", message=msg, policy=policy.name.lower())
-    if policy is SHARP_EDGES_OPTIONS.ALLOW:
-        return
-    full = (
-        f"sharp edge: {msg}. The trace specializes on the observed value; "
-        f"changes to it will NOT recompile. Pass sharp_edges='allow' to silence."
-    )
-    if policy is SHARP_EDGES_OPTIONS.ERROR:
-        raise ThunderSharpEdgeError(full)
-    import warnings
+        if obsm.enabled():
+            obsm.SHARP_EDGES.inc()
+        if events.active_log() is not None:
+            events.emit_event("sharp_edge", message=msg, policy=policy.name.lower())
+        if policy is SHARP_EDGES_OPTIONS.ALLOW:
+            return
+        full = (
+            f"sharp edge: {msg}. The trace specializes on the observed value; "
+            f"changes to it will NOT recompile. Pass sharp_edges='allow' to silence."
+        )
+        if policy is SHARP_EDGES_OPTIONS.ERROR:
+            raise ThunderSharpEdgeError(full)
+        import warnings
 
-    warnings.warn(full, ThunderSharpEdgeWarning, stacklevel=3)
+        warnings.warn(full, ThunderSharpEdgeWarning, stacklevel=3)
 
 
 @contextlib.contextmanager

@@ -11,11 +11,21 @@ acquired, the known nondeterminism entry points — the ``random`` module,
 error → ThunderSharpEdgeError) and then execute normally, so under the
 default policy behavior is unchanged but the observed value is known to be
 baked into the cached trace.
+
+The patches are process-wide for as long as the trace is acquired, and the
+traced function is only what the thread that opened them runs: a call from
+any other thread goes straight to the real function, unreported (a logging
+handler's clock is not this trace's sharp edge, and ``sharp_edges="error"``
+must not raise into a thread that is not tracing). What the interpreter runs
+on the tracing thread between two bytecodes (a ``gc`` callback, a signal
+handler, a ``__del__``) cannot be told from the user's code cheaply and may
+still be reported. The reporter's own reads never are (``common.sharp_edge``).
 """
 
 from __future__ import annotations
 
 import contextlib
+import threading
 from typing import Any
 
 from thunder_tpu.common import sharp_edge
@@ -29,12 +39,13 @@ _RANDOM_FNS = (
 _TIME_FNS = ("time", "time_ns", "perf_counter", "perf_counter_ns", "monotonic", "monotonic_ns")
 
 
-def _reporting(mod_name: str, fn_name: str, fn):
+def _reporting(mod_name: str, fn_name: str, fn, tracing_thread: int):
     def wrapper(*args, **kwargs):
-        sharp_edge(
-            f"call to {mod_name}.{fn_name}() while tracing — the returned value is "
-            f"baked into the compiled program and will NOT be re-evaluated on later calls"
-        )
+        if threading.get_ident() == tracing_thread:
+            sharp_edge(
+                f"call to {mod_name}.{fn_name}() while tracing — the returned value is "
+                f"baked into the compiled program and will NOT be re-evaluated on later calls"
+            )
         return fn(*args, **kwargs)
 
     wrapper.__name__ = fn_name
@@ -42,18 +53,22 @@ def _reporting(mod_name: str, fn_name: str, fn):
 
 
 class _ReportingEnviron:
-    """os.environ stand-in: reads report as sharp edges, everything else
-    forwards (reference: env reads inside a traced forward are baked
-    configuration, jit_ext.py sharp-edge surface)."""
+    """os.environ stand-in: the tracing thread's reads report as sharp edges,
+    everything else forwards (reference: env reads inside a traced forward
+    are baked configuration, jit_ext.py sharp-edge surface). ``_real`` is the
+    mapping it stands in for (observability/events.py reads the log's own
+    configuration from it)."""
 
-    def __init__(self, real):
+    def __init__(self, real, tracing_thread: int):
         object.__setattr__(self, "_real", real)
+        object.__setattr__(self, "_tracing_thread", tracing_thread)
 
     def _report(self, key):
-        sharp_edge(
-            f"read of os.environ[{key!r}] while tracing — the value is baked into "
-            f"the compiled program"
-        )
+        if threading.get_ident() == self._tracing_thread:
+            sharp_edge(
+                f"read of os.environ[{key!r}] while tracing — the value is baked into "
+                f"the compiled program"
+            )
 
     def __getitem__(self, key):
         self._report(key)
@@ -92,6 +107,7 @@ def sharp_edge_interceptors():
     import time
 
     saved: list[tuple[Any, str, Any]] = []
+    tracing_thread = threading.get_ident()
 
     def patch(obj, name, value):
         saved.append((obj, name, getattr(obj, name)))
@@ -101,12 +117,12 @@ def sharp_edge_interceptors():
         for fn_name in _RANDOM_FNS:
             fn = getattr(random, fn_name, None)
             if fn is not None:
-                patch(random, fn_name, _reporting("random", fn_name, fn))
+                patch(random, fn_name, _reporting("random", fn_name, fn, tracing_thread))
         for fn_name in _TIME_FNS:
             fn = getattr(time, fn_name, None)
             if fn is not None:
-                patch(time, fn_name, _reporting("time", fn_name, fn))
-        patch(os, "environ", _ReportingEnviron(os.environ))
+                patch(time, fn_name, _reporting("time", fn_name, fn, tracing_thread))
+        patch(os, "environ", _ReportingEnviron(os.environ, tracing_thread))
         grad_tok = None
         try:
             import torch

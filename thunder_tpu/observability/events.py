@@ -223,10 +223,19 @@ def set_global_path(path: Optional[str]) -> None:
 
 def _global_log() -> Optional[EventLog]:
     if not _global.get("resolved"):
-        path = os.environ.get("THUNDER_TPU_EVENTS", "").strip()
-        _global["path"] = path or None
-        _global["log"] = log_for_path(path) if path else None
-        _global["resolved"] = True
+        try:
+            # While a trace is acquired ``os.environ`` is the sharp-edge
+            # interceptors' stand-in (frontend/sharp.py), whose reads report
+            # through ``active_log``: the log's own configuration comes from
+            # the mapping it wraps.
+            environ = getattr(os.environ, "_real", os.environ)
+            path = environ.get("THUNDER_TPU_EVENTS", "").strip()
+            _global["path"] = path or None
+            _global["log"] = log_for_path(path) if path else None
+        finally:
+            # Checked once, also when the read raised: the next caller must
+            # not pay for (or re-raise) the same failure.
+            _global["resolved"] = True
     return _global["log"]
 
 
