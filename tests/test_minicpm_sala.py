@@ -255,6 +255,12 @@ def test_the_reference_chooses_and_attends_as_the_loops_do():
     np.testing.assert_allclose(np.asarray(out), attend_by_loops(q[0], k[0], v[0], want, 16), rtol=2e-4, atol=2e-5)
 
 
+def _sparse_owners(jfn):
+    """{symbol: executor} of the sparse halves a compiled function's last trace holds."""
+    return {b.sym.name: b.sym.executor.name for b in thunder_tpu.last_traces(jfn)[-1].bound_symbols
+            if b.sym.name.startswith("sparse_block")}
+
+
 def test_on_whole_spans_the_xla_executor_runs_the_same_passes_as_loops(monkeypatch):
     """``jaxex`` claims both halves where the sequence is two or more whole spans
     and the caller leaves the chunking open, and gives the decomposition's ids
@@ -266,9 +272,7 @@ def test_on_whole_spans_the_xla_executor_runs_the_same_passes_as_loops(monkeypat
         monkeypatch.setattr(jaxex, name, value)
     whole = thunder_tpu.jit(lambda q, k, v: ttorch.sparse_block_attention(q, k, v, **SPARSE))
     out = np.asarray(whole(q, k, v))
-    owners = {b.sym.name: b.sym.executor.name for b in thunder_tpu.last_traces(whole)[-1].bound_symbols
-              if b.sym.name.startswith("sparse_block")}
-    assert owners == {"sparse_block_select": "jax", "sparse_block_attend": "jax"}
+    assert _sparse_owners(whole) == {"sparse_block_select": "jax", "sparse_block_attend": "jax"}
     ids = np.asarray(thunder_tpu.jit(lambda q, k: ttorch.sparse_block_select(q, k, **SPARSE))(q, k))
     unrolled = np.asarray(thunder_tpu.jit(lambda q, k: ttorch.sparse_block_select(q, k, query_chunk=48, **SPARSE))(q, k))
     np.testing.assert_array_equal(ids, unrolled)
@@ -282,6 +286,161 @@ def test_on_whole_spans_the_xla_executor_runs_the_same_passes_as_loops(monkeypat
     short = thunder_tpu.jit(lambda q, k, v: ttorch.sparse_block_attention(q, k, v, **SPARSE))
     short(q[:, :, :200], k[:, :, :200], v[:, :, :200])
     assert "sparse_block_select" not in [b.sym.name for b in thunder_tpu.last_traces(short)[-1].bound_symbols]
+
+
+# -----------------------------------------------------------------------------
+# The last step of a pass in the loop form: the best blocks without a sort of them all
+# -----------------------------------------------------------------------------
+
+
+def _random_scores(t, seed):
+    q, k, _ = qkv(t, heads=4, groups=2, seed=seed)
+    return q, k
+
+
+def _queries_of_zeros(t, seed):
+    """Every pooled key in a query's past scores alike: every free block ties, at every query."""
+    q, k = _random_scores(t, seed)
+    return np.zeros_like(q), k
+
+
+def _keys_repeated(t, seed):
+    """Small whole numbers, so that every product and pooled mean is exact, and every block of keys a copy of one
+    of three: whole blocks tie, at the threshold too, in float32 as in the loops' float64."""
+    rng = np.random.RandomState(seed)
+    q = rng.randint(-2, 3, (1, 4, t, 16)).astype(np.float32)
+    base = rng.randint(-2, 3, (1, 2, 3, 16, 16)).astype(np.float32)
+    k = base[:, :, rng.randint(0, 3, -(-t // 16))].reshape(1, 2, -1, 16)[:, :, :t]
+    return q, np.ascontiguousarray(k)
+
+
+def _one_pooled_key_takes_all(t, seed):
+    """Scores whole multiples of 1,024 apart: each head's softmax is 1 / m on its m best pooled keys and exactly
+    0.0 on the rest, in float32 and in float64, so most blocks score 0.0 and the threshold of most rows is 0.0."""
+    q, k = _keys_repeated(t, seed)
+    return q * 32768.0, k
+
+
+SELECT_DATA = {"random": _random_scores, "queries-of-zeros": _queries_of_zeros, "keys-repeated": _keys_repeated,
+               "threshold-of-zero": _one_pooled_key_takes_all}
+# (span, queries a pass, T): the blocks a span's passes see are 4, 8, 12, 16; 3, 6, 9, 12, 15 (under topk, topk itself, and
+# counts no power of two divides); 2, 4, 6, 8, 10, 12 (three spans with no more blocks than topk)
+SELECT_SIZES = {"spans-of-64": (64, 32, 256), "spans-of-48": (48, 24, 240), "spans-of-32": (32, 16, 192)}
+
+
+@pytest.mark.parametrize("sizes", SELECT_SIZES)
+@pytest.mark.parametrize("data", SELECT_DATA)
+def test_the_loop_form_chooses_the_array_the_decomposition_and_the_loops_choose(monkeypatch, data, sizes):
+    """``jaxex``'s passes end in a selection that sorts nothing it rejects: the whole (B, G, T, topk) array is the
+    decomposition's and the loops', place for place, where scores tie, where the threshold is 0.0, where a query has
+    fewer than ``topk`` blocks in its past and where it has exactly ``topk``."""
+    from thunder_tpu.executors import jaxex
+
+    span, select, t = SELECT_SIZES[sizes]
+    monkeypatch.setattr(jaxex, "SPARSE_LOOP_SPAN", span)
+    monkeypatch.setattr(jaxex, "SPARSE_LOOP_SELECT", select)
+    q, k = SELECT_DATA[data](t, seed=len(data) + span)
+    loops = thunder_tpu.jit(lambda q, k: ttorch.sparse_block_select(q, k, **SPARSE))
+    ids = np.asarray(loops(q, k))
+    assert _sparse_owners(loops) == {"sparse_block_select": "jax"}
+    unrolled = np.asarray(thunder_tpu.jit(lambda q, k: ttorch.sparse_block_select(q, k, query_chunk=40, **SPARSE))(q, k))
+    want = select_by_loops(q[0], k[0], **SPARSE)
+    assert ids.shape == (1, 2, t, 6) and ids.dtype == np.int32
+    np.testing.assert_array_equal(ids, unrolled)
+    np.testing.assert_array_equal(ids[0], want)
+    # the cases are what they say: -1s in the first blocks and none after, the first span all forced or short
+    assert (ids[0, :, :16 * 5] == -1).any() and (ids[0, :, 16 * 5:] >= 0).all()
+    if data == "queries-of-zeros":       # the free blocks chosen are the lowest: 1, 2, 3
+        assert (ids[0, :, 16 * 6:, 3:] == [1, 2, 3]).all()
+    if data == "threshold-of-zero":      # a row that ends in the lowest free blocks in order took them at a score of 0.0
+        assert (ids[0, :, 16 * 8:, 4:] == [1, 2]).all(-1).any() or (ids[0, :, 16 * 8:, 3:] == [1, 2, 3]).all(-1).any()
+
+
+@pytest.mark.parametrize("forced", [(2, 3), (2, 4), (3, 1), (1, 5)], ids=lambda f: f"{f[0]}-first-and-{f[1]}-local")
+@pytest.mark.parametrize("data", ["random", "queries-of-zeros"])
+def test_the_loop_form_places_other_counts_of_forced_blocks_as_the_loops_do(monkeypatch, data, forced):
+    """The loop form writes the forced blocks by their numbers and takes turns for the rest only: with more
+    than one first block, with as many forced as ``topk`` (no turn at all), and in spans that hold fewer
+    blocks than are forced, the array is still the decomposition's and the loops'."""
+    from thunder_tpu.executors import jaxex
+
+    monkeypatch.setattr(jaxex, "SPARSE_LOOP_SPAN", 48)
+    monkeypatch.setattr(jaxex, "SPARSE_LOOP_SELECT", 24)
+    consts = {**SPARSE, "init_blocks": forced[0], "local_blocks": forced[1]}
+    q, k = SELECT_DATA[data](240, seed=sum(forced))
+    loops = thunder_tpu.jit(lambda q, k: ttorch.sparse_block_select(q, k, **consts))
+    ids = np.asarray(loops(q, k))
+    assert _sparse_owners(loops) == {"sparse_block_select": "jax"}
+    np.testing.assert_array_equal(ids, np.asarray(thunder_tpu.jit(
+        lambda q, k: ttorch.sparse_block_select(q, k, query_chunk=40, **consts))(q, k)))
+    np.testing.assert_array_equal(ids[0], select_by_loops(q[0], k[0], **consts))
+
+
+@pytest.mark.parametrize("nb,k,own0,forced", [(48, 48, 0, (1, 32)), (64, 64, 10, (1, 32)), (200, 64, 150, (1, 32)),
+                                              (512, 64, 504, (1, 32)), (16, 6, 0, (1, 2)), (7, 6, 3, (1, 2)),
+                                              (6, 6, 2, (1, 2)), (4, 4, 0, (1, 2)), (12, 6, 8, (3, 5))],
+                         ids=lambda v: "-".join(map(str, v)) if isinstance(v, tuple) else str(v))
+@pytest.mark.parametrize("scores", ["random", "quarters", "zeros"])
+def test_the_best_in_turn_are_top_k_s(nb, k, own0, forced, scores):
+    """The step alone against ``lax.top_k`` on rows as a pass makes them (+inf on the forced blocks, -inf after
+    the query's own, nothing negative else): every id in every place, with ties and with rows short of ``k``."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from thunder_tpu.executors import jaxex
+
+    rng = np.random.RandomState(nb + k)
+    s = {"random": lambda: rng.rand(2, 3, 64, nb) * 3, "quarters": lambda: np.round(rng.rand(2, 3, 64, nb) * 12) / 4,
+         "zeros": lambda: np.zeros((2, 3, 64, nb))}[scores]().astype(np.float32)
+    block = 4
+    b, own = np.arange(nb)[None, :], ((own0 * block + np.arange(64)) // block)[:, None]
+    s = np.where(b > own, -np.inf, np.where((b < forced[0]) | (b > own - forced[1]), np.inf, s)).astype(np.float32)
+    best, want = lax.top_k(jnp.asarray(s), k)
+    want = np.where(np.asarray(best) > -np.inf, np.asarray(want), -1)
+    got = np.asarray(jaxex._best_in_turn(jnp.asarray(s), k))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold (loop bodies, branches, calls)."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def _widest_sort(fn, *args):
+    """The widest row any ``top_k``, ``approx_top_k`` or ``sort`` equation of ``fn``'s jaxpr orders; 0 with none."""
+    import jax
+
+    widest = 0
+    for eqn in _equations(jax.make_jaxpr(fn)(*args).jaxpr):
+        if eqn.primitive.name in ("top_k", "approx_top_k"):
+            widest = max(widest, eqn.invars[0].aval.shape[-1])
+        elif eqn.primitive.name == "sort":
+            widest = max(widest, eqn.invars[0].aval.shape[eqn.params["dimension"]])
+    return widest
+
+
+def test_the_loop_form_sorts_no_row_wider_than_topk(monkeypatch):
+    """The full sort a query (196 of 1,668 ms a call on the chip, PR 36) cannot come back unnoticed on a CPU:
+    no ``top_k`` or ``sort`` in the traced loops, their bodies included, orders more than ``topk`` candidates."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from thunder_tpu.executors import jaxex
+
+    monkeypatch.setattr(jaxex, "SPARSE_LOOP_SPAN", 64)
+    monkeypatch.setattr(jaxex, "SPARSE_LOOP_SELECT", 32)
+    q, k = _random_scores(T, 0)
+    assert _widest_sort(lambda q, k: jaxex._sparse_block_select_loops(q, k, **SPARSE), q, k) <= SPARSE["topk"]
+    # and the walk does see one inside a loop's body: the last step as it was
+    before = lambda s: lax.map(lambda row: lax.top_k(row, 6)[1], s)
+    assert _widest_sort(before, jnp.zeros((4, 32, 16))) == 16
+    assert _widest_sort(lambda s: lax.map(lambda row: lax.sort(row, dimension=0), s), jnp.zeros((4, 32, 16))) == 32
 
 
 # -----------------------------------------------------------------------------

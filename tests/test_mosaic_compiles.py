@@ -580,10 +580,11 @@ def test_the_attend_kernels_checker_declines_where_the_generation_has_no_room(de
 
 def test_block_sparse_attention_over_three_spans_compiles_for_v5e_as_loops_and_a_kernel_with_no_square_of_the_sequence(one_chip, monkeypatch):
     """``minicpm-sala.fwd-t32k``'s sparse layer at three eighths of its length (12,288 positions, three spans; 32 query
-    heads on 2 key-value heads of 128): the selection is ``jaxex``'s, a ``while`` a span, the attention over the chosen
-    blocks one Mosaic call of ``pallas``, and nothing the compiled program holds has the sequence twice among its
-    dimensions or the sequence beside its 767 pooled keys: no score reaches HBM. The benchmark's readers find the
-    call in the region it was written in and in no kernel family."""
+    heads on 2 key-value heads of 128): the selection is ``jaxex``'s, a ``while`` a span and inside it one for the turns
+    that take a query's 31 free blocks, with no ``sort`` left of the one a query that ``lax.top_k`` was (PR 36); the
+    attention over the chosen blocks is one Mosaic call of ``pallas``, and nothing the compiled program holds has the
+    sequence twice among its dimensions or the sequence beside its 767 pooled keys: no score reaches HBM. The
+    benchmark's readers find the call in the region it was written in and in no kernel family."""
     import jax
     import jax.numpy as jnp
 
@@ -606,7 +607,8 @@ def test_block_sparse_attention_over_three_spans_compiles_for_v5e_as_loops_and_a
     owners = [(b.sym.name, b.sym.executor.name) for b in claimed.bound_symbols if b.sym.name.startswith("sparse_block")]
     assert owners == [("sparse_block_select", "jax"), ("sparse_block_attend", "pallas")]
     text = jax.jit(claimed.python_callable()).lower(*shapes).compile().as_text()
-    assert text.count(" while(") == 3 and text.count('custom_call_target="tpu_custom_call"') == 1
+    assert text.count(" while(") == 6 and text.count('custom_call_target="tpu_custom_call"') == 1
+    assert " sort(" not in text
     dims = [[int(d) for d in m.split(",") if d] for m in re.findall(r"(?:pred|[subf]\d+|bf16)\[([\d,]*)\]", text)]
     assert not [d for d in dims if d.count(T) >= 2 or (T in d and 767 in d)]
     assert not [d for d in dims if d[:2] == [2, 4096] and d[-1] >= 4096]  # the spans' scores, (2, 4096, keys), are gone

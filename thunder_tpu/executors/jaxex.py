@@ -426,8 +426,16 @@ ex.register_implementation("torch.linear_heads", fn=_linear_heads)
 # iterations of ``lax.map``: the queries of a span of ``SPARSE_LOOP_SPAN`` share
 # one key length (the span's end), so a layer compiles a loop body a span, and a
 # loop keeps one pass's temporaries alive. The arithmetic of a pass is the
-# decomposition's, operation for operation. The selection runs here; the
-# attention over the chosen blocks runs here only where ``pallas`` declines it
+# decomposition's, operation for operation, but for the selection's last step.
+# The decomposition ends in ``lax.top_k``, which XLA runs as a full sort of a
+# query's up to 512 block scores: 1.5 ms a pass, 196 of minicpm-sala.fwd-t32k's
+# 1,668 ms a call. Of the 64 blocks a query keeps, 33 are forced and tie at
+# +inf, so a pass here writes those by their numbers and takes the best free
+# block left, in 31 turns of a row maximum that order nothing they reject: the
+# same ids in the same places, 0.05 ms a pass (PERF.md, PR 36; a threshold by
+# bisection over the scores' bits finds the same set in less, and pays more to
+# put it in order). The selection runs here; the attention over the chosen
+# blocks runs here only where ``pallas`` declines it
 # (``pallasex._sparse_attend_checker``: heads that are not whole lanes, keys and
 # values the device's VMEM does not hold): its kernel, ahead in the executors'
 # order, keeps the scores these passes write to HBM once and read twice on the
@@ -453,6 +461,21 @@ def _by_spans(T: int, n: int, one_pass, query_axis: int):
         out = jnp.moveaxis(out, 0, query_axis)                       # (.., passes, n, ..)
         spans.append(out.reshape(*out.shape[:query_axis], -1, *out.shape[query_axis + 2:]))
     return jnp.concatenate(spans, query_axis)
+
+
+def _best_in_turn(score, rounds):
+    """(.., rounds) int32: ``lax.top_k(score, rounds)``'s ids, the lower on a tie and -1 in place of a -inf,
+    as ``rounds`` turns of taking a row's best and striking it out: nothing that is not kept is ordered."""
+    b = jnp.arange(score.shape[-1], dtype=jnp.int32)
+
+    def turn(i, carry):
+        left, ids = carry
+        at = jnp.argmax(left, -1).astype(jnp.int32)
+        ids = lax.dynamic_update_index_in_dim(ids, jnp.where(left.max(-1) > -jnp.inf, at, -1), i, -1)
+        return jnp.where(b == at[..., None], -jnp.inf, left), ids
+
+    ids = jnp.full((*score.shape[:-1], rounds), -1, jnp.int32)
+    return lax.fori_loop(0, rounds, turn, (score, ids))[1] if rounds else ids
 
 
 def _sparse_block_select_loops(q, k, *, kernel_size, kernel_stride, block_size, topk, init_blocks, local_blocks,
@@ -486,10 +509,15 @@ def _sparse_block_select_loops(q, k, *, kernel_size, kernel_stride, block_size, 
             score = jnp.maximum(score, after[..., i])
         b, own = jnp.arange(nb, dtype=jnp.int32)[None, :], (t // block_size)[:, None]
         forced = (b < init_blocks) | (b > own - local_blocks)
-        score = jnp.where(b > own, -jnp.inf, jnp.where(forced, jnp.inf, score))
-        best, ids = lax.top_k(score, min(topk, nb))
-        ids = jnp.where(best > -jnp.inf, ids.astype(jnp.int32), -1)
-        return jnp.pad(ids, ((0, 0), (0, 0), (0, 0), (0, topk - ids.shape[-1])), constant_values=-1)
+        # The forced blocks tie at +inf and so come first, the lower first: of the first ``init_blocks`` and the
+        # ``local_blocks`` that end at the query's own, those there are. Only a query that has them all has a free block.
+        kept = min(topk, nb)
+        place = jnp.arange(min(init_blocks + local_blocks, kept), dtype=jnp.int32)[None, :]
+        n_forced = jnp.minimum(own + 1, init_blocks) + jnp.clip(own + 1 - init_blocks, 0, local_blocks)
+        first = jnp.where(place < n_forced, jnp.where(place < init_blocks, place, own + 1 - n_forced + place), -1)
+        rest = _best_in_turn(jnp.where(forced | (b > own), -jnp.inf, score), kept - place.shape[-1])
+        ids = jnp.concatenate([jnp.broadcast_to(first, (B, G, *first.shape)), rest], -1)
+        return jnp.pad(ids, ((0, 0), (0, 0), (0, 0), (0, topk - kept)), constant_values=-1)
 
     return _by_spans(T, n, one_pass, 2)
 
