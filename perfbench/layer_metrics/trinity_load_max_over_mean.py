@@ -1,0 +1,5 @@
+"""Layer ``experts``: rows of the busiest expert over the mean of all of them,
+mean over the expert layers and the traced units. ``expert_load_max_over_mean``'s
+reader under the name the manifest lists for this cell."""
+
+from perfbench.layer_metrics.expert_load_max_over_mean import read  # noqa: F401

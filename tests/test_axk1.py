@@ -5,6 +5,7 @@ of which a share is held. Against the plain reference
 (``perfbench/reference/axk1.py``), which knows nothing of the program."""
 
 import dataclasses
+import re
 import json
 import os
 
@@ -458,4 +459,5 @@ def test_the_models_regions_are_named_in_the_generated_program_and_in_the_hlo():
     tiny = gpt.name_to_config("llama-tiny")
     plain = thunder_tpu.jit(lambda p, i: gpt.forward(p, i, tiny))
     plain(gpt.init_params(tiny, dtype=dtypes.float32, seed=0), idx % tiny.padded_vocab_size)
-    assert "__region" not in thunder_tpu.last_traces(plain)[-1].python()
+    # a plain model names its causal attention and nothing else (since PR 38)
+    assert re.findall(r"with __region\('([^']+)'\)", thunder_tpu.last_traces(plain)[-1].python()) == ["attn.full"] * tiny.n_layer

@@ -70,7 +70,7 @@ def test_the_registry_lists_the_model_at_its_published_sizes():
     assert mixers == ["conv", "conv"] + ["full_attention", "conv", "conv", "conv"] * 3
     assert [cfg.layer_mlp_class(i) for i in (0, 1, 2, 13)] == ["LLaMAMLP", "LLaMAMLP", "SharedRoutedMoE", "SharedRoutedMoE"]
     # an entry without the pattern has attention everywhere, as before
-    assert gpt.name_to_config("mistral-7b").layer_types == () and gpt.name_to_config("mistral-7b").layer_mixer(3) == "full_attention"
+    assert gpt.name_to_config("llama-2-7b").layer_types == () and gpt.name_to_config("llama-2-7b").layer_mixer(3) == "full_attention"
 
 
 def test_forward_through_jit_agrees_with_the_reference():
@@ -476,6 +476,6 @@ def test_the_models_regions_are_named_in_the_generated_program_and_in_the_hlo():
     opened = [line.strip() for line in run.python().splitlines() if line.strip().startswith("with __region(")]
     experts = ["with __region('moe.route'):", "with __region('moe.experts'):"]
     conv = ["with __region('conv'):"]
-    assert opened == conv + ["with __region('attn.qk_norm'):"] + experts + (conv + experts) * 2
+    assert opened == conv + ["with __region('attn.qk_norm'):", "with __region('attn.full'):"] + experts + (conv + experts) * 2
     hlo = jax.jit(run.python_callable()).lower(*jax.tree_util.tree_leaves((params, idx))).as_text(debug_info=True)
     assert all(f"/{name}/" in hlo for name in ("conv", "attn.qk_norm", "moe.route", "moe.experts"))

@@ -503,6 +503,7 @@ def _as_the_trace_names_it(text, name_prefix):
     shapes = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ", text, re.M))
     line = next(l.strip() for l in text.splitlines() if l.strip().startswith(name_prefix))
     head, operands, tail = re.match(r"(.*? custom-call\()([^)]*)(\).*)", line).groups()
+    operands = re.sub(r"/\*.*?\*/", "", operands)  # "/*index=5*/" before every fifth operand
     return head + ", ".join(f"{shapes[o]} {o}" for o in operands.split(", ")) + tail
 
 
@@ -617,3 +618,51 @@ def test_block_sparse_attention_over_three_spans_compiles_for_v5e_as_loops_and_a
     assert call.startswith("sparse_attend_fwd") and regions[call] == "attn.sparse.attend"
     assert "attn.sparse.select" in regions.values()
     assert kernel_families.match(_as_the_trace_names_it(text, "%" + call)) is None
+
+
+WINDOW_SHAPES = [
+    # (B, H, T, d), key-value heads, window, key tiles a query tile visits
+    ((1, 32, 32768, 128), 4, 2048, 3),  # trinity-mini.fwd-t32k's window layers
+    ((1, 32, 16384, 128), 8, 4096, 5),  # mistral-7b's declared window on a sequence past it
+    ((2, 8, 4096, 64), 8, 1000, 2),
+]
+
+
+@pytest.mark.parametrize("shape,groups,window,steps", WINDOW_SHAPES, ids=[f"T{s[2]}-W{w}-d{s[3]}" for s, _, w, _ in WINDOW_SHAPES])
+def test_attention_within_a_window_compiles_for_v5e_and_the_benchmark_tells_it_from_a_causal_call(one_chip, monkeypatch, shape,
+                                                                                               groups, window, steps):
+    """``flash`` claims ``torch.window_attention`` and splash compiles it for the
+    v5e under its local mask: one Mosaic call whose first operand, the mask's
+    table, has the key tiles a query tile visits as its last dimension, by which
+    the benchmark's family ``attn_window_fwd`` takes it; the causal call at the
+    same shapes keeps every key tile there and stays ``flash_fwd``'s. Nothing has
+    the sequence twice among its dimensions."""
+    import jax
+    import jax.numpy as jnp
+
+    from perfbench import flops, flops_window_moe, kernel_families
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import flashex
+
+    monkeypatch.setattr(flashex, "_interpret", lambda: False)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    B, H, T, d = shape
+    proxy = lambda h: SimpleNamespace(shape=(B, h, T, d), dtype=dtypes.bfloat16)
+    assert flashex._window_checker(proxy(H), proxy(groups), proxy(groups), window=window)
+    sds = lambda h: jax.ShapeDtypeStruct((B, h, T, d), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v: flashex._window_impl(q, k, v, window=window)).lower(sds(H), sds(groups), sds(groups)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and compiled.out_info.shape == shape
+    assert not re.search(rf"\[[\d,]*{T},[\d,]*{T}[\d,]*\]", text)
+    tiles = T // flashex._fit_block(T)
+    named = _as_the_trace_names_it(text, "%splash_mha_fwd")
+    assert f"custom-call(s8[1,{tiles},{steps}]" in named
+    hit = kernel_families.match(named)
+    assert hit[0] == "attn_window_fwd" and hit[1:] == flops_window_moe.attn_window_fwd([B, H, T, d], [1, tiles, steps])
+    assert hit[1] >= flops_window_moe.attention(T, B * H, B * groups, d, window)[0]  # the upper end of what the table allows
+    assert hit[1] <= 4.0 * d * B * H * flashex.window_tiles(T, window)  # and never more than the tiles visited
+    causal = jax.jit(lambda q, k, v: flashex._sdpa_impl(q, k, v, is_causal=True, enable_gqa=True)).lower(
+        sds(H), sds(groups), sds(groups)).compile()
+    named = _as_the_trace_names_it(causal.as_text(), "%splash_mha_fwd")
+    assert f"custom-call(s8[1,{tiles},{tiles}]" in named
+    assert kernel_families.match(named) == ("flash_fwd", *flops.flash_fwd([B, H, T, d]))

@@ -13,6 +13,10 @@ Claims:
   no (B, H, S, S) score materialization.
 - ``torch.sdpa_bwd`` (backward composite emitted by the autodiff rule) —
   splash backward kernels via the kernel's custom VJP.
+- ``torch.window_attention`` (forward) — the same kernel under splash's local
+  mask (``window - 1`` keys to the left, none to the right): a tile of
+  ``_BLOCK`` queries visits the key tiles its window touches, three at a
+  window of 2048, and no others; no (T, T) mask is built.
 
 Mask support (the reference's cudnnex builds its graph with a bias input;
 splash is mask-structured instead, so masks are handled by shape class):
@@ -195,7 +199,7 @@ def _fit_block(t: int) -> int:
 
 @lru_cache(maxsize=64)
 def _splash_kernel(H: int, Tq: int, Tkv: int, causal: bool, offset: int, interpret: bool,
-                   downcast: bool, save_res: bool = False):
+                   downcast: bool, save_res: bool = False, window: int | None = None):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
         splash_attention_mask as sm,
@@ -207,7 +211,9 @@ def _splash_kernel(H: int, Tq: int, Tkv: int, causal: bool, offset: int, interpr
         block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv,
         use_fused_bwd_kernel=True,
     )
-    if causal:
+    if window is not None:  # causal within the window: keys q - (window - 1) to q
+        head_mask = sm.LocalMask((Tq, Tkv), window_size=(window - 1, 0), offset=offset)
+    elif causal:
         head_mask = sm.CausalMask((Tq, Tkv), offset=offset)
     else:
         head_mask = sm.FullMask((Tq, Tkv))
@@ -232,7 +238,7 @@ def _scaled(q, scale: float):
     return q if scale == 1.0 else (q * jnp.asarray(scale, dtype=q.dtype)).astype(q.dtype)
 
 
-def _splash_sdpa(q, k, v, *, causal: bool, scale: float, kv_valid=None, q_valid=None):
+def _splash_sdpa(q, k, v, *, causal: bool, scale: float, kv_valid=None, q_valid=None, window=None):
     """Run splash attention with in-executor sequence padding.
 
     q: (B, H, Tq, D); k: (B, H, Tkv, D); v: (B, H, Tkv, Dv), Dv its own
@@ -265,6 +271,7 @@ def _splash_sdpa(q, k, v, *, causal: bool, scale: float, kv_valid=None, q_valid=
         # bf16 data is already narrow; keep f32 inputs at full precision in
         # SMEM (the downcast costs ~1e-3 abs error on f32 workloads).
         q.dtype == jnp.bfloat16,
+        window=window,
     )
     qs = _scaled(q, scale)
 
@@ -454,6 +461,29 @@ def _sdpa_bwd_impl(g, query, key, value, attn_mask=None, is_causal=False, scale=
     return dq.astype(query.dtype), dk.astype(key.dtype), dv.astype(value.dtype)
 
 
+def window_tiles(T: int, window: int) -> int:
+    """Score elements of the tiles ``torch.window_attention``'s kernel computes
+    for one head of ``T`` positions: the pairs of a query tile and a key tile
+    that splash's table of the local mask keeps (partly or wholly inside the
+    window), times a tile's size. The pairs the window itself has are fewer:
+    whole tiles are what the kernel can skip."""
+    Tp = T + _pad_amt(T)
+    info = _splash_kernel(1, Tp, Tp, True, 0, _interpret(), True, window=int(window)).fwd_mask_info
+    return int((np.asarray(info.block_mask) > 0).sum()) * _fit_block(Tp) ** 2
+
+
+def _window_checker(q, k, v, *, window, scale=None) -> bool:
+    return _on_tpu() and _shapes_ok(q, k, v) and _dtype_ok(q, k, v) and q.shape[-2] == k.shape[-2]
+
+
+def _window_impl(q, k, v, *, window, scale=None):
+    chaos.kernel_seam("flash", "window_attention")
+    H, D = q.shape[-3], q.shape[-1]
+    k, v = _expand_gqa(k, v, H)
+    return _splash_sdpa(q, k, v, causal=True, scale=float(scale) if scale is not None else 1.0 / math.sqrt(D),
+                        window=int(window))
+
+
 # =============================================================================
 # Residual-saving pair (transforms/attention_residuals.py; reference:
 # cudnnex.py:375 — bwd graph consumes the fwd's saved softmax stats)
@@ -558,5 +588,6 @@ def _sdpa_bwd_res_impl(g, query, key, value, out, lse, attn_mask=None, is_causal
 
 ex.register_implementation("torch.scaled_dot_product_attention", fn=_sdpa_impl, checker=_sdpa_checker)
 ex.register_implementation("torch.sdpa_bwd", fn=_sdpa_bwd_impl, checker=_bwd_checker)
+ex.register_implementation("torch.window_attention", fn=_window_impl, checker=_window_checker)
 ex.register_implementation("torch.sdpa_fwd_res", fn=_sdpa_fwd_res_impl, checker=_fwd_res_checker)
 ex.register_implementation("torch.sdpa_bwd_res", fn=_sdpa_bwd_res_impl, checker=_bwd_res_checker)

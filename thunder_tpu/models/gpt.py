@@ -16,7 +16,11 @@ and MiniCPM-SALA's: block-sparse attention whose blocks each query chooses by
 scoring pooled keys (``"sparse_attention"``) beside linear attention with a
 decay per head (``"linear_attention"``), each with its own head layout and rope
 setting, an output gate and an output norm on a mixer, and the MiniCPM
-family's scalings of the embedding, the residual and the head's input.
+family's scalings of the embedding, the residual and the head's input; and
+Trinity-Mini's (``afmoe``): two attention kinds of one head layout that differ
+by layer in mask and rope (``"sliding_attention"``: causal within
+``sliding_window`` keys, roped; ``"full_attention"``: causal, no rope), and a
+norm after each sublayer beside the one before it (``sandwich_norms``).
 
 TPU-first design: the model is a *pure function* ``forward(params, idx)``
 over a params pytree — no module object, no buffers, no in-place state. That
@@ -56,6 +60,7 @@ class GPTConfig:
     n_head: int = 12
     n_embd: int = 768
     n_query_groups: Optional[int] = None  # None → MHA (== n_head)
+    head_dim: Optional[int] = None  # a head's width where n_head of them are not n_embd; None -> n_embd // n_head
     rotary_percentage: float = 0.25
     parallel_residual: bool = True
     shared_attention_norm: bool = False
@@ -96,10 +101,17 @@ class GPTConfig:
     # YaRN, or None for the plain rope: (factor, original_max_position_embeddings,
     # beta_fast, beta_slow, mscale, mscale_all_dim)
     yarn: Optional[tuple] = None
-    # The mixer of each layer, as published: "full_attention" or "conv" (a gated
+    # The mixer of each layer, as published: "full_attention", "sliding_attention"
+    # (the same layer, causal within sliding_window keys) or "conv" (a gated
     # short convolution of conv_kernel taps). Empty is attention everywhere; a
     # model cut in depth runs the first n_layer of them.
     layer_types: tuple = ()
+    # "sliding_attention": query i sees the keys j with 0 <= i - j < sliding_window,
+    # so a sequence no longer than that attends causally. Such a layer is roped
+    # always; attn_rope is "full_attention"'s setting.
+    sliding_window: Optional[int] = None
+    # Four norms a block: h = x + N(mixer(norm_1(x))), y = h + N(mlp(norm_2(h))).
+    sandwich_norms: bool = False
     conv_kernel: int = 3
     qk_norm: bool = False  # an RMSNorm over head_size on every query and key head, before the rope
     tie_embeddings: bool = False  # the head reads wte: no lm_head_w leaf
@@ -142,7 +154,7 @@ class GPTConfig:
 
     @property
     def head_size(self) -> int:
-        return self.n_embd // self.n_head
+        return self.head_dim if self.head_dim is not None else self.n_embd // self.n_head
 
     @property
     def query_groups(self) -> int:
@@ -324,11 +336,47 @@ _add(GPTConfig(name="minicpm-sala-tiny", block_size=256, vocab_size=96, padded_v
                sparse_dense_len=64, linear_n_head=4, linear_query_groups=4, linear_rope=True,
                linear_output_norm=True, linear_output_gate=True))
 
-# Mistral — reference benchmark ladder step 5 (GQA).
+# Mistral — reference benchmark ladder step 5 (GQA). Every layer attends within
+# the published window of 4096, which is plain causal attention up to block_size.
 _add(GPTConfig(name="mistral-7b", block_size=4096, vocab_size=32000, padded_vocab_size=32000,
                n_layer=32, n_head=32, n_embd=4096, n_query_groups=8, rotary_percentage=1.0,
                parallel_residual=False, bias=False, norm_class="RMSNorm", norm_eps=1e-5,
-               mlp_class="LLaMAMLP", intermediate_size=14336))
+               mlp_class="LLaMAMLP", intermediate_size=14336,
+               layer_types=("sliding_attention",) * 32, sliding_window=4096))
+# The same blocks at test size, with room beyond the window.
+_add(GPTConfig(name="mistral-tiny", block_size=64, vocab_size=96, padded_vocab_size=96,
+               n_layer=2, n_head=4, n_embd=32, n_query_groups=2, rotary_percentage=1.0,
+               parallel_residual=False, bias=False, norm_class="RMSNorm", norm_eps=1e-5,
+               mlp_class="LLaMAMLP", intermediate_size=88,
+               layer_types=("sliding_attention",) * 2, sliding_window=16))
+
+# Trinity-Mini (huggingface.co/arcee-ai/Trinity-Mini, model_type afmoe) at its
+# published sizes: 32 query heads on 4 key-value heads of 128, normed and gated;
+# three layers in four attend within a window of 2048 keys and are roped, every
+# fourth attends to everything and has no rope; four norms a block; two dense
+# layers of 6144, then 128 experts of 1024, 8 a token by sigmoid scores plus a
+# bias, weighed by the scores over their sum times 2.826, beside one shared
+# expert; the embedding times sqrt(2048); an untied head.
+_TRINITY_LAYERS = tuple("full_attention" if i % 4 == 3 else "sliding_attention" for i in range(32))
+_add(GPTConfig(name="Trinity-Mini", block_size=131072, vocab_size=200192, padded_vocab_size=200192,
+               n_layer=32, n_head=32, n_embd=2048, n_query_groups=4, head_dim=128, rotary_percentage=1.0,
+               parallel_residual=False, bias=False, norm_class="RMSNorm", norm_eps=1e-5,
+               mlp_class="SharedRoutedMoE", intermediate_size=6144, rope_base=10000, n_expert=128,
+               n_expert_per_token=8, moe_intermediate_size=1024, n_shared_experts=1,
+               routed_scaling_factor=2.826, first_dense_layers=2, layer_types=_TRINITY_LAYERS,
+               sliding_window=2048, attn_rope=False, qk_norm=True, attn_output_gate=True,
+               sandwich_norms=True, router_bias=True, router_norm_eps=1e-20, embedding_scale=2048 ** 0.5))
+# The same blocks at test size: a dense window layer, then window, global, window
+# with experts; at T = 64 a query sees 16 keys of its 64 in the window layers.
+_add(GPTConfig(name="trinity-tiny", block_size=256, vocab_size=96, padded_vocab_size=96,
+               n_layer=4, n_head=4, n_embd=256, n_query_groups=2, head_dim=32, rotary_percentage=1.0,
+               parallel_residual=False, bias=False, norm_class="RMSNorm", norm_eps=1e-5,
+               mlp_class="SharedRoutedMoE", intermediate_size=512, rope_base=10000, n_expert=8,
+               n_expert_per_token=2, moe_intermediate_size=128, n_shared_experts=1,
+               routed_scaling_factor=2.826, first_dense_layers=1,
+               layer_types=("sliding_attention", "sliding_attention", "full_attention", "sliding_attention"),
+               sliding_window=16, attn_rope=False, qk_norm=True, attn_output_gate=True,
+               sandwich_norms=True, router_bias=True, router_norm_eps=1e-20, embedding_scale=256 ** 0.5))
 
 # Falcon family — MQA (one KV head) + shared-attention-norm parallel residual
 # (the litgpt registry's falcon geometry; reference tests run falcon-7b-like
@@ -481,6 +529,8 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
             p["attn"] = attn_params(C.n_head, C.query_groups, C.attn_output_gate)
         if not C.shared_attention_norm:
             p["norm_2"] = norm_params()
+        if C.sandwich_norms:
+            p["post_attn_norm"], p["post_mlp_norm"] = norm_params(), norm_params()
         return p
 
     params = {"wte": w(C.padded_vocab_size, C.n_embd)}
@@ -593,8 +643,12 @@ def _merge_heads(y):
 SPARSE_TILE = 128  # consecutive queries whose chosen blocks ``sparse_selection_counts`` unites
 
 
-def _attention(x, p, cos, sin, config: GPTConfig, sparse: bool = False, counts=None):
-    """Causal softmax attention between its projections. ``sparse``: InfLLM-V2's
+def _attention(x, p, cos, sin, config: GPTConfig, sparse: bool = False, counts=None, window: bool = False):
+    """Causal softmax attention between its projections. ``window``: a query sees
+    its own key and the ``sliding_window - 1`` before it, and is roped whatever
+    ``attn_rope`` says of the other layers; a sequence no longer than the window
+    is causal attention and is run as that.
+    ``sparse``: InfLLM-V2's
     layer, where every query attends to the ``sparse_topk`` blocks of
     ``sparse_block_size`` keys that its key-value head's queries score highest
     through mean-pooled keys, the first blocks and its own window always among
@@ -603,9 +657,13 @@ def _attention(x, p, cos, sin, config: GPTConfig, sparse: bool = False, counts=N
     ``SPARSE_TILE`` consecutive queries chose (``"tile_union"``)."""
     C, T = config, x.shape[1]
     H, G = C.n_head, C.query_groups
-    q, k, v = _qkv_heads(x, p, H, G, cos, sin, C, C.attn_rope)
-    if not sparse or T < C.sparse_dense_len:
-        y = ttorch.scaled_dot_product_attention(q, k, v, is_causal=True, enable_gqa=(G != H))
+    q, k, v = _qkv_heads(x, p, H, G, cos, sin, C, window or C.attn_rope)
+    if window and T > C.sliding_window:
+        with region("attn.window"):
+            y = ttorch.window_attention(q, k, v, window=C.sliding_window)
+    elif not sparse or T < C.sparse_dense_len:
+        with region("attn.full"):
+            y = ttorch.scaled_dot_product_attention(q, k, v, is_causal=True, enable_gqa=(G != H))
     else:
         how = dict(kernel_size=C.sparse_kernel_size, kernel_stride=C.sparse_kernel_stride,
                    block_size=C.sparse_block_size, topk=C.sparse_topk, init_blocks=C.sparse_init_blocks,
@@ -769,7 +827,9 @@ def _mlp(x, p, kind: str, config: GPTConfig, counts=None):
 
 
 def _mix(x, p, cos, sin, config: GPTConfig, layer: int = 0, counts=None):
-    """The layer's mixer, by the parameters it was given: the tree was built from ``layer_mixer(i)``."""
+    """The layer's mixer, by the parameters it was given: the tree was built from
+    ``layer_mixer(i)``. The two attention kinds of one head layout have the same
+    parameters and are told apart by ``layer_mixer(layer)``."""
     if "conv" in p:
         return _short_conv(x, p["conv"], config)
     if "sparse_attn" in p:
@@ -779,18 +839,19 @@ def _mix(x, p, cos, sin, config: GPTConfig, layer: int = 0, counts=None):
     if config.attention_class == "MLA":
         with region("mla"):
             return _mla_attention(x, p["attn"], cos, sin, config)
-    return _attention(x, p["attn"], cos, sin, config)
+    return _attention(x, p["attn"], cos, sin, config, window=config.layer_mixer(layer) == "sliding_attention")
 
 
 def _block(x, p, cos, sin, kind: str, config: GPTConfig, counts=None, layer: int = 0):
     scaled = (lambda y: y) if config.residual_scale == 1.0 else (lambda y: y * config.residual_scale)
+    after = (lambda y, which: _norm(y, p[which], config)) if config.sandwich_norms else (lambda y, which: y)
     n1 = _norm(x, p["norm_1"], config)
-    attn_out = scaled(_mix(n1, p, cos, sin, config, layer, counts))
+    attn_out = scaled(after(_mix(n1, p, cos, sin, config, layer, counts), "post_attn_norm"))
     if config.parallel_residual:
         n2 = n1 if config.shared_attention_norm else _norm(x, p["norm_2"], config)
-        return x + attn_out + scaled(_mlp(n2, p["mlp"], kind, config, counts))
+        return x + attn_out + scaled(after(_mlp(n2, p["mlp"], kind, config, counts), "post_mlp_norm"))
     x = x + attn_out
-    return x + scaled(_mlp(_norm(x, p["norm_2"], config), p["mlp"], kind, config, counts))
+    return x + scaled(after(_mlp(_norm(x, p["norm_2"], config), p["mlp"], kind, config, counts), "post_mlp_norm"))
 
 
 def _layers(params: dict, config: GPTConfig):

@@ -1680,6 +1680,38 @@ def sparse_block_attention(q, k, v, *, kernel_size: int, kernel_stride: int, blo
         return sparse_block_attend(q, k, v, ids, block_size=block_size, scale=scale)
 
 
+@torchsymbol(id="torch.window_attention")
+def window_attention(q, k, v, *, window: int, scale: Optional[float] = None):
+    """Causal softmax attention within a window, between its projections: q
+    (B, H, T, d), k and v (B, G, T, d) with ``H % G == 0`` -> (B, H, T, d).
+    Query ``i`` attends to the keys ``j`` with ``0 <= i - j < window``: its own
+    and the ``window - 1`` before it (Mistral's and the afmoe family's
+    ``sliding_window``).
+
+    A symbol of its own and no keyword of ``scaled_dot_product_attention``: that
+    one's id is read by five passes (the attention layout, the saved residuals,
+    the padding mask's causal analysis, autocast, the cost model), by its VJP
+    and by three more composites, and each would have to carry the window or
+    be wrong without saying so; a causal program's trace stays what it was.
+    The decomposition is the masked softmax, the mask written from two
+    ``arange``s (every row sees its own key: no row is dead), and the trace VJP
+    differentiates it as it stands; ``flash`` claims the forward through
+    splash's local mask, which never forms a (T, T) array."""
+    B, H, T, d = q.shape
+    G = k.shape[1]
+    check(window >= 1 and H % G == 0 and k.shape[2] == T and tuple(v.shape) == tuple(k.shape),
+          lambda: f"window_attention: q {tuple(q.shape)}, k {tuple(k.shape)}, v {tuple(v.shape)}, window {window}")
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    if G != H:
+        k, v = repeat_interleave(k, H // G, 1), repeat_interleave(v, H // G, 1)
+    scores = clang.maybe_convert_to_dtype(matmul(q * scale, transpose(k, -2, -1)), dtypes.float32)
+    pos = arange(0, T, device=q.device, dtype=dtypes.int32)
+    ahead = unsqueeze(pos, 1) - unsqueeze(pos, 0)                                      # (T, T): i - j
+    seen = logical_and(ahead >= 0, ahead < window)
+    scores = where(clang.expand_to(seen, scores.shape), scores, clang.full_like(scores, -float("inf")))
+    return matmul(clang.maybe_convert_to_dtype(softmax(scores, -1), v.dtype), v)
+
+
 @torchsymbol(id="torch.linear_attention")
 def linear_attention(q, k, v, decay, scale: Optional[float] = None, chunk: Optional[int] = None):
     """Causal linear attention with a constant decay a head: q, k, v
