@@ -42,11 +42,24 @@ def _heads_first(x, heads, hs):
     return ttorch.permute(ttorch.reshape(x, (B, T, heads, hs)), (0, 2, 1, 3))
 
 
-def _fused_qkv(H, G, hs, n, bias, layers=1, spare=0, q_read_twice=False, tables="bfloat16", **sdpa):
+def _fused_qkv(H, G, hs, n, bias, layers=1, spare=0, q_read_twice=False, tables="bfloat16", norm=None, window=None,
+               normed_read_twice=False, **sdpa):
     """``_attention`` of ``models/gpt.py``, ``layers`` times over: (program, arguments).
-    ``spare`` widens the projection by columns that no slice reads."""
+    ``spare`` widens the projection by columns that no slice reads. ``n`` 0: no
+    rope (the tables go unread). ``norm``: which of q and k pass through an
+    ``rms_norm`` before the rope ("qk", or "q" alone), over a head's ``hs``
+    features or, "qk_pairs", over pairs of them. ``window``: the call is
+    ``window_attention``."""
     width = (H + 2 * G) * hs + spare
-    args = [_bf16(B, T, C), *_tables(n, tables)]
+    args = [_bf16(B, T, C), *_tables(n or hs, tables)]
+    q_w, k_w = 1 + _bf16(hs, seed=11, scale=0.2), 1 + _bf16(hs, seed=12, scale=0.2)
+
+    def normed(x, which, w):
+        if which not in (norm or ""):
+            return x
+        if norm == "qk_pairs":  # a norm, but not of a head: over each pair of features
+            return ttorch.reshape(ttorch.rms_norm(ttorch.reshape(x, (*x.shape[:-1], hs // 2, 2)), (2,), w[:2], eps=1e-5), x.shape)
+        return ttorch.rms_norm(x, (hs,), w, eps=1e-5)
     for i in range(layers):
         args += [_bf16(width, C, seed=3 * i + 1, scale=0.1), _bf16(width, seed=3 * i + 2) if bias else None,
                  _bf16(C, H * hs, seed=3 * i + 3, scale=0.1)]
@@ -57,11 +70,19 @@ def _fused_qkv(H, G, hs, n, bias, layers=1, spare=0, q_read_twice=False, tables=
             q = _heads_first(qkv[..., : H * hs], H, hs)
             k = _heads_first(qkv[..., H * hs: (H + G) * hs], G, hs)
             v = _heads_first(qkv[..., (H + G) * hs: (H + 2 * G) * hs], G, hs)
-            q, k = ttorch.apply_rope(q, cos, sin), ttorch.apply_rope(k, cos, sin)
-            y = ttorch.scaled_dot_product_attention(q, k, v, **{"is_causal": True, "enable_gqa": G != H, **sdpa})
+            q, k = normed(q, "q", q_w), normed(k, "k", k_w)
+            q_normed = q
+            if n:
+                q, k = ttorch.apply_rope(q, cos, sin), ttorch.apply_rope(k, cos, sin)
+            if window:
+                y = ttorch.window_attention(q, k, v, window=window, **sdpa)
+            else:
+                y = ttorch.scaled_dot_product_attention(q, k, v, **{"is_causal": True, "enable_gqa": G != H, **sdpa})
             x = ttorch.linear(ttorch.reshape(ttorch.permute(y, (0, 2, 1, 3)), (B, T, H * hs)), proj_w)
             if q_read_twice:
                 x = x + ttorch.sum(q)
+            if normed_read_twice:
+                x = x + ttorch.sum(q_normed)
         return x
 
     return program, args
@@ -104,6 +125,14 @@ IDIOMS = {
     "four_heads_of_32_a_group": (lambda: _fused_qkv(8, 4, 32, 8, bias=False, scale=0.25), 1, 4, True),
     "two_layers": (lambda: _fused_qkv(2, 2, 64, 16, bias=True, layers=2), 2, 2, True),
     "axk1_q_path": (_latent_q, 1, 1, False),
+    # the heads normed between the permute and the rope (PR 39): the call norms, ropes and scales in one pass
+    "trinity_window_layer_normed_and_roped_gqa_at_128": (lambda: _fused_qkv(8, 2, 128, 128, bias=False, norm="qk", window=32), 1, 1, False),
+    "trinity_global_layer_normed_without_rope": (lambda: _fused_qkv(8, 2, 128, 0, bias=False, norm="qk"), 1, 1, False),
+    "normed_and_roped_under_causal_attention": (lambda: _fused_qkv(4, 1, 128, 128, bias=False, norm="qk"), 1, 1, False),
+    "normed_without_rope_under_a_window": (lambda: _fused_qkv(4, 2, 128, 0, bias=True, norm="qk", window=48, scale=0.125), 1, 1, False),
+    "lfm2_normed_heads_of_64_two_a_lane_group": (lambda: _fused_qkv(4, 2, 64, 64, bias=False, norm="qk"), 1, 2, False),
+    "normed_heads_of_64_partial_rotary_under_a_window": (lambda: _fused_qkv(4, 4, 64, 16, bias=True, norm="qk", window=32), 1, 2, False),
+    "roped_without_norm_under_a_window": (lambda: _fused_qkv(4, 2, 64, 64, bias=False, window=32), 1, 2, True),
 }
 
 
@@ -119,9 +148,11 @@ def test_rewrites_the_idiom_and_computes_what_was_written(monkeypatch, idiom):
     assert src.count("jax_linear_heads(") == sites and src.count("pallas_apply_rope(") == (1 if latent else 0)
     assert src.count("pallas_apply_rope_heads(") == (sites if latent else 2 * sites)
     assert src.count("pallas_split_heads(") == (sites if split > 1 else 0)  # v, where a group's lanes hold several heads
-    assert src.count("flash_scaled_dot_product_attention(") == src.count("scale=1.0") == sites
-    # no token-major q, k or v, and no slice of the last dimension, is left in front of attention
-    assert "jax_transpose" not in src.split("flash_scaled_dot_product_attention(")[0]
+    call = "flash_window_attention(" if "window" in idiom else "flash_scaled_dot_product_attention("
+    assert src.count(call) == src.count("scale=1.0") == sites
+    # no token-major q, k or v, no slice of the last dimension and no norm of its own is left in front of attention
+    assert "jax_transpose" not in src.split(call)[0] and "rsqrt" not in src.split(call)[0]
+    assert src.count("norm_weight=") == (2 * sites if "normed" in idiom else 0)
 
     with monkeypatch.context() as m:
         m.setattr(attention_layout, "fold_attention_layouts", lambda trc, executors: trc)
@@ -152,6 +183,130 @@ def test_a_mosaic_claim_that_fails_later_runs_the_program_as_written():
     assert np.array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
 
 
+def test_a_normed_sites_lines_decompose_into_the_program_as_written_and_keep_their_regions():
+    """The norm-rope call's decomposition is ``split_heads -> rms_norm -> apply_rope -> mul``, each step only where
+    the site has it, and it stays in the region of the norm it stands for: the attention call alone carries its own."""
+    from thunder_tpu.core.trace import region
+
+    inner, args = _fused_qkv(8, 2, 128, 128, bias=False, norm="qk", window=32)
+
+    def program(*a):  # the regions as models/gpt.py::_attention opens them, by symbol
+        import unittest.mock as mock
+
+        def inside(name, fn):
+            def wrapped(*args, **kwargs):
+                with region(name):
+                    return fn(*args, **kwargs)
+            return wrapped
+
+        with mock.patch.object(ttorch, "rms_norm", inside("attn.qk_norm", ttorch.rms_norm)), \
+                mock.patch.object(ttorch, "window_attention", inside("attn.window", ttorch.window_attention)):
+            return inner(*a)
+
+    trc = _folded_trace(program, args)
+    assert trc.tags[FOLDED] == 1
+    lines = {(b.sym.id, b.args[3] if b.sym.id == "torch.apply_rope_heads" else None): b for b in trc.bound_symbols}
+    q, k = lines["torch.apply_rope_heads", 0], lines["torch.apply_rope_heads", 8]
+    assert [s.sym.id for s in q.subsymbols] == ["torch.split_heads", "torch.rms_norm", "torch.apply_rope", "torch.mul"]
+    assert [s.sym.id for s in k.subsymbols] == ["torch.split_heads", "torch.rms_norm", "torch.apply_rope"]
+    assert q.args[5:] == (1 / math.sqrt(128), 1) and q.kwargs["eps"] == k.kwargs["eps"] == 1e-5
+    assert q.kwargs["norm_weight"].shape == (128,) and q.kwargs["norm_weight"] is not k.kwargs["norm_weight"]
+    assert q.region == k.region == "attn.qk_norm" and lines["torch.linear_heads", None].region is None
+    assert lines["torch.getitem", None].region is None  # v, a slice of the head-major projection
+    call = lines["torch.window_attention", None]
+    assert call.region == "attn.window" and call.kwargs == {"window": 32, "scale": 1.0}
+    want = thunder_tpu.jit(inner, executors=["jax"])(*args)
+    got = thunder_tpu.jit(trc.python_callable(), executors=["jax"])(*[a for a in args if a is not None])
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), atol=2e-2, rtol=2e-2)
+
+
+HEADS_KERNEL_STEPS = [(norm, rope, scale, split) for norm in (False, True) for rope in (0, 16, 64)
+                      for scale in (1.0, 0.25) for split in (1, 2) if norm or rope or scale != 1.0]
+
+
+@pytest.mark.parametrize("norm,rope,scale,split", HEADS_KERNEL_STEPS,
+                         ids=[f"norm{int(n)}-rope{r}-scale{s}-split{k}" for n, r, s, k in HEADS_KERNEL_STEPS])
+def test_the_heads_kernel_against_its_decomposition(norm, rope, scale, split):
+    """``pallasex``'s one body (interpreted) with each step on or off against ``ttorch.apply_rope_heads``'s
+    decomposition on the ``jax`` executor: heads of 64, one or two a lane group, a rotary of none, a quarter or
+    all of the head, read from the middle of the array."""
+    import jax.numpy as jnp
+
+    from thunder_tpu.executors import pallasex
+
+    hs, first, heads = 64, 2, 4
+    x = _bf16(B, 8 // split, T, hs * split, scale=2.0)
+    cos, sin = _tables(rope) if rope else (None, None)
+    how = dict(norm_weight=1 + _bf16(hs, seed=5, scale=0.3), eps=1e-5) if norm else {}
+    assert pallasex._rope_heads_checker(x, cos, sin, first, heads, scale, split, **how)
+    got = pallasex._rope_heads_impl(x, cos, sin, first, heads, scale, split, **how)
+    written = thunder_tpu.jit(lambda x, cos, sin, w: ttorch.apply_rope_heads(x, cos, sin, first, heads, scale, split, w, how.get("eps")),
+                              executors=["jax"])
+    operands = (x, cos, sin, how.get("norm_weight"))
+    want = written(*operands)
+    assert got.shape == want.shape == (B, heads, T, hs) and got.dtype == want.dtype == jnp.bfloat16
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    if not norm:  # every rounding is the decomposition's (the scale is a power of two)
+        assert np.array_equal(got, want)
+        return
+    # the norm's weight is applied in float32: one rounding fewer than the program as written, none more
+    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+    exact = np.asarray(written(*(None if a is None else a.astype(jnp.float32) for a in operands)))
+    assert np.abs(got - exact).mean() <= np.abs(want - exact).mean()
+
+
+def _sds(*shape, dtype="bfloat16"):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _rope(x, cos, sin):
+    from thunder_tpu.executors import pallasex
+
+    return pallasex._rope_impl(x, cos, sin)
+
+
+def _heads(first, heads, scale, split):
+    from thunder_tpu.executors import pallasex
+
+    return lambda x, *tables: (pallasex._rope_heads_impl(x, *tables, first, heads, scale, split) if tables
+                               else pallasex._split_heads_impl(x, first, heads, split))
+
+
+# (the call, its operands at a cell's shapes, sha256 of its jaxpr as the three bodies of PR 30 traced it)
+UNNORMED_CALLS = {
+    "mistral-7b.train_k": (_rope, [_sds(1, 8, 4096, 128), _sds(4096, 128), _sds(4096, 128)], "c8f573b235378cc6"),
+    "pythia-410m.train_q": (_rope, [_sds(4, 16, 2048, 64), _sds(2048, 16), _sds(2048, 16)], "657d3c3ae8d71828"),
+    "float32": (_rope, [_sds(1, 8, 512, 128, dtype="float32")] + [_sds(512, 128, dtype="float32")] * 2, "bc8349cdcc12dbe0"),
+    "pythia-410m.fwd_q": (_heads(0, 16, 0.125, 2), [_sds(8, 24, 2048, 128), _sds(2048, 16), _sds(2048, 16)], "3fcfcff97cfd81c5"),
+    "pythia-410m.fwd_k": (_heads(16, 16, 1.0, 2), [_sds(8, 24, 2048, 128), _sds(2048, 16), _sds(2048, 16)], "9461413da72fb50f"),
+    "pythia-410m.fwd_v": (_heads(32, 16, 1.0, 2), [_sds(8, 24, 2048, 128)], "1162be5b5c5857db"),
+    "a.x-k1.fwd_q": (_heads(0, 64, 0.1, 1), [_sds(2, 64, 4096, 192), _sds(4096, 64), _sds(4096, 64)], "7ea38d08b9aeb434"),
+    "mistral-7b_q_head_major": (_heads(0, 32, 0.088, 1), [_sds(1, 48, 4096, 128), _sds(4096, 128), _sds(4096, 128)], "778e709ef6e7e238"),
+}
+
+
+@pytest.mark.parametrize("call", UNNORMED_CALLS)
+def test_a_call_without_a_norm_traces_to_the_body_it_had(call):
+    """One body took the place of three (PR 39: a full rotary's, a partial one's, the plain split's). Where a call
+    asks for no norm its jaxpr, grid and blocks are to the letter what those three gave at the shapes of the cells
+    that run them. Whoever changes what an un-normed call computes changes these and measures ``pythia-410m.fwd``,
+    ``a.x-k1.fwd`` and ``mistral-7b.train`` on the chip; a new jax prints another text, and then they are taken anew
+    from the commit before."""
+    import hashlib
+    import re
+
+    import jax
+
+    fn, operands, sha = UNNORMED_CALLS[call]
+    text = str(jax.make_jaxpr(fn)(*operands))
+    text = re.sub(r"name=\w+", "name=K", text)  # the body's name, which is all that changed
+    text = re.sub(r" at [^\n]*\.py:\d+", "", text)
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == sha
+
+
 def _with_dropout(trc):
     at = [i for i, b in enumerate(trc.bound_symbols) if b.sym.id == attention_layout._SDPA][0]
     sdpa = trc.bound_symbols[at]
@@ -159,11 +314,11 @@ def _with_dropout(trc):
     return trc
 
 
-def _masked():
+def _masked(**how):
     import jax.numpy as jnp
 
     program, args = _fused_qkv(4, 4, 64, 16, bias=True, is_causal=False,
-                               attn_mask=jnp.ones((B, 1, 1, T), jnp.bool_))
+                               attn_mask=jnp.ones((B, 1, 1, T), jnp.bool_), **how)
     return program, args
 
 
@@ -175,6 +330,11 @@ DECLINES = {
     "no_kernel_executors": lambda: _folded_trace(*_fused_qkv(4, 4, 64, 16, bias=True), executors=["jax"]),
     # float32 tables promote q in the decomposition, so the rope kernel's checker says no
     "a_rope_call_the_kernel_declines": lambda: _folded_trace(*_fused_qkv(4, 4, 64, 16, bias=True, tables="float32")),
+    "a_norm_on_q_only": lambda: _folded_trace(*_fused_qkv(4, 4, 64, 16, bias=True, norm="q")),
+    "a_norm_that_is_not_over_a_head": lambda: _folded_trace(*_fused_qkv(4, 4, 64, 16, bias=True, norm="qk_pairs")),
+    "a_second_reader_of_the_normed_q": lambda: _folded_trace(*_fused_qkv(4, 4, 64, 16, bias=True, norm="qk", normed_read_twice=True)),
+    "neither_norm_nor_rope": lambda: _folded_trace(*_fused_qkv(4, 4, 64, 0, bias=True)),
+    "a_mask_on_a_normed_site": lambda: _folded_trace(*_masked(norm="qk")),
 }
 
 
@@ -183,7 +343,7 @@ def test_declines_and_leaves_the_program_as_written(why):
     trc = DECLINES[why]()
     assert trc.tags[FOLDED] == 0
     ids = [b.sym.id for b in trc.bound_symbols]
-    assert "torch.linear_heads" not in ids and "torch.apply_rope" in ids
+    assert "torch.linear_heads" not in ids and ("torch.apply_rope" in ids or why == "neither_norm_nor_rope")
 
 
 def test_declines_on_a_grad_trace_through_the_api():
@@ -207,17 +367,32 @@ def test_declines_without_the_flash_claim(monkeypatch):
     assert "linear_heads" not in thunder_tpu.last_traces(jfn)[-1].python()
 
 
-def test_models_gpt_forward_counts_its_layers():
+MODELS = {
+    # registry entry (cut where given): (attention sites, of them under a window, normed heads)
+    "pythia-410m": (dict(n_layer=3, n_embd=128, n_head=2, intermediate_size=256, padded_vocab_size=256, vocab_size=256),
+                    3, 0, False),
+    "trinity-tiny": ({}, 4, 3, True),   # three window layers with rope, a global one without, every head normed
+    "lfm2-tiny": ({}, 1, 0, True),      # one attention layer among three conv mixers, its heads normed
+    "minicpm-sala-tiny": ({}, 0, 0, True),  # normed heads in front of consumers the pass does not know
+}
+
+
+@pytest.mark.parametrize("name", MODELS)
+def test_models_gpt_forward_counts_its_layers(name):
     """The counter on the ``transforms`` record is the number of attention sites rewritten."""
     import dataclasses
 
     from thunder_tpu.core import dtypes
     from thunder_tpu.models import gpt
 
-    cfg = dataclasses.replace(gpt.name_to_config("pythia-410m"), n_layer=3, n_embd=128, n_head=2,
-                              intermediate_size=256, padded_vocab_size=256, vocab_size=256)
+    cut, sites, windows, normed = MODELS[name]
+    cfg = dataclasses.replace(gpt.name_to_config(name), **cut)
     params = gpt.init_params(cfg, dtype=dtypes.bfloat16, device_init=True)
-    idx = np.random.RandomState(0).randint(0, 256, (2, 128)).astype(np.int32)
+    T = min(128, cfg.block_size)
+    idx = np.random.RandomState(0).randint(0, cfg.vocab_size, (2, T)).astype(np.int32)
     jfn = thunder_tpu.jit(lambda p, i: gpt.forward(p, i, cfg))
-    jfn(params, idx)
-    assert _transforms_record(jfn)[FOLDED] == 3
+    assert np.isfinite(np.asarray(jfn(params, idx), np.float32)).all()
+    assert _transforms_record(jfn)[FOLDED] == sites
+    src = thunder_tpu.last_traces(jfn)[-1].python()
+    assert src.count("flash_window_attention(") == windows and src.count("jax_linear_heads(") == sites
+    assert src.count("norm_weight=") == (2 * sites if normed else 0)

@@ -226,6 +226,43 @@ def test_train_step_lowers_for_tpu_with_mosaic_kernels(axes, rotary, monkeypatch
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("name,cut,sites", [("trinity-tiny", dict(head_dim=128), 4), ("lfm2-tiny", dict(n_embd=256, n_query_groups=2), 1)],
+                         ids=["trinity-heads-of-128", "lfm2-two-heads-of-64-a-lane-group"])
+def test_forward_with_normed_heads_lowers_for_tpu_with_mosaic_kernels(name, cut, sites, monkeypatch):
+    """A forward program whose heads are normed, as the dispatcher rewrites it
+    (transforms/attention_layout.py): the projection head-major, one call that
+    norms, ropes (or not: Trinity's global layer) and scales q and k, the
+    attention call causal or within a window; cross-lowered for the TPU with
+    the kernels as Mosaic calls, so that what Mosaic's lowering refuses of the
+    normed call shows here."""
+    import jax
+
+    from thunder_tpu.api import trace_program
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import flashex, pallasex
+    from thunder_tpu.executors.passes import transform_for_execution
+    from thunder_tpu.extend import resolve_executors
+    from thunder_tpu.models import gpt
+    from thunder_tpu.transforms.attention_layout import FOLDED_TAG, fold_attention_layouts
+    from thunder_tpu.transforms.common import dce
+
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    monkeypatch.setattr(flashex, "_interpret", lambda: False)
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    cfg = dataclasses.replace(gpt.name_to_config(name), **cut)
+    params = gpt.init_params(cfg, dtype=dtypes.bfloat16, seed=0)
+    idx = np.random.RandomState(0).randint(0, cfg.vocab_size, (2, 64)).astype(np.int32)
+    _, trc = trace_program(lambda p, i: gpt.forward(p, i, cfg), (params, idx), {})
+    trc = fold_attention_layouts(dce(trc), resolve_executors(None))
+    assert trc.tags[FOLDED_TAG] == sites
+    extrace = transform_for_execution(trc, resolve_executors(None))
+    src = extrace.python()
+    assert src.count("pallas_apply_rope_heads(") == src.count("norm_weight=") == 2 * sites
+    flat = jax.tree_util.tree_leaves((params, idx))
+    text = jax.jit(extrace.python_callable()).trace(*flat).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+
+
 HEAD_SHAPES = {  # registry entry, what is cut, (head size, rotary features)
     # pythia-410m's: 64 wide, 25% rotary, one key-value head a query head
     "pythia-head": ("pythia-410m", dict(n_embd=128, n_head=2), (64, 16)),

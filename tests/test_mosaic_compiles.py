@@ -374,15 +374,25 @@ def test_claimed_routed_experts_compile_for_v5e_on_one_buffer_where_every_expert
 
 
 ROPE_HEADS_SHAPES = [
-    # (B, lane groups of the array, T, lanes), n, first, heads read, scale, heads a lane group
-    ((8, 24, 2048, 128), 16, 0, 16, 0.125, 2),   # pythia-410m.fwd's q out of the packed projection: two heads of 64 a group
-    ((8, 24, 2048, 128), 16, 16, 16, 1.0, 2),    # and its k
-    ((8, 24, 2048, 128), 64, 0, 16, 0.125, 2),   # full rotary on heads of 64 (tinyllama's)
-    ((8, 48, 2048, 64), 16, 0, 16, 0.125, 1),    # a head of 64 a group, where the counts of heads are odd
-    ((8, 48, 2048, 64), 16, 16, 16, 1.0, 1),
-    ((1, 48, 4096, 128), 128, 0, 32, 128 ** -0.5, 1),  # 32 query and 8 key heads of 128, full rotary
-    ((1, 48, 4096, 128), 128, 32, 8, 1.0, 1),
-    ((2, 64, 4096, 192), 64, 0, 64, 192 ** -0.5 * 1.3466 ** 2, 1),  # a.x-k1.fwd's q: every head, so in place
+    # (B, lane groups of the array, T, lanes), n (0: no rope), first, heads read, scale, heads a lane group, normed
+    ((8, 24, 2048, 128), 16, 0, 16, 0.125, 2, False),   # pythia-410m.fwd's q out of the packed projection: two heads of 64 a group
+    ((8, 24, 2048, 128), 16, 16, 16, 1.0, 2, False),    # and its k
+    ((8, 24, 2048, 128), 64, 0, 16, 0.125, 2, False),   # full rotary on heads of 64 (tinyllama's)
+    ((8, 48, 2048, 64), 16, 0, 16, 0.125, 1, False),    # a head of 64 a group, where the counts of heads are odd
+    ((8, 48, 2048, 64), 16, 16, 16, 1.0, 1, False),
+    ((1, 48, 4096, 128), 128, 0, 32, 128 ** -0.5, 1, False),  # 32 query and 8 key heads of 128, full rotary
+    ((1, 48, 4096, 128), 128, 32, 8, 1.0, 1, False),
+    ((2, 64, 4096, 192), 64, 0, 64, 192 ** -0.5 * 1.3466 ** 2, 1, False),  # a.x-k1.fwd's q: every head, so in place
+    # normed heads (PR 39). trinity-mini.fwd-t32k: 32 query and 4 key heads of 128 at 32,768 positions, a window
+    # layer's q and k (normed, roped, q scaled) and the global layer's (normed, no rope)
+    ((1, 40, 32768, 128), 128, 0, 32, 128 ** -0.5, 1, True),
+    ((1, 40, 32768, 128), 128, 32, 4, 1.0, 1, True),
+    ((1, 40, 32768, 128), 0, 0, 32, 128 ** -0.5, 1, True),
+    ((1, 40, 32768, 128), 0, 32, 4, 1.0, 1, True),
+    # lfm2-8b-a1b.fwd: 32 query and 8 key heads of 64, two a lane group, each half normed by itself
+    ((2, 24, 4096, 128), 64, 0, 32, 0.125, 2, True),
+    ((2, 24, 4096, 128), 64, 32, 8, 1.0, 2, True),
+    ((2, 24, 4096, 128), 16, 0, 32, 0.125, 2, True),    # and under a partial rotary
 ]
 
 
@@ -404,20 +414,37 @@ def _heads_call_compiled(monkeypatch, one_chip, call, checker, shape, tables, do
     return compiled
 
 
-@pytest.mark.parametrize("shape,n,first,heads,scale,split", ROPE_HEADS_SHAPES,
-                         ids=[f"{s[-1] // k}-{n}-heads{f}to{f + h}of{s[1] * k}" for s, n, f, h, _, k in ROPE_HEADS_SHAPES])
-def test_rope_on_some_heads_of_a_head_major_array_compiles_for_v5e(one_chip, monkeypatch, shape, n, first, heads, scale, split):
+@pytest.mark.parametrize("shape,n,first,heads,scale,split,normed", ROPE_HEADS_SHAPES,
+                         ids=[f"{s[-1] // k}-{n}-heads{f}to{f + h}of{s[1] * k}{'-normed' * nd}" for s, n, f, h, _, k, nd in ROPE_HEADS_SHAPES])
+def test_rope_on_some_heads_of_a_head_major_array_compiles_for_v5e(one_chip, monkeypatch, shape, n, first, heads, scale, split, normed):
     """``apply_rope_heads`` as transforms/attention_layout.py writes it: one call
     that reads its heads by the block index and writes them scaled, each head
-    to a (T, hs) of its own where a group's lanes hold several."""
+    to a (T, hs) of its own where a group's lanes hold several; normed first
+    where the model norms its heads, and not rotated where the layer has no rope."""
     from thunder_tpu.executors import pallasex
 
-    tables = [(shape[-2], n)] * 2
-    compiled = _heads_call_compiled(
-        monkeypatch, one_chip, lambda x, c, s: pallasex._rope_heads_impl(x, c, s, first, heads, scale, split),
-        lambda x, c, s: pallasex._rope_heads_checker(x, c, s, first, heads, scale, split), shape, tables,
-        donate=(heads, split) == (shape[1], 1))
+    tables = [(shape[-2], n)] * 2 if n else []
+    weight = [(shape[-1] // split,)] if normed else []
+
+    def operands(rest):
+        cos, sin = rest[:2] if n else (None, None)
+        return (cos, sin, first, heads, scale, split), (dict(norm_weight=rest[-1], eps=1e-5) if normed else {})
+
+    def call(x, *rest):
+        args, norm = operands(rest)
+        return pallasex._rope_heads_impl(x, *args, **norm)
+
+    def checker(x, *rest):
+        args, norm = operands(rest)
+        return pallasex._rope_heads_checker(x, *args, **norm)
+
+    compiled = _heads_call_compiled(monkeypatch, one_chip, call, checker, shape, tables + weight,
+                                    donate=(heads, split) == (shape[1], 1))
     assert compiled.out_info.shape == (shape[0], heads, shape[2], shape[3] // split)
+    text = compiled.as_text()
+    # one pass: nothing but the kernel touches an array of the heads' size, in float32 or otherwise
+    assert "f32[" + ",".join(map(str, shape[:1] + (heads,) + shape[2:3])) not in text
+    assert not [line for line in text.splitlines() if " fusion(" in line and f"{shape[2]},{shape[3] // split}]" in line.split(" fusion(")[0]]
 
 
 def test_split_heads_compiles_for_v5e(one_chip, monkeypatch):
@@ -495,6 +522,55 @@ def test_forward_of_pythia_has_no_layout_copy_in_front_of_attention_on_the_v5e(o
     assert len(layout_instructions(written.as_text())) == 2 * 6 + 1
     assert len(layout_instructions(folded.as_text())) == 1
     assert executable_needs(folded)[0] <= executable_needs(written)[0]
+
+
+def test_forward_of_trinity_norms_its_heads_in_one_pass_on_the_v5e(one_chip, monkeypatch):
+    """trinity-mini.fwd-t32k's program at depth 4 (three window layers with rope and the global one without, every
+    head normed), as the dispatcher rewrites it, compiled for the described chip. Token-major the heads' norm costs
+    each layer a float32 q with the tokens in the lanes, a float32 relayout of it, the mean square, the norm's pass
+    and q's scaling, and the same on k (PERF.md, PR 39): none of them is left, the projection is written head-major
+    by its own dot, and two calls a layer norm, rope and scale; no family of the benchmark's takes them for its own."""
+    import jax
+    import jax.numpy as jnp
+
+    from perfbench import kernel_families, manifest
+    from perfbench.jobs import gpt_model
+    from thunder_tpu.api import trace_program
+    from thunder_tpu.executors import flashex, pallasex
+    from thunder_tpu.executors.passes import transform_for_execution
+    from thunder_tpu.extend import resolve_executors
+    from thunder_tpu.models import gpt
+    from thunder_tpu.transforms.attention_layout import FOLDED_TAG, fold_attention_layouts
+    from thunder_tpu.transforms.common import dce
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    monkeypatch.setattr(flashex, "_interpret", lambda: False)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    cell = manifest.load_cell("trinity-mini.fwd-t32k")
+    keys = manifest.published(cell)
+    keys.update(num_hidden_layers=4)
+    cfg = gpt_model.gpt_config(keys)
+    assert [cfg.layer_mixer(i) for i in range(4)] == ["sliding_attention"] * 3 + ["full_attention"]
+    shapes = gpt_model.param_shapes(cfg)
+    tokens = jax.ShapeDtypeStruct((cell.traffic["batch"], cell.traffic["seq"]), jnp.int32)
+    flat = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip) for a in jax.tree_util.tree_leaves((shapes, tokens))]
+    _, trc = trace_program(lambda p, i: gpt.forward(p, i, cfg, last=cell.traffic["last"]), (shapes, tokens), {})
+    trc = fold_attention_layouts(dce(trc), resolve_executors(None))
+    assert trc.tags[FOLDED_TAG] == 4
+    extrace = transform_for_execution(trc, resolve_executors(None))
+    assert gpt_model.kernels_claimed(extrace) == 4 * 3 + 2  # q, k and the attention call a layer; two dispatches
+    text = jax.jit(extrace.python_callable()).lower(*flat).compile().as_text()
+    for gone in ("f32[1,32768,32,128]", "f32[1,32768,4096]", "f32[32,32768]", "f32[1,32768,4,128]", "f32[1,32768,512]", "f32[4,32768]"):
+        assert gone not in text, gone
+    written = _arrays_written(text)
+    assert ("broadcast_multiply_fusion", "bf16[32,32768,128]") not in written          # q's scaling rides on the call
+    assert written.count(("convolution_bitcast_fusion", "bf16[1,40,32768,128]")) == 4  # the dot writes head-major itself
+    mosaic = re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text, re.M)
+    calls = [call for call in (_as_the_trace_names_it(text, name + " ") for name in mosaic) if "custom-call(bf16[1,40,32768,128]" in call]
+    assert len(calls) == 8
+    # x, the norm's weight, then the tables where the layer ropes
+    operands = sorted(re.findall(r"(\w+\[[\d,]*\])\S* %", call.split("custom-call(")[1].split("), custom_call_target")[0]) for call in calls)
+    assert operands == [["bf16[1,40,32768,128]", "f32[1,128]"]] * 2 + [["bf16[1,40,32768,128]", "f32[1,128]", "bf16[32768,128]", "bf16[32768,128]"]] * 6
 
 
 def _as_the_trace_names_it(text, name_prefix):

@@ -344,17 +344,38 @@ def _rope_write(out_ref, rows, scale: float = 1.0):
         out_ref[0, i] = rows[:, i * hs:(i + 1) * hs].astype(out_ref.dtype)
 
 
-def _rope_kernel(x_ref, cos_ref, sin_ref, out_ref, *, half: int, scale: float = 1.0):
+def _head_norm(rows, weight, *, eps: float, hs: int):
+    """``rms_norm`` of each ``hs`` of the rows' D lanes (D // hs heads side by
+    side, each by its own mean square) times ``weight`` (1, D), in float32 and
+    left there for the steps that follow. Heads narrower than the rows are
+    told apart by a lane mask, so nothing narrower than the block is ever cut
+    out of it."""
+    import jax
     import jax.numpy as jnp
 
-    x = x_ref[_rope_rows(x_ref)]
+    x = rows.astype(jnp.float32)
+    sq, D = x * x, x.shape[-1]
+    if hs == D:
+        ms = jnp.sum(sq, -1, keepdims=True)
+    else:
+        head = jax.lax.broadcasted_iota(jnp.int32, sq.shape, 1) // hs
+        ms = jnp.zeros_like(sq)
+        for i in range(D // hs):
+            ms = jnp.where(head == i, jnp.sum(jnp.where(head == i, sq, 0.0), -1, keepdims=True), ms)
+    return x * jax.lax.rsqrt(ms * (1.0 / hs) + eps) * weight
+
+
+def _rotate_half(x, cos_ref, sin_ref):
+    import jax.numpy as jnp
+
+    half = x.shape[-1] // 2
     x1 = x[..., :half]
     x2 = x[..., half:]
     rotated = jnp.concatenate([-x2, x1], axis=-1)
-    _rope_write(out_ref, x * cos_ref[...] + rotated * sin_ref[...], scale)
+    return x * cos_ref[...] + rotated * sin_ref[...]
 
 
-def _rope_partial_kernel(x_ref, cos_ref, sin_ref, out_ref, *, n: int, hs: int, scale: float = 1.0):
+def _rotate_part(x, cos_ref, sin_ref, *, n: int, hs: int):
     """Rotary on the first ``n`` of every ``hs`` of D lanes (D // hs heads side
     by side) without a slice narrower than the block: ``cos`` comes padded with
     ones and ``sin`` with zeros, and rotate-half is a product with the D x D
@@ -365,7 +386,6 @@ def _rope_partial_kernel(x_ref, cos_ref, sin_ref, out_ref, *, n: int, hs: int, s
     import jax
     import jax.numpy as jnp
 
-    x = x_ref[_rope_rows(x_ref)]
     D, half = x.shape[-1], n // 2
     src = jax.lax.broadcasted_iota(jnp.int32, (D, D), 0)
     dst = jax.lax.broadcasted_iota(jnp.int32, (D, D), 1)
@@ -376,7 +396,27 @@ def _rope_partial_kernel(x_ref, cos_ref, sin_ref, out_ref, *, n: int, hs: int, s
                      jnp.where((src == dst - half) & (at >= half) & (at < n), 1.0, 0.0)).astype(x.dtype)
     exact = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None  # bf16 operands are exact as they are
     rotated = jnp.dot(x, perm, preferred_element_type=jnp.float32, precision=exact).astype(x.dtype)
-    _rope_write(out_ref, x * cos_ref[...] + rotated * sin_ref[...], scale)
+    return x * cos_ref[...] + rotated * sin_ref[...]
+
+
+def _heads_kernel(x_ref, *refs, hs: int, eps=None, rotate=None, scale: float = 1.0):
+    """A block of rows with the steps the call asks for, in the program's
+    order: each head's norm (a weight ref and ``eps``), the rotation (cos and
+    sin refs and ``rotate``), the scale. Normed rows are float32 from there
+    to the one rounding at the write, under a full rotary or none; rows that
+    are not normed are rotated in their own dtype, as the rope call has always
+    run. With no step it hands the rows on."""
+    *refs, out_ref = refs
+    rows = x_ref[_rope_rows(x_ref)]
+    if eps is not None:
+        rows = _head_norm(rows, refs[0][...], eps=eps, hs=hs)
+    if rotate is not None:
+        if rotate is not _rotate_half:
+            # The one place normed rows leave float32 before the write: a partial rotary's permutation product
+            # is exact, and one MXU pass, in the tables' dtype only. They are rounded twice there, not once.
+            rows = rows.astype(refs[-1].dtype)
+        rows = rotate(rows, *refs[-2:])
+    _rope_write(out_ref, rows, scale)
 
 
 def _rope_block(T: int) -> int:
@@ -387,22 +427,22 @@ def _rope_block(T: int) -> int:
     return bt
 
 
-def _rope_kernel_and_tables(T: int, D: int, cos, sin, scale: float = 1.0, split: int = 1):
-    """(kernel body, sequence rows a block, cos, sin) for rows of D lanes that
-    hold ``split`` heads side by side, under (T, n) tables. All but one head of
-    full rotary take tables of full width, ones and zeros beyond a head's n,
-    which keeps the call's three operands."""
+def _rotation_and_tables(T: int, D: int, cos, sin, split: int = 1):
+    """(the rotation, cos, sin) for rows of D lanes that hold ``split`` heads
+    side by side, under (T, n) tables. All but one head of full rotary take
+    tables of full width, ones and zeros beyond a head's n, which keeps the
+    call's operands."""
     import jax.numpy as jnp
 
-    n, hs, bt = cos.shape[-1], D // split, _rope_block(T)
+    n, hs = cos.shape[-1], D // split
     if n == D:
-        return partial(_rope_kernel, half=D // 2, scale=scale), bt, cos, sin
+        return _rotate_half, cos, sin
     if n != hs:
         cos = jnp.concatenate([cos, jnp.ones((T, hs - n), cos.dtype)], axis=-1)
         sin = jnp.concatenate([sin, jnp.zeros((T, hs - n), sin.dtype)], axis=-1)
     if split != 1:
         cos, sin = jnp.tile(cos, (1, split)), jnp.tile(sin, (1, split))
-    return partial(_rope_partial_kernel, n=n, hs=hs, scale=scale), bt, cos, sin
+    return partial(_rotate_part, n=n, hs=hs), cos, sin
 
 
 def _rope_impl(x, cos, sin):
@@ -416,9 +456,10 @@ def _rope_impl(x, cos, sin):
         # The VJP needs cos and sin only, never x, so where the rotary is
         # partial x is dead after the call: in place.
         in_place = {0: 0} if cos.shape[-1] != D else {}
-        kernel, bt, cos, sin = _rope_kernel_and_tables(T, D, cos, sin)
+        bt = _rope_block(T)
+        rotate, cos, sin = _rotation_and_tables(T, D, cos, sin)
         out = pl.pallas_call(
-            kernel,
+            partial(_heads_kernel, hs=D, rotate=rotate),
             grid=(B * H, T // bt),
             in_specs=[
                 pl.BlockSpec((1, bt, D), lambda i, j: (i, j, 0), memory_space=pltpu.VMEM),
@@ -446,6 +487,12 @@ ex.register_implementation("torch.apply_rope", fn=_rope_impl, checker=_rope_chec
 # the projection writes whole tiles and the calls read them whole; each head
 # leaves to a (T, hs) of its own, which is what the attention kernel takes. q
 # leaves times the softmax scale, which costs attention a pass over q otherwise.
+# Where the model norms its heads (an RMSNorm over each head's hs features
+# between the projection and the rope: LFM2, Trinity) the same call takes the
+# norm's weight and norms the block it holds, in float32, before it rotates;
+# where a layer has no rope (Trinity's global ones) it takes no tables and
+# rotates nothing. Token-major, the norm costs XLA a float32 copy of q to put
+# a head's features in the lanes and three more passes (PERF.md, PR 39).
 
 
 def heads_per_lane_group(hs: int, *head_counts: int) -> int:
@@ -466,61 +513,72 @@ def _heads_checker(x, first, heads, split) -> bool:
             and (split == 1 or (dt in (dtypes.bfloat16, dtypes.float32) and L * dt.bytes <= 512)))
 
 
-def _rope_heads_checker(x, cos, sin, first, heads, scale=1.0, split=1):
-    """``_rope_checker``'s word on the heads that are read."""
+def _rope_heads_checker(x, cos, sin, first, heads, scale=1.0, split=1, norm_weight=None, eps=None):
+    """``_rope_checker``'s word on the heads that are read, where they are
+    roped; a norm's weight is one head's, in the heads' dtype (another
+    promotes in the decomposition)."""
     first, heads, split = int(pyval(first)), int(pyval(heads)), int(pyval(split))
-    if not _heads_checker(x, first, heads, split):
+    if not _heads_checker(x, first, heads, split) or (cos is None) != (sin is None):
         return False
     B, _, T, L = x.shape
-    return _rope_checker(SimpleNamespace(shape=(B, heads, T, L // split), dtype=x.dtype), cos, sin)
+    if norm_weight is not None and not (tuple(getattr(norm_weight, "shape", ())) == (L // split,)
+                                        and norm_weight.dtype == x.dtype and eps is not None):
+        return False
+    return cos is None or _rope_checker(SimpleNamespace(shape=(B, heads, T, L // split), dtype=x.dtype), cos, sin)
 
 
 def _split_heads_checker(x, first, heads, split):
     return _heads_checker(x, int(pyval(first)), int(pyval(heads)), int(pyval(split)))
 
 
-def _heads_call(kernel_and_tables, x, tables, first: int, heads: int, split: int):
+def _heads_call(x, first: int, heads: int, split: int, *, cos=None, sin=None, norm_weight=None, eps=None,
+                scale: float = 1.0):
     """One call over (batch, lane groups read, blocks of the sequence): group
     ``first // split + g`` of x (B, P, T, L) in, heads ``split * g`` and on of
-    (B, heads, T, L // split) out."""
+    (B, heads, T, L // split) out, normed, roped and scaled where asked.
+    Operands: x, then the norm's weight as float32 (1, L), then cos and sin."""
     import jax
+    import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    def shard(x, *tables):
+    normed, roped = norm_weight is not None, cos is not None
+
+    def shard(x, *rows):
         B, P, T, L = x.shape
-        kernel, bt, *tables = kernel_and_tables(T, L, *tables)
-        table = pl.BlockSpec((bt, L), lambda b, g, j: (j, 0), memory_space=pltpu.VMEM)
+        bt, rotate, operands, in_specs = _rope_block(T), None, [], []
+        if normed:
+            operands.append(jnp.tile(rows[0].astype(jnp.float32), split).reshape(1, L))
+            in_specs.append(pl.BlockSpec((1, L), lambda b, g, j: (0, 0), memory_space=pltpu.VMEM))
+        if roped:
+            rotate, *tables = _rotation_and_tables(T, L, *rows[-2:], split=split)
+            operands += tables
+            in_specs += [pl.BlockSpec((bt, L), lambda b, g, j: (j, 0), memory_space=pltpu.VMEM)] * 2
         return pl.pallas_call(
-            kernel,
+            partial(_heads_kernel, hs=L // split, eps=float(eps) if normed else None, rotate=rotate, scale=float(scale)),
             grid=(B, heads // split, T // bt),
             in_specs=[pl.BlockSpec((1, 1, bt, L), lambda b, g, j: (b, first // split + g, j, 0),
-                                   memory_space=pltpu.VMEM), *(table for _ in tables)],
+                                   memory_space=pltpu.VMEM), *in_specs],
             out_specs=pl.BlockSpec((1, split, bt, L // split), lambda b, g, j: (b, g, j, 0), memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((B, heads, T, L // split), x.dtype),
             input_output_aliases={0: 0} if (heads, split) == (P, 1) else {},  # every head: x's own buffer, as _rope_impl's
             interpret=_interpret(),
-        )(x, *tables)
+        )(x, *operands)
 
+    rows = [a.astype(x.dtype) for a in (norm_weight, cos, sin) if a is not None]
     with jax.enable_x64(False):
-        return per_batch_shard(shard, x, *(t.astype(x.dtype) for t in tables), replicated=tuple(range(1, 1 + len(tables))))
+        return per_batch_shard(shard, x, *rows, replicated=tuple(range(1, 1 + len(rows))))
 
 
-def _rope_heads_impl(x, cos, sin, first, heads, scale=1.0, split=1):
+def _rope_heads_impl(x, cos, sin, first, heads, scale=1.0, split=1, norm_weight=None, eps=None):
     chaos.kernel_seam("pallas", "apply_rope_heads")
-    split = int(split)
-    return _heads_call(partial(_rope_kernel_and_tables, scale=float(scale), split=split), x, (cos, sin),
-                       int(first), int(heads), split)
-
-
-def _split_kernel(x_ref, out_ref):
-    _rope_write(out_ref, x_ref[_rope_rows(x_ref)])
+    return _heads_call(x, int(first), int(heads), int(split), cos=cos, sin=sin, norm_weight=norm_weight, eps=eps,
+                       scale=float(scale))
 
 
 def _split_heads_impl(x, first, heads, split):
     chaos.kernel_seam("pallas", "split_heads")
-
-    return _heads_call(lambda T, L: (_split_kernel, _rope_block(T)), x, (), int(first), int(heads), int(split))
+    return _heads_call(x, int(first), int(heads), int(split))
 
 
 ex.register_implementation("torch.apply_rope_heads", fn=_rope_heads_impl, checker=_rope_heads_checker)

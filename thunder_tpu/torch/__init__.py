@@ -1083,10 +1083,13 @@ def layer_norm(a, normalized_shape, weight=None, bias=None, eps: float = 1e-5):
     return normed
 
 
+RMS_NORM_EPS = 1e-6  # what ``rms_norm`` takes for an ``eps`` of None, for whoever reads a norm off a trace
+
+
 @torchsymbol("torch.nn.functional.rms_norm")
 def rms_norm(a, normalized_shape, weight=None, eps: Optional[float] = None):
     if eps is None:
-        eps = 1e-6
+        eps = RMS_NORM_EPS
     n = len(tuple(normalized_shape))
     dims = tuple(range(a.ndim - n, a.ndim))
     compute_dtype = dtypes.float32 if a.dtype in (dtypes.bfloat16, dtypes.float16) else a.dtype
@@ -1409,12 +1412,20 @@ def split_heads(x, first: int, heads: int, split: int = 1):
 
 
 @torchsymbol(id="torch.apply_rope_heads")
-def apply_rope_heads(x, cos, sin, first: int, heads: int, scale: float = 1.0, split: int = 1):
-    """``apply_rope`` on ``split_heads(x, first, heads, split)``, times
-    ``scale``. Attention's softmax scale rides here on q, so that
-    ``scaled_dot_product_attention(scale=1.0)`` multiplies nothing."""
-    roped = apply_rope(split_heads(x, first, heads, split), cos, sin)
-    return roped if scale == 1.0 else mul(roped, scale)
+def apply_rope_heads(x, cos, sin, first: int, heads: int, scale: float = 1.0, split: int = 1,
+                     norm_weight=None, eps: Optional[float] = None):
+    """What stands between the packed projection and attention, on the heads
+    ``split_heads(x, first, heads, split)``: ``rms_norm`` over each head's
+    features under ``norm_weight`` (hs,) where one is given, ``apply_rope``
+    where there are tables (``cos`` and ``sin`` None: a layer without rope),
+    times ``scale``. Attention's softmax scale rides here on q, so that the
+    attention call (``scale=1.0``) multiplies nothing."""
+    h = split_heads(x, first, heads, split)
+    if norm_weight is not None:
+        h = rms_norm(h, (h.shape[-1],), norm_weight, eps)
+    if cos is not None:
+        h = apply_rope(h, cos, sin)
+    return h if scale == 1.0 else mul(h, scale)
 
 
 @torchsymbol(id="torch.short_conv")
@@ -1951,7 +1962,7 @@ def _register_composite_vjps():
         bound.update(bsym.kwargs)
         eps = bound.get("eps")
         dx, dw = rms_norm_bwd(g, bound["a"], bound.get("weight"),
-                              1e-6 if eps is None else float(pyval(eps)))
+                              RMS_NORM_EPS if eps is None else float(pyval(eps)))
         grad_map = {"a": dx}
         if bound.get("weight") is not None:
             grad_map["weight"] = dw

@@ -21,6 +21,17 @@ head a (T, hs) of its own: ``split_heads`` does that for v, which is a plain
 slice where the heads fill the lanes. Where only q comes through ``linear ->
 reshape -> permute -> apply_rope`` (latent attention) it does that part.
 
+The idiom is matched by what the trace shows, in each spelling of it (PR 39):
+between the permute and the attention call q and k may pass through a
+``rms_norm`` over each head's features (LFM2, Trinity: token-major that norm
+costs a float32 copy of q and three passes more), through ``apply_rope``,
+through both in that order, and q and k alike; the call may be
+``scaled_dot_product_attention`` or ``window_attention``. ``apply_rope_heads``
+takes the norm's weight and tables that may be absent, and does in one pass
+what the site has. A site with neither norm nor rope is left alone. A new line
+keeps the region of the line it stands for: the attention call alone carries
+the call's.
+
 Forward programs only: in a trace that holds a backward q, k and v have a
 second reader and nothing matches. It asks the checkers first: where ``pallas``
 would not take the rope call or ``flash`` the attention call (a CPU run
@@ -33,6 +44,7 @@ from __future__ import annotations
 
 import math
 import time
+from typing import NamedTuple, Optional
 
 from thunder_tpu.core.proxies import Proxy, TensorProxy, pyval, variableify
 from thunder_tpu.core.pytree import tree_flatten
@@ -44,9 +56,10 @@ from thunder_tpu.transforms.attention_residuals import _bound_sdpa
 FOLDED_TAG = "attention_layouts_folded"  # how many attention sites the pass rewrote
 
 _SDPA = "torch.scaled_dot_product_attention"
+_WINDOW = "torch.window_attention"
 _HEADS_FIRST = (0, 2, 1, 3)
 # who has to take each new line for the rewrite to pay: asked of the checkers before anything is changed
-_CLAIMED_BY = {"torch.apply_rope_heads": "pallas", "torch.split_heads": "pallas", _SDPA: "flash"}
+_CLAIMED_BY = {"torch.apply_rope_heads": "pallas", "torch.split_heads": "pallas", _SDPA: "flash", _WINDOW: "flash"}
 
 
 class _Uses:
@@ -111,32 +124,68 @@ def _last_dim_slice(uses: _Uses, s):
     return lin, a, b, i
 
 
-def _roped(uses: _Uses, p):
-    """``p`` as ``apply_rope(x, cos, sin)`` read by the attention call alone:
-    (x, cos, sin, the index), or None."""
-    i = uses.made_by(p, "torch.apply_rope")
-    return None if i is None else (*uses.bsyms[i].args, i)
+def _bound_call(call) -> dict:
+    """An attention call's operands under ``_bound_sdpa``'s names, whichever of the two it is."""
+    if call.sym.id == _SDPA:
+        return _bound_sdpa(call.args, call.kwargs)
+    q, k, v = call.args
+    return dict(query=q, key=k, value=v, attn_mask=None, dropout_p=0.0, scale=call.kwargs.get("scale"),
+                window=call.kwargs["window"])
+
+
+class _Steps(NamedTuple):
+    """What stands between a projection and the attention call on q or on k."""
+
+    src: TensorProxy        # the projection, or the slice of it, that the heads are cut from
+    how: dict               # ``apply_rope_heads``'s cos and sin (None: no rope) and, where normed, norm_weight and eps
+    region: Optional[str]   # of the innermost step, which the new line keeps
+    gone: list              # the indices the new line stands for
+
+    def asked(self) -> set:
+        return {key for key, a in self.how.items() if a is not None}
+
+
+def _head_steps(uses: _Uses, p) -> Optional[_Steps]:
+    """``p`` (B, h, T, hs) as ``apply_rope(rms_norm(x, (hs,), weight, eps), cos, sin)``,
+    either step or both left out, each read by the next alone and x token-major
+    out of a projection; None where it is anything else, or neither."""
+    from thunder_tpu.torch import RMS_NORM_EPS
+
+    how, gone = dict(cos=None, sin=None), []
+    if (i := uses.made_by(p, "torch.apply_rope")) is not None:
+        p, how["cos"], how["sin"] = uses.bsyms[i].args
+        gone.append(i)
+    if (i := uses.made_by(p, "torch.rms_norm")) is not None:
+        norm = dict(zip(("a", "normalized_shape", "weight", "eps"), uses.bsyms[i].args), **uses.bsyms[i].kwargs)
+        p, weight, hs = norm["a"], norm.get("weight"), p.shape[-1]
+        if tuple(norm["normalized_shape"]) != (hs,) or tuple(getattr(weight, "shape", ())) != (hs,):
+            return None
+        eps = norm.get("eps")
+        how.update(norm_weight=weight, eps=RMS_NORM_EPS if eps is None else float(pyval(eps)))
+        gone.append(i)
+    if not gone or (tm := _token_major(uses, p)) is None:
+        return None
+    return _Steps(tm[0], how, uses.bsyms[gone[-1]].region, gone + tm[1])
 
 
 def _match(uses: _Uses, b: dict):
     """What of the idiom stands in front of an attention call: the operands of
     the rewrite and the indices that go, or None.
 
-    Packed: q, k and v are the three slices that tile one ``linear``'s output.
-    Else q alone, straight from a ``linear`` of its own."""
+    Packed: q, k and v are the three slices that tile one ``linear``'s output,
+    q and k through the same steps. Else q alone, straight from a ``linear``
+    of its own."""
     q, k, v = b["query"], b["key"], b["value"]
-    rq = _roped(uses, q)
-    if rq is None or (tq := _token_major(uses, rq[0])) is None:
+    if (sq := _head_steps(uses, q)) is None:
         return None
-    gone = [rq[3], *tq[1]]
-    lin_at = uses.made_by(tq[0], "torch.linear")
+    lin_at = uses.made_by(sq.src, "torch.linear")
     if lin_at is not None:
-        return dict(lin=lin_at, q_tables=rq[1:3], gone=[*gone, lin_at])
+        return dict(lin=lin_at, q=sq, gone=[*sq.gone, lin_at])
 
-    rk = _roped(uses, k)
-    if rk is None or (tk := _token_major(uses, rk[0])) is None or (tv := _token_major(uses, v)) is None:
+    sk, tv = _head_steps(uses, k), _token_major(uses, v)
+    if sk is None or tv is None or sq.asked() != sk.asked():
         return None
-    slices = [_last_dim_slice(uses, s) for s in (tq[0], tk[0], tv[0])]
+    slices = [_last_dim_slice(uses, s) for s in (sq.src, sk.src, tv[0])]
     if any(s is None for s in slices) or len({s[0].name for s in slices}) != 1:
         return None
     lin = slices[0][0]
@@ -145,39 +194,51 @@ def _match(uses: _Uses, b: dict):
     tiles = [(s[1], s[2]) for s in slices] == [(0, H * hs), (H * hs, (H + G) * hs), ((H + G) * hs, lin.shape[-1])]
     if lin_at is None or not tiles or tuple(v.shape) != tuple(k.shape):
         return None
-    gone += [rk[3], *tk[1], *tv[1], *(s[3] for s in slices), lin_at]
-    return dict(lin=lin_at, q_tables=rq[1:3], k_tables=rk[1:3], gone=gone)
+    return dict(lin=lin_at, q=sq, k=sk, v_region=uses.bsyms[tv[1][0]].region,
+                gone=[*sq.gone, *sk.gone, *tv[1], *(s[3] for s in slices), lin_at])
 
 
 def _rewritten(trc: TraceCtx, uses: _Uses, at: int, b: dict, m: dict) -> list:
-    """The lines that take the attention call's place."""
+    """The lines that take the attention call's place, each in the region of
+    the line it stands for."""
     import thunder_tpu.torch as ltorch
 
-    sdpa = uses.bsyms[at]
+    call = uses.bsyms[at]
     q, k, v = b["query"], b["key"], b["value"]
     x, w, *bias = uses.bsyms[m["lin"]].args
     H, G = q.shape[1], k.shape[1]
     scale = float(pyval(b["scale"])) if b["scale"] is not None else 1.0 / math.sqrt(q.shape[-1])
+
+    def heads(packed, steps: _Steps, first, count, scale, split):
+        trc.region, how = steps.region, dict(steps.how)
+        return ltorch.apply_rope_heads(packed, how.pop("cos"), how.pop("sin"), first, count, scale, split, **how)
+
     with tracectx(trc):
-        region, trc.region = trc.region, sdpa.region
+        region = trc.region
         trc.push_scope(lines := [])
         try:
             split = 1
-            if "k_tables" in m:  # packed: k and v come out of the same projection
+            trc.region = uses.bsyms[m["lin"]].region
+            if "k" in m:  # packed: k and v come out of the same projection
                 split = heads_per_lane_group(q.shape[-1], H, G)
                 packed = ltorch.linear_heads(x, w, *bias, heads=(H + 2 * G) // split)
-                k = ltorch.apply_rope_heads(packed, *m["k_tables"], H, G, 1.0, split)
+                k = heads(packed, m["k"], H, G, 1.0, split)
+                trc.region = m["v_region"]
                 v = ltorch.split_heads(packed, H + G, G, split) if split > 1 else packed[:, H + G:]
             else:
                 packed = ltorch.linear_heads(x, w, *bias, heads=H)
-            q = ltorch.apply_rope_heads(packed, *m["q_tables"], 0, H, scale, split)
-            y = ltorch.scaled_dot_product_attention(q, k, v, is_causal=b["is_causal"], scale=1.0,
-                                                    enable_gqa=b["enable_gqa"])
+            q = heads(packed, m["q"], 0, H, scale, split)
+            trc.region = call.region
+            if call.sym.id == _SDPA:
+                y = ltorch.scaled_dot_product_attention(q, k, v, is_causal=b["is_causal"], scale=1.0,
+                                                        enable_gqa=b["enable_gqa"])
+            else:
+                y = ltorch.window_attention(q, k, v, window=b["window"], scale=1.0)
         finally:
             trc.pop_scope()
             trc.region = region
     # the result under the name its readers know
-    lines[-1] = lines[-1].from_bsym_swap_proxies({variableify(y): sdpa.output})
+    lines[-1] = lines[-1].from_bsym_swap_proxies({variableify(y): call.output})
     return lines
 
 
@@ -187,16 +248,16 @@ def fold_attention_layouts(trc: TraceCtx, executors) -> TraceCtx:
     executors = tuple(executors or ())
     names = {getattr(e, "name", None) for e in executors}
     ids = {str(b.sym.id) for b in trc.bound_symbols}
-    if not {"pallas", "flash"} <= names or _SDPA not in ids or any("_bwd" in i for i in ids):
+    if not {"pallas", "flash"} <= names or not {_SDPA, _WINDOW} & ids or any("_bwd" in i for i in ids):
         return trc
     start = time.perf_counter_ns()
     uses = _Uses(trc)
     put: dict[int, list] = {}
     gone: set[int] = set()
-    for at, sdpa in enumerate(uses.bsyms):
-        if sdpa.sym.id != _SDPA:
+    for at, call in enumerate(uses.bsyms):
+        if call.sym.id not in (_SDPA, _WINDOW):
             continue
-        b = _bound_sdpa(sdpa.args, sdpa.kwargs)
+        b = _bound_call(call)
         if b["attn_mask"] is not None or float(pyval(b["dropout_p"])) != 0.0:
             continue
         m = _match(uses, b)
