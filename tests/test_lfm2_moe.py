@@ -348,12 +348,12 @@ def test_the_grouped_matmuls_tiles_divide_the_width_where_a_multiple_of_the_lane
 
 def _bias_left_out_of_the_choice(monkeypatch, cfg, params):
     real = ttorch.moe_route
-    monkeypatch.setattr(ttorch, "moe_route", lambda x, w, k, g, tg, scale, bias, eps: real(x, w, k, g, tg, scale, None, eps))
+    monkeypatch.setattr(ttorch, "moe_route", lambda x, w, k, g, tg, scale, bias, eps, *how: real(x, w, k, g, tg, scale, None, eps, *how))
     return cfg, params
 
 
 def _bias_added_into_the_weights(monkeypatch, cfg, params):
-    def route(x, w, k, g, tg, scale, bias, eps):
+    def route(x, w, k, g, tg, scale, bias, eps, *how):  # how: the score function and normalising, this model's the defaults
         biased = ttorch.sigmoid(ttorch.linear(x.to(dtypes.float32), w.to(dtypes.float32))) + bias
         top_w, top_i = ttorch.topk(biased, k, -1)
         return top_i, top_w / (ttorch.sum(top_w, -1, True) + eps) * scale

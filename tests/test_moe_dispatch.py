@@ -49,6 +49,11 @@ def _one_expert_idle(rng):
     return _distinct(rng, 128, 4, [e for e in range(8) if e != 5])
 
 
+def _every_choice_held_here(n, k, held, offset=0):
+    """The router's worst case: every token's k choices among the ``held`` experts here."""
+    return lambda rng: _distinct(rng, n, k, range(offset, offset + held))
+
+
 CASES = {
     # name: (held, expert_offset, n_expert, top_i from an rng, lax.cond in the program, gmm poisoned)
     "every-expert-held-k-4-of-8": (8, 0, 8, _every_expert_held, False, False),
@@ -58,7 +63,16 @@ CASES = {
     "a-token-whose-every-choice-is-held-elsewhere": (3, 3, 12, _an_eighth_lands_here, True, False),
     "a-token-with-two-choices-held-here": (3, 3, 12, _an_eighth_lands_here, True, False),
     "rows-beyond-the-groups-poisoned-with-nan": (3, 3, 12, _an_eighth_lands_here, True, True),
+    # PR 40: the worst case goes over the short buffer in passes, and no buffer of min(k, held) * N rows exists:
+    # 16 of 768 outputs held and 12 a token (longcat-flash-omni.fwd-t16k's routed layer), every choice held here,
+    "worst-case-12-choices-all-among-16-held-of-768-in-3-passes": (16, 0, 768, _every_choice_held_here(128, 12, 16), True, False),
+    "worst-case-in-passes-with-nan-beyond-the-groups": (16, 0, 768, _every_choice_held_here(128, 12, 16), True, True),
+    # and a.x-k1's stand-in shapes (4 of 16 held from 4, 4 a token, 256 tokens): 1,024 rows through a buffer of 512.
+    "worst-case-axk1-stand-in-4-held-of-16-in-2-passes": (4, 4, 16, _every_choice_held_here(256, 4, 4, 4), True, False),
 }
+PASSES = {"3-of-12-held-at-an-offset-fits-the-short-buffer": 1, "3-of-12-held-one-row-over-the-short-buffer": 2,
+          "worst-case-12-choices-all-among-16-held-of-768-in-3-passes": 3, "worst-case-in-passes-with-nan-beyond-the-groups": 3,
+          "worst-case-axk1-stand-in-4-held-of-16-in-2-passes": 2}
 
 
 def _poison_rows_beyond_the_groups(monkeypatch):
@@ -100,6 +114,11 @@ def test_the_claimed_dispatch_is_the_composites_decomposition(case, monkeypatch)
         assert short == 1024 and here.sum() - short == (1 if "over" in case else 0)
     if case == "an-expert-with-no-rows":
         assert not (top_i == 5).any()
+    if case in PASSES:
+        assert pallasex.expert_buffer_passes(here.sum(), n, k, held, total) == PASSES[case]
+        assert pallasex.expert_buffer_rows(n, k, held, total) == 512 * (2 if case.startswith("3-of-12") else 1) < min(k, held) * n
+    if case.startswith("worst-case"):
+        assert here.all() and here.sum() == min(k, held) * n
     if poison:
         _poison_rows_beyond_the_groups(monkeypatch)
 
@@ -177,11 +196,15 @@ def test_the_dispatchs_program_for_the_tpu_moves_each_row_once_each_way(monkeypa
     assert gathers_of_rows(lines) == [f"tensor<{rows}x{C}xbf16>"] + [f"tensor<{n}x{C}xbf16>"] * k
 
     # Some experts held elsewhere: the only select on rows is the mask's, on bf16, a gather of (N, C) at a time
-    # (both buffers' branches call it); still no float32 of a buffer's shape.
+    # (the one pass and the loop of passes both call it); still no float32 of the buffer's shape, and since PR 40
+    # nothing at all of the worst case's rows: both branches work on the short buffer.
     lines = lines_of(4 * held)
     selects = [line for line in lines if "stablehlo.select" in line and f"x{C}xbf16>" in line]
     assert selects and all(f"tensor<{n}x{C}xbf16>" in line for line in selects)
-    short = 2 * k * n * held // (4 * held)
-    assert gathers_of_rows(lines) == sorted([f"tensor<{rows}x{C}xbf16>", f"tensor<{short}x{C}xbf16>"]
-                                            + [f"tensor<{n}x{C}xbf16>"] * 2 * k)
-    assert not [line for line in lines if "xf32>" in line and (f"{rows}x{C}x" in line or f"{short}x{C}x" in line)]
+    short = pallasex.expert_buffer_rows(n, k, held, 4 * held)
+    assert short == 2 * k * n * held // (4 * held) < rows
+    # the one pass brings its rows back by k gathers; a pass of the loop by one gather in a loop over the choices
+    assert gathers_of_rows(lines) == sorted([f"tensor<{short}x{C}xbf16>"] * 2 + [f"tensor<{n}x{C}xbf16>"] * (k + 1))
+    assert sum("stablehlo.while" in line for line in lines) >= 2
+    assert not [line for line in lines if f"<{rows}x{C}x" in line or f"<{rows}x{h}x" in line]
+    assert not [line for line in lines if f"tensor<{short}x{C}xf32>" in line]

@@ -303,9 +303,10 @@ def test_grouped_matmul_compiles_for_v5e_as_a_kernel_that_walks_the_groups(one_c
 def test_claimed_routed_experts_compile_for_v5e_with_the_short_buffer_and_the_worst_case(one_chip, monkeypatch):
     """a.x-k1.fwd's expert layer as the pallas executor claims it: 8192 tokens, 8
     choices among 192, 12 held experts of 7168 x 2048. Three megablox calls on
-    each of the two buffers (8192 rows, twice what an even router sends here,
-    and the worst case's 65536), chosen by one conditional on the count of rows
-    routed here."""
+    the short buffer (8192 rows, twice what an even router sends here) in each of
+    two branches, chosen by one conditional on the count of rows routed here:
+    once over the buffer, or, since PR 40, in passes over it, a loop; the worst
+    case's 65536 rows are no array's any more."""
     import jax
     import jax.numpy as jnp
 
@@ -317,19 +318,76 @@ def test_claimed_routed_experts_compile_for_v5e_with_the_short_buffer_and_the_wo
               "w_gate": ((12, 7168, 2048), "bfloat16"), "w_up": ((12, 7168, 2048), "bfloat16"),
               "w_down": ((12, 2048, 7168), "bfloat16")}
     assert pallasex._moe_experts_checker(*(SimpleNamespace(shape=s, dtype=getattr(dtypes, d)) for s, d in shapes.values()))
+    assert pallasex.expert_buffer_rows(8192, 8, 12, 192) == 8192 and pallasex.expert_buffer_passes(65536, 8192, 8, 12, 192) == 8
     sds = [jax.ShapeDtypeStruct(s, getattr(jnp, d), sharding=one_chip) for s, d in shapes.values()]
     compiled = jax.jit(lambda *a: pallasex._moe_experts_impl(*a, 0, 192)).lower(*sds).compile()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 6 and " conditional(" in text
-    assert all(f"bf16[{rows},7168]" in text for rows in (8192, 65536)) and "bf16[16384,7168]" not in text
-    # The way back: 8 gathers of (8192, 7168) that one fusion masks (a select, on bf16), weighs and sums, in either
-    # branch; no array of a buffer's size in float32 or stacked, and no fill after a gather.
+    assert text.count('custom_call_target="tpu_custom_call"') == 6 and " conditional(" in text and " while(" in text
+    assert "bf16[8192,7168]" in text and "[65536,7168]" not in text and "[65536,2048]" not in text and "bf16[16384,7168]" not in text
+    # The way back of the one pass: 8 gathers of (8192, 7168) that one fusion masks (a select, on bf16), weighs and
+    # sums; no array stacked, no fill after a gather; the only float32 of (N, C) is the passes' running sum.
     written = _arrays_written(text)
-    assert not [made for made in written if made[1].startswith("f32[") and made[1].endswith(",7168]")]
+    assert "f32[65536," not in text and "f32[8,8192,7168]" not in text
     assert not [made for made in written if made[1] in ("bf16[8,8192,7168]", "bf16[8192,8,7168]")]
     assert "broadcast_select_fusion" not in dict(written)
-    assert written.count(("fusion", "bf16[8192,7168]")) == 1 + 8 + 8  # into the short buffer, and back from either
-    assert written.count(("add_convert_fusion", "bf16[8192,7168]")) == 2
+    assert written.count(("add_convert_fusion", "bf16[8192,7168]")) == 1  # the one pass's sum; the passes' is a loop's
+
+
+def test_claimed_routed_experts_of_longcat_go_over_their_buffer_in_passes_and_hold_no_worst_case(one_chip, monkeypatch):
+    """longcat-flash-omni.fwd-t16k's routed layer as the pallas executor claims
+    it: 16,384 tokens, 12 choices among 768 outputs, 16 held experts of 6144 x
+    2048. The worst case is 196,608 rows (8 GB of temporaries, ISSUE 40); the
+    buffer is 8,192, gone over once or in a loop of passes, and nothing in the
+    compiled program has the worst case's rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from perfbench.run import executable_needs
+    from thunder_tpu.core import dtypes
+    from thunder_tpu.executors import pallasex
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    shapes = {"x": ((16384, 6144), "bfloat16"), "top_i": ((16384, 12), "int32"), "top_w": ((16384, 12), "float32"),
+              "w_gate": ((16, 6144, 2048), "bfloat16"), "w_up": ((16, 6144, 2048), "bfloat16"),
+              "w_down": ((16, 2048, 6144), "bfloat16")}
+    assert pallasex._moe_experts_checker(*(SimpleNamespace(shape=s, dtype=getattr(dtypes, d)) for s, d in shapes.values()))
+    assert pallasex.expert_buffer_rows(16384, 12, 16, 768) == 8192
+    sds = [jax.ShapeDtypeStruct(s, getattr(jnp, d), sharding=one_chip) for s, d in shapes.values()]
+    compiled = jax.jit(lambda *a: pallasex._moe_experts_impl(*a, 0, 768)).lower(*sds).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6 and " conditional(" in text and text.count(" while(") >= 2
+    assert "bf16[8192,6144]" in text and "[196608,6144]" not in text and "[196608,2048]" not in text
+    needs, sizes = executable_needs(compiled)
+    assert sizes["temp_size_in_bytes"] < 4.0e9  # the k gathers of (N, C) of the one pass, 2.4 GB, are the most of it
+
+
+def test_the_cell_of_longcat_compiles_for_v5e_with_no_array_of_the_worst_cases_rows(one_chip, monkeypatch):
+    """longcat-flash-omni.fwd-t16k's program at depth 1, as the cell's job lowers
+    it (the dispatcher's pass applied): 7 claimed kernels' calls and the routed
+    layer's two branches; no array of 196,608 rows among its instructions; the
+    regions the cell's readers ask for are in its text."""
+    from perfbench import manifest
+    from perfbench.jobs import forward_scmoe
+    from perfbench.layer_metrics import _regions
+    from perfbench.run import executable_needs
+    from thunder_tpu.executors import flashex, pallasex
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    monkeypatch.setattr(flashex, "_interpret", lambda: False)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    cell = manifest.load_cell("longcat-flash-omni.fwd-t16k")
+    keys = manifest.published(cell)
+    keys.update(num_hidden_layers=1)
+    topo = SimpleNamespace(devices=[next(iter(one_chip.device_set))])
+    compiled = forward_scmoe.lower_for(cell, keys, cell.traffic["batch"], cell.traffic["seq"], topo).compile()
+    text = compiled.as_text()
+    assert "196608,6144]" not in text and "196608,2048]" not in text and "bf16[8192,6144]" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * 3 + 2 * 3  # q's rope, k's rope, attention a sublayer; gmm thrice a branch
+    assert " conditional(" in text and " while(" in text
+    needs, sizes = executable_needs(compiled)
+    assert sizes["temp_size_in_bytes"] < 5.0e9 and needs < 8.0e9
+    found = _regions.of_instructions(forward_scmoe.forward_window_moe.an_instruction_a_line(text), forward_scmoe.REGIONS)
+    assert set(found.values()) == set(forward_scmoe.REGIONS)
 
 
 def test_claimed_routed_experts_compile_for_v5e_on_one_buffer_where_every_expert_is_held(one_chip, monkeypatch):

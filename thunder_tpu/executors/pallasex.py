@@ -595,13 +595,19 @@ ex.register_implementation("torch.split_heads", fn=_split_heads_impl, checker=_s
 # buffer is what costs: at 12 of 192 experts held a sixteenth of its rows hold
 # work, and XLA's gather of 65,536 rows of 7168 takes 13.9 ms a layer where the
 # grouped matmuls take 5 to 6 (PERF.md, PR 27). Claimed here, the same steps run
-# on a short buffer when the rows routed here fit it, and on the worst case when
-# they do not: one lax.cond on a count the router just made, so no token is ever
-# dropped and no call fails. The short buffer is twice what an even router sends
-# here, 2 * k * N * held / n_expert rows; where that is no shorter than the
-# worst case (every expert held: mixtral) there is one buffer and no branch. The
-# grouped matmul is megablox's gmm (30.2 ms a call of a.x-k1.fwd against 37.4
-# for XLA's own ragged dot, same seed).
+# on a short buffer, twice what an even router sends here, 2 * k * N * held /
+# n_expert rows (expert_buffer_rows). When the rows routed here fit it, once;
+# when they do not, in passes over that same buffer (PR 40): a loop over
+# ceil(rows here / buffer) chunks of the sorted pairs, each chunk's group sizes
+# clipped to it, its rows weighed into a float32 (N, C) sum. One lax.cond on a
+# count the router just made chooses, so no token is ever dropped, no call
+# fails, and nothing is sized by the worst case: at 16 of 768 outputs held, 12 a
+# token and 16,384 tokens the worst case was 196,608 rows, 8 GB of temporaries
+# for a branch an even router never takes, where the buffer is 8,192 rows.
+# Where the short buffer is no shorter than the worst case (every expert held:
+# mixtral) there is one buffer and no branch. The grouped matmul is megablox's
+# gmm (30.2 ms a call of a.x-k1.fwd against 37.4 for XLA's own ragged dot, same
+# seed).
 #
 # The dispatch moves each row once each way (PR 32). The pairs lie choice-major
 # (pair j * N + n), so the way back is k gathers of (N, C) and never a (rows, C)
@@ -644,6 +650,20 @@ def _moe_experts_checker(x, top_i, top_w, w_gate, w_up, w_down, expert_offset=0,
     return (min(k, held) * N) % _GMM_TILING[0] == 0 and C % _LANE == 0 and H % _LANE == 0
 
 
+def expert_buffer_rows(N: int, k: int, held: int, n_expert=None) -> int:
+    """Rows of the buffer the claimed ``moe_experts`` works on for N tokens of k
+    choices among ``n_expert`` outputs, ``held`` of them here: twice the even
+    load in whole row tiles, and never more than the worst case."""
+    even = -(-k * N * held // (n_expert or held))  # the rows an even router sends here
+    short = -(-_SHORT_BUFFER_OVER_EVEN_LOAD * even // _GMM_TILING[0]) * _GMM_TILING[0]
+    return min(short, min(k, held) * N)
+
+
+def expert_buffer_passes(rows_here: int, N: int, k: int, held: int, n_expert=None) -> int:
+    """How often the claimed ``moe_experts`` goes over its buffer when the router sends ``rows_here`` rows here."""
+    return max(1, -(-int(rows_here) // expert_buffer_rows(N, k, held, n_expert)))
+
+
 def _moe_experts_impl(x, top_i, top_w, w_gate, w_up, w_down, expert_offset=0, n_expert=None):
     chaos.kernel_seam("pallas", "moe_experts")
     import jax
@@ -651,10 +671,8 @@ def _moe_experts_impl(x, top_i, top_w, w_gate, w_up, w_down, expert_offset=0, n_
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     (N, C), k, held = x.shape, top_i.shape[1], w_gate.shape[0]
-    full = min(k, held) * N
     tm = _GMM_TILING[0]
-    even = -(-k * N * held // (n_expert or held))  # the rows an even router sends here
-    short = -(-_SHORT_BUFFER_OVER_EVEN_LOAD * even // tm) * tm
+    rows = expert_buffer_rows(N, k, held, n_expert)
 
     def grouped(a, b, sizes):
         return gmm(a, b, sizes, preferred_element_type=a.dtype,
@@ -673,28 +691,57 @@ def _moe_experts_impl(x, top_i, top_w, w_gate, w_up, w_down, expert_offset=0, n_
         sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32), axis=0, dtype=jnp.int32)
         weight = top_w.astype(jnp.float32)
 
-        def on_buffer_of(rows):
-            def run():
-                xs = x.at[order[:rows] % N].get(mode=in_bounds)
-                gate = grouped(xs, w_gate, sizes).astype(jnp.float32)
-                h = (jax.nn.silu(gate) * grouped(xs, w_up, sizes).astype(jnp.float32)).astype(x.dtype)
-                ys = grouped(h, w_down, sizes)
-                came = jnp.minimum(slot, rows - 1).reshape(k, N)
-                out = 0.0
-                for j in range(k):
-                    back = ys.at[came[j]].get(mode=in_bounds)
-                    if masked:  # selected out, never multiplied: such a row may be NaN
-                        back = jnp.where(here[:, j, None], back, jnp.zeros((), back.dtype))
-                    out = out + back.astype(jnp.float32) * weight[:, j, None]
-                # The barrier keeps the k converts in the sum's fusion: a reshape after the call draws the sum into
-                # the caller's next fusion otherwise and leaves each behind, a float32 (N, C) written and read.
-                return jax.lax.optimization_barrier(out.astype(x.dtype))
+        def experts(xs, sizes):
+            gate = grouped(xs, w_gate, sizes).astype(jnp.float32)
+            h = (jax.nn.silu(gate) * grouped(xs, w_up, sizes).astype(jnp.float32)).astype(x.dtype)
+            return grouped(h, w_down, sizes)
 
-            return run
+        def weighed(ys, came, keep, out):
+            """``out`` plus the k gathers of ys (rows, C) by came (k, N), each weighed in float32; ``keep`` (N, k)
+            or None selects, never multiplies: a row the grouped matmul did not compute may be NaN."""
+            for j in range(k):
+                back = ys.at[came[j]].get(mode=in_bounds)
+                if keep is not None:
+                    back = jnp.where(keep[:, j, None], back, jnp.zeros((), back.dtype))
+                out = out + back.astype(jnp.float32) * weight[:, j, None]
+            return out
 
-        if short >= full:
-            return on_buffer_of(full)()
-        return jax.lax.cond(jnp.sum(sizes) <= short, on_buffer_of(short), on_buffer_of(full))
+        def once():
+            ys = experts(x.at[order[:rows] % N].get(mode=in_bounds), sizes)
+            out = weighed(ys, jnp.minimum(slot, rows - 1).reshape(k, N), here if masked else None, 0.0)
+            # The barrier keeps the k converts in the sum's fusion: a reshape after the call draws the sum into
+            # the caller's next fusion otherwise and leaves each behind, a float32 (N, C) written and read.
+            return jax.lax.optimization_barrier(out.astype(x.dtype))
+
+        def in_passes():
+            """The rows routed here are more than the buffer: chunk p is the sorted pairs [p rows, (p + 1) rows),
+            an expert's group in it what of its run of the sorted pairs lies inside. A chunk's rows go back a choice
+            at a time, in a loop, so that one gather of (N, C) is alive beside the float32 sum and not k of them
+            (2.4 GB at 12 choices of 16,384 tokens of 6144). The sum adds a token's k terms in another order than
+            ``once`` does, and nothing else differs."""
+            ends = jnp.cumsum(sizes)
+            at, kept, by_choice = slot.reshape(k, N), here.T, weight.T
+
+            def one(p, out):
+                lo = p * rows
+                inside = jnp.clip(ends, lo, lo + rows) - jnp.clip(ends - sizes, lo, lo + rows)
+                pairs = order.at[jnp.minimum(lo + jnp.arange(rows, dtype=jnp.int32), k * N - 1)].get(mode=in_bounds)
+                ys = experts(x.at[pairs % N].get(mode=in_bounds), inside)
+
+                def one_choice(j, out):
+                    back = ys.at[jnp.clip(at[j] - lo, 0, rows - 1)].get(mode=in_bounds)
+                    keep = kept[j] & (at[j] >= lo) & (at[j] < lo + rows)  # selected out, never multiplied: may be NaN
+                    back = jnp.where(keep[:, None], back, jnp.zeros((), back.dtype))
+                    return out + back.astype(jnp.float32) * by_choice[j][:, None]
+
+                return jax.lax.fori_loop(0, k, one_choice, out)
+
+            out = jax.lax.fori_loop(0, -(-ends[-1] // rows), one, jnp.zeros((N, C), jnp.float32))
+            return jax.lax.optimization_barrier(out.astype(x.dtype))
+
+        if rows == min(k, held) * N:
+            return once()
+        return jax.lax.cond(jnp.sum(sizes) <= rows, once, in_passes)
 
 
 ex.register_implementation("torch.moe_experts", fn=_moe_experts_impl, checker=_moe_experts_checker)

@@ -20,7 +20,11 @@ family's scalings of the embedding, the residual and the head's input; and
 Trinity-Mini's (``afmoe``): two attention kinds of one head layout that differ
 by layer in mask and rope (``"sliding_attention"``: causal within
 ``sliding_window`` keys, roped; ``"full_attention"``: causal, no rope), and a
-norm after each sublayer beside the one before it (``sandwich_norms``).
+norm after each sublayer beside the one before it (``sandwich_norms``); and
+LongCat-Flash's (``"ShortcutMoE"``): a double layer of two latent-attention
+sublayers and two dense FFNs with one routed layer on a shortcut across them,
+whose softmax router scores zero-compute (identity) experts beside the real ones
+(``zero_expert_num``) and leaves the chosen weights as they are.
 
 TPU-first design: the model is a *pure function* ``forward(params, idx)``
 over a params pytree — no module object, no buffers, no in-place state. That
@@ -67,7 +71,10 @@ class GPTConfig:
     bias: bool = True
     norm_class: str = "LayerNorm"  # or "RMSNorm"
     norm_eps: float = 1e-5
-    mlp_class: str = "GptNeoxMLP"  # or "LLaMAMLP" / "MoEMLP" / "SharedRoutedMoE"
+    # "GptNeoxMLP" (GELU), "LLaMAMLP" (SwiGLU), "MoEMLP" (mixtral-style), "SharedRoutedMoE" (a router over experts of
+    # which a share may be held, beside shared ones) or "ShortcutMoE": no MLP kind but a block's, the double layer
+    # whose two sublayers have a dense SwiGLU each and share one routed layer (_shortcut_block).
+    mlp_class: str = "GptNeoxMLP"
     intermediate_size: Optional[int] = None
     rope_base: int = 10000
     # MoE (mlp_class="MoEMLP", mixtral-style SwiGLU experts: softmax over the
@@ -80,6 +87,12 @@ class GPTConfig:
     n_expert_groups: int = 1  # the router picks from the best n_limited_groups of these
     n_limited_groups: int = 1
     routed_scaling_factor: float = 1.0
+    # The router's scores: "sigmoid", or "softmax" over all its outputs; the chosen scores over their sum, or
+    # (norm_topk_prob False) as they are. zero_expert_num outputs beyond the n_expert real ones are zero-compute
+    # experts: a chosen one adds its weight times the router's input itself (zero_expert_type "identity").
+    scoring_func: str = "sigmoid"
+    norm_topk_prob: bool = True
+    zero_expert_num: int = 0
     # Layers [0, first_dense_layers) keep the dense LLaMAMLP at mlp_hidden; the
     # rest have mlp_class. The per-layer setting the registry has.
     first_dense_layers: int = 0
@@ -98,6 +111,10 @@ class GPTConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_interleaved: bool = False  # published pairs are (x0,x1),(x2,x3)..: de-interleaved, then rotate-half
+    # q after its up-projection times sqrt(n_embd / q_lora_rank); the normed kv latent times
+    # sqrt(n_embd / kv_lora_rank) before its up-projection (the shared rope key does not carry it).
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     # YaRN, or None for the plain rope: (factor, original_max_position_embeddings,
     # beta_fast, beta_slow, mscale, mscale_all_dim)
     yarn: Optional[tuple] = None
@@ -206,11 +223,18 @@ class GPTConfig:
     @property
     def softmax_scale(self) -> Optional[float]:
         """None is sdpa's own ``D**-0.5``. Latent attention under YaRN
-        multiplies it by ``m**2``, ``m = 0.1 * mscale_all_dim * ln(factor) + 1``."""
+        multiplies it by ``m**2``, ``m = 0.1 * mscale_all_dim * ln(factor) + 1``.
+        ``mla_scale_q_lora``'s factor on q rides here: ``(a q) . k`` is ``a (q . k)``,
+        so no activation and no weight is rounded for it."""
         if self.attention_class != "MLA":
             return None
         m = _yarn_mscale(self.yarn[0], self.yarn[5]) if self.yarn else 1.0
-        return self.qk_head_dim ** -0.5 * m * m
+        q_lora = (self.n_embd / self.q_lora_rank) ** 0.5 if self.mla_scale_q_lora else 1.0
+        return self.qk_head_dim ** -0.5 * m * m * q_lora
+
+    @property
+    def router_outputs(self) -> int:
+        return self.n_expert + self.zero_expert_num
 
 
 configs: dict[str, GPTConfig] = {}
@@ -378,6 +402,30 @@ _add(GPTConfig(name="trinity-tiny", block_size=256, vocab_size=96, padded_vocab_
                sliding_window=16, attn_rope=False, qk_norm=True, attn_output_gate=True,
                sandwich_norms=True, router_bias=True, router_norm_eps=1e-20, embedding_scale=256 ** 0.5))
 
+# LongCat-Flash-Omni's language model (huggingface.co/meituan-longcat/LongCat-Flash-Omni, Meituan's 560B-A27B) at
+# its published sizes: 28 double layers, each two latent-attention sublayers (64 heads of 128 + 64 rope and 128,
+# both latents' scales, rope base 1e7, no YaRN) with a dense SwiGLU of 12288 each and one routed layer on a
+# shortcut across them: a softmax router over 512 experts of 2048 and 256 zero-compute ones, 12 a token by score
+# plus a bias, weighed 6 times the score, not renormalised; no shared expert; an untied head.
+_add(GPTConfig(name="LongCat-Flash-Omni", block_size=131072, vocab_size=131072, padded_vocab_size=131072,
+               n_layer=28, n_head=64, n_embd=6144, rotary_percentage=1.0, parallel_residual=False,
+               bias=False, norm_class="RMSNorm", norm_eps=1e-5, mlp_class="ShortcutMoE",
+               intermediate_size=12288, rope_base=10000000, n_expert=512, n_expert_per_token=12,
+               moe_intermediate_size=2048, routed_scaling_factor=6.0, scoring_func="softmax",
+               norm_topk_prob=False, zero_expert_num=256, router_bias=True, attention_class="MLA",
+               q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+               v_head_dim=128, rope_interleaved=True, mla_scale_q_lora=True, mla_scale_kv_lora=True))
+# The same blocks at test size: 16 experts and 8 zero-compute ones, 4 a token, a share of 4 held from 4.
+_add(GPTConfig(name="longcat-tiny", block_size=64, vocab_size=96, padded_vocab_size=96,
+               n_layer=2, n_head=2, n_embd=32, rotary_percentage=1.0, parallel_residual=False,
+               bias=False, norm_class="RMSNorm", norm_eps=1e-5, mlp_class="ShortcutMoE",
+               intermediate_size=64, rope_base=10000000, n_expert=16, n_expert_per_token=4,
+               moe_intermediate_size=16, routed_scaling_factor=6.0, scoring_func="softmax",
+               norm_topk_prob=False, zero_expert_num=8, router_bias=True, experts_held=4, expert_offset=4,
+               attention_class="MLA", q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16,
+               qk_rope_head_dim=8, v_head_dim=16, rope_interleaved=True, mla_scale_q_lora=True,
+               mla_scale_kv_lora=True))
+
 # Falcon family — MQA (one KV head) + shared-attention-norm parallel residual
 # (the litgpt registry's falcon geometry; reference tests run falcon-7b-like
 # configs through thunder).
@@ -499,11 +547,11 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
             # Routed experts are stored (expert, in, out), the grouped matmul's
             # layout, and only the held ones: the share is configuration.
             E, H = C.held_experts, C.expert_hidden
-            p = {"router_w": w(C.n_expert, C.n_embd),
+            p = {"router_w": w(C.router_outputs, C.n_embd),
                  "experts_gate": w(E, C.n_embd, H), "experts_up": w(E, C.n_embd, H),
                  "experts_down": w(E, H, C.n_embd, std=0.02 / np.sqrt(2 * C.n_layer))}
             if C.router_bias:  # a buffer: float32 whatever the weights are, and no gradient reaches it
-                p["router_bias"] = jnp.zeros((C.n_expert,), dtype=jnp.float32)
+                p["router_bias"] = jnp.zeros((C.router_outputs,), dtype=jnp.float32)
             if C.n_shared_experts:
                 p["shared"] = swiglu_params(C.n_shared_experts * H)
             return p
@@ -516,6 +564,11 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
         return p
 
     def block_params(i):
+        if C.layer_mlp_class(i) == "ShortcutMoE":
+            # Two sublayers, each a sequential block's leaves with a dense SwiGLU, and the one routed layer.
+            sub = lambda: {"norm_1": norm_params(), "attn": attn_params(C.n_head, C.query_groups, False),
+                           "norm_2": norm_params(), "mlp": swiglu_params(C.mlp_hidden)}
+            return {"sub_0": sub(), "sub_1": sub(), "moe": mlp_params("SharedRoutedMoE")}
         p: dict[str, Any] = {"norm_1": norm_params(), "mlp": mlp_params(C.layer_mlp_class(i))}
         mixer = C.layer_mixer(i)
         if mixer == "conv":
@@ -751,6 +804,8 @@ def _mla_attention(x, p, cos, sin, config: GPTConfig):
     q = ttorch.permute(ttorch.reshape(ttorch.linear(c_q, q_w), (B, T, H, dr + dn)), (0, 2, 1, 3))
     kv_a = ttorch.linear(x, kv_a_w)                                          # (B, T, L + dr)
     c_kv = ttorch.rms_norm(kv_a[..., :L], (L,), p["kv_a_norm"]["weight"], eps=config.norm_eps)
+    if config.mla_scale_kv_lora:  # on the activation: no power of two, so folded into a bf16 weight it would round otherwise
+        c_kv = c_kv * (C / L) ** 0.5
     k_pe = ttorch.reshape(kv_a[..., L:], (B, 1, T, dr))                      # one rope key for all heads
     kv = ttorch.permute(ttorch.reshape(ttorch.linear(c_kv, p["kv_b_w"]), (B, T, H, dn + dv)), (0, 2, 1, 3))
 
@@ -786,19 +841,24 @@ def _moe_mlp(x, p, config: GPTConfig):
 def _shared_routed_moe(x, p, config: GPTConfig, counts=None):
     """``SwiGLU_shared(x) + sum_i w_i SwiGLU_i(x)`` over the chosen experts
     held here (``experts_held`` from ``expert_offset``): the router scores all
-    ``n_expert``, normalises over all k chosen, and this chip adds its own
-    experts' part. ``counts`` collects, a layer, the rows each held expert got
-    (``"rows"``) and, where the router has a bias, the (token, choice) pairs
-    whose expert the same router without its bias does not choose (``"changed"``)."""
+    its outputs, weighs all k chosen, and this chip adds its own experts' part.
+    A chosen zero-compute expert (an output past ``n_expert``) weighs ``x``
+    itself: that term is the token's own chip's to add, whole, as a shared
+    expert's is. ``counts`` collects, a layer, the rows each held expert got
+    (``"rows"``), where the router has a bias the (token, choice) pairs whose
+    expert the same router without its bias does not choose (``"changed"``),
+    and where it has zero-compute experts the pairs that chose one (``"zero"``)."""
     B, T, C = x.shape
     xf = ttorch.reshape(x, (B * T, C))
 
     def route(bias):
         return ttorch.moe_route(xf, p["router_w"], config.n_expert_per_token, config.n_expert_groups,
-                                config.n_limited_groups, config.routed_scaling_factor, bias, config.router_norm_eps)
+                                config.n_limited_groups, config.routed_scaling_factor, bias, config.router_norm_eps,
+                                config.scoring_func, config.norm_topk_prob)
 
     with region("moe.route"):
         top_i, top_w = route(p.get("router_bias"))
+    zero = top_i >= config.n_expert if config.zero_expert_num else None
     if counts is not None:
         held = ttorch.arange(config.expert_offset, config.expert_offset + config.held_experts,
                              device=x.device, dtype=top_i.dtype)
@@ -806,9 +866,16 @@ def _shared_routed_moe(x, p, config: GPTConfig, counts=None):
         if "router_bias" in p:
             kept = ttorch.unsqueeze(top_i, -1) == ttorch.unsqueeze(route(None)[0], 1)        # (N, k, k)
             counts["changed"].append(top_i.shape[0] * top_i.shape[1] - ttorch.sum(kept.to(dtypes.int32), (0, 1, 2)))
+        if zero is not None:
+            counts["zero"].append(ttorch.sum(zero.to(dtypes.int32), (0, 1)))
     with region("moe.experts"):
+        # The router's output count, zero-compute experts among it, is what an even load is reckoned over.
         out = ttorch.moe_experts(xf, top_i, top_w, p["experts_gate"], p["experts_up"], p["experts_down"],
-                                 config.expert_offset, config.n_expert)
+                                 config.expert_offset, p["router_w"].shape[0])
+    if zero is not None:
+        with region("moe.zero"):
+            weight = ttorch.sum(ttorch.where(zero, top_w, 0.0), -1, True)                      # (N, 1) float32
+            out = out + (xf.float() * weight).to(x.dtype)
     if config.n_shared_experts:
         with region("moe.shared"):
             out = out + _swiglu(xf, p["shared"])
@@ -842,7 +909,26 @@ def _mix(x, p, cos, sin, config: GPTConfig, layer: int = 0, counts=None):
     return _attention(x, p["attn"], cos, sin, config, window=config.layer_mixer(layer) == "sliding_attention")
 
 
+def _shortcut_block(x, p, cos, sin, config: GPTConfig, counts=None, layer: int = 0):
+    """LongCat-Flash's double layer: two sublayers of a mixer and a dense SwiGLU,
+    and one routed layer that reads the first sublayer's normed output and joins
+    the residual only after the second sublayer's FFN (in a deployment the
+    experts' exchange runs behind the dense FFN and the second mixer):
+
+        h1 = x + Mix_0(N(x));  m = N(h1);  s = Routed(m);  h2 = h1 + FFN_0(m)
+        h3 = h2 + Mix_1(N(h2));  y = h3 + FFN_1(N(h3)) + s"""
+    a, b = p["sub_0"], p["sub_1"]
+    h1 = x + _mix(_norm(x, a["norm_1"], config), a, cos, sin, config, layer, counts)
+    m = _norm(h1, a["norm_2"], config)
+    shortcut = _shared_routed_moe(m, p["moe"], config, counts)
+    h2 = h1 + _swiglu(m, a["mlp"])
+    h3 = h2 + _mix(_norm(h2, b["norm_1"], config), b, cos, sin, config, layer, counts)
+    return h3 + (_swiglu(_norm(h3, b["norm_2"], config), b["mlp"]) + shortcut)
+
+
 def _block(x, p, cos, sin, kind: str, config: GPTConfig, counts=None, layer: int = 0):
+    if kind == "ShortcutMoE":
+        return _shortcut_block(x, p, cos, sin, config, counts, layer)
     scaled = (lambda y: y) if config.residual_scale == 1.0 else (lambda y: y * config.residual_scale)
     after = (lambda y, which: _norm(y, p[which], config)) if config.sandwich_norms else (lambda y, which: y)
     n1 = _norm(x, p["norm_1"], config)
@@ -885,12 +971,15 @@ def forward(params: dict, idx, config: GPTConfig, last: Optional[int] = None):
 def router_counts(params: dict, idx, config: GPTConfig):
     """What the routers of the expert layers do with these ids, by the program's
     own count: ``(rows (expert layers, experts held), changed (expert layers,)
-    or None)``. ``rows`` are the (token, choice) pairs sent to each expert held
-    here, which is what its grouped matmuls compute; ``changed``, where the
-    router has a bias, the pairs whose expert the router without it leaves out."""
-    counts: dict = {"rows": [], "changed": []}
+    or None)`` and, for a router with zero-compute experts, ``zero (expert
+    layers,)`` as a third. ``rows`` are the (token, choice) pairs sent to each
+    expert held here, which is what its grouped matmuls compute; ``changed``,
+    where the router has a bias, the pairs whose expert the router without it
+    leaves out; ``zero`` the pairs that chose a zero-compute expert."""
+    counts: dict = {"rows": [], "changed": [], "zero": []}
     _hidden(params, idx, config, counts)
-    return ttorch.stack(counts["rows"], 0), (ttorch.stack(counts["changed"], 0) if counts["changed"] else None)
+    out = (ttorch.stack(counts["rows"], 0), ttorch.stack(counts["changed"], 0) if counts["changed"] else None)
+    return out + (ttorch.stack(counts["zero"], 0),) if counts["zero"] else out
 
 
 def sparse_selection_counts(params: dict, idx, config: GPTConfig):
@@ -898,7 +987,7 @@ def sparse_selection_counts(params: dict, idx, config: GPTConfig):
     count: (sparse layers, B, key-value heads, T // SPARSE_TILE) int32, the distinct
     blocks the queries of each tile of ``SPARSE_TILE`` consecutive positions
     chose between them (forced blocks included; ``sparse_topk`` if they all agree)."""
-    counts: dict = {"rows": [], "changed": [], "tile_union": []}
+    counts: dict = {"rows": [], "changed": [], "zero": [], "tile_union": []}
     _hidden(params, idx, config, counts)
     return ttorch.stack(counts["tile_union"], 0)
 

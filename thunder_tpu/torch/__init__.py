@@ -1452,24 +1452,32 @@ def short_conv(bcu, w):
 
 @torchsymbol(id="torch.moe_route")
 def moe_route(x, router_w, top_k: int, n_group: int = 1, topk_group: int = 1,
-              routed_scaling_factor: float = 1.0, bias=None, norm_eps: float = 1e-20):
-    """Sigmoid scores with group-limited top-k (the DeepSeek-V3 family's
-    router; LFM2's with ``bias``): x (N, C), router_w (E, C) ->
-    ``(top_i (N, k) int64, top_w (N, k) float32)``.
+              routed_scaling_factor: float = 1.0, bias=None, norm_eps: float = 1e-20,
+              scoring_func: str = "sigmoid", norm_topk_prob: bool = True):
+    """A router's choice and weights: x (N, C), router_w (E, C) ->
+    ``(top_i (N, k) int64, top_w (N, k) float32)``. E counts every output of
+    the router, whatever stands behind one: an expert held here or elsewhere,
+    or a zero-compute expert that ``moe_experts`` never sees.
 
-    Scores are ``sigmoid(float32(x) float32(W)^T)``. The E experts lie in
-    ``n_group`` groups; a group's score is the sum of its two best scores; the
-    best ``topk_group`` groups stay, the others' scores are masked to 0, and
-    the top ``k`` of what is left are chosen. ``bias`` (E,) float32, where
-    given, is added to the scores for the choice, groups' and experts' alike,
-    and takes no part in the weights. The weights are the unmasked, unbiased
-    scores of the chosen, over their sum plus ``norm_eps`` and multiplied by
-    ``routed_scaling_factor``. Float32 throughout, as published:
-    a bf16 score flips near-tied choices."""
+    ``scoring_func`` says what a score is: ``"sigmoid"`` of each logit
+    ``float32(x) float32(W)^T`` by itself (the DeepSeek-V3 family's router;
+    LFM2's and Trinity's with ``bias``), or ``"softmax"`` over all E logits
+    (LongCat-Flash's: a score is a share of 1, so every output takes part in
+    every other's). The E outputs lie in ``n_group`` groups; a group's score is
+    the sum of its two best scores; the best ``topk_group`` groups stay, the
+    others' scores are masked to 0, and the top ``k`` of what is left are
+    chosen. ``bias`` (E,) float32, where given, is added to the scores for the
+    choice, groups' and experts' alike, and takes no part in the weights. The
+    weights are the unmasked, unbiased scores of the chosen times
+    ``routed_scaling_factor``: over their sum plus ``norm_eps`` first
+    (``norm_topk_prob``), or unnormalised, as they are, where a token's weights
+    need not add up to the same for every token. Float32 throughout, as
+    published: a bf16 score flips near-tied choices."""
     N, E = x.shape[0], router_w.shape[0]
     check(E % n_group == 0, lambda: f"{E} experts do not divide into {n_group} groups")
-    scores = sigmoid(linear(clang.maybe_convert_to_dtype(x, dtypes.float32),
-                            clang.maybe_convert_to_dtype(router_w, dtypes.float32)))
+    check(scoring_func in ("sigmoid", "softmax"), lambda: f"moe_route: no scoring function {scoring_func!r}")
+    logits = linear(clang.maybe_convert_to_dtype(x, dtypes.float32), clang.maybe_convert_to_dtype(router_w, dtypes.float32))
+    scores = sigmoid(logits) if scoring_func == "sigmoid" else softmax(logits, -1)
     choose_from = scores if bias is None else scores + bias
     if n_group > 1:
         grouped = reshape(choose_from, (N, n_group, E // n_group))
@@ -1481,7 +1489,9 @@ def moe_route(x, router_w, top_k: int, n_group: int = 1, topk_group: int = 1,
         choose_from = reshape(where(keep, grouped, clang.full_like(grouped, 0.0)), (N, E))
     _, top_i = topk(choose_from, top_k, -1)
     top_w = take_along_dim(scores, top_i, 1)
-    return top_i, top_w / (sum(top_w, -1, True) + norm_eps) * routed_scaling_factor
+    if norm_topk_prob:
+        top_w = top_w / (sum(top_w, -1, True) + norm_eps)
+    return top_i, top_w * routed_scaling_factor
 
 
 @torchsymbol(id="torch.moe_experts")
@@ -1491,7 +1501,11 @@ def moe_experts(x, top_i, top_w, w_gate, w_up, w_down, expert_offset: int = 0, n
     (E_held, C, H) and ``w_down`` (E_held, H, C) are experts ``expert_offset``
     to ``expert_offset + E_held``. Returns (N, C):
     ``sum_i w_i SwiGLU_{e_i}(x)`` over a token's chosen experts held here.
-    What the other experts would add is their holder's to compute.
+    What the other experts would add is their holder's to compute: an index
+    outside ``[expert_offset, expert_offset + E_held)`` adds nothing here,
+    whether it names an expert held elsewhere or, past the real experts, one
+    that computes nothing (a zero-compute expert's term is the caller's to add).
+    ``top_w`` is taken as it is, normalised or not.
 
     The (token, choice) pairs are sorted by local expert, pairs of experts
     held elsewhere last; the tokens' rows are gathered in that order, each
@@ -1500,10 +1514,11 @@ def moe_experts(x, top_i, top_w, w_gate, w_up, w_down, expert_offset: int = 0, n
     the inverse permutation, weighted and summed in float32. The buffer has
     ``min(k, E_held) * N`` rows, the most a router that picks k distinct
     experts can send here: no token is ever dropped. Work follows the rows
-    routed here, not the buffer. ``n_expert`` is how many experts the router
-    chose among (``None``: those held, all of them): nothing here needs it, an
-    implementation may size a shorter buffer by the ``k * N * E_held /
-    n_expert`` rows an even router sends here, as long as it keeps every row."""
+    routed here, not the buffer. ``n_expert`` is how many outputs the router
+    chose among, zero-compute experts counted (``None``: those held, all of
+    them): nothing here needs it, an implementation may size a shorter buffer
+    by the ``k * N * E_held / n_expert`` rows an even router sends here and go
+    over it as often as the rows need, as long as it keeps every row."""
     N, C = x.shape
     k, held = top_i.shape[1], w_gate.shape[0]
     rows = builtins_min(k, held) * N
