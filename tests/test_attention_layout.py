@@ -1,7 +1,8 @@
 """transforms/attention_layout.py: q, k and v leave their projection
 head-major. The pass rewrites the idiom ``models/gpt.py::_attention`` writes
-(and the part of it that latent attention has), declines whatever it cannot
-prove is that idiom, and the rewritten program computes what was written."""
+(and the part of it that latent attention has, and ``_linear_attention``'s),
+declines whatever it cannot prove is that idiom, and the rewritten program
+computes what was written."""
 
 import math
 
@@ -9,8 +10,10 @@ import numpy as np
 import pytest
 
 import thunder_tpu
+import thunder_tpu.clang as clang
 import thunder_tpu.torch as ttorch
 from thunder_tpu.api import trace_program
+from thunder_tpu.core import dtypes
 from thunder_tpu.extend import resolve_executors
 from thunder_tpu.transforms import attention_layout
 from thunder_tpu.transforms.common import dce
@@ -43,13 +46,14 @@ def _heads_first(x, heads, hs):
 
 
 def _fused_qkv(H, G, hs, n, bias, layers=1, spare=0, q_read_twice=False, tables="bfloat16", norm=None, window=None,
-               normed_read_twice=False, **sdpa):
+               normed_read_twice=False, linear=None, **sdpa):
     """``_attention`` of ``models/gpt.py``, ``layers`` times over: (program, arguments).
     ``spare`` widens the projection by columns that no slice reads. ``n`` 0: no
     rope (the tables go unread). ``norm``: which of q and k pass through an
     ``rms_norm`` before the rope ("qk", or "q" alone), over a head's ``hs``
     features or, "qk_pairs", over pairs of them. ``window``: the call is
-    ``window_attention``."""
+    ``window_attention``. ``linear``: the call is ``linear_attention`` with a
+    decay a head and these keywords (``_linear_attention``'s site)."""
     width = (H + 2 * G) * hs + spare
     args = [_bf16(B, T, C), *_tables(n or hs, tables)]
     q_w, k_w = 1 + _bf16(hs, seed=11, scale=0.2), 1 + _bf16(hs, seed=12, scale=0.2)
@@ -74,7 +78,10 @@ def _fused_qkv(H, G, hs, n, bias, layers=1, spare=0, q_read_twice=False, tables=
             q_normed = q
             if n:
                 q, k = ttorch.apply_rope(q, cos, sin), ttorch.apply_rope(k, cos, sin)
-            if window:
+            if linear is not None:
+                decay = clang.tensor_from_sequence([0.25 * (h + 1) for h in range(H)], device=x.device, dtype=dtypes.float32)
+                y = ttorch.linear_attention(q, k, v, decay, **linear)
+            elif window:
                 y = ttorch.window_attention(q, k, v, window=window, **sdpa)
             else:
                 y = ttorch.scaled_dot_product_attention(q, k, v, **{"is_causal": True, "enable_gqa": G != H, **sdpa})
@@ -133,6 +140,12 @@ IDIOMS = {
     "lfm2_normed_heads_of_64_two_a_lane_group": (lambda: _fused_qkv(4, 2, 64, 64, bias=False, norm="qk"), 1, 2, False),
     "normed_heads_of_64_partial_rotary_under_a_window": (lambda: _fused_qkv(4, 4, 64, 16, bias=True, norm="qk", window=32), 1, 2, False),
     "roped_without_norm_under_a_window": (lambda: _fused_qkv(4, 2, 64, 64, bias=False, window=32), 1, 2, True),
+    # linear attention as a consumer (PR 41): MiniCPM-SALA's linear layers, q and k normed and roped, as many key heads as
+    # query heads; the call keeps its own scale, which it applies to its float32 output
+    "minicpm_sala_linear_layer_normed_and_roped_at_128": (lambda: _fused_qkv(8, 8, 128, 128, bias=False, norm="qk", linear={}), 1, 1, False),
+    "linear_normed_heads_of_64_with_a_scale_and_a_chunk_of_its_own": (lambda: _fused_qkv(4, 4, 64, 64, bias=False, norm="qk", linear=dict(scale=0.2, chunk=32)), 1, 2, False),
+    "linear_roped_without_norm": (lambda: _fused_qkv(4, 4, 64, 64, bias=False, linear=dict(chunk=64)), 1, 2, True),
+    "linear_normed_without_rope_two_layers": (lambda: _fused_qkv(2, 2, 128, 0, bias=True, norm="qk", layers=2, linear={}), 2, 1, False),
 }
 
 
@@ -148,10 +161,15 @@ def test_rewrites_the_idiom_and_computes_what_was_written(monkeypatch, idiom):
     assert src.count("jax_linear_heads(") == sites and src.count("pallas_apply_rope(") == (1 if latent else 0)
     assert src.count("pallas_apply_rope_heads(") == (sites if latent else 2 * sites)
     assert src.count("pallas_split_heads(") == (sites if split > 1 else 0)  # v, where a group's lanes hold several heads
-    call = "flash_window_attention(" if "window" in idiom else "flash_scaled_dot_product_attention("
-    assert src.count(call) == src.count("scale=1.0") == sites
+    if "linear" in idiom:  # nobody claims the consumer, so its decomposition follows q's head call, the first layer's last new line
+        front = "pallas_apply_rope_heads(".join(src.split("pallas_apply_rope_heads(")[:2])
+        assert "scale=1.0" not in src and "flash_" not in src  # both head calls take 1.0: the scale stays in the call
+    else:
+        call = "flash_window_attention(" if "window" in idiom else "flash_scaled_dot_product_attention("
+        assert src.count(call) == src.count("scale=1.0") == sites
+        front = src.split(call)[0]
     # no token-major q, k or v, no slice of the last dimension and no norm of its own is left in front of attention
-    assert "jax_transpose" not in src.split(call)[0] and "rsqrt" not in src.split(call)[0]
+    assert "jax_transpose" not in front and "rsqrt" not in front
     assert src.count("norm_weight=") == (2 * sites if "normed" in idiom else 0)
 
     with monkeypatch.context() as m:
@@ -163,8 +181,9 @@ def test_rewrites_the_idiom_and_computes_what_was_written(monkeypatch, idiom):
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     if same_bits:  # the scale is a power of two: scaling before the rounding or after it is the same bits
         assert np.array_equal(got, want)
-    else:
-        np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+    else:  # no softmax bounds a linear site's output (it reads in the tens here): the same tolerance at its scale
+        at = max(1.0, float(np.abs(want).max())) if "linear" in idiom else 1.0
+        np.testing.assert_allclose(got, want, atol=2e-2 * at, rtol=2e-2)
 
 
 def test_a_mosaic_claim_that_fails_later_runs_the_program_as_written():
@@ -183,26 +202,33 @@ def test_a_mosaic_claim_that_fails_later_runs_the_program_as_written():
     assert np.array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
 
 
+def _in_regions(inner, **region_of):
+    """``inner`` with each named ``ttorch`` symbol called inside its region, as ``models/gpt.py`` opens them."""
+    import unittest.mock as mock
+    from contextlib import ExitStack
+
+    from thunder_tpu.core.trace import region
+
+    def inside(name, fn):
+        def wrapped(*args, **kwargs):
+            with region(name):
+                return fn(*args, **kwargs)
+        return wrapped
+
+    def program(*a):
+        with ExitStack() as stack:
+            for symbol, name in region_of.items():
+                stack.enter_context(mock.patch.object(ttorch, symbol, inside(name, getattr(ttorch, symbol))))
+            return inner(*a)
+
+    return program
+
+
 def test_a_normed_sites_lines_decompose_into_the_program_as_written_and_keep_their_regions():
     """The norm-rope call's decomposition is ``split_heads -> rms_norm -> apply_rope -> mul``, each step only where
     the site has it, and it stays in the region of the norm it stands for: the attention call alone carries its own."""
-    from thunder_tpu.core.trace import region
-
     inner, args = _fused_qkv(8, 2, 128, 128, bias=False, norm="qk", window=32)
-
-    def program(*a):  # the regions as models/gpt.py::_attention opens them, by symbol
-        import unittest.mock as mock
-
-        def inside(name, fn):
-            def wrapped(*args, **kwargs):
-                with region(name):
-                    return fn(*args, **kwargs)
-            return wrapped
-
-        with mock.patch.object(ttorch, "rms_norm", inside("attn.qk_norm", ttorch.rms_norm)), \
-                mock.patch.object(ttorch, "window_attention", inside("attn.window", ttorch.window_attention)):
-            return inner(*a)
-
+    program = _in_regions(inner, rms_norm="attn.qk_norm", window_attention="attn.window")
     trc = _folded_trace(program, args)
     assert trc.tags[FOLDED] == 1
     lines = {(b.sym.id, b.args[3] if b.sym.id == "torch.apply_rope_heads" else None): b for b in trc.bound_symbols}
@@ -335,6 +361,12 @@ DECLINES = {
     "a_second_reader_of_the_normed_q": lambda: _folded_trace(*_fused_qkv(4, 4, 64, 16, bias=True, norm="qk", normed_read_twice=True)),
     "neither_norm_nor_rope": lambda: _folded_trace(*_fused_qkv(4, 4, 64, 0, bias=True)),
     "a_mask_on_a_normed_site": lambda: _folded_trace(*_masked(norm="qk")),
+    # linear attention's sites (PR 41) decline for the reasons the others do
+    "a_second_reader_of_a_linear_sites_q": lambda: _folded_trace(*_fused_qkv(4, 4, 64, 64, bias=False, norm="qk", linear={}, q_read_twice=True)),
+    "a_linear_site_whose_q_and_k_differ_in_their_steps": lambda: _folded_trace(*_fused_qkv(4, 4, 64, 64, bias=False, norm="q", linear={})),
+    "a_linear_sites_heads_call_the_kernel_declines": lambda: _folded_trace(*_fused_qkv(4, 4, 64, 64, bias=False, norm="qk", linear={}, tables="float32")),
+    "a_linear_site_without_pallas": lambda: _folded_trace(*_fused_qkv(4, 4, 64, 64, bias=False, norm="qk", linear={}), executors=["flash", "jax"]),
+    "a_linear_site_with_neither_norm_nor_rope": lambda: _folded_trace(*_fused_qkv(4, 4, 64, 0, bias=False, linear={})),
 }
 
 
@@ -343,11 +375,12 @@ def test_declines_and_leaves_the_program_as_written(why):
     trc = DECLINES[why]()
     assert trc.tags[FOLDED] == 0
     ids = [b.sym.id for b in trc.bound_symbols]
-    assert "torch.linear_heads" not in ids and ("torch.apply_rope" in ids or why == "neither_norm_nor_rope")
+    assert "torch.linear_heads" not in ids and ("torch.apply_rope" in ids or "neither_norm_nor_rope" in why)
 
 
-def test_declines_on_a_grad_trace_through_the_api():
-    program, args = _fused_qkv(4, 4, 64, 16, bias=False)
+@pytest.mark.parametrize("consumer", ["causal", "linear"])
+def test_declines_on_a_grad_trace_through_the_api(consumer):
+    program, args = _fused_qkv(4, 4, 64, 16, bias=False, **({"linear": {}} if consumer == "linear" else {}))
     args = [a for a in args if a is not None]
     loss = lambda x, cos, sin, qkv_w, proj_w: ttorch.sum(program(x, cos, sin, qkv_w, None, proj_w).float())
     vg = thunder_tpu.value_and_grad(loss, argnums=(0, 3))
@@ -355,6 +388,28 @@ def test_declines_on_a_grad_trace_through_the_api():
     assert _transforms_record(vg)[FOLDED] == 0
     src = thunder_tpu.last_traces(vg)[-1].python()
     assert "linear_heads" not in src and "pallas_apply_rope(" in src
+
+
+def test_a_linear_site_folds_without_flash_and_its_call_keeps_scale_decay_and_chunk(monkeypatch):
+    """The consumer is XLA's decomposition, which nobody claims and the pass asks nobody to: ``pallas`` alone is
+    enough. The call stays as written on the new q, k and v, its scale on its float32 output; both head calls take
+    1.0. The head calls stand in the norm's region, the call alone in its own, v and the projection in none."""
+    monkeypatch.delenv("THUNDER_FLASH_FORCE")
+    inner, args = _fused_qkv(4, 4, 128, 128, bias=False, norm="qk", linear=dict(scale=0.2, chunk=32))
+    program = _in_regions(inner, rms_norm="attn.qk_norm", linear_attention="attn.linear")
+    written = {b.sym.id: b for b in _folded_trace(program, args, executors=["jax"]).bound_symbols}["torch.linear_attention"]
+    trc = _folded_trace(program, args, executors=["pallas", "jax"])
+    assert trc.tags[FOLDED] == 1
+    lines = {(b.sym.id, b.args[3] if b.sym.id == "torch.apply_rope_heads" else None): b for b in trc.bound_symbols}
+    q, k, call = lines["torch.apply_rope_heads", 0], lines["torch.apply_rope_heads", 4], lines["torch.linear_attention", None]
+    assert q.args[5:] == k.args[5:] == (1.0, 1)
+    assert [s.sym.id for s in q.subsymbols] == [s.sym.id for s in k.subsymbols] == ["torch.split_heads", "torch.rms_norm", "torch.apply_rope"]
+    assert call.args[:3] == (q.output, k.output, lines["torch.getitem", None].output)
+    assert call.args[3].name == written.args[3].name and call.kwargs == written.kwargs == {"scale": 0.2, "chunk": 32}
+    assert call.output.name == written.output.name
+    assert q.region == k.region == "attn.qk_norm" and call.region == "attn.linear"
+    assert lines["torch.linear_heads", None].region is None and lines["torch.getitem", None].region is None
+    assert not {"torch.rms_norm", "torch.apply_rope", "torch.permute"} & {b.sym.id for b in trc.bound_symbols[:trc.bound_symbols.index(call)]}
 
 
 def test_declines_without_the_flash_claim(monkeypatch):
@@ -373,7 +428,8 @@ MODELS = {
                     3, 0, False),
     "trinity-tiny": ({}, 4, 3, True),   # three window layers with rope, a global one without, every head normed
     "lfm2-tiny": ({}, 1, 0, True),      # one attention layer among three conv mixers, its heads normed
-    "minicpm-sala-tiny": ({}, 0, 0, True),  # normed heads in front of consumers the pass does not know
+    # three linear layers, normed and roped, behind a sparse layer whose consumer the pass does not know (PR 41)
+    "minicpm-sala-tiny": ({}, 3, 0, True),
 }
 
 
@@ -396,3 +452,29 @@ def test_models_gpt_forward_counts_its_layers(name):
     src = thunder_tpu.last_traces(jfn)[-1].python()
     assert src.count("flash_window_attention(") == windows and src.count("jax_linear_heads(") == sites
     assert src.count("norm_weight=") == (2 * sites if normed else 0)
+
+
+@pytest.mark.parametrize("T", [128, 256])
+def test_a_sparse_and_linear_model_folds_its_linear_layers_and_computes_what_was_written(monkeypatch, T):
+    """MiniCPM-SALA's stand-in (sparse, linear, linear, linear; ``qk_norm``, ``linear_rope``): the linear layers' sites
+    fold and ``sparse_block_attention``'s does not (its q has two readers in the counting program, and a scale on q
+    would enter the selection: PERF.md section 7). The folded program's logits are the written one's."""
+    from thunder_tpu.models import gpt
+
+    sites = 3  # the linear layers
+    cfg = gpt.name_to_config("minicpm-sala-tiny")
+    params = gpt.init_params(cfg, dtype=dtypes.bfloat16, device_init=True)
+    idx = np.random.RandomState(1).randint(0, cfg.vocab_size, (2, T)).astype(np.int32)
+    folded = thunder_tpu.jit(lambda p, i: gpt.forward(p, i, cfg))
+    got = folded(params, idx)
+    assert _transforms_record(folded)[FOLDED] == sites
+    src = thunder_tpu.last_traces(folded)[-1].python()
+    assert src.count("jax_linear_heads(") == sites and src.count("pallas_apply_rope_heads(") == 2 * sites
+    assert src.count("pallas_split_heads(") == sites and "pallas_apply_rope(" not in src
+    assert src.count("norm_weight=") == 2 * sites  # of the four layers' eight normed q and k, the linear layers' six
+    with monkeypatch.context() as m:
+        m.setattr(attention_layout, "fold_attention_layouts", lambda trc, executors: trc)
+        written = thunder_tpu.jit(lambda p, i: gpt.forward(p, i, cfg))
+        want = written(params, idx)
+    assert thunder_tpu.last_traces(written)[-1].python().count("pallas_apply_rope(") == 2 * sites
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), atol=2e-2, rtol=2e-2)

@@ -631,6 +631,68 @@ def test_forward_of_trinity_norms_its_heads_in_one_pass_on_the_v5e(one_chip, mon
     assert operands == [["bf16[1,40,32768,128]", "f32[1,128]"]] * 2 + [["bf16[1,40,32768,128]", "f32[1,128]", "bf16[32768,128]", "bf16[32768,128]"]] * 6
 
 
+def test_forward_of_minicpm_sala_hands_linear_attention_its_heads_with_no_copy_on_the_v5e(one_chip, monkeypatch):
+    """minicpm-sala.fwd-t32k's program at depth 2 (the sparse layer and the first linear one) at the cell's 32,768
+    positions, as the dispatcher rewrites it since PR 41, compiled for the described chip. Token-major a linear layer
+    pays, on q and on k, a float32 copy turned head-major, a slice converted to float32 and the norm's own pass, and
+    a copy of v (PERF.md, PR 41): none is left. The packed projection is written head-major by its dot, two calls
+    norm and rope out of it, and ``attn.linear``'s first instructions read their outputs as they lie. The sparse
+    layer's site, whose consumer the pass does not know, keeps what it had."""
+    import jax
+    import jax.numpy as jnp
+
+    from perfbench import manifest
+    from perfbench.jobs import gpt_model
+    from thunder_tpu.api import trace_program
+    from thunder_tpu.executors import flashex, pallasex
+    from thunder_tpu.executors.passes import transform_for_execution
+    from thunder_tpu.extend import resolve_executors
+    from thunder_tpu.models import gpt
+    from thunder_tpu.transforms.attention_layout import FOLDED_TAG, fold_attention_layouts
+    from thunder_tpu.transforms.common import dce
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    monkeypatch.setattr(flashex, "_interpret", lambda: False)
+    monkeypatch.setattr(pallasex, "_device_kind", lambda: next(iter(one_chip.device_set)).device_kind)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    cell = manifest.load_cell("minicpm-sala.fwd-t32k")
+    keys = manifest.published(cell)
+    keys.update(num_hidden_layers=2)
+    cfg = gpt_model.gpt_config(keys)
+    assert [cfg.layer_mixer(i) for i in range(2)] == ["sparse_attention", "linear_attention"]
+    shapes = gpt_model.param_shapes(cfg)
+    tokens = jax.ShapeDtypeStruct((cell.traffic["batch"], cell.traffic["seq"]), jnp.int32)
+    flat = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip) for a in jax.tree_util.tree_leaves((shapes, tokens))]
+
+    def compiled(folded: bool):
+        _, trc = trace_program(lambda p, i: gpt.forward(p, i, cfg, last=cell.traffic["last"]), (shapes, tokens), {})
+        trc = dce(trc)
+        if folded:
+            trc = fold_attention_layouts(trc, resolve_executors(None))
+            assert trc.tags[FOLDED_TAG] == 1
+        extrace = transform_for_execution(trc, resolve_executors(None))
+        assert gpt_model.kernels_claimed(extrace) == 3  # the attention over the chosen blocks; q's and k's rope, or norm and rope
+        text = jax.jit(extrace.python_callable()).lower(*flat).compile().as_text()
+        return text, _arrays_written(text)
+
+    text, written = compiled(False)
+    # as written: the sparse layer's q and both of the linear layer's, float32 and turned; v's copies; the norms' passes
+    assert written.count(("copy", "f32[1,32768,32,128]")) == 3 and written.count(("copy", "bf16[1,32,32768,128]")) == 2
+    assert written.count(("fusion", "bf16[32,32768,128]")) == 2 and written.count(("convolution_bitcast_fusion", "bf16[1,32768,12288]")) == 1
+    text, written = compiled(True)
+    assert written.count(("copy", "f32[1,32768,32,128]")) == 1 and written.count(("copy", "bf16[1,32,32768,128]")) == 1
+    assert written.count(("fusion", "bf16[32,32768,128]")) == 0
+    assert written.count(("convolution_bitcast_fusion", "bf16[1,96,32768,128]")) == 1
+    assert written.count(("convolution_bitcast_fusion", "bf16[1,32768,12288]")) == 0
+    # the two head calls: x, the norm's weight, the tables; what reads them is a bitcast into linear attention's chunks
+    entry = text[text.index("ENTRY"):]
+    heads = re.findall(r"^\s*(%[\w.\-]+) = bf16\[1,32,32768,128\]\S* custom-call\((%[\w.\-]+), [^\n]*tpu_custom_call", entry, re.M)
+    assert len(heads) == 2 and len({packed for _, packed in heads}) == 1
+    for name, _ in heads:
+        readers = re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = \S+ ([\w\-]+)\([^\n]*" + re.escape(name) + r"[,)]", entry, re.M)
+        assert readers == ["bitcast"], (name, readers)
+
+
 def _as_the_trace_names_it(text, name_prefix):
     """The instruction whose name starts with ``name_prefix``, its operands with their shapes: the compiled text
     leaves an operand's shape to its own line, the device trace's event names (``kernel_families.match``'s input) do not."""

@@ -26,18 +26,27 @@ between the permute and the attention call q and k may pass through a
 ``rms_norm`` over each head's features (LFM2, Trinity: token-major that norm
 costs a float32 copy of q and three passes more), through ``apply_rope``,
 through both in that order, and q and k alike; the call may be
-``scaled_dot_product_attention`` or ``window_attention``. ``apply_rope_heads``
-takes the norm's weight and tables that may be absent, and does in one pass
-what the site has. A site with neither norm nor rope is left alone. A new line
-keeps the region of the line it stands for: the attention call alone carries
-the call's.
+``scaled_dot_product_attention``, ``window_attention`` or, since PR 41,
+``linear_attention`` (MiniCPM-SALA's linear layers: as many key heads as query
+heads, q and k normed and roped). ``apply_rope_heads`` takes the norm's weight
+and tables that may be absent, and does in one pass what the site has. A site
+with neither norm nor rope is left alone. A new line keeps the region of the
+line it stands for: the attention call alone carries the call's.
+
+The softmax calls take ``scale=1.0`` and q's head call the scale. Linear
+attention has no softmax to move a scale through: it applies its scale to its
+float32 output, and goes on doing so. Its call is written again as it stood, on
+the new q, k and v, with its ``decay``, ``scale`` and ``chunk``; both head
+calls take 1.0.
 
 Forward programs only: in a trace that holds a backward q, k and v have a
-second reader and nothing matches. It asks the checkers first: where ``pallas``
-would not take the rope call or ``flash`` the attention call (a CPU run
-without the kernels), the program stays as written. Each new symbol keeps the
-program as written as its decomposition, so a claim that fails later still
-computes it.
+second reader and nothing matches. It asks the checkers first, about the lines
+that somebody has to take for the rewrite to pay (``_CLAIMED_BY``): where
+``pallas`` would not take the rope call or ``flash`` a softmax call (a CPU run
+without the kernels), the program stays as written. A consumer need not be
+claimed: linear attention runs as XLA's decomposition, and the pass asks
+nobody to take it. Each new symbol keeps the program as written as its
+decomposition, so a claim that fails later still computes it.
 """
 
 from __future__ import annotations
@@ -57,8 +66,11 @@ FOLDED_TAG = "attention_layouts_folded"  # how many attention sites the pass rew
 
 _SDPA = "torch.scaled_dot_product_attention"
 _WINDOW = "torch.window_attention"
+_LINEAR = "torch.linear_attention"
+_CONSUMERS = (_SDPA, _WINDOW, _LINEAR)
 _HEADS_FIRST = (0, 2, 1, 3)
-# who has to take each new line for the rewrite to pay: asked of the checkers before anything is changed
+# who has to take each new line for the rewrite to pay: asked of the checkers before anything is changed. Linear
+# attention is XLA's decomposition, which nobody claims, so it is not asked about.
 _CLAIMED_BY = {"torch.apply_rope_heads": "pallas", "torch.split_heads": "pallas", _SDPA: "flash", _WINDOW: "flash"}
 
 
@@ -125,12 +137,11 @@ def _last_dim_slice(uses: _Uses, s):
 
 
 def _bound_call(call) -> dict:
-    """An attention call's operands under ``_bound_sdpa``'s names, whichever of the two it is."""
+    """An attention call's q, k and v under ``_bound_sdpa``'s names, whichever of the three it is."""
     if call.sym.id == _SDPA:
         return _bound_sdpa(call.args, call.kwargs)
-    q, k, v = call.args
-    return dict(query=q, key=k, value=v, attn_mask=None, dropout_p=0.0, scale=call.kwargs.get("scale"),
-                window=call.kwargs["window"])
+    q, k, v = call.args[:3]
+    return dict(query=q, key=k, value=v, attn_mask=None, dropout_p=0.0, scale=call.kwargs.get("scale"))
 
 
 class _Steps(NamedTuple):
@@ -207,7 +218,10 @@ def _rewritten(trc: TraceCtx, uses: _Uses, at: int, b: dict, m: dict) -> list:
     q, k, v = b["query"], b["key"], b["value"]
     x, w, *bias = uses.bsyms[m["lin"]].args
     H, G = q.shape[1], k.shape[1]
-    scale = float(pyval(b["scale"])) if b["scale"] is not None else 1.0 / math.sqrt(q.shape[-1])
+    if call.sym.id == _LINEAR:  # its scale is on the float32 output, and stays in the call
+        scale = 1.0
+    else:
+        scale = float(pyval(b["scale"])) if b["scale"] is not None else 1.0 / math.sqrt(q.shape[-1])
 
     def heads(packed, steps: _Steps, first, count, scale, split):
         trc.region, how = steps.region, dict(steps.how)
@@ -232,8 +246,10 @@ def _rewritten(trc: TraceCtx, uses: _Uses, at: int, b: dict, m: dict) -> list:
             if call.sym.id == _SDPA:
                 y = ltorch.scaled_dot_product_attention(q, k, v, is_causal=b["is_causal"], scale=1.0,
                                                         enable_gqa=b["enable_gqa"])
-            else:
-                y = ltorch.window_attention(q, k, v, window=b["window"], scale=1.0)
+            elif call.sym.id == _WINDOW:
+                y = ltorch.window_attention(q, k, v, window=call.kwargs["window"], scale=1.0)
+            else:  # decay, scale and chunk as the program gave them
+                y = ltorch.linear_attention(q, k, v, *call.args[3:], **call.kwargs)
         finally:
             trc.pop_scope()
             trc.region = region
@@ -248,14 +264,14 @@ def fold_attention_layouts(trc: TraceCtx, executors) -> TraceCtx:
     executors = tuple(executors or ())
     names = {getattr(e, "name", None) for e in executors}
     ids = {str(b.sym.id) for b in trc.bound_symbols}
-    if not {"pallas", "flash"} <= names or not {_SDPA, _WINDOW} & ids or any("_bwd" in i for i in ids):
+    if "pallas" not in names or not ids.intersection(_CONSUMERS) or any("_bwd" in i for i in ids):
         return trc
     start = time.perf_counter_ns()
     uses = _Uses(trc)
     put: dict[int, list] = {}
     gone: set[int] = set()
     for at, call in enumerate(uses.bsyms):
-        if call.sym.id not in (_SDPA, _WINDOW):
+        if call.sym.id not in _CONSUMERS:
             continue
         b = _bound_call(call)
         if b["attn_mask"] is not None or float(pyval(b["dropout_p"])) != 0.0:
