@@ -827,8 +827,8 @@ WINDOW_SHAPES = [
 @pytest.mark.parametrize("shape,groups,window,steps", WINDOW_SHAPES, ids=[f"T{s[2]}-W{w}-d{s[3]}" for s, _, w, _ in WINDOW_SHAPES])
 def test_attention_within_a_window_compiles_for_v5e_and_the_benchmark_tells_it_from_a_causal_call(one_chip, monkeypatch, shape,
                                                                                                groups, window, steps):
-    """``flash`` claims ``torch.window_attention`` and splash compiles it for the
-    v5e under its local mask: one Mosaic call whose first operand, the mask's
+    """``flash`` claims ``torch.window_attention`` where ``pallas`` has not (here by splash's own entry point, whatever
+    the shapes) and splash compiles it for the v5e under its local mask: one Mosaic call whose first operand, the mask's
     table, has the key tiles a query tile visits as its last dimension, by which
     the benchmark's family ``attn_window_fwd`` takes it; the causal call at the
     same shapes keeps every key tile there and stays ``flash_fwd``'s. Nothing has
@@ -856,9 +856,76 @@ def test_attention_within_a_window_compiles_for_v5e_and_the_benchmark_tells_it_f
     hit = kernel_families.match(named)
     assert hit[0] == "attn_window_fwd" and hit[1:] == flops_window_moe.attn_window_fwd([B, H, T, d], [1, tiles, steps])
     assert hit[1] >= flops_window_moe.attention(T, B * H, B * groups, d, window)[0]  # the upper end of what the table allows
-    assert hit[1] <= 4.0 * d * B * H * flashex.window_tiles(T, window)  # and never more than the tiles visited
+    assert hit[1] <= 4.0 * d * B * H * flashex.splash_window_tiles(T, window)  # and never more than the tiles visited
     causal = jax.jit(lambda q, k, v: flashex._sdpa_impl(q, k, v, is_causal=True, enable_gqa=True)).lower(
         sds(H), sds(groups), sds(groups)).compile()
     named = _as_the_trace_names_it(causal.as_text(), "%splash_mha_fwd")
     assert f"custom-call(s8[1,{tiles},{tiles}]" in named
     assert kernel_families.match(named) == ("flash_fwd", *flops.flash_fwd([B, H, T, d]))
+
+
+OWN_WINDOW_SHAPES = {
+    # (B, H, T, d), key-value heads, window
+    "trinity-mini.fwd-t32k": ((1, 32, 32768, 128), 4, 2048),
+    "a-key-head-a-query-head-heads-of-256": ((2, 4, 8192, 256), 4, 1000),
+    "mistral-7b-past-its-window": ((1, 32, 16384, 128), 8, 4096),
+    "131072-positions": ((1, 32, 131072, 128), 4, 2048),  # a step holds its window's span, whatever the length
+}
+
+
+@pytest.mark.parametrize("shape,groups,window", OWN_WINDOW_SHAPES.values(), ids=OWN_WINDOW_SHAPES)
+def test_the_own_window_kernel_compiles_for_v5e_and_expands_nothing(one_chip, monkeypatch, shape, groups, window):
+    """``pallas`` stands in front of ``flash`` and takes ``torch.window_attention`` on bf16 heads of 128 (PR 42): one
+    Mosaic call, ``window_attend_fwd``, on k and v as the symbol hands them, (B, G, T, d): nothing in front of it has
+    them at the query heads' count, nothing has the sequence twice among its dimensions, the call lives in the default
+    scope of VMEM within what the checker reckoned (a step holds its window's span of k and v, not the sequence), keeps
+    the symbol's region, and is no family's of the benchmark's (``attn_window_fwd`` is splash's table; the rope family
+    tells a call by its shapes). Its two results that nothing writes are q's shape: where k and v expanded stood."""
+    import jax
+    import jax.numpy as jnp
+
+    import thunder_tpu.torch as ttorch
+    from perfbench import kernel_families
+    from perfbench.layer_metrics import _regions
+    from thunder_tpu.api import trace_program
+    from thunder_tpu.core.trace import region
+    from thunder_tpu.executors import flashex, pallasex
+    from thunder_tpu.executors.passes import transform_for_execution
+    from thunder_tpu.extend import resolve_executors
+    from thunder_tpu.transforms.common import dce
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    monkeypatch.setattr(flashex, "_interpret", lambda: False)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    B, H, T, d = shape
+    shapes = [jax.ShapeDtypeStruct((B, h, T, d), jnp.bfloat16, sharding=one_chip) for h in (H, groups, groups)]
+
+    def program(q, k, v):
+        with region("attn.window"):
+            return ttorch.window_attention(q, k, v, window=window, scale=1.0)
+
+    _, comp = trace_program(program, shapes, {})
+    claimed = transform_for_execution(dce(comp), resolve_executors(None))
+    assert [(b.sym.name, b.sym.executor.name) for b in claimed.bound_symbols if b.sym.name == "window_attention"] == [
+        ("window_attention", "pallas")]
+    compiled = jax.jit(claimed.python_callable()).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and jax.tree_util.tree_leaves(compiled.out_info)[0].shape == shape
+    assert not re.search(rf"\[[\d,]*{T},[\d,]*{T}[\d,]*\]", text)
+    if groups != H:
+        front = text.split("ENTRY")[1].split("%window_attend_fwd")[0].splitlines()[1:]
+        wide = [line for line in front if f" = bf16[{B},{H},{T},{d}]" in line]
+        assert len(wide) == 1 and " parameter(0)" in wide[0]  # q as it came, and no k or v at its heads' count
+        assert " broadcast(" not in text.split("ENTRY")[1]
+    call = next(line for line in text.splitlines() if line.strip().startswith("%window_attend_fwd"))
+    assert '"scoped_memory_configs":[]' in call  # it asks for no VMEM beyond the default scope, and uses less than it reckoned
+    used = int(re.search(r'"used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"', call).group(1))
+    assert used <= pallasex._window_attend_vmem(T, window, H // groups, d, 2) <= 3 * pallasex._SCOPED_VMEM_DEFAULT // 4
+    named = _as_the_trace_names_it(text, "%window_attend_fwd")
+    operands = re.findall(r"(\w+\[[\d,]*\])\S* %", named.split("custom-call(")[1].split("), custom_call_target")[0])
+    assert operands == [f"bf16[{B},{groups},{H // groups},{T},{d}]", f"bf16[{B},{groups},{T},{d}]", f"bf16[{B},{groups},{T},{d}]"]
+    assert kernel_families.match(named) is None
+    results = re.findall(r"bf16\[[\d,]*\]", call.split(" = ")[1].split(" custom-call(")[0])
+    assert results == [f"bf16[{B},{groups},{H // groups},{T},{d}]"] * 3
+    name = call.split(" = ")[0].strip().lstrip("%")
+    assert _regions.of_instructions(text, ("attn.window", "attn.full"))[name] == "attn.window"

@@ -270,33 +270,62 @@ def test_the_window_symbols_gradients_are_the_masked_softmaxs(window):
         assert rel(g, np.asarray(w, np.float64)) < 1e-5
 
 
-@pytest.mark.parametrize("t,window,heads,groups", [(300, 100, 4, 2), (384, 129, 2, 2), (256, 128, 4, 1), (200, 1000, 2, 1)],
-                         ids=["T300-W100", "T384-W129", "T256-W128", "window-past-the-sequence"])
-def test_the_claimed_kernel_in_interpret_mode_against_the_decomposition(monkeypatch, t, window, heads, groups):
-    """``flash`` takes the call (bf16, on the CPU only when forced) and runs
-    splash under the local mask, interpreted; the window's edge falls inside a
-    tile and the sequence is padded to the lanes."""
+@pytest.mark.parametrize("t,window,heads,groups,d,owner", [
+    (300, 100, 4, 2, 128, "flash"), (384, 129, 2, 2, 128, "flash"), (256, 128, 4, 1, 64, "flash"), (200, 1000, 2, 1, 128, "flash"),
+    # pallasex's own kernel (PR 42), tiles of 256 x 256: one, two and eight query heads a key-value head
+    (512, 300, 2, 2, 128, "pallas"), (768, 256, 4, 2, 128, "pallas"), (512, 1000, 8, 1, 128, "pallas"),
+    (768, 300, 2, 1, 128, "pallas"), (256, 128, 4, 1, 128, "pallas"), (512, 1, 2, 1, 128, "pallas"), (512, 2048, 2, 1, 256, "pallas")],
+    ids=["T300-W100", "T384-W129-no-tile-divides-it", "T256-W128-heads-of-64", "window-past-the-sequence",
+         "own-window-off-both-tiles", "own-window-of-one-tile", "own-window-past-the-sequence-eight-heads-a-key-head",
+         "own-a-rows-first-tile-wholly-masked", "own-T256-W128-the-stand-ins", "own-only-its-own-key", "own-heads-of-256"])
+def test_the_claimed_kernel_in_interpret_mode_against_the_decomposition(monkeypatch, t, window, heads, groups, d, owner):
+    """bf16, on the CPU only when forced. ``pallas`` is asked first and takes
+    heads of whole lane groups on a sequence its tiles of 256 divide: the
+    window's far edge falls inside a key tile, or on its border, or before the
+    sequence (the loop is then the causal one), and at T = 768 under a window of
+    300 the last query tile's first key tile holds no key of its later rows.
+    ``flash`` takes the rest and runs splash under the local mask, the window's
+    edge inside a tile and the sequence padded to the lanes. Interpreted."""
     import jax.numpy as jnp
 
     monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
-    q, k, v = (jnp.asarray(a, jnp.bfloat16) for a in qkv(t, heads, groups, d=128, seed=t))
+    q, k, v = (jnp.asarray(a, jnp.bfloat16) for a in qkv(t, heads, groups, d=d, seed=t))
     jfn = thunder_tpu.jit(lambda q, k, v: ttorch.window_attention(q, k, v, window=window))
     got = np.asarray(jfn(q, k, v).astype(jnp.float32))
     line = thunder_tpu.last_traces(jfn)[-1].bound_symbols[0]
-    assert (str(line.sym.id), line.sym.executor.name) == ("torch.window_attention", "flash")
+    assert (str(line.sym.id), line.sym.executor.name) == ("torch.window_attention", owner)
     want = masked_softmax_attention(*(np.asarray(a.astype(jnp.float32)) for a in (q, k, v)), window)
-    assert rel(got, want) < 1e-2
+    assert np.isfinite(got).all() and rel(got, want) < 1e-2
     monkeypatch.delenv("THUNDER_FLASH_FORCE")
     plain = thunder_tpu.jit(lambda q, k, v: ttorch.window_attention(q, k, v, window=window))
     assert rel(np.asarray(plain(q, k, v).astype(jnp.float32)), want) < 1e-2
-    assert thunder_tpu.last_traces(plain)[-1].bound_symbols[0].sym.executor.name != "flash"
+    assert thunder_tpu.last_traces(plain)[-1].bound_symbols[0].sym.executor.name not in ("flash", "pallas")
+
+
+def test_the_own_kernel_takes_a_scale_or_finds_it_folded(monkeypatch):
+    """The layout pass hands q scaled and ``scale=1.0``; any other scale is q's before the call, as splash has it."""
+    import jax.numpy as jnp
+
+    from thunder_tpu.executors import pallasex
+
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    q, k, v = (jnp.asarray(a, jnp.bfloat16) for a in qkv(512, 4, 2, d=128, seed=3))
+    want = masked_softmax_attention(*(np.asarray(a.astype(jnp.float32)) for a in (q, k, v)), 200)
+    folded = (q * jnp.asarray(128 ** -0.5, jnp.bfloat16)).astype(jnp.bfloat16)
+    for got in (pallasex._window_attend_impl(folded, k, v, window=200, scale=1.0),
+                pallasex._window_attend_impl(q * 2, k, v, window=200, scale=0.5 * 128 ** -0.5)):
+        assert rel(np.asarray(got.astype(jnp.float32)), want) < 1e-2
+
+
+def _like(shape, dtype):
+    return type("P", (), {"shape": shape, "dtype": dtype})()
 
 
 def test_what_the_window_claim_declines_is_the_decompositions(monkeypatch):
     from thunder_tpu.executors import flashex
 
     monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
-    like = lambda shape, dtype: type("P", (), {"shape": shape, "dtype": dtype})()
+    like = _like
     bf16 = lambda *shape: like(shape, dtypes.bfloat16)
     assert flashex._window_checker(bf16(1, 4, 256, 128), bf16(1, 2, 256, 128), bf16(1, 2, 256, 128), window=64)
     assert not flashex._window_checker(like((1, 4, 256, 128), dtypes.float32), like((1, 2, 256, 128), dtypes.float32),
@@ -307,18 +336,103 @@ def test_what_the_window_claim_declines_is_the_decompositions(monkeypatch):
     assert not flashex._window_checker(bf16(1, 4, 256, 128), bf16(1, 2, 256, 128), bf16(1, 2, 256, 128), window=64)
 
 
+OWN_DECLINES = {
+    # what the own kernel's checker sees at trinity's size -> who has the symbol then, on a call small enough to run here
+    "float32": (dict(dtype=dtypes.float32), "neither"),
+    "float16": (dict(dtype=dtypes.float16), "flash"),
+    "heads-of-64": (dict(d=64), "flash"),
+    "no-tile-divides-T": (dict(t=32768 + 128, small_t=384), "flash"),
+    "a-windows-span-past-the-vmem": (dict(window=16384, scope=1024 * 1024), "flash"),
+}
+
+
+def test_the_own_kernels_checker_takes_trinitys_window_layers(monkeypatch):
+    from thunder_tpu.executors import pallasex
+
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    shapes = lambda t, dtype=dtypes.bfloat16: [_like((1, h, t, 128), dtype) for h in (32, 4, 4)]
+    assert pallasex._window_attend_checker(*shapes(32768), window=2048)
+    # nine key tiles of 256 of k and of v, twice; q's and the output's blocks, twice; q turned and the accumulator; scores
+    assert pallasex._window_attend_vmem(32768, 2048, 8, 128, 2) == 2359296 + 2097152 + 1572864 + 1048576 <= 3 * 16 * 2 ** 20 // 4
+    assert pallasex._window_attend_checker(*shapes(131072), window=2048)  # a step holds its window's span, whatever T is
+    assert pallasex._window_attend_checker(*shapes(32768), window=4096)
+    assert not pallasex._window_attend_checker(*shapes(32768), window=16384)  # 16,640 keys a step: splash's, as before
+    assert not pallasex._window_attend_checker(*shapes(32768), window=32768)  # causal over 32,768: splash's
+    q, k, v = shapes(32768)
+    assert not pallasex._window_attend_checker(q, k, _like((1, 4, 32768, 64), dtypes.bfloat16), window=2048)
+    assert not pallasex._window_attend_checker(q, k, _like((1, 2, 32768, 128), dtypes.bfloat16), window=2048)
+    monkeypatch.delenv("THUNDER_FLASH_FORCE")
+    assert not pallasex._window_attend_checker(*shapes(32768), window=2048)  # no chip and nothing forced: nobody's
+
+
+@pytest.mark.parametrize("case", OWN_DECLINES)
+def test_where_the_own_kernels_checker_declines_the_parents_claim_stands(monkeypatch, case):
+    """What the checker refuses at trinity's size, and at a size that runs here
+    who the execution trace names for the same refusal: ``flash`` for bf16 and
+    float16, the decomposition for float32, as before PR 42."""
+    import jax.numpy as jnp
+
+    from thunder_tpu.executors import pallasex
+
+    how, owner = OWN_DECLINES[case]
+    dtype, d = how.get("dtype", dtypes.bfloat16), how.get("d", 128)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    big = [_like((1, h, how.get("t", 32768), d), dtype) for h in (32, 4, 4)]
+    assert not pallasex._window_attend_checker(*big, window=how.get("window", 2048))
+    if "scope" in how:  # a scope of VMEM that holds less: at 256 positions the span no longer fits what the checker reckons
+        monkeypatch.setattr(pallasex, "_SCOPED_VMEM_DEFAULT", how["scope"])
+    t = how.get("small_t", 256)
+    q, k, v = (jnp.asarray(a, dtypes.to_jax_dtype(dtype)) for a in qkv(t, 4, 2, d=d, seed=1))
+    jfn = thunder_tpu.jit(lambda q, k, v: ttorch.window_attention(q, k, v, window=100))
+    got = np.asarray(jfn(q, k, v).astype(jnp.float32))
+    name = thunder_tpu.last_traces(jfn)[-1].bound_symbols[0].sym.executor.name
+    assert (name if name in ("flash", "pallas") else "neither") == owner
+    want = masked_softmax_attention(*(np.asarray(a.astype(jnp.float32)) for a in (q, k, v)), 100)
+    assert rel(got, want) < (1e-5 if owner == "neither" else 1e-2)
+
+
 @pytest.mark.parametrize("t,window,tiles", [(32768, 2048, 93), (8192, 2048, 21), (4096, 1024, 7), (4096, 1025, 7), (4096, 1026, 9), (2048, 4096, 3)],
                          ids=lambda x: str(x))
 def test_the_tiles_the_kernel_visits_by_hand(t, window, tiles):
-    """Tiles of 1024: a query tile visits its own key tile and those its window
-    reaches back into; the count is the kernel's own table's."""
+    """splash's tiles of 1024: a query tile visits its own key tile and those its
+    window reaches back into; the count is the kernel's own table's."""
     from thunder_tpu.executors import flashex
 
     assert flashex._fit_block(t) == 1024
-    assert flashex.window_tiles(t, window) == tiles * 1024 * 1024
+    assert flashex.splash_window_tiles(t, window) == tiles * 1024 * 1024
     by_hand = sum(1 for i in range(t // 1024) for j in range(t // 1024)
                   if j <= i and (i * 1024 - (j * 1024 + 1023)) < window)
     assert by_hand == tiles
+
+
+@pytest.mark.parametrize("t,window,tiles", [(32768, 2048, 36 + 120 * 9), (8192, 2048, 36 + 24 * 9), (4096, 1024, 10 + 12 * 5),
+                                            (4096, 1025, 10 + 12 * 5), (4096, 1281, 15 + 11 * 6), (2048, 4096, 36), (256, 128, 1)],
+                         ids=lambda x: str(x))
+def test_the_tiles_the_own_kernel_visits_by_hand(t, window, tiles):
+    """Tiles of 256 x 256: a query tile walks from the key tile that holds its
+    first query's oldest key to its own, nine at a window of 2,048 (2,304 keys
+    for 2,048) and fewer in the first eight. ``flashex.window_tiles`` is that
+    count where the own kernel takes bf16 heads of 128 at that length and
+    window, and splash's where it does not."""
+    from thunder_tpu.executors import flashex, pallasex
+
+    assert pallasex._WINDOW_ATTEND_TILES == (256, 256)
+    assert pallasex.window_attend_tiles(t, window) == tiles * 256 * 256
+    by_hand = sum(1 for i in range(t // 256) for j in range(t // 256) if j <= i and (i * 256 - (j * 256 + 255)) < window)
+    assert by_hand == tiles
+    assert flashex.window_tiles(t, window) == tiles * 256 * 256
+    if (t, window) == (32768, 2048):
+        pairs = t * window - window * (window - 1) // 2
+        assert round(tiles * 256 * 256 / pairs, 4) == 1.125 and round(flashex.splash_window_tiles(t, window) / pairs, 4) == 1.5
+    if t <= 4096:  # splash's table of 128-wide tiles is slow to build past that
+        assert flashex.window_tiles(t + 128, window) == flashex.splash_window_tiles(t + 128, window)  # no tile divides it
+
+
+def test_the_counter_is_splashs_where_the_own_kernel_declines_the_window():
+    from thunder_tpu.executors import flashex, pallasex
+
+    assert not pallasex.window_attend_fits(16384, 16384, 1, 128, 2)  # causal over 16,384: a span past the scope of VMEM
+    assert flashex.window_tiles(16384, 16384) == flashex.splash_window_tiles(16384, 16384) == 136 * 1024 * 1024
 
 
 def test_the_causal_kernel_is_yesterdays_whatever_the_window_cache_holds():

@@ -16,7 +16,16 @@ Claims:
 - ``torch.window_attention`` (forward) — the same kernel under splash's local
   mask (``window - 1`` keys to the left, none to the right): a tile of
   ``_BLOCK`` queries visits the key tiles its window touches, three at a
-  window of 2048, and no others; no (T, T) mask is built.
+  window of 2048, and no others; no (T, T) mask is built. Since PR 42 this
+  claim is the second asked: ``pallas`` stands in front of ``flash`` and its
+  ``window_attend_fwd`` (``pallasex._window_attend_checker``) takes a call of
+  bf16 heads of a multiple of 128 whose sequence its tiles of 256 divide and
+  whose window's span of k and v fits the default scope of VMEM (windows to
+  some 7,000 keys at eight query heads a key-value head); every other call
+  (heads of 64, float16, a sequence that needs padding, a longer window) comes
+  here as before, and float32 goes to the decomposition. ``window_tiles``
+  counts what the kernel that takes bf16 heads of 128 visits,
+  ``splash_window_tiles`` what this one does.
 
 Mask support (the reference's cudnnex builds its graph with a bias input;
 splash is mask-structured instead, so masks are handled by shape class):
@@ -462,11 +471,23 @@ def _sdpa_bwd_impl(g, query, key, value, attn_mask=None, is_causal=False, scale=
 
 
 def window_tiles(T: int, window: int) -> int:
-    """Score elements of the tiles ``torch.window_attention``'s kernel computes
-    for one head of ``T`` positions: the pairs of a query tile and a key tile
-    that splash's table of the local mask keeps (partly or wholly inside the
-    window), times a tile's size. The pairs the window itself has are fewer:
-    whole tiles are what the kernel can skip."""
+    """Score elements of the tiles that the kernel which claims
+    ``torch.window_attention`` on bf16 heads of 128 computes for one head of
+    ``T`` positions: ``pallasex``'s where its checker takes such a sequence
+    (its tiles divide it, the window's span fits its VMEM; reckoned at one
+    query head a key-value head), splash's otherwise. The pairs the window
+    itself has are fewer: whole tiles are what either can skip."""
+    from thunder_tpu.executors import pallasex
+
+    if pallasex.window_attend_fits(int(T), int(window), 1, 128, 2):
+        return pallasex.window_attend_tiles(int(T), int(window))
+    return splash_window_tiles(T, window)
+
+
+def splash_window_tiles(T: int, window: int) -> int:
+    """That count for splash: the pairs of a query tile and a key tile that its
+    table of the local mask keeps (partly or wholly inside the window), times a
+    tile's size."""
     Tp = T + _pad_amt(T)
     info = _splash_kernel(1, Tp, Tp, True, 0, _interpret(), True, window=int(window)).fwd_mask_info
     return int((np.asarray(info.block_mask) > 0).sum()) * _fit_block(Tp) ** 2

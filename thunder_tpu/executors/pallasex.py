@@ -919,3 +919,182 @@ def _sparse_attend_impl(q, k, v, block_ids, *, block_size, scale=None, query_chu
 
 
 ex.register_implementation("torch.sparse_block_attend", fn=_sparse_attend_impl, checker=_sparse_attend_checker)
+
+
+# =============================================================================
+# Attention within a window (torch.window_attention, ahead of flash)
+# =============================================================================
+#
+# The body above under a window's mask, for the layers whose queries see their
+# own key and the ``window - 1`` before it. splash skips a tile only whole and
+# pays a grid step a pair of tiles, so its tiles are 1024 and a window of 2,048
+# visits three key tiles a query tile, half again the pairs the window keeps.
+# Here a grid step is a tile of tq queries of the R = H / G heads that share a
+# key-value head, and the key tiles its window touches are walked in a
+# ``fori_loop`` inside the step: small tiles cost no grid step, and nine key
+# tiles of 256 (2,304 keys for 2,048) are 1.125 times the pairs. k and v come
+# as ``window_attention`` hands them, (B, G, T, d), and are read once for the
+# R query heads: nothing expands them. A step holds the span of keys its
+# window touches and no more (``pl.Element`` indexing: neighbouring steps'
+# spans overlap), so the call lives in the default scope of VMEM whatever T
+# is. A key tile that every query of the tile sees whole takes no mask; the
+# tile at the window's far edge and the tiles on the diagonal compare
+# positions, once for the R heads. A query none of whose keys lie in a masked
+# tile keeps a maximum of -inf there and adds nothing, as above.
+#
+# The checker takes bf16 heads of a multiple of 128 whose T the tiles divide
+# and whose span fits the VMEM it reckons; every other call is splash's
+# (``flashex``), as before.
+
+# Measured on the v5e at trinity-mini.fwd-t32k's shapes, a layer of 32 on 4 heads of 128, 32,768 positions, window 2,048
+# (PERF.md, PR 42; splash at 1024 x 1024 reads 12.28 ms and 13.22 with k and v expanded in front of it): 256 x 256 reads
+# 9.52 ms, 256 x 128 9.60, 256 x 512 10.23, 512 x 512 10.64, 128 x 256 11.23, 256 x 1024 11.80, 512 x 256 11.84,
+# 128 x 128 12.17: 3.93 ps a score visited, and key tiles of 256 visit 1.125 times the pairs where 512 visit 1.25. With q
+# contracted on its last dimension in every pair (as the body above has it) 256 x 256 read 10.91, with every tile masked
+# 10.96 for that, with k and v whole in VMEM and v turned in front of the call 9.58.
+_WINDOW_ATTEND_TILES = (256, 256)  # queries and keys a tile
+
+
+def _window_attend_walk(T: int, window: int):
+    """The key tiles each query tile walks, from the one that holds its first
+    query's oldest key to its causal frontier."""
+    tq, tk = _WINDOW_ATTEND_TILES
+    return [-(-(qi + 1) * tq // tk) - max(qi * tq - (window - 1), 0) // tk for qi in range(T // tq)]
+
+
+def window_attend_tiles(T: int, window: int) -> int:
+    """Score elements of the tiles the kernel computes for one head of ``T`` positions."""
+    tq, tk = _WINDOW_ATTEND_TILES
+    return sum(_window_attend_walk(T, window)) * tq * tk
+
+
+def _window_attend_vmem(T: int, window: int, R: int, d: int, itemsize: int) -> int:
+    """What a call holds in VMEM, reckoned as ``_sparse_attend_vmem`` does: the
+    spans of k and v and the blocks of q and the output, each twice for the
+    pipeline; q turned and the accumulator; some four (tk, tq) of float32 at
+    once."""
+    tq, tk = _WINDOW_ATTEND_TILES
+    span = max(_window_attend_walk(T, window)) * tk
+    return 4 * span * d * itemsize + 4 * R * tq * d * itemsize + R * d * tq * (itemsize + 4) + 4 * tk * tq * 4
+
+
+def window_attend_fits(T: int, window: int, R: int, d: int, itemsize: int) -> bool:
+    """Whether the call's shapes are the kernel's: heads of whole lane groups,
+    a sequence both tiles divide, and a window's span of k and v within three
+    quarters of the default scope of VMEM, which is all the call asks for."""
+    tq, tk = _WINDOW_ATTEND_TILES
+    return (d % _LANE == 0 and T % tq == 0 and T % tk == 0
+            and _window_attend_vmem(T, window, R, d, itemsize) <= 3 * _SCOPED_VMEM_DEFAULT // 4)
+
+
+def _window_attend_checker(q, k, v, *, window, scale=None) -> bool:
+    from thunder_tpu.executors import flashex
+
+    if not flashex._on_tpu() or any(len(getattr(a, "shape", ())) != 4 for a in (q, k, v)):
+        return False
+    if any(dtypes.to_dtype(a.dtype) is not dtypes.bfloat16 for a in (q, k, v)):  # float32 keeps its precision
+        return False
+    (B, H, T, d), G, window = q.shape, k.shape[1], int(pyval(window))
+    return (window >= 1 and H % G == 0 and tuple(k.shape) == tuple(v.shape) == (B, G, T, d)
+            and B % batch_shards() == 0 and window_attend_fits(int(T), window, H // G, int(d), 2))
+
+
+def _window_attend_kernel(q_ref, k_ref, v_ref, out_ref, _, __, qt_scr, m_scr, l_scr, acc_scr, *,
+                          window: int, tk: int, T: int):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    R, tq = q_ref.shape[2], q_ref.shape[3]
+    first_query = pl.program_id(2) * tq
+    first_tile = jnp.maximum(first_query - (window - 1), 0) // tk
+    held_from = jnp.minimum(first_tile * tk, T - k_ref.shape[2])                  # the first key of the span this step holds
+    f32 = jnp.float32
+
+    m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, f32)
+    l_scr[...] = jnp.zeros(l_scr.shape, f32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, f32)
+    for r in range(R):  # q turned once a step: contracted on its last dimension it is turned again for every key tile (+14%)
+        qt_scr[r] = q_ref[0, 0, r].astype(f32).T.astype(qt_scr.dtype)
+
+    def pair(kt, masked: bool):
+        first_key = kt * tk
+        rows = pl.ds(pl.multiple_of(first_key - held_from, tk), tk)
+        keys = k_ref[0, 0, rows, :]                                              # (tk, d)
+        values = v_ref[0, 0, rows, :].astype(f32).T.astype(v_ref.dtype)          # (d, tk), turned once for the R heads
+        if masked:  # the mask of the pair, once for the R heads: a key is kept if its query is 0 to window - 1 ahead of it
+            ahead = (first_query - first_key + jax.lax.broadcasted_iota(jnp.int32, (tk, tq), 1)
+                     - jax.lax.broadcasted_iota(jnp.int32, (tk, tq), 0))
+            seen = (ahead >= 0) & (ahead < window)
+        for r in range(R):
+            s = jnp.dot(keys, qt_scr[r], preferred_element_type=f32)              # (tk, tq)
+            if masked:
+                s = jnp.where(seen, s, -jnp.inf)
+            m_was = m_scr[r]
+            m = jnp.maximum(m_was, jnp.max(s, axis=0, keepdims=True))
+            at = jnp.where(m == -jnp.inf, 0.0, m) if masked else m               # a row with no key yet adds nothing
+            shrink = jnp.exp(m_was - at)
+            p = jnp.exp(s - at)
+            l_scr[r] = l_scr[r] * shrink + jnp.sum(p, axis=0, keepdims=True)
+            acc_scr[r] = acc_scr[r] * shrink + jnp.dot(values, p.astype(values.dtype), preferred_element_type=f32)
+            m_scr[r] = m
+
+    def step(kt, carry):
+        # every query of the tile sees every key of the tile: none is after its query, none has left the window
+        whole = (kt * tk + tk - 1 <= first_query) & (first_query + tq - 1 - kt * tk < window)
+        pl.when(whole)(lambda: pair(kt, False))
+        pl.when(jnp.logical_not(whole))(lambda: pair(kt, True))
+        return carry
+
+    jax.lax.fori_loop(first_tile, (first_query + tq + tk - 1) // tk, step, 0)
+    for r in range(R):
+        out_ref[0, 0, r] = (acc_scr[r] / l_scr[r]).T.astype(out_ref.dtype)
+
+
+def _window_attend_impl(q, k, v, *, window, scale=None):
+    chaos.kernel_seam("pallas", "window_attention")
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from thunder_tpu.executors import flashex
+
+    scale = float(scale) if scale is not None else q.shape[-1] ** -0.5
+    window = int(window)
+    tq, tk = _WINDOW_ATTEND_TILES
+
+    def shard(q, k, v):
+        (B, H, T, d), G = q.shape, k.shape[1]
+        R, span = H // G, max(_window_attend_walk(T, window)) * tk
+
+        def held(b, g, i):  # the keys a step holds, by their first: neighbouring steps' spans overlap
+            first = jnp.maximum(i * tq - (window - 1), 0) // tk * tk
+            return b, g, pl.multiple_of(jnp.minimum(first, T - span), tk), 0
+
+        keys = pl.BlockSpec((pl.Element(1), pl.Element(1), pl.Element(span), pl.Element(d)), held, memory_space=pltpu.VMEM)
+        rows = pl.BlockSpec((1, 1, R, tq, d), lambda b, g, i: (b, g, 0, i, 0), memory_space=pltpu.VMEM)
+        like_q = jax.ShapeDtypeStruct((B, G, R, T, d), q.dtype)
+        out = pl.pallas_call(
+            partial(_window_attend_kernel, window=window, tk=tk, T=T),
+            grid=(B, G, T // tq),
+            in_specs=[rows, keys, keys],
+            # Two results that nothing writes or reads stand where splash's call had k and v expanded to the query
+            # heads. They cost no time, and the attention layers are not the program's peak; but without two more
+            # arrays of q's size alive at the call XLA's heap plans trinity-mini.fwd-t32k's temporaries 248 MB
+            # larger, though fewer bytes are alive at their peak (PERF.md, PR 42; section 7).
+            out_specs=[rows, pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
+            out_shape=[like_q, like_q, like_q],
+            scratch_shapes=[pltpu.VMEM((R, d, tq), q.dtype), pltpu.VMEM((R, 1, tq), jnp.float32),
+                            pltpu.VMEM((R, 1, tq), jnp.float32), pltpu.VMEM((R, d, tq), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel")),
+            name="window_attend_fwd",
+            interpret=_interpret(),
+        )(q.reshape(B, G, R, T, d), k, v)[0]
+        return out.reshape(B, H, T, d)
+
+    with jax.enable_x64(False):
+        return per_batch_shard(shard, flashex._scaled(q, scale), k, v)  # the softmax scale is q's, as splash has it
+
+
+ex.register_implementation("torch.window_attention", fn=_window_attend_impl, checker=_window_attend_checker)

@@ -43,7 +43,8 @@ Forward programs only: in a trace that holds a backward q, k and v have a
 second reader and nothing matches. It asks the checkers first, about the lines
 that somebody has to take for the rewrite to pay (``_CLAIMED_BY``): where
 ``pallas`` would not take the rope call or ``flash`` a softmax call (a CPU run
-without the kernels), the program stays as written. A consumer need not be
+without the kernels), the program stays as written; a window call may be
+``pallas``'s own kernel's or splash's, by its shapes. A consumer need not be
 claimed: linear attention runs as XLA's decomposition, and the pass asks
 nobody to take it. Each new symbol keeps the program as written as its
 decomposition, so a claim that fails later still computes it.
@@ -71,7 +72,8 @@ _CONSUMERS = (_SDPA, _WINDOW, _LINEAR)
 _HEADS_FIRST = (0, 2, 1, 3)
 # who has to take each new line for the rewrite to pay: asked of the checkers before anything is changed. Linear
 # attention is XLA's decomposition, which nobody claims, so it is not asked about.
-_CLAIMED_BY = {"torch.apply_rope_heads": "pallas", "torch.split_heads": "pallas", _SDPA: "flash", _WINDOW: "flash"}
+_CLAIMED_BY = {"torch.apply_rope_heads": ("pallas",), "torch.split_heads": ("pallas",), _SDPA: ("flash",),
+               _WINDOW: ("pallas", "flash")}
 
 
 class _Uses:
@@ -280,7 +282,7 @@ def fold_attention_layouts(trc: TraceCtx, executors) -> TraceCtx:
         if m is None:
             continue
         lines = _rewritten(trc, uses, at, b, m)
-        if any(would_claim(line, executors) != _CLAIMED_BY[line.sym.id] for line in lines if line.sym.id in _CLAIMED_BY):
+        if any(would_claim(line, executors) not in _CLAIMED_BY[line.sym.id] for line in lines if line.sym.id in _CLAIMED_BY):
             continue
         put[at] = lines
         gone.update(m["gone"])
