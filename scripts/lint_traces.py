@@ -31,14 +31,6 @@ Usage:
         # hazards are flagged; the de-opt ladder under the chaos oom@<3
         # memory ceiling reaches its fitting level with strictly fewer
         # failed XLA compiles than blind climbing
-    python scripts/lint_traces.py --schedule
-        # comm-scheduler smoke (ISSUE 13; docs/performance.md "collective
-        # overlap"): the fsdp4·tp2 grad trace schedules with hidden wire
-        # > 0 for the top fsdp synchronize and a grad reduce_scatter,
-        # re-certifies with the identical per-axis order, backs its hoists
-        # off under a capacity squeeze instead of predicting an OOM, and
-        # a chaos-corrupted placement (sched_bad) or compile failure
-        # demotes cleanly to the unscheduled order / L1
     python scripts/lint_traces.py --chaos
         # resilience smoke (docs/robustness.md): run the GPT gradient
         # pipeline under a canned fault schedule (kernel raise, compile
@@ -615,195 +607,6 @@ def _static_smoke() -> int:
         os.environ.pop("THUNDER_TPU_HBM_BYTES", None)
 
     print(f"\nlint_traces --static: {n_errors} error(s)")
-    return n_errors
-
-
-def _schedule_smoke() -> int:
-    """--schedule: the comm-scheduler smoke (ISSUE 13). Four parts:
-
-    1. **Scheduling the fsdp4·tp2 grad trace**: the explicit-collective
-       FSDP×TP fw+bw trace schedules with ≥1 hoist, re-certifies with the
-       identical per-axis collective order, passes the full verifier, and
-       the post-schedule prediction shows hidden wire > 0 for the top
-       movable fsdp ``synchronize`` AND for a grad ``reduce_scatter``.
-    2. **Liveness-constrained placement**: with ``capacity_bytes`` set
-       between the unscheduled and fully-hoisted predicted peaks, the
-       hoists must back off to placements whose predicted peak fits —
-       never schedule a predicted OOM.
-    3. **Bad schedule demotes cleanly** (chaos ``sched_bad``): a corrupted
-       placement is caught by the pass's own interval validation; the
-       compile falls back to the unscheduled certified order with a
-       ``sharp_edge`` event (replay-correlated), and the result is
-       unchanged.
-    4. **De-opt ladder**: a chaos ``compile_fail`` climbs to L1, where the
-       scheduler (like fusion) is disabled — the recovery path compiles
-       without it instead of wedging.
-    """
-    import json
-    import tempfile
-
-    os.environ.setdefault("THUNDER_TPU_RETRY_BACKOFF_S", "0")
-
-    import numpy as np
-    import thunder_tpu as ttpu
-    import thunder_tpu.clang as clang
-    from thunder_tpu.analysis import Severity, verify
-    from thunder_tpu.analysis import schedule as sched_mod
-    from thunder_tpu.analysis.liveness import plan_liveness
-    from thunder_tpu.api import trace_program
-    from thunder_tpu.distributed import prims as dist
-    from thunder_tpu.executors.passes import transform_for_execution
-    from thunder_tpu.extend import resolve_executors
-    from thunder_tpu.resilience import chaos as chaos_mod
-    from thunder_tpu.transforms.autodiff import grad_transform
-    from thunder_tpu.transforms.common import dce
-    from thunder_tpu.transforms.comm_schedule import schedule_collectives
-
-    n_errors = 0
-    rng = np.random.RandomState(0)
-    layers, d, B, fsdp_g, tp_g = 3, 64, 16, 4, 2
-    ws = [rng.randn(d // fsdp_g, d).astype(np.float32) for _ in range(layers)]
-    x = rng.randn(B, d).astype(np.float32)
-
-    def fsdp_tp_loss(*flat_in):
-        *w_shards, xv = flat_in
-        h = xv
-        for w_shard in w_shards:
-            w_full = dist.synchronize(w_shard, "fsdp", fsdp_g, "fsdp")
-            h = clang.matmul(h, clang.transpose(w_full, 0, 1))
-            h = dist.all_reduce(h, "tp", tp_g, op="avg")
-            h = clang.tanh(h)
-        return clang.mean(clang.mul(h, h))
-
-    def build():
-        _, comp = trace_program(fsdp_tp_loss, (*ws, x), {})
-        comp = dce(comp)
-        comp = grad_transform(comp, return_value=True)
-        return transform_for_execution(comp, resolve_executors(["jax"]))
-
-    # -- 1. schedule + recertify + hidden>0 for sync and reduce_scatter -------
-    print("--- schedule smoke: fsdp4-tp2 grad trace through the scheduler")
-    extrace = build()
-    cert0 = sched_mod.stamp(extrace)
-    scheduled, rep = schedule_collectives(extrace, device="cpu")
-    pred = sched_mod.predict_overlap(scheduled, device="cpu")
-    sync_sites = [s for s in pred.sites if s.sym == "synchronize"]
-    rs_sites = [s for s in pred.sites if s.sym == "reduce_scatter"]
-    top_sync = max(sync_sites, key=lambda s: s.hidden_us, default=None)
-    top_rs = max(rs_sites, key=lambda s: s.hidden_us, default=None)
-    cert1 = sched_mod.certify(scheduled)
-    errors = [d for d in verify(scheduled) if d.severity >= Severity.ERROR]
-    ok = (
-        rep is not None and rep.moves >= 1
-        and cert1.axis_order == cert0.axis_order
-        and scheduled.tags.get("collective_order") == cert1.axis_order
-        and not errors
-        and top_sync is not None and top_sync.hidden_us > 0
-        and top_rs is not None and top_rs.hidden_us > 0
-    )
-    if ok:
-        print(f"    scheduled OK: {rep.moves} move(s), axis order preserved, "
-              f"verifier clean; hidden {top_sync.label()}="
-              f"{top_sync.hidden_us:.1f}us, {top_rs.label()}="
-              f"{top_rs.hidden_us:.1f}us (exposed "
-              f"{rep.exposed_pct_before:.0f}% -> {rep.exposed_pct_after:.0f}%)")
-    else:
-        n_errors += 1
-        print(f"    FAILED: moves={getattr(rep, 'moves', None)} "
-              f"order_ok={cert1.axis_order == cert0.axis_order} "
-              f"errors={errors} sync={top_sync} rs={top_rs}")
-
-    # -- 2. liveness back-off under a capacity squeeze ------------------------
-    # Forward-only chain: the grad trace's peak sits in the backward (big
-    # cotangents), so the squeeze is demonstrated where gathers dominate —
-    # hoisting every synchronize materializes all full weights at once.
-    print("--- schedule smoke: capacity squeeze forces hoist back-off")
-
-    def build_fwd():
-        _, comp = trace_program(fsdp_tp_loss, (*ws, x), {})
-        comp = dce(comp)
-        return transform_for_execution(comp, resolve_executors(["jax"]))
-
-    fwd0 = build_fwd()
-    sched_free, rep_free = schedule_collectives(fwd0, device="cpu")
-    p0 = plan_liveness(fwd0, include_rows=False).peak_bytes
-    p1 = plan_liveness(sched_free, include_rows=False).peak_bytes
-    if not p1 > p0:
-        n_errors += 1
-        print(f"    FAILED: hoisting should raise the predicted peak "
-              f"({p0} -> {p1})")
-    else:
-        cap = (p0 + p1) // 2
-        sched_cap, rep_cap = schedule_collectives(
-            build_fwd(), device="cpu", capacity_bytes=cap
-        )
-        p_cap = plan_liveness(sched_cap, include_rows=False).peak_bytes
-        if rep_cap is not None and rep_cap.backoffs >= 1 and p_cap <= cap:
-            print(f"    back-off OK: free peak {p1 / 1e3:.1f}KB > capacity "
-                  f"{cap / 1e3:.1f}KB -> {rep_cap.backoffs} back-off(s), "
-                  f"constrained peak {p_cap / 1e3:.1f}KB fits")
-        else:
-            n_errors += 1
-            print(f"    FAILED: backoffs={getattr(rep_cap, 'backoffs', None)} "
-                  f"peak {p_cap} vs capacity {cap} (free {p1})")
-
-    # -- 3. chaos sched_bad: corrupted placement demotes to unscheduled -------
-    print("--- schedule smoke: sched_bad chaos falls back cleanly")
-    from thunder_tpu.observability import events as obs_events
-
-    log = os.path.join(tempfile.mkdtemp(prefix="ttpu_sched_"), "events.jsonl")
-    extrace = build()
-    order_before = sched_mod.certify(extrace).axis_order
-    with obs_events.event_scope(obs_events.log_for_path(log)):
-        with chaos_mod.chaos_scope("sched_bad*1"):
-            fell_back, rep_bad = schedule_collectives(extrace, device="cpu")
-    recs = [json.loads(l) for l in open(log)]
-    kinds = [r.get("kind") for r in recs]
-    injected = any(r.get("kind") == "fault_injected" and r.get("seam") == "sched_bad"
-                   for r in recs)
-    rejected = any(r.get("kind") == "sharp_edge"
-                   and r.get("policy") == "comm_schedule_fallback"
-                   for r in recs)
-    # The replay correlation rule itself must accept the fallback as the
-    # seam's recovery (FAULT_RECOVERY_KINDS sched_bad -> sharp_edge).
-    from thunder_tpu.analysis.events import replay_events
-
-    _, replay_diags = replay_events(log)
-    uncorrelated = [d for d in replay_diags
-                    if d.rule == "events.unrecovered-fault"]
-    ok = (
-        fell_back is extrace and rep_bad is None
-        and sched_mod.certify(fell_back).axis_order == order_before
-        and injected and rejected and not uncorrelated
-    )
-    if ok:
-        print("    sched_bad OK: corrupted placement rejected, unscheduled "
-              "order kept, fault_injected + sharp_edge correlated")
-    else:
-        n_errors += 1
-        print(f"    FAILED: fell_back={fell_back is extrace} rep={rep_bad} "
-              f"injected={injected} rejected={rejected} kinds={kinds}")
-
-    # -- 4. compile_fail climbs the ladder; L1 compiles without the scheduler -
-    print("--- schedule smoke: compile_fail de-opts to L1 (scheduler off)")
-    xb = rng.randn(8, 8).astype(np.float32)
-
-    def chain(xv):
-        h = clang.tanh(clang.matmul(xv, xv))
-        return clang.sum(clang.mul(h, h))
-
-    baseline = float(np.asarray(ttpu.jit(chain, executors=["jax"])(xb)))
-    jf = ttpu.jit(chain, executors=["jax"], chaos="compile_fail*1;seed=3")
-    out = float(np.asarray(jf(xb)))
-    level = jf._lc_cd._deopt_level
-    if abs(out - baseline) < 1e-6 and level == 1:
-        print(f"    de-opt OK: recovered at L1 (fusion/donation/comm-schedule "
-              f"off), result matches baseline")
-    else:
-        n_errors += 1
-        print(f"    FAILED: level={level} out={out} baseline={baseline}")
-
-    print(f"\nlint_traces --schedule: {n_errors} error(s)")
     return n_errors
 
 
@@ -1662,7 +1465,7 @@ def _critpath_smoke() -> int:
 
     # Armed recorder over a synthetic 4-host fleet: injected skews the
     # estimator must RECOVER (the falsifiable alignment loop), a static
-    # wire split charging exposed-ICI/DCN, and the comm scheduler's
+    # wire split charging exposed-ICI/DCN, and the schedule certificate's
     # predicted exposed-pct for the three-way cross-check. event_sample=8
     # is the at-scale config: emitted events and gauge refreshes ride a
     # 1-in-8 duty cycle while the estimator/ledger/detector feed keep
@@ -2022,7 +1825,7 @@ def _chaos_multihost_inner() -> int:
     return n_errors
 
 
-_USAGE = ("usage: lint_traces.py [pattern] | --static | --schedule | --chaos | "
+_USAGE = ("usage: lint_traces.py [pattern] | --static | --chaos | "
           "--chaos-multihost | --soak | --federation | --hlo | "
           "--roofline | --critpath | --events <log.jsonl> [...] "
           "[--storm-threshold N]")
@@ -2047,8 +1850,6 @@ def main(argv=None) -> int:
         print("--- static smoke: liveness prediction vs instrument='memory'")
         return 1 if _static_smoke() else 0
 
-    if "--schedule" in argv:
-        return 1 if _schedule_smoke() else 0
 
     if "--soak" in argv:
         return 1 if _soak_smoke() else 0

@@ -12,6 +12,7 @@ import pytest
 import thunder_tpu
 import thunder_tpu.clang as clang
 import thunder_tpu.torch as ttorch
+from thunder_tpu import pipeline
 from thunder_tpu.api import trace_program
 from thunder_tpu.core import dtypes
 from thunder_tpu.extend import resolve_executors
@@ -173,7 +174,7 @@ def test_rewrites_the_idiom_and_computes_what_was_written(monkeypatch, idiom):
     assert src.count("norm_weight=") == (2 * sites if "normed" in idiom else 0)
 
     with monkeypatch.context() as m:
-        m.setattr(attention_layout, "fold_attention_layouts", lambda trc, executors: trc)
+        m.setattr(pipeline, "REWRITES", tuple(r for r in pipeline.REWRITES if r is not attention_layout.fold_attention_layouts))
         written = thunder_tpu.jit(program)
         want = written(*args)
     assert FOLDED not in _transforms_record(written)
@@ -473,7 +474,7 @@ def test_a_sparse_and_linear_model_folds_its_linear_layers_and_computes_what_was
     assert src.count("pallas_split_heads(") == sites and "pallas_apply_rope(" not in src
     assert src.count("norm_weight=") == 2 * sites  # of the four layers' eight normed q and k, the linear layers' six
     with monkeypatch.context() as m:
-        m.setattr(attention_layout, "fold_attention_layouts", lambda trc, executors: trc)
+        m.setattr(pipeline, "REWRITES", tuple(r for r in pipeline.REWRITES if r is not attention_layout.fold_attention_layouts))
         written = thunder_tpu.jit(lambda p, i: gpt.forward(p, i, cfg))
         want = written(params, idx)
     assert thunder_tpu.last_traces(written)[-1].python().count("pallas_apply_rope(") == 2 * sites

@@ -9,6 +9,9 @@ TPU, with `jax.lax` collectives on an ICI/DCN device mesh for distribution.
 
 Public surface mirrors the reference's thunder/__init__.py: `jit`,
 `last_traces`, `compile_data`, `grad`, ThunderModule, etc.
+
+Which passes run between an acquired trace and a claimed one, and in which
+order, is said once, in `thunder_tpu/pipeline.py`; every front end calls it.
 """
 
 __version__ = "0.1.0"

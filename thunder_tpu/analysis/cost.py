@@ -641,8 +641,8 @@ def trace_cost(trace: TraceCtx, device: Any = None) -> TraceCost:
 
 def cost_report(fn: Callable, *args, executors: Any = None, device: Any = None,
                 **kwargs) -> TraceCost:
-    """Trace ``fn`` on the example inputs through the default pass pipeline
-    (acquisition → DCE → CSE → claiming) and return the :class:`TraceCost`
+    """Trace ``fn`` on the example inputs through the pass pipeline the
+    dispatcher runs (``thunder_tpu/pipeline.py``) and return the :class:`TraceCost`
     of the resulting execution trace — the static half of the attribution
     workflow (``examine.cost_report`` re-exports this; docs/performance.md).
 
@@ -650,19 +650,17 @@ def cost_report(fn: Callable, *args, executors: Any = None, device: Any = None,
     function is traced (mirroring ``examine.lint``); to cost the exact
     trace an entry executed, call :func:`trace_cost` on
     ``compile_stats(jfn).last_traces[-1]`` instead."""
+    from thunder_tpu import pipeline
     from thunder_tpu.api import trace_program
     from thunder_tpu.core.trace import debug_checks
-    from thunder_tpu.executors.passes import transform_for_execution
     from thunder_tpu.extend import resolve_executors
-    from thunder_tpu.transforms.common import cse, dce
 
     cd = getattr(fn, "_lc_cd", None)
     if cd is not None:
         fn = cd.fn
     with debug_checks(False):
         _, comp = trace_program(fn, args, kwargs)
-        comp = cse(dce(comp))
-        extrace = transform_for_execution(comp, resolve_executors(executors))
+        extrace = pipeline.compile_trace(pipeline.clean(comp)[-1], resolve_executors(executors)).claimed
     return trace_cost(extrace, device)
 
 

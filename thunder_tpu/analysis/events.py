@@ -133,12 +133,6 @@ FAULT_RECOVERY_KINDS: dict[str, frozenset] = {
     # autopilot does when a fresher fault (host loss, hang) interrupts the
     # guard's re-run mid-flight (ISSUE 11 overlapping-fault scenarios).
     "sdc": frozenset({"sdc_rerun", "elastic_resume"}),
-    # A corrupted comm-scheduler placement is recovered by the pass's own
-    # interval validation rejecting the schedule and falling back to the
-    # unscheduled trace (a sharp_edge record with
-    # policy="comm_schedule_fallback" — only those count, see the replay's
-    # sharp_edge handling below).
-    "sched_bad": frozenset({"sharp_edge"}),
     # Tiered-checkpoint seams (ISSUE 14): a torn background flush is
     # recovered when the checkpoint pipeline demonstrably keeps working —
     # a later successful flush/save commit, or a restore that fell past the
@@ -475,11 +469,6 @@ def replay_events(
                 bucket_by_cid[(*_writer(rec), rec["compile_id"])] = str(rec["buckets"])
             elif kind == "sharp_edge":
                 sharp_edges.append(str(rec["message"]))
-                # The comm scheduler's fallback record is the recovery event
-                # of an injected sched_bad placement (FAULT_RECOVERY_KINDS);
-                # ordinary sharp edges must not satisfy that correlation.
-                if rec.get("policy") == "comm_schedule_fallback":
-                    recovery_positions.setdefault("sharp_edge", []).append(lineno)
             elif kind == "fault_injected":
                 fault_events.append((lineno, str(rec["seam"]), rec))
             elif kind == "autopilot_decision":

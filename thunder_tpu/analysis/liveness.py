@@ -534,8 +534,8 @@ def _plan_without_donation(trace: TraceCtx, device) -> MemoryPlan:
 
 def memory_report(fn: Callable, *args, executors: Any = None, device: Any = None,
                   **kwargs) -> MemoryPlan:
-    """Trace ``fn`` on the example inputs through the default pass pipeline
-    (acquisition → DCE → CSE → claiming → del_last_used) and return the
+    """Trace ``fn`` on the example inputs through the pass pipeline the
+    dispatcher runs (``thunder_tpu/pipeline.py``, then del_last_used) and return the
     :class:`MemoryPlan` of the resulting execution trace — the static
     memory half of the planner suite (``examine.memory_report`` re-exports
     this; docs/performance.md).
@@ -544,19 +544,18 @@ def memory_report(fn: Callable, *args, executors: Any = None, device: Any = None
     function is traced (mirroring ``examine.cost_report``); the exact plan
     of a compiled entry — donation and bucket padding included — is on the
     entry itself (``cache_info(jfn)`` → ``predicted_peak_bytes``)."""
+    from thunder_tpu import pipeline
     from thunder_tpu.api import trace_program
     from thunder_tpu.core.trace import debug_checks
-    from thunder_tpu.executors.passes import del_last_used, transform_for_execution
+    from thunder_tpu.executors.passes import del_last_used
     from thunder_tpu.extend import resolve_executors
-    from thunder_tpu.transforms.common import cse, dce
 
     cd = getattr(fn, "_lc_cd", None)
     if cd is not None:
         fn = cd.fn
     with debug_checks(False):
         _, comp = trace_program(fn, args, kwargs)
-        comp = cse(dce(comp))
-        extrace = transform_for_execution(comp, resolve_executors(executors))
+        extrace = pipeline.compile_trace(pipeline.clean(comp)[-1], resolve_executors(executors)).claimed
         extrace = del_last_used(extrace)
     return plan_liveness(extrace, device=device)
 

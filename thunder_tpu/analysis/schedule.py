@@ -81,7 +81,7 @@ class CollectiveSite:
     # First bsym index that consumes one of the site's outputs (the RETURN
     # index when only the return reads it): the right end of the overlap
     # window — compute strictly between the site and this line can hide the
-    # wire transfer (predict_overlap; the comm scheduler maximizes it).
+    # wire transfer (predict_overlap).
     first_consumer: Optional[int] = None
 
     @property
@@ -364,8 +364,8 @@ class OverlapPrediction:
     device: str
     sites: list = field(default_factory=list)
     # Per-line compute budget (µs) left after every site consumed its
-    # share — what the comm scheduler's hoist scan must price NEW window
-    # rows at, so two sites never count the same GEMM twice.
+    # share — what a move of a site must price NEW window rows at, so two
+    # sites never count the same GEMM twice.
     residual_budget: dict = field(default_factory=dict)
 
     @property
@@ -419,9 +419,7 @@ def predict_overlap(trace: TraceCtx, *, device: Any = None,
     Windows share compute: each line's budget is consumed by sites in
     program order, so two collectives cannot both claim the same GEMM.
     ``hidden = min(wire, window-budget consumed)``; the rest is exposed.
-    The comm scheduler (transforms/comm_schedule.py) moves sites inside
-    their certified intervals to maximize exactly this number, and the
-    ``sched.exposed-collective`` rule reports it per site."""
+    The ``sched.exposed-collective`` rule reports it per site."""
     from thunder_tpu.analysis.cost import resolve_device_spec, trace_cost
 
     dev = resolve_device_spec(device)
@@ -540,10 +538,9 @@ def exposed_collective(ctx: VerifyContext) -> None:
     """Advisory (INFO): per collective site, the statically predicted
     hidden/exposed wire time (:func:`predict_overlap`) — the compile-time
     twin of the measured lane segmentation. A site whose predicted wire
-    time is mostly exposed is a scheduling opportunity the comm scheduler
-    (``transforms/comm_schedule.py``) either already declined (pinned, or
-    a liveness back-off) or has not seen. Never an error: exposure is a
-    speed bug, not a correctness one."""
+    time is mostly exposed is a scheduling opportunity: no pass of the
+    program moves a collective today. Never an error: exposure is a speed
+    bug, not a correctness one."""
     from thunder_tpu.distributed.prims import is_collective_bsym
 
     if not any(is_collective_bsym(b) for b in ctx.bsyms):
@@ -564,8 +561,7 @@ def exposed_collective(ctx: VerifyContext) -> None:
             + (f" at L{s.first_consumer}" if s.first_consumer is not None else "")
             + ")",
             bsym_index=s.index,
-            hint="transforms/comm_schedule.schedule_collectives moves the site "
-            "inside its certified [earliest, latest] interval to grow the "
-            "window; a pinned or backed-off site needs more independent "
+            hint="the site may move inside its certified [earliest, latest] "
+            "interval to grow the window; a pinned site needs more independent "
             "compute or a smaller transfer (quantized collectives)",
         )

@@ -53,14 +53,15 @@ from thunder_tpu.executors import bridge, jaxex, pythonex  # register executors 
 from thunder_tpu.executors import flashex, pallasex  # higher-priority kernel executors  # noqa: F401
 from thunder_tpu.executors import quantex  # opt-in int8 executor (registered, not default)  # noqa: F401
 from thunder_tpu.executors.passes import del_last_used, transform_for_execution
+from thunder_tpu import pipeline  # isort: skip  # after the executors: a transform imports pallasex, and the default order is the import order
 from thunder_tpu.extend import resolve_executors
 from thunder_tpu.observability import events as obs_events
 from thunder_tpu.observability import metrics as obsm
 from thunder_tpu.resilience import chaos as chaos_mod
 from thunder_tpu.resilience import deopt as deopt_mod
 from thunder_tpu.resilience import watchdog as watchdog_mod
-from thunder_tpu.transforms.common import cse, dce
-from thunder_tpu.transforms.rng import RNG_TAG, functionalize_rng_ops
+from thunder_tpu.transforms.common import dce
+from thunder_tpu.transforms.rng import RNG_TAG
 
 
 # =============================================================================
@@ -636,11 +637,8 @@ def _compile_entry_impl(
 
     value_guards = value_guards_of(comp_trc)
 
-    computation_traces = [comp_trc]
-    comp_trc = dce(comp_trc)
-    computation_traces.append(comp_trc)
-    comp_trc = cse(comp_trc)
-    computation_traces.append(comp_trc)
+    computation_traces = [comp_trc, *pipeline.clean(comp_trc)]
+    comp_trc = computation_traces[-1]
 
     if sym_spec is not None:
         # Thread validity masks through reductions over bucket-padded dims and
@@ -659,50 +657,28 @@ def _compile_entry_impl(
             for w in pad_warnings:
                 warnings.warn(f"cache='symbolic values': {w}", stacklevel=2)
 
-    # Trace-to-trace transforms requested at jit() time (grad, autocast, ...).
-    trace_transforms = cd.compile_options.get("_trace_transforms", ())
-    for tt in trace_transforms:
-        comp_trc = tt(comp_trc)
-        computation_traces.append(comp_trc)
+    # Trace-to-trace transforms requested at jit() time (grad, autocast, ...),
+    # then the rewrites (not from de-opt ladder level 1, "disable fusion", up),
+    # RNG functionalization and the claim: thunder_tpu/pipeline.py.
+    trace_transforms = tuple(cd.compile_options.get("_trace_transforms", ()))
     if sym_spec is not None and trace_transforms:
         # The grad/autocast rewrite minted new output proxies (grads); re-run
         # the provenance analysis on the transformed trace so the crop plan
         # covers them exactly (transforms/padmask.py).
         from thunder_tpu.transforms.padmask import analyze_crop_plan
 
-        sym_spec.crop_plan = analyze_crop_plan(comp_trc, sym_spec)
+        def replanned(trc):
+            sym_spec.crop_plan = analyze_crop_plan(trc, sym_spec)
+            return trc
 
-    # Joint-trace attention-residual saving: when grad produced fw+bw in one
-    # trace, let the flash backward consume saved (out, lse) instead of
-    # recomputing the forward kernel (transforms/attention_residuals.py).
-    # Skipped at de-opt ladder level ≥ 1 ("disable fusion").
-    if deopt_level < 1:
-        from thunder_tpu.transforms.attention_residuals import save_sdpa_residuals_joint
-
-        comp_trc = save_sdpa_residuals_joint(comp_trc, cd.executors_list)
-        # and let cross-entropy read the 16-bit logits that a program upcast
-        # for it alone (transforms/cross_entropy_upcast.py)
-        from thunder_tpu.transforms.cross_entropy_upcast import fold_cross_entropy_upcasts
-
-        comp_trc = fold_cross_entropy_upcasts(comp_trc, cd.executors_list)
-        # and, in a forward program, let q, k and v leave their projection
-        # head-major (transforms/attention_layout.py)
-        from thunder_tpu.transforms.attention_layout import fold_attention_layouts
-
-        folded = fold_attention_layouts(comp_trc, cd.executors_list)
-        if folded is not comp_trc:
-            computation_traces.append(comp_trc := folded)
-
-    comp_trc = functionalize_rng_ops(comp_trc)
-    if comp_trc.tags.get(RNG_TAG):
-        computation_traces.append(comp_trc)
-
-    phases["transforms"] = (timer_ns() - _phase_mark) / 1e9
-    _phase_mark = timer_ns()
-    extrace = transform_for_execution(comp_trc, cd.executors_list)
-    computation_traces.append(extrace)
-    phases["claim"] = (timer_ns() - _phase_mark) / 1e9
-    _phase_mark = timer_ns()
+        trace_transforms += (replanned,)
+    own_s = (timer_ns() - _phase_mark) / 1e9  # value guards and pad masks: the dispatcher's own
+    compiled = pipeline.compile_trace(comp_trc, cd.executors_list, transforms=trace_transforms,
+                                      rewrites=deopt_level < 1)
+    computation_traces.extend(compiled.traces)
+    extrace = compiled.claimed
+    phases["transforms"] = own_s + compiled.seconds["transforms"]
+    phases["claim"] = compiled.seconds["claim"]
 
     # Chaos seam: NaN-poison a chosen BoundSymbol (after claiming, so the
     # poison survives into both the staged entry and the instrumented
@@ -738,17 +714,11 @@ def _compile_entry_impl(
         and on_nan_opt != "rerun-instrumented"
         and jaxex._donation_active()
     )
-    extrace, static_plan, static_cert = _static_planner(
+    static_plan, static_cert = _static_planner(
         extrace, sym_spec,
         donate=donate_buckets,
         rerun_capable=on_nan_opt == "rerun-instrumented",
-        # The comm scheduler rides the same advisory phase; the de-opt
-        # ladder disables it from L1 up (like fusion) so a bad schedule
-        # demotes cleanly through the existing recovery loop.
-        comm_schedule=deopt_level < 1,
     )
-    if extrace is not claimed_extrace:
-        computation_traces.append(extrace)
     phases["static_analysis"] = (timer_ns() - _phase_mark) / 1e9
     _phase_mark = timer_ns()  # codegen span starts after the planner
 
@@ -860,27 +830,14 @@ def _compile_entry_impl(
         entry.stats.predicted_peak_bytes = int(static_plan.peak_bytes)
     entry.schedule_certificate = static_cert
     cs.trace_seconds += entry.stats.trace_s
-    comm_sched_tag = extrace.tags.get("comm_schedule")
-    from thunder_tpu.transforms.attention_layout import FOLDED_TAG as LAYOUTS_FOLDED_TAG
-    from thunder_tpu.transforms.cross_entropy_upcast import FOLDED_TAG
-
     for phase in ("trace", "transforms", "claim", "static_analysis", "codegen",
                   "staging"):
-        extra = {}
-        if phase == "transforms":  # by presence, as below: a de-optimized compile ran no such pass
-            extra = {tag: comp_trc.tags[tag] for tag in (FOLDED_TAG, LAYOUTS_FOLDED_TAG) if tag in comp_trc.tags}
+        extra = compiled.extras.get(phase, {})
         if phase == "static_analysis" and static_plan is not None:
             extra = dict(
                 predicted_peak_bytes=int(static_plan.peak_bytes),
                 collective_sites=len(static_cert.sites) if static_cert else 0,
             )
-            # Comm-scheduler outcome, by PRESENCE only: entries the pass
-            # never touched (no collectives, disabled, de-opted) carry none.
-            if comm_sched_tag:
-                extra["comm_schedule_moves"] = comm_sched_tag.get("moves")
-                extra["comm_schedule_exposed_pct"] = comm_sched_tag.get(
-                    "exposed_pct_after"
-                )
         _record_compile_phase(compile_id, phase, phases.get(phase, 0.0), **extra)
 
     # Observability: compile-side metrics + the compile_end event carrying
@@ -918,16 +875,12 @@ def _compile_entry_impl(
     return entry
 
 
-def _static_planner(extrace: TraceCtx, sym_spec, *, donate: bool,
-                    rerun_capable: bool, comm_schedule: bool = False):
-    """The compile pipeline's static_analysis phase (ISSUE 10 + 13): stamp
-    donation metadata on the claimed execution trace, run the certificate-
-    driven collective-overlap scheduler (``transforms/comm_schedule.py`` —
-    the donation tags must land first so its liveness back-off prices the
-    real plan), plan the result's HBM liveness, and certify its collective
-    schedule. Returns ``(extrace, MemoryPlan | None, ScheduleCertificate |
-    None)`` — planning/scheduling failures degrade to the input trace and
-    None, never break a compile."""
+def _static_planner(extrace: TraceCtx, sym_spec, *, donate: bool, rerun_capable: bool):
+    """The compile pipeline's static_analysis phase (ISSUE 10): stamp
+    donation metadata on the claimed execution trace, plan its HBM liveness,
+    and certify its collective schedule. Returns ``(MemoryPlan | None,
+    ScheduleCertificate | None)`` — a planning failure degrades to None,
+    never breaks a compile."""
     try:
         from thunder_tpu.analysis import liveness as live_mod
         from thunder_tpu.analysis import schedule as sched_mod
@@ -941,23 +894,16 @@ def _static_planner(extrace: TraceCtx, sym_spec, *, donate: bool,
         extrace.tags["donated_inputs"] = donated_names
         if rerun_capable:
             extrace.tags["rerun_reads_inputs"] = True
-        if comm_schedule:
-            from thunder_tpu.transforms import comm_schedule as comm_sched
-
-            if comm_sched.enabled():
-                extrace, _ = comm_sched.schedule_collectives(extrace)
         plan = live_mod.plan_liveness(
             extrace, donated=donated_names, include_rows=False
         )
         # Certify + stamp the per-axis collective order baseline; the
         # sched.uncertified-reorder rule diffs later passes against it, and
-        # the watchdog attaches the axis order to timeout diagnoses. (The
-        # scheduler already recertified its own output; stamping again is
-        # idempotent on the preserved per-axis order.)
+        # the watchdog attaches the axis order to timeout diagnoses.
         cert = sched_mod.stamp(extrace)
-        return extrace, plan, cert
+        return plan, cert
     except Exception:  # noqa: BLE001 — the planner is advisory, never fatal
-        return extrace, None, None
+        return None, None
 
 
 def _resolve_instrument_hooks(cd: CompileData) -> tuple:
@@ -1014,6 +960,17 @@ def _next_key():
 
     _global_rng["seed"] += 1
     return jax.random.PRNGKey(_global_rng["seed"])
+
+
+def keyed_callable(claimed: TraceCtx) -> Callable:
+    """``claimed.python_callable()`` for a front end that threads no RNG key of
+    its own: a trace that draws random numbers takes its key last since
+    ``pipeline.LOWER``, and gets the process's next one where it is called
+    (under ``jax.jit``: where it is staged)."""
+    fn = claimed.python_callable()
+    if not claimed.tags.get(RNG_TAG):
+        return fn
+    return lambda *args: fn(*args, _next_key())
 
 
 def _build_epilogue(muts: list) -> Callable:
@@ -2077,11 +2034,8 @@ def _staged_flat_fn(fn: Callable, args: tuple, kwargs: Optional[dict] = None,
     """Trace+claim fn for the given example args → flat jax callable whose
     inputs are the TENSOR leaves of (args, kwargs) in pytree order (number/
     string leaves are prologue-guarded constants baked into the trace).
-    ``trace_transforms`` (e.g. grad_transform) run after dce/cse, mirroring
-    _compile_entry's pipeline — this is what lets vmap compose with a
-    grad-compiled function."""
-    from thunder_tpu.executors.passes import transform_for_execution
-
+    ``trace_transforms`` (e.g. grad_transform) are what lets vmap compose
+    with a grad-compiled function."""
     _, comp = trace_program(fn, args, kwargs or {})
     if getattr(comp, "_input_mutations", None):
         # ADVICE r5 #2: this path re-stages without the jit epilogue, so a
@@ -2094,11 +2048,9 @@ def _staged_flat_fn(fn: Callable, args: tuple, kwargs: Optional[dict] = None,
             "epilogue does not run on this path) — make the function pure or "
             "apply updates outside it"
         )
-    comp = cse(dce(comp))
-    for tt in trace_transforms:
-        comp = tt(comp)
-    extrace = transform_for_execution(comp, resolve_executors(executors))
-    return extrace.python_callable()
+    compiled = pipeline.compile_trace(pipeline.clean(comp)[-1], resolve_executors(executors),
+                                      transforms=trace_transforms)
+    return keyed_callable(compiled.claimed)
 
 
 def _unwrap_compiled(fn: Callable) -> tuple[Callable, tuple]:

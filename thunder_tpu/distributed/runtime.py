@@ -13,7 +13,7 @@ latency-hiding scheduler rather than stream juggling.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Sequence
+from typing import Callable
 
 
 def shard_map_callable(fn: Callable, mesh, in_specs, out_specs, *, check_vma: bool = False,
@@ -48,44 +48,37 @@ def compile_with_collectives(
     out_specs,
     *,
     grad: bool = False,
-    comm_schedule: bool = False,
-    comm_schedule_opts: Optional[dict] = None,
 ):
     """Trace ``fn`` through the framework pipeline (so dist_prims record into
     the trace), then stage the claimed trace under shard_map over ``mesh``.
 
-    ``comm_schedule=True`` runs the certificate-driven collective-overlap
-    scheduler over the claimed trace first (transforms/comm_schedule.py):
-    fsdp ``synchronize`` gathers hoist to async-prefetch positions, the
-    re-certified trace stages in the scheduled order.
-
-    Returns the jitted callable (flat args in trace order).
+    Returns the jitted callable (flat args in trace order) and the claimed
+    trace.
     """
+    from functools import partial
+
+    from thunder_tpu import pipeline
     from thunder_tpu.api import trace_program
-    from thunder_tpu.executors.passes import transform_for_execution
     from thunder_tpu.extend import resolve_executors
     from thunder_tpu.transforms.autodiff import grad_transform
-    from thunder_tpu.transforms.common import dce
 
     _, comp = trace_program(fn, example_args, {})
-    comp = dce(comp)
-    if grad:
-        comp = grad_transform(comp, return_value=True)
-    extrace = transform_for_execution(
-        comp, resolve_executors(None),
-        comm_schedule=comm_schedule, comm_schedule_opts=comm_schedule_opts,
-    )
+    extrace = pipeline.compile_trace(
+        pipeline.clean(comp)[-1], resolve_executors(None),
+        transforms=(partial(grad_transform, return_value=True),) if grad else (),
+    ).claimed
     return stage_collective_trace(extrace, mesh, in_specs, out_specs), extrace
 
 
 def stage_collective_trace(extrace, mesh, in_specs, out_specs) -> Callable:
     """Stage an already-claimed collective-bearing execution trace under
     shard_map over ``mesh`` (the tail of :func:`compile_with_collectives`,
-    split out so callers holding a transformed trace — e.g. one rewritten
-    by the comm scheduler — can restage it without re-tracing)."""
+    split out so callers holding a transformed trace can restage it
+    without re-tracing)."""
+    from thunder_tpu.api import keyed_callable
     from thunder_tpu.distributed.prims import collective_trace_lines
 
-    inner = extrace.python_callable()
+    inner = keyed_callable(extrace)
     # Certify the collective schedule (ISSUE 10): stamps the per-axis order
     # baseline on the trace and hands the watchdog the certified order so a
     # timeout names the collectives that must already have completed before

@@ -109,8 +109,8 @@ def examine(fn: Callable, *args, **kwargs) -> dict:
 
 
 def lint(fn: Callable, *args, executors: Optional[Any] = None, verbose: bool = True, **kwargs) -> list:
-    """Trace ``fn`` on the given example inputs, run the default pass
-    pipeline (acquisition → DCE → CSE → claiming → del_last_used), and run
+    """Trace ``fn`` on the given example inputs, run the pass pipeline the
+    dispatcher runs (``thunder_tpu/pipeline.py``, then del_last_used), and run
     the static verifier (thunder_tpu/analysis) over every stage. Returns the
     full list of :class:`~thunder_tpu.analysis.Diagnostic`s; with ``verbose``
     pretty-prints each one with the offending generated trace line.
@@ -120,12 +120,12 @@ def lint(fn: Callable, *args, executors: Optional[Any] = None, verbose: bool = T
     so it doubles as a trace-quality report. Rule ids and the
     suppression/extension story: docs/trace_invariants.md.
     """
+    from thunder_tpu import pipeline
     from thunder_tpu.analysis import attach_trace_lines, verify
     from thunder_tpu.api import trace_program
     from thunder_tpu.core.trace import debug_checks, mark
-    from thunder_tpu.executors.passes import del_last_used, transform_for_execution
+    from thunder_tpu.executors.passes import del_last_used
     from thunder_tpu.extend import resolve_executors
-    from thunder_tpu.transforms.common import cse, dce
 
     # A thunder-compiled function: lint the UNDERLYING function (tracing the
     # wrapper would trace the dispatch machinery) and report its cache state
@@ -138,26 +138,20 @@ def lint(fn: Callable, *args, executors: Optional[Any] = None, verbose: bool = T
     # The pipeline below must not raise mid-way even when THUNDER_TPU_CHECKS
     # is set globally — lint's contract is collect-everything.
     with debug_checks(False):
-        # record_input_mutations=True mirrors the jit() pipeline: an
-        # input-mutating fn gets the same {"__out", "__muts"} epilogue
-        # structure in its trace, so lint verifies the program that would
-        # actually compile.
+        # record_input_mutations=True as jit() has it: an input-mutating fn
+        # gets the same {"__out", "__muts"} epilogue structure in its trace,
+        # so lint verifies the program that would actually compile.
         plg, comp = trace_program(fn, args, kwargs, record_input_mutations=True)
         mark(comp, "Acquisition")
         mark(plg, "Prologue construction")
-        stages: list[tuple[str, TraceCtx]] = [("Prologue construction", plg), ("Acquisition", comp)]
-        comp = dce(comp)
-        stages.append(("Dead Code Elimination", comp))
-        comp = cse(comp)
-        stages.append(("Common Subexpression Elimination", comp))
-        extrace = transform_for_execution(comp, resolve_executors(executors))
-        stages.append(("Transform for execution", extrace))
-        extrace = del_last_used(extrace)
-        stages.append(("Delete Last Used", extrace))
+        cleaned = pipeline.clean(comp)
+        made = pipeline.compile_trace(cleaned[-1], resolve_executors(executors)).traces
+        extrace = del_last_used(made[-1])
+        stages: list[TraceCtx] = [plg, comp, *cleaned, *made, extrace]
 
     diagnostics = []
-    for name, trc in stages:
-        diags = verify(trc, pass_name=name)
+    for trc in stages:
+        diags = verify(trc)
         attach_trace_lines(diags, trc)
         diagnostics.extend(diags)
 

@@ -8,6 +8,9 @@ Claiming walks each top-level bound symbol: the first executor in priority
 order whose checker accepts it claims it whole; otherwise the pass descends
 into the symbol's decomposition (subsymbols). Terminal prims must be claimed
 by someone (the JAX executor covers all of them).
+
+Which passes run before the claim, and in which order, is not said here:
+``thunder_tpu/pipeline.py`` owns that list, and every front end calls it.
 """
 
 from __future__ import annotations
@@ -55,19 +58,8 @@ def would_claim(bsym: BoundSymbol, executors: Sequence[Executor]):
     return None
 
 
-def transform_for_execution(
-    trace: TraceCtx,
-    executors_list: Sequence[Executor],
-    *,
-    comm_schedule: bool = False,
-    comm_schedule_opts: dict | None = None,
-) -> TraceCtx:
-    """Claim every bound symbol, run fusion passes, and — when
-    ``comm_schedule=True`` and ``THUNDER_TPU_COMM_SCHEDULE`` permits — run
-    the certificate-driven collective-overlap scheduler
-    (``transforms/comm_schedule.py``) over the claimed trace.
-    ``comm_schedule_opts`` forwards ``device``/``capacity_bytes``/
-    ``arg_divisors`` to the scheduler."""
+def transform_for_execution(trace: TraceCtx, executors_list: Sequence[Executor]) -> TraceCtx:
+    """Claim every bound symbol and run the fusion passes."""
     start = time.perf_counter_ns()
     executors_list = tuple(executors_list) + get_always_executors()
     new_bsyms: list[BoundSymbol] = []
@@ -123,16 +115,7 @@ def transform_for_execution(
 
     extrace.tags["claim_breakdown"] = _claim_breakdown(extrace)
     extrace.tags["collective_bytes"] = _collective_bytes(extrace)
-    extrace = wrap_in_trace_provenance(extrace, "Transform for execution", start)
-
-    if comm_schedule:
-        from thunder_tpu.transforms import comm_schedule as comm_sched
-
-        if comm_sched.enabled():
-            extrace, _ = comm_sched.schedule_collectives(
-                extrace, **(comm_schedule_opts or {})
-            )
-    return extrace
+    return wrap_in_trace_provenance(extrace, "Transform for execution", start)
 
 
 def _claim_breakdown(trace: TraceCtx) -> dict[str, int]:

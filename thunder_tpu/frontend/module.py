@@ -565,12 +565,11 @@ class ThunderModule:
     def _compile_checked(self, args: tuple, kwargs: dict, _force_replicated_data: bool = False) -> dict:
         import jax
 
-        from thunder_tpu.api import trace_program
+        from thunder_tpu import pipeline
+        from thunder_tpu.api import keyed_callable, trace_program
         from thunder_tpu.executors import bridge
-        from thunder_tpu.executors.passes import transform_for_execution
         from thunder_tpu.extend import resolve_executors
         from thunder_tpu.transforms.autodiff import forward_and_backward_from_trace
-        from thunder_tpu.transforms.common import cse, dce
 
         module = self._module
         dist_n = self._dist_axis_size()
@@ -741,7 +740,7 @@ class ThunderModule:
         from thunder_tpu.core.concrete import value_guards_of
 
         vguards = value_guards_of(comp)
-        comp = cse(dce(comp))
+        comp = pipeline.clean(comp)[-1]
 
         # Mark requires_grad on the trace's tensor args. Trace args align
         # with the concrete tensor leaves of ((params, *args), kwargs) in
@@ -850,7 +849,7 @@ class ThunderModule:
             """jax.jit for single-device; shard_map over the mesh when a
             ddp/fsdp config is active (collectives in the trace reference
             the mesh axis by name)."""
-            fn = trc.python_callable()
+            fn = keyed_callable(trc)
             if wrap is not None:
                 fn = wrap(fn)
             if dist_axis is None:
@@ -858,16 +857,19 @@ class ThunderModule:
             from thunder_tpu.distributed.runtime import shard_map_callable
 
             if in_specs is None:
-                in_specs = tuple(spec_of(a) for a in trc.args)
+                from thunder_tpu.transforms.rng import RNG_TAG
+
+                own = trc.args[:-1] if trc.tags.get(RNG_TAG) else trc.args  # the key is keyed_callable's
+                in_specs = tuple(spec_of(a) for a in own)
             return shard_map_callable(fn, self._dist["mesh"], in_specs, out_specs)
 
         has_updates = isinstance(comp.output, dict) and "__updates" in comp.output
 
         try:
             if not needs_grad:
-                ex = transform_for_execution(comp, executors)
+                compiled = pipeline.compile_trace(comp, executors)
                 out_specs = tree_map(out_spec_of, comp.output) if dist_axis else None
-                return {"fwd": stage(ex, out_specs), "bwd": None, "traces": [comp, ex],
+                return {"fwd": stage(compiled.claimed, out_specs), "bwd": None, "traces": [comp, *compiled.traces],
                         "has_updates": has_updates, "value_guards": vguards}
 
             fw, bw = forward_and_backward_from_trace(comp)
@@ -890,8 +892,8 @@ class ThunderModule:
                     and dist_n > 1
                 )
                 fw, bw = rematerialize_forward_and_backward(fw, bw, remat_collectives=zero3)
-            fw_ex = transform_for_execution(fw, executors)
-            bw_ex = transform_for_execution(bw, executors)
+            fw_traces = pipeline.compile_trace(fw, executors).traces
+            fw_ex, bw_ex = fw_traces[-1], pipeline.compile_trace(bw, executors).claimed
 
             if dist_axis is None:
                 fw_out_specs = bw_out_specs = bw_in_specs = None
@@ -950,7 +952,7 @@ class ThunderModule:
             "fwd": stage(fw_ex, fw_out_specs),
             "bwd": stage(bw_ex, bw_out_specs, bw_in_specs, wrap=bw_wrap),
             "wrt_kinds": wrt_kinds,
-            "traces": [comp, fw_ex, bw_ex],
+            "traces": [comp, *fw_traces, bw_ex],
             "has_updates": has_updates,
             "nosync": nosync,
             "accum": self._nosync_accum,

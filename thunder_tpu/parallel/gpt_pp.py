@@ -36,16 +36,15 @@ def _staged(fn, example_args, executors: Optional[Sequence[str]]):
     The callable's positional args are the TENSOR leaves of example_args in
     pytree order (jax flatten: dict keys sorted) — callers must pass live
     values flattened the same way."""
-    from thunder_tpu.api import trace_program
+    from thunder_tpu import pipeline
+    from thunder_tpu.api import keyed_callable, trace_program
     from thunder_tpu.core.pytree import tree_flatten
-    from thunder_tpu.executors.passes import transform_for_execution
     from thunder_tpu.extend import resolve_executors
-    from thunder_tpu.transforms.common import cse, dce
 
     _, comp = trace_program(fn, example_args, {})
-    call = transform_for_execution(
-        cse(dce(comp)), resolve_executors(list(executors) if executors else None)
-    ).python_callable()
+    call = keyed_callable(pipeline.compile_trace(
+        pipeline.clean(comp)[-1], resolve_executors(list(executors) if executors else None)
+    ).claimed)
 
     def flat_call(*live_args):
         flat, _ = tree_flatten((tuple(live_args), {}))

@@ -98,66 +98,35 @@ def _phase(program, name: str):
     _record_compile_phase(program, name, time.perf_counter() - t0, **extra)
 
 
-def _compile_loss_and_grads(config: GPTConfig, params, idx, targets, executors=None,
-                            *, mesh=None, param_specs=None, comm_schedule=True):
+def _compile_loss_and_grads(config: GPTConfig, params, idx, targets, executors=None):
     """Trace loss_fn through the framework pipeline → a pure jax callable
     taking the flat tensor leaves and returning (loss, grads_tuple).
 
-    ``comm_schedule`` runs the certificate-driven collective-overlap
-    scheduler (transforms/comm_schedule.py) over the claimed joint trace —
-    a no-op when the trace routes its collectives through the SPMD
-    partitioner instead of dist_prims, so the pjit path keeps its exact
-    program; trace-level FSDP/TP steps get their gathers prefetched. The
-    mesh/param_specs (when given) divide sharded inputs so the scheduler's
-    liveness back-off prices per-device bytes.
-
     Claiming runs under the caller's ``kernel_mesh`` declaration, so the
     kernel checkers size their blocks on one batch shard's rows."""
-    from thunder_tpu.api import trace_program
-    from thunder_tpu.executors.passes import transform_for_execution
+    from thunder_tpu import pipeline
+    from thunder_tpu.api import _record_compile_phase, keyed_callable, trace_program
     from thunder_tpu.extend import resolve_executors
     from thunder_tpu.observability.events import current_compile_id
-    from thunder_tpu.transforms.attention_residuals import save_sdpa_residuals_joint
     from thunder_tpu.transforms.autodiff import grad_transform
-    from thunder_tpu.transforms.common import dce
-    from thunder_tpu.transforms.cross_entropy_upcast import FOLDED_TAG, fold_cross_entropy_upcasts
 
-    # The phases carry the ``jit`` path's names where the stage is the
-    # ``jit`` path's (docs/observability.md, "Compile-phase spans").
+    # The phases carry the ``jit`` path's names (docs/observability.md,
+    # "Compile-phase spans").
     program = current_compile_id()  # build_train_step's compile_scope
-    ex_list = resolve_executors(executors)
     fn = lambda p, i, t: loss_fn(p, i, t, config)  # noqa: E731
     with _phase(program, "trace"):
         _, comp = trace_program(fn, (params, idx, targets), {})
-        comp = dce(comp)
-    with _phase(program, "transforms") as extra:
-        joint = grad_transform(comp, return_value=True)
-        joint = save_sdpa_residuals_joint(joint, ex_list)
-        joint = fold_cross_entropy_upcasts(joint, ex_list)
-        extra[FOLDED_TAG] = joint.tags[FOLDED_TAG]
-        divisors = None
-        if mesh is not None and param_specs is not None:
-            from thunder_tpu.analysis.liveness import arg_divisors_from_specs
-
-            try:
-                # The joint trace shares its args with the claimed trace, so
-                # the divisors computed here hold for the scheduler's input.
-                divisors = arg_divisors_from_specs(joint, param_specs, mesh=mesh)
-            except Exception:  # noqa: BLE001 — divisors refine, never gate
-                divisors = None
-    with _phase(program, "claim") as extra:  # holds the comm scheduler on this path
-        extrace = transform_for_execution(
-            joint, ex_list,
-            comm_schedule=comm_schedule,
-            comm_schedule_opts={"arg_divisors": divisors} if divisors else None,
-        )
-        comm_sched_tag = extrace.tags.get("comm_schedule")
-        if comm_sched_tag:  # by presence only, as static_analysis carries it on the jit path
-            extra["comm_schedule_moves"] = comm_sched_tag.get("moves")
-            extra["comm_schedule_exposed_pct"] = comm_sched_tag.get("exposed_pct_after")
+    t0 = time.perf_counter()
+    comp = pipeline.clean(comp)[-1]
+    clean_s = time.perf_counter() - t0
+    compiled = pipeline.compile_trace(comp, resolve_executors(executors),
+                                      transforms=(partial(grad_transform, return_value=True),))
+    _record_compile_phase(program, "transforms", clean_s + compiled.seconds["transforms"],
+                          **compiled.extras["transforms"])
+    _record_compile_phase(program, "claim", compiled.seconds["claim"])
     with _phase(program, "codegen"):
-        run = extrace.python_callable()
-    return run, extrace
+        run = keyed_callable(compiled.claimed)
+    return run, compiled.claimed
 
 
 def build_train_step(
@@ -217,7 +186,6 @@ def build_train_step(
         with kernel_mesh(mesh, batch_axes):
             loss_and_grads, extrace = _compile_loss_and_grads(
                 config, params, idx, targets, executors=executors,
-                mesh=mesh, param_specs=param_specs,
             )
         # The state goes in as the step hands it back (an int32 array, and
         # under a mesh laid out by ``opt_sh``): a first call whose arguments

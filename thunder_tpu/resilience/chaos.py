@@ -125,7 +125,7 @@ from thunder_tpu.observability import metrics as obsm
 SEAMS = (
     "kernel_raise", "compile_fail", "compile_timeout", "oom", "nan",
     "straggler", "ckpt_io", "preempt", "cache_corrupt",
-    "collective_hang", "host_loss", "sdc", "sched_bad",
+    "collective_hang", "host_loss", "sdc",
     "snap_torn", "snap_corrupt", "snap_slow",
     "slice_loss", "dcn_partition", "slice_slow", "slice_flap",
 )
@@ -542,20 +542,6 @@ def straggler_seam(site: str = "step") -> None:
         rule.fired += 1
         _record(rule, site)
         time.sleep(rule.delay_s)
-
-
-def sched_seam(site_key: str, placement: int, latest: int) -> int:
-    """Comm-scheduler seam (transforms/comm_schedule.py): when an armed
-    ``sched_bad`` rule matches the collective site, corrupt the computed
-    placement to one past the site's certified ``latest`` — the scheduler's
-    own interval validation must catch it and fall back to the unscheduled
-    trace (a bad schedule demotes cleanly instead of compiling a potential
-    cross-host deadlock). Returns ``placement`` unchanged when not armed."""
-    if active() is None:
-        return placement
-    if _should_fire("sched_bad", site_key) is not None:
-        return latest + 8
-    return placement
 
 
 def checkpoint_seam() -> None:

@@ -7,10 +7,8 @@ backoff:
 
 ====  ==========================================================
 L0    normal compilation
-L1    disable fusion passes, the collective-overlap scheduler
-      (transforms/comm_schedule.py — a bad schedule demotes to the
-      certified program order instead of wedging), and XLA buffer
-      donation
+L1    disable fusion passes (the rewrites of ``thunder_tpu/pipeline.py``)
+      and XLA buffer donation
 L2    L1 + aggressive rematerialization (transforms/rematerialization
       recomputes longer chains regardless of saved-byte accounting)
 L3    L2 + exact shapes (no bucket padding; shrinks live memory for
