@@ -24,7 +24,12 @@ norm after each sublayer beside the one before it (``sandwich_norms``); and
 LongCat-Flash's (``"ShortcutMoE"``): a double layer of two latent-attention
 sublayers and two dense FFNs with one routed layer on a shortcut across them,
 whose softmax router scores zero-compute (identity) experts beside the real ones
-(``zero_expert_num``) and leaves the chosen weights as they are.
+(``zero_expert_num``) and leaves the chosen weights as they are; and Granite
+4.0-H's (``granitemoehybrid``): Mamba-2 mixers (``"mamba"``: a packed projection
+to a gate, ``[x | B | C]`` and a step a head, a short causal convolution, a
+state-space recurrence whose decay each token sets, a gated norm) beside
+rope-less grouped-query attention under its own softmax scale
+(``attention_scale``).
 
 TPU-first design: the model is a *pure function* ``forward(params, idx)``
 over a params pytree — no module object, no buffers, no in-place state. That
@@ -119,9 +124,10 @@ class GPTConfig:
     # beta_fast, beta_slow, mscale, mscale_all_dim)
     yarn: Optional[tuple] = None
     # The mixer of each layer, as published: "full_attention", "sliding_attention"
-    # (the same layer, causal within sliding_window keys) or "conv" (a gated
-    # short convolution of conv_kernel taps). Empty is attention everywhere; a
-    # model cut in depth runs the first n_layer of them.
+    # (the same layer, causal within sliding_window keys), "conv" (a gated
+    # short convolution of conv_kernel taps), "sparse_attention", "linear_attention"
+    # or "mamba" (below). Empty is attention everywhere; a model cut in depth runs
+    # the first n_layer of them.
     layer_types: tuple = ()
     # "sliding_attention": query i sees the keys j with 0 <= i - j < sliding_window,
     # so a sequence no longer than that attends causally. Such a layer is roped
@@ -168,6 +174,18 @@ class GPTConfig:
     linear_output_norm: bool = False
     linear_output_gate: bool = False
     decay_depth: Optional[int] = None
+    # The softmax scale of "full_attention" and "sliding_attention" layers; None is sdpa's own head_size**-0.5.
+    attention_scale: Optional[float] = None
+    # "mamba" (Mamba-2): ssm_n_head heads of ssm_head_dim (together the expanded width), a state of ssm_state a
+    # head, B and C shared by the heads of each of ssm_groups groups, a causal convolution of ssm_conv_kernel taps
+    # with a bias over [x | B | C]. ssm_chunk is the decomposition's chunk (None: ttorch.SSM_SCAN_CHUNK), the
+    # program's own choice and no part of the equations.
+    ssm_n_head: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    ssm_conv_kernel: int = 4
+    ssm_chunk: Optional[int] = None
 
     @property
     def head_size(self) -> int:
@@ -215,6 +233,20 @@ class GPTConfig:
         """g of each head of linear-attention layer ``layer`` (0-based, of ``decay_depth``)."""
         H, L = self.linear_heads, self.decay_depth if self.decay_depth is not None else self.n_layer
         return tuple(2.0 ** (-8.0 * (h + 1) / H) * (1.0 - layer / max(L - 1, 1) + 1e-5) for h in range(H))
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_n_head * self.ssm_head_dim
+
+    @property
+    def ssm_conv_channels(self) -> int:
+        """``[x | B | C]``: what the convolution runs over."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def ssm_chunk_size(self) -> int:
+        """The chunk ``ssm_scan`` is called with: what the program publishes of its decomposition."""
+        return self.ssm_chunk if self.ssm_chunk is not None else ttorch.SSM_SCAN_CHUNK
 
     @property
     def qk_head_dim(self) -> int:
@@ -426,6 +458,28 @@ _add(GPTConfig(name="longcat-tiny", block_size=64, vocab_size=96, padded_vocab_s
                qk_rope_head_dim=8, v_head_dim=16, rope_interleaved=True, mla_scale_q_lora=True,
                mla_scale_kv_lora=True))
 
+# Granite 4.0-H Micro (huggingface.co/ibm-granite/granite-4.0-h-micro, model_type granitemoehybrid, no routed
+# part: num_local_experts 0) at its published sizes: 36 Mamba-2 mixers (64 heads of 64 on one group's B and C, state
+# 128, 4 taps) and, at layers 5, 15, 25 and 35, grouped-query attention (32 on 8 heads of 64) with no rope and a
+# softmax scale of 1/64; SwiGLU of 8192; the Granite multipliers (embedding 12, residual 0.22, logits over 8); the
+# head is the embedding.
+_GRANITE_H_LAYERS = tuple("full_attention" if i % 10 == 5 else "mamba" for i in range(40))
+_add(GPTConfig(name="granite-4.0-h-micro", block_size=131072, vocab_size=100352, padded_vocab_size=100352,
+               n_layer=40, n_head=32, n_embd=2048, n_query_groups=8, rotary_percentage=1.0,
+               parallel_residual=False, bias=False, norm_class="RMSNorm", norm_eps=1e-5, mlp_class="LLaMAMLP",
+               intermediate_size=8192, rope_base=10000, layer_types=_GRANITE_H_LAYERS, attn_rope=False,
+               attention_scale=0.015625, tie_embeddings=True, embedding_scale=12.0, residual_scale=0.22,
+               logit_divisor=8.0, ssm_n_head=64, ssm_head_dim=64, ssm_state=128, ssm_groups=1, ssm_conv_kernel=4))
+# The same blocks at test size: heads of 64 in both mixers, and a chunk of 64 so that T = 256 is four chunks.
+_add(GPTConfig(name="granite-h-tiny", block_size=256, vocab_size=96, padded_vocab_size=96,
+               n_layer=6, n_head=2, n_embd=128, n_query_groups=1, rotary_percentage=1.0,
+               parallel_residual=False, bias=False, norm_class="RMSNorm", norm_eps=1e-5, mlp_class="LLaMAMLP",
+               intermediate_size=256, rope_base=10000,
+               layer_types=("mamba", "full_attention", "mamba", "mamba", "full_attention", "mamba"), attn_rope=False,
+               attention_scale=0.015625, tie_embeddings=True, embedding_scale=12.0, residual_scale=0.22,
+               logit_divisor=8.0, ssm_n_head=4, ssm_head_dim=64, ssm_state=32, ssm_groups=1, ssm_conv_kernel=4,
+               ssm_chunk=64))
+
 # Falcon family — MQA (one KV head) + shared-attention-norm parallel residual
 # (the litgpt registry's falcon geometry; reference tests run falcon-7b-like
 # configs through thunder).
@@ -528,6 +582,21 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
         return {"in_proj_w": w(3 * C.n_embd, C.n_embd), "conv_w": w(C.n_embd, C.conv_kernel),
                 "out_proj_w": w(C.n_embd, C.n_embd, std=0.02 / np.sqrt(2 * C.n_layer))}
 
+    def mamba_params():
+        # in_proj_w's rows are [z | x | B | C | dt]; conv_w as conv_params' (oldest tap first), over [x | B | C].
+        # A_log, dt_bias and D are float32 whatever the weights are (the decay is computed in float32), drawn
+        # as Mamba-2 draws them: A in [1, 16], a step in [0.001, 0.1] log-uniform through the inverse softplus.
+        H = C.ssm_n_head
+        uniform = lambda lo, hi: rng.uniform(lo, hi, size=(H,)).astype(np.float32)  # the host's generator on either path
+        step = np.exp(uniform(np.log(0.001), np.log(0.1)))
+        return {"in_proj_w": w(C.ssm_inner + C.ssm_conv_channels + H, C.n_embd),
+                "conv_w": w(C.ssm_conv_channels, C.ssm_conv_kernel), "conv_b": zeros(C.ssm_conv_channels),
+                "dt_bias": jnp.asarray(step + np.log(-np.expm1(-step)), dtype=jnp.float32),
+                "A_log": jnp.asarray(np.log(uniform(1.0, 16.0)), dtype=jnp.float32),
+                "D": jnp.ones((H,), dtype=jnp.float32),
+                "norm": {"weight": ones(C.ssm_inner)},
+                "out_proj_w": w(C.n_embd, C.ssm_inner, std=0.02 / np.sqrt(2 * C.n_layer))}
+
     def swiglu_params(hidden):
         p = {
             "fc_1_w": w(hidden, C.n_embd),
@@ -573,6 +642,8 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
         mixer = C.layer_mixer(i)
         if mixer == "conv":
             p["conv"] = conv_params()
+        elif mixer == "mamba":
+            p["mamba"] = mamba_params()
         elif mixer == "sparse_attention":
             p["sparse_attn"] = attn_params(C.n_head, C.query_groups, C.attn_output_gate)
         elif mixer == "linear_attention":
@@ -711,12 +782,13 @@ def _attention(x, p, cos, sin, config: GPTConfig, sparse: bool = False, counts=N
     C, T = config, x.shape[1]
     H, G = C.n_head, C.query_groups
     q, k, v = _qkv_heads(x, p, H, G, cos, sin, C, window or C.attn_rope)
+    scale = {} if C.attention_scale is None else {"scale": C.attention_scale}  # a trace without it stays as it was
     if window and T > C.sliding_window:
         with region("attn.window"):
-            y = ttorch.window_attention(q, k, v, window=C.sliding_window)
+            y = ttorch.window_attention(q, k, v, window=C.sliding_window, **scale)
     elif not sparse or T < C.sparse_dense_len:
         with region("attn.full"):
-            y = ttorch.scaled_dot_product_attention(q, k, v, is_causal=True, enable_gqa=(G != H))
+            y = ttorch.scaled_dot_product_attention(q, k, v, is_causal=True, enable_gqa=(G != H), **scale)
     else:
         how = dict(kernel_size=C.sparse_kernel_size, kernel_stride=C.sparse_kernel_stride,
                    block_size=C.sparse_block_size, topk=C.sparse_topk, init_blocks=C.sparse_init_blocks,
@@ -773,6 +845,30 @@ def _short_conv(x, p, config: GPTConfig):
     then ``out_proj``."""
     with region("conv"):
         return ttorch.linear(ttorch.short_conv(ttorch.linear(x, p["in_proj_w"]), p["conv_w"]), p["out_proj_w"])
+
+
+def _mamba(x, p, config: GPTConfig):
+    """The Mamba-2 mixer: ``[z | xBC | dt] = in_proj(x)``; ``[x | B | C] =
+    silu(conv(xBC) + b)``, causal and depthwise; ``dt = softplus(dt + dt_bias)``
+    and ``A = -exp(A_log)`` in float32; head h's state ``S_t = exp(dt_t A) S_{t-1}
+    + dt_t x_t B_t^T``, ``y_t = S_t C_t + D x_t``; ``RMSNorm(y * silu(z))`` over
+    all heads' features; ``out_proj``."""
+    C = config
+    B, T, _ = x.shape
+    H, P, G, N, inner = C.ssm_n_head, C.ssm_head_dim, C.ssm_groups, C.ssm_state, C.ssm_inner
+    zxbcdt, conv_end = ttorch.linear(x, p["in_proj_w"]), inner + C.ssm_conv_channels
+    z, xbc, dt = zxbcdt[..., :inner], zxbcdt[..., inner:conv_end], zxbcdt[..., conv_end:]
+    with region("ssm.conv"):
+        xbc = ttorch.causal_conv_silu(xbc, p["conv_w"], p["conv_b"])
+    xs = ttorch.reshape(xbc[..., :inner], (B, T, H, P))
+    Bm = ttorch.reshape(xbc[..., inner:inner + G * N], (B, T, G, N))
+    Cm = ttorch.reshape(xbc[..., inner + G * N:], (B, T, G, N))
+    with region("ssm.scan"):
+        dt = ttorch.softplus(dt.float() + p["dt_bias"].float())
+        y = ttorch.ssm_scan(xs, dt, -ttorch.exp(p["A_log"].float()), Bm, Cm, p["D"], chunk=C.ssm_chunk_size)
+    with region("ssm.gate_norm"):
+        y = ttorch.gated_rms_norm(ttorch.reshape(y, (B, T, inner)), z, p["norm"]["weight"], eps=C.norm_eps)
+    return ttorch.linear(y, p["out_proj_w"])
 
 
 def _deinterleave_rows(w):
@@ -899,6 +995,8 @@ def _mix(x, p, cos, sin, config: GPTConfig, layer: int = 0, counts=None):
     parameters and are told apart by ``layer_mixer(layer)``."""
     if "conv" in p:
         return _short_conv(x, p["conv"], config)
+    if "mamba" in p:
+        return _mamba(x, p["mamba"], config)
     if "sparse_attn" in p:
         return _attention(x, p["sparse_attn"], cos, sin, config, sparse=True, counts=counts)
     if "linear_attn" in p:

@@ -1783,6 +1783,127 @@ def linear_attention(q, k, v, decay, scale: Optional[float] = None, chunk: Optio
     return o if n * C == T else o[:, :, :T]
 
 
+# A state-space mixer whose decay each token sets (Mamba-2, as Granite 4.0-H
+# publishes it): the convolution in front of the recurrence, the recurrence, and
+# the gated norm behind it. Composites, token-major as the model has them, so
+# that an executor can claim each whole and choose its own layout.
+
+SSM_SCAN_CHUNK = 256  # positions a chunk of ``ssm_scan``'s decomposition; the published kernel's constant too
+
+
+@torchsymbol(id="torch.causal_conv_silu")
+def causal_conv_silu(x, w, bias=None):
+    """``silu(conv(x) + bias)``: x (B, T, C), w (C, K) a depthwise causal filter,
+    oldest tap first, bias (C,) or None; ``c[t] = sum_j w[:, j] * x[t - (K - 1 - j)]``
+    with ``x[<0] = 0`` (Mamba's convolution over its packed ``[x | B | C]``).
+
+    K shifted products of one left-padded x, as ``short_conv`` has them and for
+    its reason; the sum, the bias and the SiLU in float32 and rounded once, which
+    XLA runs as one pass over x."""
+    C, K = w.shape
+    check(x.ndim == 3 and x.shape[-1] == C, lambda: f"causal_conv_silu: {tuple(x.shape)} is not (B, T, {C})")
+    T, f32 = x.shape[1], dtypes.float32
+    z = pad(x, (0, 0, K - 1, 0))
+    up = lambda a: clang.maybe_convert_to_dtype(a, f32)  # a tap's slice first, then float32: no float32 copy of x is whole
+    wf = up(w)
+    c = up(z[:, 0:T]) * wf[:, 0]
+    for j in range(1, K):
+        c = c + up(z[:, j:j + T]) * wf[:, j]
+    if bias is not None:
+        c = c + clang.maybe_convert_to_dtype(bias, f32)
+    return clang.maybe_convert_to_dtype(silu(c), x.dtype)
+
+
+@torchsymbol(id="torch.gated_rms_norm")
+def gated_rms_norm(y, z, weight, eps: float = RMS_NORM_EPS):
+    """``RMSNorm(y * silu(z))`` over the last dimension, the gate first and the
+    norm over all of its features (Mamba-2's ``norm_before_gate=False`` with one
+    group): y, z (..., C), weight (C,). Float32 inside, rounded once."""
+    check(tuple(y.shape) == tuple(z.shape) and tuple(weight.shape) == (y.shape[-1],),
+          lambda: f"gated_rms_norm: y {tuple(y.shape)}, z {tuple(z.shape)}, weight {tuple(weight.shape)}")
+    f32 = dtypes.float32
+    g = clang.maybe_convert_to_dtype(y, f32) * silu(clang.maybe_convert_to_dtype(z, f32))
+    normed = g * clang.rsqrt(clang.mean(g * g, (-1,), True) + eps)
+    return clang.maybe_convert_to_dtype(normed * clang.maybe_convert_to_dtype(weight, f32), y.dtype)
+
+
+@torchsymbol(id="torch.ssm_scan")
+def ssm_scan(x, dt, A, B, C, D=None, chunk: Optional[int] = None):
+    """The Mamba-2 recurrence (state-space duality) between its convolution and
+    its gated norm: x (B, T, H, P), ``dt`` (B, T, H) float32 and positive (after
+    its softplus), ``A`` (H,) float32 and negative, B and C (B, T, G, N) with
+    ``H % G == 0`` (a group's B and C are shared by its ``H / G`` heads), ``D``
+    (H,) or None -> (B, T, H, P). Head h, state ``S`` (P, N), ``S_{-1} = 0``:
+
+        S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T,    y_t = S_t C_t + D x_t
+
+    The decay is data, so the mask within a chunk and the carry between chunks are
+    arrays a batch and a head, where ``linear_attention`` has constants. With
+    ``cum`` the running sum of ``dt A`` inside a chunk of ``chunk`` positions:
+    within the chunk the masked quadratic form ``(C B^T * exp(cum_i - cum_j)) (dt
+    x)``, ``C B^T`` once a group; a chunk's summary ``sum_j exp(cum_end - cum_j)
+    dt_j x_j B_j^T``; the state entering chunk c one lower-triangular product over
+    the summaries with ``exp`` of the differences of the chunks' running totals;
+    its part of the output ``exp(cum_i) C_i S_in``. No loop over positions, nothing
+    of shape (T, T). ``dt A <= 0`` and every exponent is a sum of those: nothing
+    overflows. Decays and sums in float32; the matmuls' operands in x's dtype."""
+    Bn, T, H, P = x.shape
+    G, N = B.shape[2], B.shape[3]
+    check(tuple(dt.shape) == (Bn, T, H) and tuple(A.shape) == (H,) and H % G == 0
+          and tuple(B.shape) == (Bn, T, G, N) and tuple(C.shape) == (Bn, T, G, N),
+          lambda: f"ssm_scan: x {tuple(x.shape)}, dt {tuple(dt.shape)}, A {tuple(A.shape)}, B {tuple(B.shape)}, "
+                  f"C {tuple(C.shape)}")
+    R = H // G
+    L = builtins_min(chunk or SSM_SCAN_CHUNK, T)
+    n = -(-T // L)
+    x_in = x
+    if n * L != T:  # a padded step has dt = 0: it decays nothing and adds nothing; its rows are dropped
+        x, B, C = (pad(a, (0, 0, 0, 0, 0, n * L - T)) for a in (x, B, C))
+        dt = pad(dt, (0, 0, 0, n * L - T))
+    f32 = dtypes.float32
+    up = lambda a: clang.maybe_convert_to_dtype(a, f32)
+    lowp = lambda a: clang.maybe_convert_to_dtype(a, x.dtype)
+    dt = up(dt)
+    xd = reshape(lowp(up(x) * unsqueeze(dt, -1)), (Bn, n, L, G, R * P))                # dt_j x_j, a group's heads side by side
+    cum = cumsum(reshape(dt * reshape(up(A), (1, 1, H)), (Bn, n, L, H)), 2)            # (B, n, L, H), decreasing
+    cum_h = permute(cum, (0, 1, 3, 2))                                                 # (B, n, H, L)
+    Bg, Cg = (permute(reshape(a, (Bn, n, L, G, N)), (0, 1, 3, 2, 4)) for a in (B, C))  # (B, n, G, L, N)
+
+    pos = arange(0, L, device=x.device, dtype=dtypes.int32)
+    causal = clang.expand_to(unsqueeze(pos, 1) >= unsqueeze(pos, 0), (Bn, n, H, L, L))
+    ahead = unsqueeze(cum_h, -1) - unsqueeze(cum_h, -2)                                # [i, j] = cum_i - cum_j
+    decay = exp(where(causal, ahead, clang.full_like(ahead, -float("inf"))))
+    # A matmul's result has its operands' dtype: what is turned between two of them is turned in that dtype, and
+    # float32 begins where the terms are added.
+    cb = up(matmul(Cg, transpose(Bg, -2, -1)))                                         # (B, n, G, L, L)
+    m = lowp(reshape(unsqueeze(cb, 3) * reshape(decay, (Bn, n, G, R, L, L)), (Bn, n, H, L, L)))
+    xh = permute(reshape(xd, (Bn, n, L, H, P)), (0, 1, 3, 2, 4))                       # (B, n, H, L, P)
+    y = up(permute(matmul(m, xh), (0, 1, 3, 2, 4)))                                    # (B, n, L, H, P)
+    if n > 1:
+        to_end = exp(cum[:, :, L - 1:L] - cum)                                         # (B, n, L, H): from j to the chunk's end
+        xw = lowp(up(reshape(xd, (Bn, n, L, H, P))) * unsqueeze(to_end, -1))
+        xw = permute(reshape(xw, (Bn, n, L, G, R * P)), (0, 1, 3, 2, 4))               # (B, n, G, L, R P)
+        summaries = matmul(transpose(Bg, -2, -1), xw)                                  # (B, n, G, N, R P)
+        total = permute(cum[:, :, L - 1], (0, 2, 1))                                   # (B, H, n): a chunk's whole decay
+        upto = cumsum(total, 2)
+        between = unsqueeze(upto - total, -1) - unsqueeze(upto, -2)                    # [c, c']: chunks c' + 1 .. c - 1
+        steps = arange(0, n, device=x.device, dtype=dtypes.int32)
+        earlier = clang.expand_to(unsqueeze(steps, 1) > unsqueeze(steps, 0), (Bn, H, n, n))
+        carry = exp(where(earlier, between, clang.full_like(between, -float("inf"))))  # (B, H, n, n) float32
+        by_head = permute(reshape(summaries, (Bn, n, G, N, R, P)), (0, 2, 4, 1, 3, 5))  # (B, G, R, n, N, P)
+        entering = lowp(matmul(carry, up(reshape(by_head, (Bn, H, n, N * P)))))
+        entering = permute(reshape(entering, (Bn, G, R, n, N, P)), (0, 3, 1, 4, 2, 5))  # (B, n, G, N, R, P)
+        from_start = matmul(Cg, reshape(entering, (Bn, n, G, N, R * P)))               # (B, n, G, L, R P)
+        from_start = up(reshape(permute(from_start, (0, 1, 3, 2, 4)), (Bn, n, L, H, P)))
+        y = y + from_start * unsqueeze(exp(cum), -1)
+    y = reshape(y, (Bn, n * L, H, P))
+    if n * L != T:
+        y = y[:, :T]
+    if D is not None:
+        y = y + up(x_in) * reshape(up(D), (1, 1, H, 1))
+    return lowp(y)
+
+
 @torchsymbol(id="torch.sdpa_fwd_res")
 def sdpa_fwd_res(query, key, value, attn_mask=None, is_causal: bool = False,
                  scale: Optional[float] = None, enable_gqa: bool = False):
