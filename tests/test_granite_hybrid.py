@@ -213,6 +213,59 @@ def test_flash_claims_the_attention_layers_under_the_published_scale(monkeypatch
     assert rel(np.asarray(claimed.astype(jnp.float32)), np.asarray(plain.astype(jnp.float32))) < 2e-2
 
 
+def _bf16_sibling(chunk=128):
+    """The stand-in with its matrices in bf16 and a chunk the kernel takes (its own 64 is the decomposition's)."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg, params, _ = built()
+    params = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16) if a.ndim > 1 or a.shape[0] > 64 else a, params)
+    return dataclasses.replace(cfg, ssm_chunk=chunk), params
+
+
+def test_pallas_claims_every_scan_at_the_kernels_shapes(monkeypatch):
+    """bf16, 32 heads of 64 on a state of 128, 256 positions in chunks of 128: ``pallas`` owns the four ``ssm_scan``
+    (interpreted here), ``flash`` the two attention calls, and the logits are the unclaimed program's."""
+    import jax.numpy as jnp
+
+    cfg, params = _bf16_sibling()
+    idx = batch(256)
+    plain = thunder_tpu.jit(lambda p, i: gpt.forward(p, i, cfg, last=32))(params, idx)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    jfn = thunder_tpu.jit(lambda p, i: gpt.forward(p, i, cfg, last=32))
+    claimed = jfn(params, idx)
+    owners = [(b.sym.name, b.sym.executor.name) for b in thunder_tpu.last_traces(jfn)[-1].bound_symbols
+              if b.sym.executor is not None and b.sym.executor.name in ("flash", "pallas")]
+    scan, attention = ("ssm_scan", "pallas"), ("scaled_dot_product_attention", "flash")
+    assert owners == [scan, attention, scan, scan, attention, scan]
+    assert rel(np.asarray(claimed.astype(jnp.float32)), np.asarray(plain.astype(jnp.float32))) < 2e-2
+    # at the stand-in's own chunk of 64 the scans are the decomposition's, as before
+    own = thunder_tpu.jit(lambda p, i: gpt.forward(p, i, dataclasses.replace(cfg, ssm_chunk=64), last=32))
+    own(params, idx)
+    assert "ssm_scan" not in [b.sym.name for b in thunder_tpu.last_traces(own)[-1].bound_symbols]
+
+
+def test_the_trace_vjp_differentiates_the_decomposition_where_the_forward_would_be_claimed(monkeypatch):
+    """With the kernel registered and its shapes met, ``value_and_grad`` still descends into ``ssm_scan``'s
+    decomposition (there is no backward kernel): nothing of the step is the scan kernel's, and the loss and the
+    gradients are those of the step with nothing forced."""
+    import jax
+
+    cfg, params = _bf16_sibling()
+    idx = batch(256)
+    targets = np.roll(idx, -1, 1)
+    step = lambda: thunder_tpu.value_and_grad(lambda p, i, t: gpt.loss_fn(p, i, t, cfg))
+    want_loss, want = step()(params, idx, targets)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    forced = step()
+    loss, got = forced(params, idx, targets)
+    names = [b.sym.name for trace in thunder_tpu.last_traces(forced)[-1:] for b in trace.bound_symbols]
+    assert "ssm_scan" not in names
+    assert abs(float(loss) - float(want_loss)) < 2e-2 * abs(float(want_loss))
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert np.isfinite(np.asarray(g, np.float32)).all() and g.shape == w.shape
+
+
 def test_a_roped_sibling_keeps_its_scale_through_the_layout_pass(monkeypatch):
     """``fold_attention_layouts`` moves the softmax scale onto q's head call: the
     published one where the model has one, not ``head_size ** -0.5``."""

@@ -929,3 +929,94 @@ def test_the_own_window_kernel_compiles_for_v5e_and_expands_nothing(one_chip, mo
     assert results == [f"bf16[{B},{groups},{H // groups},{T},{d}]"] * 3
     name = call.split(" = ")[0].strip().lstrip("%")
     assert _regions.of_instructions(text, ("attn.window", "attn.full"))[name] == "attn.window"
+
+
+SSM_SCAN_SHAPES = {
+    # (B, T, H, P), groups, state, chunk
+    "granite-4.0-h-micro.fwd-t16k": ((1, 16384, 64, 64), 1, 128, 256),
+    "eight-groups-of-16-heads": ((1, 4096, 128, 64), 8, 128, 256),
+    "two-heads-a-group-a-state-of-64-a-batch-of-2": ((2, 512, 4, 64), 2, 64, 128),
+    "a-chunk-of-512": ((1, 2048, 32, 64), 1, 128, 512),
+}
+
+
+@pytest.mark.parametrize("shape,groups,state,chunk", SSM_SCAN_SHAPES.values(), ids=SSM_SCAN_SHAPES)
+def test_the_state_space_scan_compiles_for_v5e_as_one_call_that_reads_token_major(one_chip, monkeypatch, shape, groups, state, chunk):
+    """``pallas`` takes ``torch.ssm_scan`` on bf16 heads of 64 (PR 45): one Mosaic call, ``ssm_scan_fwd``, on x seen as
+    (B, T, H P) and dt, B and C as the symbol hands them, token-major; nothing of the decomposition is left (no array
+    with a chunk twice among its dimensions, no float32 array of x's size), the call uses no more VMEM than the checker
+    reckoned and asks for the generation's half where three quarters of the default scope are short, keeps the
+    symbol's region, and is no family's of the benchmark's."""
+    import jax
+    import jax.numpy as jnp
+
+    import thunder_tpu.torch as ttorch
+    from perfbench import kernel_families
+    from perfbench.layer_metrics import _regions
+    from thunder_tpu.api import trace_program
+    from thunder_tpu.core.trace import region
+    from thunder_tpu.executors import flashex, pallasex
+    from thunder_tpu.executors.passes import transform_for_execution
+    from thunder_tpu.extend import resolve_executors
+    from thunder_tpu.transforms.common import dce
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    monkeypatch.setattr(pallasex, "_device_kind", lambda: next(iter(one_chip.device_set)).device_kind)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    (B, T, H, P), f32 = shape, jnp.float32
+    like = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    shapes = [like(shape), like((B, T, H), f32), like((H,), f32), like((B, T, groups, state)), like((B, T, groups, state)), like((H,), f32)]
+
+    def program(x, dt, A, Bm, Cm, D):
+        with region("ssm.scan"):
+            return ttorch.ssm_scan(x, dt, A, Bm, Cm, D, chunk=chunk)
+
+    _, comp = trace_program(program, shapes, {})
+    claimed = transform_for_execution(dce(comp), resolve_executors(None))
+    assert [(b.sym.name, b.sym.executor.name) for b in claimed.bound_symbols if b.sym.name == "ssm_scan"] == [("ssm_scan", "pallas")]
+    with jax.enable_x64(True):  # as the dispatcher's runtime has it: nothing in the kernel may widen an index to 64 bits
+        compiled = jax.jit(claimed.python_callable()).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and jax.tree_util.tree_leaves(compiled.out_info)[0].shape == shape
+    assert not re.search(rf"\[[\d,]*{chunk},[\d,]*{chunk}[\d,]*\]", text) and not re.search(rf"f32\[[\d,]*{T},[\d,]*{H * P}\]|f32\[[\d,]*{H},{P}\]", text)
+    call = next(line for line in text.splitlines() if line.strip().startswith("%ssm_scan_fwd"))
+    needed = pallasex._ssm_scan_vmem(chunk, H, state, groups, 2)
+    asked = re.search(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+","size":"(\d+)"', call)
+    assert (int(asked.group(1)) if asked else 16 * 2 ** 20) == pallasex._ssm_scan_scope(needed) == (
+        16 * 2 ** 20 if needed <= 12 * 2 ** 20 else 64 * 2 ** 20)
+    used = int(re.search(r'"used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"', call).group(1))
+    assert used <= needed
+    named = _as_the_trace_names_it(text, "%ssm_scan_fwd")
+    operands = re.findall(r"(\w+\[[\d,]*\])\S* %", named.split("custom-call(")[1].split("), custom_call_target")[0])
+    assert operands == [f"bf16[{B},{T},{H * P}]", f"f32[{B},{T},{H}]", f"f32[1,{H}]", f"bf16[{B},{T},{groups * state}]",
+                        f"bf16[{B},{T},{groups * state}]", f"f32[{H}]"]
+    assert kernel_families.match(named) is None
+    name = call.split(" = ")[0].strip().lstrip("%")
+    assert _regions.of_instructions(text, ("ssm.conv", "ssm.scan", "ssm.gate_norm"))[name] == "ssm.scan"
+
+
+def test_the_cell_of_granite_compiles_for_v5e_with_the_scan_in_its_region(one_chip, monkeypatch):
+    """granite-4.0-h-micro.fwd-t16k's program at depth 2 (two Mamba-2 layers, 16,384 positions), as the cell's job
+    lowers it: a Mosaic call a layer, each in region ``ssm.scan`` where ``ssm_scan_ms`` and ``ssm_scan_roofline`` look for
+    the region's instructions, and beside it in that region only what makes dt and A; no float32 array of the
+    activation's size is left anywhere in the layer."""
+    from perfbench import manifest
+    from perfbench.jobs import forward_ssm
+    from perfbench.layer_metrics import _regions
+    from thunder_tpu.executors import flashex, pallasex
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    monkeypatch.setattr(flashex, "_interpret", lambda: False)
+    monkeypatch.setattr(pallasex, "_device_kind", lambda: next(iter(one_chip.device_set)).device_kind)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    cell = manifest.load_cell("granite-4.0-h-micro.fwd-t16k")
+    keys = manifest.published(cell)
+    keys.update(num_hidden_layers=2, reduced=[*keys["reduced"], "num_hidden_layers"])
+    topo = SimpleNamespace(devices=[next(iter(one_chip.device_set))])
+    text = forward_ssm.lower_for(cell, keys, cell.traffic["batch"], cell.traffic["seq"], topo).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    found = _regions.of_instructions(forward_ssm.forward_window_moe.an_instruction_a_line(text), forward_ssm.REGIONS)
+    scans = sorted(name for name, where in found.items() if where == "ssm.scan" and name.startswith("ssm_scan_fwd"))
+    assert len(scans) == 2 and set(found.values()) == {"ssm.conv", "ssm.scan", "ssm.gate_norm"}
+    entry = text.split("ENTRY")[1]
+    assert not re.search(r" = f32\[[\d,]*16384,4096\]| = f32\[[\d,]*64,256,64,64\]", entry)  # the decomposition's arrays

@@ -28,7 +28,7 @@ partitioner turns them into collectives.
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from types import SimpleNamespace
 
 from thunder_tpu.core import dtypes
@@ -1098,3 +1098,261 @@ def _window_attend_impl(q, k, v, *, window, scale=None):
 
 
 ex.register_implementation("torch.window_attention", fn=_window_attend_impl, checker=_window_attend_checker)
+
+
+# =============================================================================
+# The state-space recurrence whose decay each token sets (torch.ssm_scan)
+# =============================================================================
+#
+# ``ssm_scan``'s chunked decomposition with the state carried in VMEM. XLA runs
+# the decomposition as some ten float32 arrays of the activation's size a layer,
+# turned between token-major and head-major, with the chunks' summaries and the
+# entering states in HBM and an (n, n) product over them (PERF.md, PR 44: 7.5
+# ms a layer of granite-4.0-h-micro.fwd-t16k for a bound of 0.34). Here a grid
+# step is one chunk of positions of every head: x is seen as (B, T, H P), the
+# heads side by side in the lanes as the model's projection wrote them, so x,
+# dt, B and C are read token-major, once, and y is written token-major, once.
+# The grid walks a sequence's chunks in order and the state, (N, P) a head and
+# float32, stays in a VMEM scratch from chunk to chunk: the entering state's
+# part of the output is one matmul against it, and it leaves the step as
+# ``exp(total) S + (B^T * dt_j exp(cum_end - cum_j)) x``.
+#
+# Within a step: the running sum ``cum`` of ``dt A`` for every head by a product
+# with a lower-triangular matrix of ones, ``C B^T`` once a group, and then a
+# loop over the heads, ``_SSM_SCAN_HEADS`` a turn. Two heads of 64 fill a lane
+# group of x, and a lane group is what the MXU is given: a head's masked form
+# ``C B^T * exp(cum_i - cum_j) * dt_j``, made in tiles of 128 queries by 128
+# keys at and under the diagonal (the tiles above it are never made) and
+# rounded to x's dtype, goes against both heads' x as it lies in VMEM, and the
+# head's own lanes of the result are kept; likewise ``B^T * dt_j exp(cum_end -
+# cum_j)`` for the state. dt and the decays are a number a position and head,
+# and x holds positions down its sublanes: to scale x's rows by them each
+# would have to be spread over the lanes, which goes through the XLU, 7 cycles
+# a register on one of three units (a first form that scaled x that way spent
+# 85% of the XLU and ran a third slower by the compiler's own schedule). So
+# everything that belongs to a key is folded into the masked form, where keys
+# lie along the lanes and a head's row spreads over sublanes for nothing, and
+# only the queries' ``cum_i`` is spread over lanes, 32 registers a head and
+# chunk. ``cum`` and ``dt`` are turned, (H, L), by a product with the identity.
+# The loop's index chooses the heads, so a turn's columns and rows lie in
+# scratches of their own, found by their first index.
+#
+# Precision is the decomposition's or better: decays, sums and the state in
+# float32, the matmuls' operands in x's dtype with float32 accumulation; x
+# itself goes to the MXU unrounded where the decomposition rounds ``dt x``,
+# and where the decomposition rounds ``C B^T``, the summaries and the entering
+# state to x's dtype between two matmuls, nothing is rounded here. Every
+# exponent is a sum of ``dt A <= 0``.
+#
+# The checker takes bf16 x, B and C, heads of 64 on a state of 64 or 128, an even
+# count of heads a group, whole chunks of a multiple of 128 positions, and a
+# step whose blocks and state fit the VMEM it asks for; every other call is the
+# decomposition's. The trace VJP differentiates the decomposition: there is no
+# backward kernel.
+
+# Measured on the v5e at granite-4.0-h-micro.fwd-t16k's shapes, a layer's call alone with the two copies that turn x
+# and y between (T, 64, 64) and (T, 4096) around it (PERF.md, PR 45; XLA's decomposition reads 8.38 ms there): a turn of
+# 8 heads 1.734 ms, of 4 heads 1.797, of 2 heads 1.932 (an earlier form); at a chunk of 128 2.097, of 512 1.837, the
+# chunk being the call's. By the compiler's schedule for the described chip (`--xla_jf_dump_to` writes the final
+# bundles): a turn of 8 heads 2,184 bundles, the MXU's 384 pushes of 16 rows 70% of them; a turn of 4 1,120. In the
+# cell's program the call reads 0.876 ms a layer (a first form that scaled x's rows, 2,835 bundles a turn, 1.254).
+_SSM_SCAN_HEADS = 8  # heads a turn of the loop inside a step, unrolled
+_SSM_SCAN_HEAD = 64  # the head the kernel is written for: two fill a lane group
+
+
+def _ssm_scan_turn(H: int, G: int):
+    """Heads a turn of the loop takes, all of one group and whole lane groups
+    of x, or None where the heads do not fall that way."""
+    R = H // G
+    u = min(_SSM_SCAN_HEADS, R)
+    return u if R % u == 0 and u % 2 == 0 else None
+
+
+def _ssm_scan_vmem(L: int, H: int, N: int, G: int, itemsize: int) -> int:
+    """What a step holds in VMEM: the blocks of x and y, of dt (its heads padded
+    to the lanes) and of B and C, each twice for the pipeline; the state, the
+    turns' columns and rows, ``C B^T`` and B turned in float32 and C a group;
+    the triangle and the running sums before the loop, and some twelve float32
+    arrays of a tile of queries by a lane group inside it."""
+    u, P = _ssm_scan_turn(H, G) or 2, _SSM_SCAN_HEAD
+    blocks = 4 * L * H * P * itemsize + 2 * L * max(H, _LANE) * 4 + 4 * L * max(G * N, _LANE) * itemsize
+    scratch = (N * H * P * 4 + (H // u) * L * (_LANE + 3 * 8) * 4 + G * (L * L + max(N, 8) * L) * 4
+               + G * L * max(N, _LANE) * itemsize)
+    return blocks + scratch + 2 * L * L * 4 + 6 * L * max(H, _LANE) * 4 + 12 * _LANE * _LANE * 4
+
+
+def _ssm_scan_scope(needed: int) -> int:
+    """The scope of VMEM a call lives in whose step holds ``needed`` bytes: the
+    default where three quarters of it hold the step, else what
+    ``_ce_vmem_limit()`` asks of the device's generation; the checker holds
+    the step to three quarters of either."""
+    return _SCOPED_VMEM_DEFAULT if needed <= 3 * _SCOPED_VMEM_DEFAULT // 4 else _ce_vmem_limit()
+
+
+def _ssm_scan_chunk(chunk, T: int) -> int:
+    import thunder_tpu.torch as ttorch
+
+    return min(int(chunk) if chunk else ttorch.SSM_SCAN_CHUNK, int(T))  # as the decomposition reads its argument
+
+
+def _ssm_scan_checker(x, dt, A, B, C, D=None, chunk=None) -> bool:
+    from thunder_tpu.executors import flashex
+
+    if not flashex._on_tpu() or len(getattr(x, "shape", ())) != 4 or len(getattr(B, "shape", ())) != 4:
+        return False
+    if any(dtypes.to_dtype(a.dtype) is not dtypes.bfloat16 for a in (x, B, C)):  # float32 keeps its precision
+        return False
+    (Bn, T, H, P), (G, N) = x.shape, B.shape[2:]
+    L = _ssm_scan_chunk(pyval(chunk), T)
+    if not (P == _SSM_SCAN_HEAD and N in (64, 128) and H % G == 0 and _ssm_scan_turn(int(H), int(G)) is not None
+            and L % _LANE == 0 and T % L == 0 and Bn % batch_shards() == 0):
+        return False
+    needed = _ssm_scan_vmem(L, int(H), int(N), int(G), 2)
+    return needed <= 3 * _ssm_scan_scope(needed) // 4
+
+
+def _ssm_scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, N: int, G: int, u: int):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    *d_ref, y_ref, s_scr, cols_scr, rows_scr, cb_scr, bt_scr, c_scr = refs
+    d_ref = d_ref[0] if d_ref else None  # D, (H,) in SMEM, where the call has one
+    L, H = dt_ref.shape[1], dt_ref.shape[2]
+    R, P, tile = H // G, _SSM_SCAN_HEAD, _LANE
+    f32, lowp = jnp.float32, x_ref.dtype
+    iota = lambda shape, axis: jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+    identity = lambda n, dtype: (iota((n, n), 0) == iota((n, n), 1)).astype(dtype)
+    nn, nt = (((1,), (0,)), ((), ())), (((1,), (1,)), ((), ()))  # a @ b, a @ b^T
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_scr[...] = jnp.zeros(s_scr.shape, f32)
+
+    def summed(ones, terms, dims):
+        """``ones`` (exact in bf16) against float32 ``terms``, exact to the float32 accumulation: the terms split
+        into three bf16 parts that sum to them, a pass of the MXU each, where ``Precision.HIGHEST`` makes six."""
+        total = 0.0
+        for _ in range(3):
+            part = terms.astype(jnp.bfloat16)
+            total = total + jax.lax.dot_general(ones, part, dims, preferred_element_type=f32)
+            terms = terms - part.astype(f32)
+        return total
+
+    # Every head's running sum of dt A down the chunk, (L, H): a sum of float32 terms under a triangle of ones. A
+    # query's side of the mask wants it down the sublanes, a column a head; everything that belongs to a key wants its
+    # positions along the lanes, a row a head (a row spreads over sublanes for nothing, a column over lanes through the
+    # XLU, which is what a step has least of): so cum and dt are turned, (H, L), by a product with the identity.
+    dt = dt_ref[0]
+    ones_below = (iota((L, L), 0) >= iota((L, L), 1)).astype(jnp.bfloat16)
+    cum = summed(ones_below, dt * a_ref[...], nn)
+    cum_t, dt_t = (summed(identity(H, jnp.bfloat16), a, nt) for a in (cum, dt))
+    to_end = dt_t * jnp.exp(cum_t[:, L - 1:L] - cum_t)                          # dt_j times the decay from j to the chunk's end
+    for o in range(H // u):  # a turn's heads: their columns of cum side by side, their rows of cum, dt and to_end together
+        cols_scr[o, :, 0:u] = cum[:, o * u:(o + 1) * u]
+        for k, rows in enumerate((cum_t, dt_t, to_end)):
+            rows_scr[o, k, 0:u, :] = rows[o * u:(o + 1) * u, :]
+    for g in range(G):
+        keys, queries = b_ref[0, :, g * N:(g + 1) * N], c_ref[0, :, g * N:(g + 1) * N]
+        cb_scr[g] = jax.lax.dot_general(queries, keys, nt, preferred_element_type=f32)
+        # B turned, (N, L), by a product with the identity: one nonzero a sum, exact, and no transpose to lay out
+        bt_scr[g] = jax.lax.dot_general(identity(N, lowp), keys, nt, preferred_element_type=f32)
+        c_scr[g] = queries
+
+    left = iota((1, tile), 1) < P  # the first head's lanes of a lane group
+    spread = lambda a, b: jnp.where(left, a, b)  # each of two heads' values over its own lanes
+    on_or_below = iota((tile, tile), 0) >= iota((tile, tile), 1)
+    tiles = [slice(t * tile, (t + 1) * tile) for t in range(L // tile)]
+
+    def turn(o, carry):
+        first = o * u
+        g = jax.lax.div(first, jnp.int32(R))  # (`//` on a traced index goes through 64 bits where the runtime has x64 on)
+        cum_j, dt_j, to_end_j = rows_scr[o, 0], rows_scr[o, 1], rows_scr[o, 2]  # (8, L) each, the first u rows
+        for pair in range(0, u, 2):
+            heads = (pair, pair + 1)
+            lanes = pl.ds(pl.multiple_of((first + pair) * P, tile), tile)
+            state = s_scr[:, lanes]                                             # (N, 128)
+            entering = state.astype(lowp)
+            for r in range(L // tile):  # a tile of queries
+                cum_i = [jnp.broadcast_to(cols_scr[o, tiles[r], h:h + 1], (tile, tile)) for h in heads]
+                y = jnp.dot(c_scr[g, tiles[r], :], entering, preferred_element_type=f32) * jnp.exp(spread(*cum_i))
+                within = []
+                for h, down in zip(heads, cum_i):  # a head's masked form against both heads' x: its own lanes of the result are kept
+                    for t in range(r + 1):  # the tiles of keys at and before the queries'
+                        ahead = down - cum_j[h:h + 1, tiles[t]]
+                        if t == r:
+                            ahead = jnp.where(on_or_below, ahead, -jnp.inf)
+                        m = (jnp.exp(ahead) * cb_scr[g, tiles[r], tiles[t]] * dt_j[h:h + 1, tiles[t]]).astype(lowp)
+                        part = jnp.dot(m, x_ref[0, tiles[t], lanes], preferred_element_type=f32)
+                        acc = part if t == 0 else acc + part
+                    within.append(acc)
+                y = y + spread(*within)
+                if d_ref is not None:
+                    y = y + x_ref[0, tiles[r], lanes].astype(f32) * spread(*(d_ref[first + h] for h in heads))
+                y_ref[0, tiles[r], lanes] = y.astype(y_ref.dtype)
+            # what the chunk adds to each head's state: B^T (to_end x), to_end along B^T's lanes
+            adds = [jnp.dot((bt_scr[g] * to_end_j[h:h + 1, :]).astype(lowp), x_ref[0, :, lanes], preferred_element_type=f32)
+                    for h in heads]
+            decay = spread(*(jnp.exp(cum_j[h:h + 1, L - 1:L]) for h in heads))  # a chunk's whole decay, (1, 128)
+            s_scr[:, lanes] = state * decay + spread(*adds)
+        return carry
+
+    jax.lax.fori_loop(0, H // u, turn, 0)
+
+
+@lru_cache(maxsize=32)
+def _ssm_scan_call(L: int, u: int, scope: int, interpret: bool):
+    """The call for chunks of L positions and turns of u heads in a scope of
+    VMEM, as one jitted function: a model's layers call it one after another
+    with the same shapes, and jax traces the kernel's body and lowers it for
+    Mosaic once for all of them (traced anew a layer, the 36 of
+    granite-4.0-h-micro.fwd-t16k cost its set-up 5 s: PERF.md, PR 45).
+    Everything the trace reads outside its operands is in the cache's key."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def call(x, dt, B, C, A, *D):
+        (Bn, T, H, P), (G, N) = x.shape, B.shape[2:]
+        a_chunk = lambda width: pl.BlockSpec((1, L, width), lambda b, c: (b, c, 0), memory_space=pltpu.VMEM)
+        in_specs = [a_chunk(H * P), a_chunk(H), pl.BlockSpec((1, H), lambda b, c: (0, 0), memory_space=pltpu.VMEM),
+                    a_chunk(G * N), a_chunk(G * N), *([pl.BlockSpec(memory_space=pltpu.SMEM)] if D else [])]
+        out = pl.pallas_call(
+            partial(_ssm_scan_kernel, N=N, G=G, u=u),
+            grid=(Bn, T // L),
+            in_specs=in_specs,
+            out_specs=a_chunk(H * P),
+            out_shape=jax.ShapeDtypeStruct((Bn, T, H * P), x.dtype),
+            scratch_shapes=[pltpu.VMEM((N, H * P), jnp.float32), pltpu.VMEM((H // u, L, _LANE), jnp.float32),
+                            pltpu.VMEM((H // u, 3, 8, L), jnp.float32), pltpu.VMEM((G, L, L), jnp.float32),
+                            pltpu.VMEM((G, N, L), jnp.float32), pltpu.VMEM((G, L, N), x.dtype)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                **({} if scope == _SCOPED_VMEM_DEFAULT else {"vmem_limit_bytes": scope})),
+            name="ssm_scan_fwd",
+            interpret=interpret,
+        )(x.reshape(Bn, T, H * P), dt, A.reshape(1, H), B.reshape(Bn, T, G * N), C.reshape(Bn, T, G * N), *D)
+        return out.reshape(Bn, T, H, P)
+
+    return jax.jit(call)
+
+
+def _ssm_scan_impl(x, dt, A, B, C, D=None, chunk=None):
+    chaos.kernel_seam("pallas", "ssm_scan")
+    import jax
+    import jax.numpy as jnp
+
+    def shard(x, dt, B, C, A, *D):
+        (_, T, H, _), (G, N) = x.shape, B.shape[2:]
+        L = _ssm_scan_chunk(chunk, T)
+        scope = _ssm_scan_scope(_ssm_scan_vmem(L, H, N, G, x.dtype.itemsize))
+        return _ssm_scan_call(L, _ssm_scan_turn(H, G), scope, _interpret())(x, dt, B, C, A, *D)
+
+    f32 = jnp.float32
+    skip = () if D is None else (D.astype(f32),)
+    with jax.enable_x64(False):
+        return per_batch_shard(shard, x, dt.astype(f32), B, C, A.astype(f32), *skip, replicated=(4, 5))
+
+
+ex.register_implementation("torch.ssm_scan", fn=_ssm_scan_impl, checker=_ssm_scan_checker)
