@@ -1020,3 +1020,114 @@ def test_the_cell_of_granite_compiles_for_v5e_with_the_scan_in_its_region(one_ch
     assert len(scans) == 2 and set(found.values()) == {"ssm.conv", "ssm.scan", "ssm.gate_norm"}
     entry = text.split("ENTRY")[1]
     assert not re.search(r" = f32\[[\d,]*16384,4096\]| = f32\[[\d,]*64,256,64,64\]", entry)  # the decomposition's arrays
+
+
+SSM_SCAN_PACKED_SHAPES = {
+    # (B, T), heads, groups, state, chunk: the packed array is (B, T, 64 heads + 2 groups * state)
+    "granite-4.0-h-micro.fwd-t16k": ((1, 16384), 64, 1, 128, 256),       # blocks of 4096, 128, 128 at 0, 32, 33
+    "eight-groups-of-16-heads": ((1, 4096), 128, 8, 128, 256),           # blocks of 8192, 1024, 1024 at 0, 8, 9
+    "two-groups-on-a-state-of-64-a-batch-of-2": ((2, 512), 4, 2, 64, 128),
+}
+
+
+@pytest.mark.parametrize("bt,heads,groups,state,chunk", SSM_SCAN_PACKED_SHAPES.values(), ids=SSM_SCAN_PACKED_SHAPES)
+def test_the_packed_state_space_scan_compiles_for_v5e_and_reads_one_array_by_block_index(one_chip, monkeypatch, bt, heads, groups,
+                                                                                         state, chunk):
+    """``pallas`` takes ``torch.ssm_scan_packed`` (PR 46): the same Mosaic call, ``ssm_scan_fwd``, whose first, fourth
+    and fifth operand are one array, the convolution's ``[x | B | C]`` as it lies; nothing is cut out of it in front of
+    the call (no ``slice``, no copy), the call asks for the VMEM the three-array call asks for, and it keeps its region."""
+    import jax
+    import jax.numpy as jnp
+
+    import thunder_tpu.torch as ttorch
+    from perfbench.layer_metrics import _regions
+    from thunder_tpu.api import trace_program
+    from thunder_tpu.core.trace import region
+    from thunder_tpu.executors import pallasex
+    from thunder_tpu.executors.passes import transform_for_execution
+    from thunder_tpu.extend import resolve_executors
+    from thunder_tpu.transforms.common import dce
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    monkeypatch.setattr(pallasex, "_device_kind", lambda: next(iter(one_chip.device_set)).device_kind)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    (B, T), W, f32 = bt, heads * 64 + 2 * groups * state, jnp.float32
+    like = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    shapes = [like((B, T, W)), like((B, T, heads), f32), like((heads,), f32), like((heads,), f32)]
+
+    def program(xbc, dt, A, D):
+        with region("ssm.scan"):
+            return ttorch.ssm_scan_packed(xbc, dt, A, D, heads=heads, groups=groups, state=state, chunk=chunk)
+
+    _, comp = trace_program(program, shapes, {})
+    claimed = transform_for_execution(dce(comp), resolve_executors(None))
+    assert [(b.sym.name, b.sym.executor.name) for b in claimed.bound_symbols if "ssm_scan" in b.sym.name] == [("ssm_scan_packed", "pallas")]
+    with jax.enable_x64(True):
+        compiled = jax.jit(claimed.python_callable()).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and jax.tree_util.tree_leaves(compiled.out_info)[0].shape == (B, T, heads, 64)
+    entry = text.split("ENTRY")[1]
+    assert " slice(" not in entry and " fusion(" not in entry
+    call = next(line for line in text.splitlines() if line.strip().startswith("%ssm_scan_fwd"))
+    asked = re.search(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+","size":"(\d+)"', call)
+    assert (int(asked.group(1)) if asked else 16 * 2 ** 20) == pallasex._ssm_scan_scope(pallasex._ssm_scan_vmem(chunk, heads, state, groups, 2))
+    named = _as_the_trace_names_it(text, "%ssm_scan_fwd")
+    operands = re.findall(r"(\w+\[[\d,]*\])\S* (%[\w.\-]+)", named.split("custom-call(")[1].split("), custom_call_target")[0])
+    assert [shape for shape, _ in operands] == [f"bf16[{B},{T},{W}]", f"f32[{B},{T},{heads}]", f"f32[1,{heads}]", f"bf16[{B},{T},{W}]",
+                                                f"bf16[{B},{T},{W}]", f"f32[{heads}]"]
+    # one array, three times, and as the program was handed it
+    assert operands[0][1] == operands[3][1] == operands[4][1] and re.search(rf"{re.escape(operands[0][1])} = \S+ parameter\(0\)", entry)
+    name = call.split(" = ")[0].strip().lstrip("%")
+    assert _regions.of_instructions(text, ("ssm.conv", "ssm.scan", "ssm.gate_norm"))[name] == "ssm.scan"
+
+
+def test_the_cell_of_granite_as_the_dispatcher_rewrites_it_cuts_nothing_out_in_front_of_its_scans(one_chip, monkeypatch):
+    """granite-4.0-h-micro.fwd-t16k's program at depth 2 through ``pipeline.compile_trace``, which is what the
+    dispatcher does to a trace (the job's ``lower_for`` leaves the rewrites out and compiles the program as written:
+    the test above). As written each layer holds two standalone slices, ``bf16[1,16384,4352]`` out of ``in_proj``'s
+    result for the convolution and ``bf16[1,16384,4096]`` out of the convolution's for the scan (PERF.md, PR 46: 30.8
+    ms of the cell's 782.7 ms call); rewritten it holds neither, B and C are no results of their own, each scan's first,
+    fourth and fifth operand are the convolution's one result, and the convolution's fusion reads ``in_proj``'s."""
+    import jax
+    import jax.numpy as jnp
+
+    from perfbench import manifest
+    from perfbench.jobs import forward_ssm, gpt_model
+    from perfbench.layer_metrics import _regions
+    from thunder_tpu import pipeline
+    from thunder_tpu.api import trace_program
+    from thunder_tpu.executors import flashex, pallasex
+    from thunder_tpu.extend import resolve_executors
+    from thunder_tpu.models import gpt
+
+    monkeypatch.setattr(pallasex, "_interpret", lambda: False)
+    monkeypatch.setattr(flashex, "_interpret", lambda: False)
+    monkeypatch.setattr(pallasex, "_device_kind", lambda: next(iter(one_chip.device_set)).device_kind)
+    monkeypatch.setenv("THUNDER_FLASH_FORCE", "1")
+    cell = manifest.load_cell("granite-4.0-h-micro.fwd-t16k")
+    keys = manifest.published(cell)
+    keys.update(num_hidden_layers=2, reduced=[*keys["reduced"], "num_hidden_layers"])
+    cfg = gpt_model.gpt_config(keys)
+    assert [cfg.layer_mixer(i) for i in range(2)] == ["mamba"] * 2
+    shapes = gpt_model.param_shapes(cfg)
+    tokens = jax.ShapeDtypeStruct((cell.traffic["batch"], cell.traffic["seq"]), jnp.int32)
+    flat = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip) for a in jax.tree_util.tree_leaves((shapes, tokens))]
+    _, trc = trace_program(lambda p, i: gpt.forward(p, i, cfg, last=cell.traffic["last"]), (shapes, tokens), {})
+    compiled = pipeline.compile_trace(pipeline.clean(trc)[-1], resolve_executors(None))
+    assert compiled.extras["transforms"]["ssm_layouts_folded"] == 2 and gpt_model.kernels_claimed(compiled.claimed) == 2
+    text = jax.jit(compiled.claimed.python_callable()).lower(*flat).compile().as_text()
+    entry = text.split("ENTRY")[1]
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert not re.search(r" = bf16\[1,16384,(4096|4352|128)\]\S* slice\(", entry)
+    written = _arrays_written(text)
+    assert written.count(("multiply_convert_fusion", "bf16[1,16384,4352]")) == 2, written
+    assert not [w for w in written if w[1] == "bf16[1,16384,128]"]
+    found = _regions.of_instructions(forward_ssm.forward_window_moe.an_instruction_a_line(text), forward_ssm.REGIONS)
+    scans = sorted(name for name, where in found.items() if where == "ssm.scan" and name.startswith("ssm_scan_fwd"))
+    assert len(scans) == 2
+    for name in scans:
+        named = _as_the_trace_names_it(entry, f"%{name} ")
+        operands = re.findall(r"(\w+\[[\d,]*\])\S* (%[\w.\-]+)", named.split("custom-call(")[1].split("), custom_call_target")[0])
+        assert [shape for shape, _ in operands][::3] == ["bf16[1,16384,4352]"] * 2 and operands[0] == operands[3] == operands[4]
+        conv = next(line for line in entry.splitlines() if line.strip().startswith(operands[0][1] + " = "))
+        assert found[operands[0][1].lstrip("%")] == "ssm.conv" and "fusion(%convolution_bitcast_fusion" in conv

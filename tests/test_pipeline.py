@@ -21,7 +21,7 @@ from thunder_tpu.core import dtypes
 from thunder_tpu.extend import resolve_executors
 from thunder_tpu.models import gpt
 from thunder_tpu.parallel.train import _compile_loss_and_grads
-from thunder_tpu.transforms import attention_layout, cross_entropy_upcast
+from thunder_tpu.transforms import attention_layout, cross_entropy_upcast, ssm_layout
 from thunder_tpu.transforms.attention_residuals import save_sdpa_residuals_joint
 from thunder_tpu.transforms.autodiff import grad_transform
 from thunder_tpu.transforms.common import cse, dce
@@ -129,6 +129,15 @@ ENTRY_POINTS = {
 }
 
 
+def test_the_rewrites_stand_in_this_order_and_these_counts_go_into_the_transforms_record():
+    """A layout fold reads the trace the folds before it left: the state-space
+    fold (PR 46) is the last, after the attention fold."""
+    assert [step.__name__ for step in pipeline.REWRITES] == [
+        "save_sdpa_residuals_joint", "fold_cross_entropy_upcasts", "fold_attention_layouts", "fold_ssm_layouts"]
+    assert pipeline._COUNTED == (cross_entropy_upcast.FOLDED_TAG, attention_layout.FOLDED_TAG, ssm_layout.FOLDED_TAG)
+    assert pipeline._COUNTED == ("cross_entropy_upcasts_folded", "attention_layouts_folded", "ssm_layouts_folded")
+
+
 @pytest.mark.parametrize("entry", ENTRY_POINTS)
 def test_every_entry_point_runs_the_owners_list_in_the_owners_order(stages_run, entry):
     drive, programs = ENTRY_POINTS[entry]
@@ -180,6 +189,9 @@ DECLINES = {  # the rewrite, the kind of trace it declines (0: the forward, 1: t
     "fold_cross_entropy_upcasts_a_trace_with_no_cross_entropy": (
         cross_entropy_upcast.fold_cross_entropy_upcasts, 0, (cross_entropy_upcast.FOLDED_TAG, 0)),
     "save_sdpa_residuals_joint_a_forward": (save_sdpa_residuals_joint, 0, None),
+    # a trace with no ``ssm_scan`` in it: the same object, and no count of sites that were never looked for
+    "fold_ssm_layouts_a_forward_without_a_scan": (ssm_layout.fold_ssm_layouts, 0, None),
+    "fold_ssm_layouts_a_joint_trace_without_a_scan": (ssm_layout.fold_ssm_layouts, 1, None),
 }
 
 
@@ -194,6 +206,7 @@ def test_a_rewrite_leaves_the_trace_it_declines_as_it_was(case, cell):
     assert len(after.bound_symbols) == len(before) and all(a is b for a, b in zip(after.bound_symbols, before))
     if said is not None:
         assert after.tags[said[0]] == said[1]
+    assert ssm_layout.FOLDED_TAG not in after.tags
 
 
 @pytest.mark.parametrize("cell", ["pythia-410m.train", "mistral-7b.train"])

@@ -1149,6 +1149,17 @@ ex.register_implementation("torch.window_attention", fn=_window_attend_impl, che
 # step whose blocks and state fit the VMEM it asks for; every other call is the
 # decomposition's. The trace VJP differentiates the decomposition: there is no
 # backward kernel.
+#
+# x, B and C are the three parts of one array, the convolution's ``[x | B | C]``,
+# and a custom call's operand lies whole in HBM: handed three slices, XLA writes
+# x out as a copy in front of every call (0.41 ms a layer in that cell: PERF.md,
+# PR 46). ``torch.ssm_scan_packed`` (``transforms/ssm_layout.py`` writes it where
+# the program cut the three out of one array for this call alone) is the same
+# call on the packed array, given three times, each ``BlockSpec`` at its block's
+# index along the last dimension: block 0 of H P columns, blocks ``H P / (G N)``
+# and one more of G N. That wants G N whole lane groups and H P a multiple of
+# G N; a packed call that is not so is its decomposition's, the three slices
+# and ``ssm_scan``.
 
 # Measured on the v5e at granite-4.0-h-micro.fwd-t16k's shapes, a layer's call alone with the two copies that turn x
 # and y between (T, 64, 64) and (T, 4096) around it (PERF.md, PR 45; XLA's decomposition reads 8.38 ms there): a turn of
@@ -1301,23 +1312,34 @@ def _ssm_scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, N: int, G: int, 
 
 
 @lru_cache(maxsize=32)
-def _ssm_scan_call(L: int, u: int, scope: int, interpret: bool):
+def _ssm_scan_call(L: int, u: int, scope: int, interpret: bool, packed: tuple | None = None):
     """The call for chunks of L positions and turns of u heads in a scope of
     VMEM, as one jitted function: a model's layers call it one after another
     with the same shapes, and jax traces the kernel's body and lowers it for
     Mosaic once for all of them (traced anew a layer, the 36 of
     granite-4.0-h-micro.fwd-t16k cost its set-up 5 s: PERF.md, PR 45).
-    Everything the trace reads outside its operands is in the cache's key."""
+    Everything the trace reads outside its operands is in the cache's key.
+    ``packed`` (G, N): x, B and C are one array, ``[x | B | C]`` (B, T, H P + 2 G
+    N), given three times; the same three blocks are read out of it by their
+    index along its last dimension, and the kernel sees the refs it always saw."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     def call(x, dt, B, C, A, *D):
-        (Bn, T, H, P), (G, N) = x.shape, B.shape[2:]
-        a_chunk = lambda width: pl.BlockSpec((1, L, width), lambda b, c: (b, c, 0), memory_space=pltpu.VMEM)
+        Bn, T, H = dt.shape
+        if packed is None:
+            P, (G, N), b_at, c_at = x.shape[3], B.shape[2:], 0, 0
+            x, B, C = x.reshape(Bn, T, H * P), B.reshape(Bn, T, G * N), C.reshape(Bn, T, G * N)
+        else:  # x is block 0 of H P columns; B and C are the two blocks of G N columns behind it
+            G, N = packed
+            P = (x.shape[2] - 2 * G * N) // H
+            b_at = H * P // (G * N)
+            c_at = b_at + 1
+        a_chunk = lambda width, at=0: pl.BlockSpec((1, L, width), lambda b, c: (b, c, at), memory_space=pltpu.VMEM)
         in_specs = [a_chunk(H * P), a_chunk(H), pl.BlockSpec((1, H), lambda b, c: (0, 0), memory_space=pltpu.VMEM),
-                    a_chunk(G * N), a_chunk(G * N), *([pl.BlockSpec(memory_space=pltpu.SMEM)] if D else [])]
+                    a_chunk(G * N, b_at), a_chunk(G * N, c_at), *([pl.BlockSpec(memory_space=pltpu.SMEM)] if D else [])]
         out = pl.pallas_call(
             partial(_ssm_scan_kernel, N=N, G=G, u=u),
             grid=(Bn, T // L),
@@ -1332,22 +1354,22 @@ def _ssm_scan_call(L: int, u: int, scope: int, interpret: bool):
                 **({} if scope == _SCOPED_VMEM_DEFAULT else {"vmem_limit_bytes": scope})),
             name="ssm_scan_fwd",
             interpret=interpret,
-        )(x.reshape(Bn, T, H * P), dt, A.reshape(1, H), B.reshape(Bn, T, G * N), C.reshape(Bn, T, G * N), *D)
+        )(x, dt, A.reshape(1, H), B, C, *D)
         return out.reshape(Bn, T, H, P)
 
     return jax.jit(call)
 
 
-def _ssm_scan_impl(x, dt, A, B, C, D=None, chunk=None):
+def _ssm_scan_impl(x, dt, A, B, C, D=None, chunk=None, packed=None):
     chaos.kernel_seam("pallas", "ssm_scan")
     import jax
     import jax.numpy as jnp
 
     def shard(x, dt, B, C, A, *D):
-        (_, T, H, _), (G, N) = x.shape, B.shape[2:]
+        (_, T, H), (G, N) = dt.shape, packed or B.shape[2:]
         L = _ssm_scan_chunk(chunk, T)
         scope = _ssm_scan_scope(_ssm_scan_vmem(L, H, N, G, x.dtype.itemsize))
-        return _ssm_scan_call(L, _ssm_scan_turn(H, G), scope, _interpret())(x, dt, B, C, A, *D)
+        return _ssm_scan_call(L, _ssm_scan_turn(H, G), scope, _interpret(), packed)(x, dt, B, C, A, *D)
 
     f32 = jnp.float32
     skip = () if D is None else (D.astype(f32),)
@@ -1355,4 +1377,24 @@ def _ssm_scan_impl(x, dt, A, B, C, D=None, chunk=None):
         return per_batch_shard(shard, x, dt.astype(f32), B, C, A.astype(f32), *skip, replicated=(4, 5))
 
 
+def _ssm_scan_packed_checker(xbc, dt, A, D=None, *, heads, groups, state, chunk=None) -> bool:
+    """``_ssm_scan_checker`` on the x, B and C that ``[x | B | C]`` holds, and what
+    reading them by block index needs besides: B's and C's G N columns whole lane
+    groups, and x's H P a multiple of them. Any other packed call is its
+    decomposition's, and so ``ssm_scan``'s own claim on the three slices."""
+    if len(getattr(xbc, "shape", ())) != 3:
+        return False
+    (Bn, T, W), H, G, N = xbc.shape, int(pyval(heads)), int(pyval(groups)), int(pyval(state))
+    inner = W - 2 * G * N
+    if inner <= 0 or inner % H or (G * N) % _LANE or inner % (G * N):
+        return False
+    part = lambda *shape: SimpleNamespace(shape=(Bn, T, *shape), dtype=xbc.dtype)
+    return _ssm_scan_checker(part(H, inner // H), dt, A, part(G, N), part(G, N), D, chunk)
+
+
+def _ssm_scan_packed_impl(xbc, dt, A, D=None, *, heads, groups, state, chunk=None):
+    return _ssm_scan_impl(xbc, dt, A, xbc, xbc, D, chunk, packed=(groups, state))
+
+
 ex.register_implementation("torch.ssm_scan", fn=_ssm_scan_impl, checker=_ssm_scan_checker)
+ex.register_implementation("torch.ssm_scan_packed", fn=_ssm_scan_packed_impl, checker=_ssm_scan_packed_checker)

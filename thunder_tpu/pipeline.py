@@ -28,13 +28,15 @@ from thunder_tpu.transforms.attention_residuals import save_sdpa_residuals_joint
 from thunder_tpu.transforms.common import cse, dce
 from thunder_tpu.transforms.cross_entropy_upcast import FOLDED_TAG, fold_cross_entropy_upcasts
 from thunder_tpu.transforms.rng import functionalize_rng_ops
+from thunder_tpu.transforms.ssm_layout import FOLDED_TAG as SSM_LAYOUTS_FOLDED_TAG
+from thunder_tpu.transforms.ssm_layout import fold_ssm_layouts
 
 # trace -> trace: what every front end does to an acquired trace
 CLEAN = (dce, cse)
 # (the caller's trace transforms run here: grad, autocast)
 # (trace, executors) -> trace: each rewrites for a kernel an executor of the
 # list would claim; the de-opt ladder's level 1 ("no fusion") leaves them out
-REWRITES = (save_sdpa_residuals_joint, fold_cross_entropy_upcasts, fold_attention_layouts)
+REWRITES = (save_sdpa_residuals_joint, fold_cross_entropy_upcasts, fold_attention_layouts, fold_ssm_layouts)
 # trace -> trace: always
 LOWER = (functionalize_rng_ops,)
 # (trace, executors) -> trace: the claim
@@ -42,7 +44,7 @@ CLAIM = transform_for_execution
 
 # What the ``transforms`` phase record carries beside its seconds, by presence:
 # a compile that ran no rewrite carries none.
-_COUNTED = (FOLDED_TAG, LAYOUTS_FOLDED_TAG)
+_COUNTED = (FOLDED_TAG, LAYOUTS_FOLDED_TAG, SSM_LAYOUTS_FOLDED_TAG)
 
 
 class Compiled(NamedTuple):

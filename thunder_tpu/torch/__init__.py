@@ -1792,23 +1792,30 @@ SSM_SCAN_CHUNK = 256  # positions a chunk of ``ssm_scan``'s decomposition; the p
 
 
 @torchsymbol(id="torch.causal_conv_silu")
-def causal_conv_silu(x, w, bias=None):
+def causal_conv_silu(x, w, bias=None, columns: Optional[tuple] = None):
     """``silu(conv(x) + bias)``: x (B, T, C), w (C, K) a depthwise causal filter,
     oldest tap first, bias (C,) or None; ``c[t] = sum_j w[:, j] * x[t - (K - 1 - j)]``
     with ``x[<0] = 0`` (Mamba's convolution over its packed ``[x | B | C]``).
+    With ``columns=(a, b)`` the convolution is of ``x[..., a:b]``, ``b - a = C``,
+    and x is the wider array those columns lie in (``transforms/ssm_layout.py``
+    hands it the projection whole: XLA fuses a tap cut out of the padded whole
+    into the pass, and writes a copy of a slice that is padded afterwards).
 
     K shifted products of one left-padded x, as ``short_conv`` has them and for
     its reason; the sum, the bias and the SiLU in float32 and rounded once, which
     XLA runs as one pass over x."""
     C, K = w.shape
-    check(x.ndim == 3 and x.shape[-1] == C, lambda: f"causal_conv_silu: {tuple(x.shape)} is not (B, T, {C})")
+    lo, hi = (0, x.shape[-1]) if columns is None else columns
+    check(x.ndim == 3 and 0 <= lo and hi <= x.shape[-1] and hi - lo == C,
+          lambda: f"causal_conv_silu: columns {lo}:{hi} of {tuple(x.shape)} are not (B, T, {C})")
     T, f32 = x.shape[1], dtypes.float32
     z = pad(x, (0, 0, K - 1, 0))
     up = lambda a: clang.maybe_convert_to_dtype(a, f32)  # a tap's slice first, then float32: no float32 copy of x is whole
+    tap = (lambda j: up(z[:, j:j + T])) if columns is None else (lambda j: up(z[:, j:j + T, lo:hi]))
     wf = up(w)
-    c = up(z[:, 0:T]) * wf[:, 0]
+    c = tap(0) * wf[:, 0]
     for j in range(1, K):
-        c = c + up(z[:, j:j + T]) * wf[:, j]
+        c = c + tap(j) * wf[:, j]
     if bias is not None:
         c = c + clang.maybe_convert_to_dtype(bias, f32)
     return clang.maybe_convert_to_dtype(silu(c), x.dtype)
@@ -1902,6 +1909,24 @@ def ssm_scan(x, dt, A, B, C, D=None, chunk: Optional[int] = None):
     if D is not None:
         y = y + up(x_in) * reshape(up(D), (1, 1, H, 1))
     return lowp(y)
+
+
+@torchsymbol(id="torch.ssm_scan_packed")
+def ssm_scan_packed(xbc, dt, A, D=None, *, heads: int, groups: int, state: int, chunk: Optional[int] = None):
+    """``ssm_scan`` on the convolution's result as it lies: xbc (B, T, H P + 2 G N)
+    is ``[x | B | C]`` along its last dimension, H ``heads``, G ``groups``, N
+    ``state`` -> (B, T, H, P). What ``transforms/ssm_layout.py`` writes where a
+    program cut the three out of one array for ``ssm_scan`` alone: an executor
+    that claims it reads them where they are, and the decomposition is the
+    program as it was written, the three slices, their reshapes and ``ssm_scan``."""
+    Bn, T, W = xbc.shape
+    inner = W - 2 * groups * state
+    check(inner > 0 and inner % heads == 0,
+          lambda: f"ssm_scan_packed: {tuple(xbc.shape)} is not [x | B | C] for {heads} heads, {groups} groups of {state}")
+    x = reshape(xbc[..., :inner], (Bn, T, heads, inner // heads))
+    B = reshape(xbc[..., inner:inner + groups * state], (Bn, T, groups, state))
+    C = reshape(xbc[..., inner + groups * state:], (Bn, T, groups, state))
+    return ssm_scan(x, dt, A, B, C, D, chunk=chunk)
 
 
 @torchsymbol(id="torch.sdpa_fwd_res")

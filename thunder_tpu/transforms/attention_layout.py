@@ -56,12 +56,12 @@ import math
 import time
 from typing import NamedTuple, Optional
 
-from thunder_tpu.core.proxies import Proxy, TensorProxy, pyval, variableify
-from thunder_tpu.core.pytree import tree_flatten
+from thunder_tpu.core.proxies import TensorProxy, pyval, variableify
 from thunder_tpu.core.trace import TraceCtx, from_trace, tracectx, wrap_in_trace_provenance
 from thunder_tpu.executors.pallasex import heads_per_lane_group
 from thunder_tpu.executors.passes import would_claim
 from thunder_tpu.transforms.attention_residuals import _bound_sdpa
+from thunder_tpu.transforms.uses import Uses, dims, last_dim_slice
 
 FOLDED_TAG = "attention_layouts_folded"  # how many attention sites the pass rewrote
 
@@ -76,36 +76,11 @@ _CLAIMED_BY = {"torch.apply_rope_heads": ("pallas",), "torch.split_heads": ("pal
                _WINDOW: ("pallas", "flash")}
 
 
-class _Uses:
-    """Who writes and who reads each proxy of a trace, by index."""
-
-    def __init__(self, trc: TraceCtx):
-        self.bsyms = trc.bound_symbols
-        self.writer: dict[str, int] = {}
-        self.readers: dict[str, list[int]] = {}
-        for i, b in enumerate(self.bsyms):
-            for p in b.flat_proxy_outs:
-                self.writer[p.name] = i
-            for name in dict.fromkeys(p.name for p in b.flat_proxy_args):
-                self.readers.setdefault(name, []).append(i)
-        self.returned = {p.name for p in tree_flatten(trc.output)[0] if isinstance(p, Proxy)}
-
-    def made_by(self, p, sym_id: str, readers: int = 1):
-        """The index of the ``sym_id`` that wrote ``p`` as its one output, if
-        ``p`` has just ``readers`` readers and does not leave the trace."""
-        if not isinstance(p, TensorProxy) or p.name in self.returned:
-            return None
-        i = self.writer.get(p.name)
-        if i is None or self.bsyms[i].sym.id != sym_id or self.bsyms[i].output is not p:
-            return None
-        return i if len(self.readers.get(p.name, ())) == readers else None
-
-
-def _token_major(uses: _Uses, p):
+def _token_major(uses: Uses, p):
     """``p`` (B, h, T, hs) as ``permute(reshape(s, (B, T, h, hs)), (0, 2, 1, 3))``:
     (s, the two indices), or None."""
     perm = uses.made_by(p, "torch.permute")
-    if perm is None or tuple(_dims(uses.bsyms[perm].args[1:])) != _HEADS_FIRST:
+    if perm is None or tuple(dims(uses.bsyms[perm].args[1:])) != _HEADS_FIRST:
         return None
     mid = uses.bsyms[perm].args[0]
     resh = uses.made_by(mid, "torch.reshape")
@@ -116,26 +91,6 @@ def _token_major(uses: _Uses, p):
     if tuple(mid.shape) != (B, T, h, hs) or tuple(getattr(s, "shape", ())) != (B, T, h * hs):
         return None
     return s, [perm, resh]
-
-
-def _dims(rest):
-    return rest[0] if len(rest) == 1 and isinstance(rest[0], (tuple, list)) else rest
-
-
-def _last_dim_slice(uses: _Uses, s):
-    """``s`` as ``lin[..., a:b]``: (lin, a, b, the index), or None."""
-    i = uses.made_by(s, "torch.getitem")
-    if i is None:
-        return None
-    lin, key = uses.bsyms[i].args
-    key = key if isinstance(key, tuple) else (key,)
-    *lead, last = key
-    whole = lambda k: k is Ellipsis or (isinstance(k, slice) and k == slice(None))
-    if not (all(whole(k) for k in lead) and isinstance(last, slice) and last.step in (None, 1)
-            and (Ellipsis in lead or len(key) == len(lin.shape))):
-        return None
-    a, b, _ = last.indices(lin.shape[-1])
-    return lin, a, b, i
 
 
 def _bound_call(call) -> dict:
@@ -158,7 +113,7 @@ class _Steps(NamedTuple):
         return {key for key, a in self.how.items() if a is not None}
 
 
-def _head_steps(uses: _Uses, p) -> Optional[_Steps]:
+def _head_steps(uses: Uses, p) -> Optional[_Steps]:
     """``p`` (B, h, T, hs) as ``apply_rope(rms_norm(x, (hs,), weight, eps), cos, sin)``,
     either step or both left out, each read by the next alone and x token-major
     out of a projection; None where it is anything else, or neither."""
@@ -181,7 +136,7 @@ def _head_steps(uses: _Uses, p) -> Optional[_Steps]:
     return _Steps(tm[0], how, uses.bsyms[gone[-1]].region, gone + tm[1])
 
 
-def _match(uses: _Uses, b: dict):
+def _match(uses: Uses, b: dict):
     """What of the idiom stands in front of an attention call: the operands of
     the rewrite and the indices that go, or None.
 
@@ -198,7 +153,7 @@ def _match(uses: _Uses, b: dict):
     sk, tv = _head_steps(uses, k), _token_major(uses, v)
     if sk is None or tv is None or sq.asked() != sk.asked():
         return None
-    slices = [_last_dim_slice(uses, s) for s in (sq.src, sk.src, tv[0])]
+    slices = [last_dim_slice(uses, s) for s in (sq.src, sk.src, tv[0])]
     if any(s is None for s in slices) or len({s[0].name for s in slices}) != 1:
         return None
     lin = slices[0][0]
@@ -211,7 +166,7 @@ def _match(uses: _Uses, b: dict):
                 gone=[*sq.gone, *sk.gone, *tv[1], *(s[3] for s in slices), lin_at])
 
 
-def _rewritten(trc: TraceCtx, uses: _Uses, at: int, b: dict, m: dict) -> list:
+def _rewritten(trc: TraceCtx, uses: Uses, at: int, b: dict, m: dict) -> list:
     """The lines that take the attention call's place, each in the region of
     the line it stands for."""
     import thunder_tpu.torch as ltorch
@@ -269,7 +224,7 @@ def fold_attention_layouts(trc: TraceCtx, executors) -> TraceCtx:
     if "pallas" not in names or not ids.intersection(_CONSUMERS) or any("_bwd" in i for i in ids):
         return trc
     start = time.perf_counter_ns()
-    uses = _Uses(trc)
+    uses = Uses(trc)
     put: dict[int, list] = {}
     gone: set[int] = set()
     for at, call in enumerate(uses.bsyms):
