@@ -5,7 +5,7 @@ from both and compare. Nothing runs and no chip is needed; a minute a cell.
 
     JAX_PLATFORMS=cpu python3 scripts/hash_cells.py [--workload CELL ...] [--text DIR]
 
-A train cell goes through its job's own ``lower_for`` (``build_train_step``);
+A train cell (job ``train`` or ``train_loop``) goes through ``build_train_step``;
 a forward cell through ``trace_program``, ``pipeline.clean`` and
 ``pipeline.compile_trace``, as the dispatcher takes it. What is hashed is
 ``lowered.as_text()`` with every Mosaic payload decoded (a
@@ -96,7 +96,7 @@ def main(argv=None) -> int:
         cell = manifest.load_cell(name)
         keys, job = manifest.published(cell), cell.traffic["job"]
         with kernels_compiled_not_interpreted(), persistent_cache_off():
-            if job == "train":
+            if job.startswith("train"):  # train, train_loop: build_train_step
                 lowered = train.lower_for(cell, keys, cell.traffic["batch"], cell.traffic["seq"], topo)
             else:
                 lowered = lower_forward(cell, keys, topo)

@@ -22,6 +22,9 @@ import pytest
 # - `minicpm-sala.fwd-t32k`'s at 6 (the three linear layers' rope of q and of k); since PR 41 the pass folds those three
 #   sites (heads of 64, two a lane group: q's call, k's call and v's split) and the count is 9:
 #   tests/test_sparse_linear_stand_in_claims.py holds the test whole at 9.
+# One more reads the manifest's order: `granite-4.0-h-micro.fwd-t16k`'s letter-for-letter test takes Granite's
+# configuration as `configs[-1]`, which since PR 47 is `ouro-2.6b`'s (a new entry goes at the end of its list):
+# tests/test_ssm_cell_letter_pin.py holds the test whole, with the entry found by name.
 # A `benchmark` PR moves the asserts (or derives the counts from the trace: PERF.md section 7) and takes this and
 # those files out.
 _PINNED_AT_THE_PARENTS_CLAIMS = {
@@ -31,6 +34,8 @@ _PINNED_AT_THE_PARENTS_CLAIMS = {
         "pins kernels_claimed == 13; 15 since PR 39: see tests/test_window_moe_stand_in_claims.py",
     "test_pb_sparse_linear_job.py::test_the_check_passes_the_system_and_fails_the_reference_at_float8":
         "pins kernels_claimed == 6; 9 since PR 41: see tests/test_sparse_linear_stand_in_claims.py",
+    "test_pb_ssm_job.py::test_the_cell_is_the_issues_letter_for_letter":
+        "reads granite's configuration as configs[-1]; ouro-2.6b's since PR 47: see tests/test_ssm_cell_letter_pin.py",
 }
 
 

@@ -302,6 +302,60 @@ class TestPallasCrossEntropy:
         want = float(F.cross_entropy(torch.from_numpy(logits), torch.from_numpy(target), reduction="sum"))
         np.testing.assert_allclose(got, want, rtol=1e-3)
 
+    def test_per_row_losses_are_claimed_and_ignored_rows_read_zero(self):
+        torch = pytest.importorskip("torch")
+        import torch.nn.functional as F
+
+        logits = _t(32, 256, scale=2.0)
+        target = np.random.RandomState(4).randint(0, 256, (32,)).astype(np.int64)
+        target[[3, 17]] = -100
+        jf = thunder_tpu.jit(lambda l, t: ttorch.cross_entropy(l, t, reduction="none"))
+        got = np.asarray(jf(logits, target))
+        assert "pallas_cross_entropy(" in thunder_tpu.last_traces(jf)[-1].python()
+        want = F.cross_entropy(torch.from_numpy(logits), torch.from_numpy(target), reduction="none").numpy()
+        assert got.shape == (32,) and got[3] == got[17] == 0.0
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+        decomposed = thunder_tpu.jit(lambda l, t: ttorch.cross_entropy(l, t, reduction="none"), executors=jax_only)
+        np.testing.assert_allclose(got, np.asarray(decomposed(logits, target)), rtol=1e-5, atol=1e-6)
+
+    def test_a_per_row_cotangent_is_claimed_by_the_backward_kernel(self):
+        """The trace VJP of a per-row loss is one ``torch.cross_entropy_bwd`` with
+        the row's own cotangent, the kernel claims it, and the gradient is the
+        decomposition's (and torch's), ignored rows zero."""
+        torch = pytest.importorskip("torch")
+        import torch.nn.functional as F
+
+        logits = _t(32, 256, scale=2.0)
+        target = np.random.RandomState(5).randint(0, 256, (32,)).astype(np.int64)
+        target[9] = -100
+        scale = np.random.RandomState(6).rand(32).astype(np.float32)
+
+        def loss(l, t, w):
+            return ttorch.sum(ttorch.cross_entropy(l, t, reduction="none") * w)
+
+        vg = thunder_tpu.value_and_grad(loss)
+        value, (dl, _) = vg(logits, target, scale)
+        trc = thunder_tpu.last_traces(vg)[-1]
+        bwd = [b for b in trc.bound_symbols if b.sym.id == "torch.cross_entropy_bwd"]
+        assert len(bwd) == 1 and bwd[0].sym.executor.name == "pallas" and bwd[0].args[0].shape == (32,)
+        assert not any("log_softmax" in b.sym.name for b in trc.bound_symbols)
+        tl = torch.from_numpy(logits).requires_grad_(True)
+        (F.cross_entropy(tl, torch.from_numpy(target), reduction="none") * torch.from_numpy(scale)).sum().backward()
+        np.testing.assert_allclose(np.asarray(dl), tl.grad.numpy(), rtol=1e-4, atol=1e-6)
+        assert not np.asarray(dl)[9].any()
+        decomposed = thunder_tpu.value_and_grad(loss, executors=jax_only)
+        dv, (dd, _) = decomposed(logits, target, scale)
+        np.testing.assert_allclose(np.asarray(dl), np.asarray(dd), rtol=1e-5, atol=1e-7)
+        np.testing.assert_allclose(float(value), float(dv), rtol=1e-6)
+
+    @pytest.mark.parametrize("reduction,claimed", [("mean", True), ("sum", True), ("none", True), ("batchmean", False)])
+    def test_the_checkers_take_each_reduction_the_kernels_compute(self, reduction, claimed):
+        from thunder_tpu.executors import pallasex
+
+        logits, target = _t(32, 256), np.zeros((32,), dtype=np.int64)
+        assert bool(pallasex._ce_checker(logits, target, reduction=reduction)) == claimed
+        assert bool(pallasex._ce_bwd_checker(np.ones(32, np.float32), logits, target, reduction=reduction)) == claimed
+
     def test_unclaimed_on_bad_vocab(self):
         logits = _t(16, 96)  # 96 % 128 != 0
         target = np.zeros((16,), dtype=np.int64)
@@ -330,6 +384,7 @@ class TestCrossEntropyUpcastFold:
     bf16 gradient; loss and gradients are the ones of the program as written."""
 
     N = 32
+    ROW_WEIGHTS = np.linspace(0.25, 2.0, N, dtype=np.float32)
 
     def _program(self, V, as_written_logits=lambda l: l.float(), **ce):
         x, w = _bt(4, 8, 64), _bt(V, 64, seed=1)
@@ -337,7 +392,8 @@ class TestCrossEntropyUpcastFold:
 
         def loss(x, w, t):
             logits = as_written_logits(ttorch.linear(x, w))
-            return ttorch.cross_entropy(ttorch.reshape(logits, (self.N, V)), t, **ce)
+            out = ttorch.cross_entropy(ttorch.reshape(logits, (self.N, V)), t, **ce)
+            return out if ce.get("reduction") != "none" else ttorch.sum(out * self.ROW_WEIGHTS)  # a cotangent a row
 
         return loss, (x, w, target)
 
@@ -355,7 +411,7 @@ class TestCrossEntropyUpcastFold:
 
         monkeypatch.setattr(pipeline, "REWRITES", tuple(r for r in pipeline.REWRITES if r is not fold_cross_entropy_upcasts))
 
-    @pytest.mark.parametrize("case", ["several_chunks", "one_chunk", "ignored_rows", "sum"])
+    @pytest.mark.parametrize("case", ["several_chunks", "one_chunk", "ignored_rows", "sum", "none", "none_ignored_rows"])
     def test_folded_pair_equals_the_program_as_written_bit_for_bit(self, monkeypatch, case):
         from thunder_tpu.executors import pallasex
 
@@ -363,8 +419,8 @@ class TestCrossEntropyUpcastFold:
         if case == "several_chunks":  # two whole chunks and a ragged one
             V = 640
             monkeypatch.setattr(pallasex, "_CE_CHUNK", 256)
-        loss, args = self._program(V, **({"reduction": "sum"} if case == "sum" else {}))
-        if case == "ignored_rows":
+        loss, args = self._program(V, **({"reduction": case.split("_")[0]} if case.startswith(("sum", "none")) else {}))
+        if case.endswith("ignored_rows"):
             args[2][[0, 5, 31]] = -100
 
         folded = thunder_tpu.value_and_grad(loss, argnums=(0, 1))

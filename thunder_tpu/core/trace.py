@@ -276,8 +276,9 @@ def region(name: str):
     ``from_bsym`` keep it, and the generated program runs those lines under
     ``jax.named_scope(name)``, so the name is in the HLO's metadata and on the
     profile's rows whichever executor ran the line. Regions nest as ``a/b``.
-    Symbols a pass makes anew (a backward, a ``del``) carry none. Outside a
-    trace this does nothing."""
+    The backward that ``transforms/autodiff.py`` writes for a symbol of region
+    ``r`` carries ``r/bwd``; other symbols a pass makes anew (a ``del``) carry
+    none. Outside a trace this does nothing."""
     trace = get_tracectx()
     if trace is None:
         yield

@@ -16,7 +16,8 @@ and those against the VMEM of the device's generation (``_ce_vmem_limit``),
 so the checker declines on a chip where nothing would compile.
 
 Claims ``torch.cross_entropy`` and the ``torch.cross_entropy_bwd``
-composite emitted by the autodiff rule. Falls back to the decomposition
+composite emitted by the autodiff rule, under each reduction: "mean", "sum",
+or "none", a loss a row and a cotangent a row. Falls back to the decomposition
 when shapes don't block-align or the dtype is neither (checker), exactly
 like the reference's executor checkers.
 
@@ -104,17 +105,20 @@ def _ce_shapes_ok(input, target) -> bool:
             and _ce_block_n(int(N) // shards, int(V), dtype.bytes) is not None)
 
 
+_CE_REDUCTIONS = ("mean", "sum", "none")  # "none": the loss a row, and a row's own cotangent in the backward
+
+
 def _ce_checker(input, target, weight=None, ignore_index=-100, reduction="mean", label_smoothing=0.0):
     return (
         weight is None
         and float(pyval(label_smoothing)) == 0.0
-        and reduction in ("mean", "sum")
+        and reduction in _CE_REDUCTIONS
         and _ce_shapes_ok(input, target)
     )
 
 
 def _ce_bwd_checker(g, input, target, ignore_index=-100, reduction="mean"):
-    return reduction in ("mean", "sum") and _ce_shapes_ok(input, target)
+    return reduction in _CE_REDUCTIONS and _ce_shapes_ok(input, target)
 
 
 # =============================================================================
@@ -259,6 +263,8 @@ def _ce_impl(input, target, weight=None, ignore_index=-100, reduction="mean", la
     loss = _ce_call(
         partial(_ce_fwd_kernel, ignore_index=int(ignore_index)), _LANE, jnp.float32, input, tgt
     )[:, 0]
+    if reduction == "none":  # zero where the target is ignore_index: the kernel weighs each row by it
+        return loss
     total = jnp.sum(loss)
     if reduction == "sum":
         return total
@@ -276,7 +282,7 @@ def _ce_bwd_impl(g, input, target, ignore_index=-100, reduction="mean"):
     if reduction == "mean":
         count = jnp.maximum(jnp.sum(valid), 1.0)
         row_scale = _lanes(g.astype(jnp.float32) * valid / count)
-    else:
+    else:  # sum, or none: g a scalar or (N,)
         row_scale = _lanes(g.astype(jnp.float32) * valid)
     return _ce_call(
         partial(_ce_bwd_kernel, ignore_index=int(ignore_index)), V, input.dtype, input, tgt, row_scale

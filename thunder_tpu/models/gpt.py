@@ -186,6 +186,11 @@ class GPTConfig:
     ssm_groups: int = 1
     ssm_conv_kernel: int = 4
     ssm_chunk: Optional[int] = None
+    # Looped depth (Ouro's LoopLM): the layer list runs loop_steps times with the same weights, ln_f after each step
+    # and the normed state fed to the next. With more than one step an exit gate (exit_gate_w, exit_gate_b) reads
+    # each step's normed state, and loss_fn is the exit objective (_exit_loss), its entropy weighed by exit_entropy.
+    loop_steps: int = 1
+    exit_entropy: float = 0.0
 
     @property
     def head_size(self) -> int:
@@ -480,6 +485,20 @@ _add(GPTConfig(name="granite-h-tiny", block_size=256, vocab_size=96, padded_voca
                logit_divisor=8.0, ssm_n_head=4, ssm_head_dim=64, ssm_state=32, ssm_groups=1, ssm_conv_kernel=4,
                ssm_chunk=64))
 
+# Ouro-2.6B (huggingface.co/ByteDance/Ouro-2.6B, model_type ouro, a LoopLM) at its published sizes: 48 layers of
+# 16 query and 16 key-value heads of 128, rope on the whole head at 1e6, SwiGLU of 5632, four norms a block, an
+# untied head; the layer list runs total_ut_steps = 4 times with the same weights, and an exit gate learns from
+# every step's loss (β = 0.1 is not published).
+_add(GPTConfig(name="ouro-2.6b", block_size=65536, vocab_size=49152, padded_vocab_size=49152,
+               n_layer=48, n_head=16, n_embd=2048, n_query_groups=16, head_dim=128, rotary_percentage=1.0,
+               parallel_residual=False, bias=False, norm_class="RMSNorm", norm_eps=1e-6, mlp_class="LLaMAMLP",
+               intermediate_size=5632, rope_base=1000000, sandwich_norms=True, loop_steps=4, exit_entropy=0.1))
+# The same blocks at test size: heads of 128, two layers looped four times.
+_add(GPTConfig(name="ouro-tiny", block_size=64, vocab_size=256, padded_vocab_size=256,
+               n_layer=2, n_head=2, n_embd=256, n_query_groups=2, head_dim=128, rotary_percentage=1.0,
+               parallel_residual=False, bias=False, norm_class="RMSNorm", norm_eps=1e-6, mlp_class="LLaMAMLP",
+               intermediate_size=512, rope_base=1000000, sandwich_norms=True, loop_steps=4, exit_entropy=0.1))
+
 # Falcon family — MQA (one KV head) + shared-attention-norm parallel residual
 # (the litgpt registry's falcon geometry; reference tests run falcon-7b-like
 # configs through thunder).
@@ -668,6 +687,8 @@ def init_params(config: GPTConfig, *, dtype=dtypes.bfloat16, seed: int = 0, devi
     params["ln_f"] = norm_params()
     if not C.tie_embeddings:
         params["lm_head_w"] = w(C.padded_vocab_size, C.n_embd)
+    if C.loop_steps > 1:  # a Linear(n_embd -> 1) with a bias, per token
+        params["exit_gate_w"], params["exit_gate_b"] = w(1, C.n_embd), zeros(1)
     return params
 
 
@@ -1044,26 +1065,39 @@ def _layers(params: dict, config: GPTConfig):
     return [(p, config.layer_mlp_class(i)) for i, p in enumerate(blocks)]
 
 
-def _hidden(params: dict, idx, config: GPTConfig, counts=None, last: Optional[int] = None):
+def _hidden(params: dict, idx, config: GPTConfig, counts=None, last: Optional[int] = None, states=None):
+    """The head's input. The layer list runs ``loop_steps`` times with the same
+    parameters (the same positions feed the rope each time); between two steps
+    ``ln_f`` norms the state and the normed state feeds the next, and is
+    appended to ``states`` where it is given. ``last`` slices after the loop."""
     B, T = idx.shape
     x = ttorch.embedding(idx, params["wte"])  # (B, T, C)
     if config.embedding_scale != 1.0:
         x = x * config.embedding_scale
     cos, sin = _rope_cache(T, config, device=x.device, dtype=x.dtype)
-    for layer, (p, kind) in enumerate(_layers(params, config)):
-        x = _block(x, p, cos, sin, kind, config, counts, layer)
+    for step in range(config.loop_steps):
+        if step:
+            x = _norm(x, params["ln_f"], config)
+            if states is not None:
+                states.append(x)
+        for layer, (p, kind) in enumerate(_layers(params, config)):
+            x = _block(x, p, cos, sin, kind, config, counts, layer)
     if last is not None:
         x = x[:, T - last:]
     x = _norm(x, params["ln_f"], config)
     return x if config.logit_divisor == 1.0 else x / config.logit_divisor
 
 
+def _head(x, params: dict, config: GPTConfig):
+    return ttorch.linear(x, params["wte" if config.tie_embeddings else "lm_head_w"])
+
+
 def forward(params: dict, idx, config: GPTConfig, last: Optional[int] = None):
-    """Token ids (B, T) int → logits (B, T, padded_vocab_size); with ``last``
-    the final norm and the head run on the last ``last`` positions only, what
-    a prefill reads: (B, last, padded_vocab_size)."""
-    return ttorch.linear(_hidden(params, idx, config, last=last),
-                         params["wte" if config.tie_embeddings else "lm_head_w"])
+    """Token ids (B, T) int → logits (B, T, padded_vocab_size) of the last loop
+    step, what an exit threshold of 1 serves; with ``last`` the final norm and
+    the head run on the last ``last`` positions only, what a prefill reads:
+    (B, last, padded_vocab_size)."""
+    return _head(_hidden(params, idx, config, last=last), params, config)
 
 
 def router_counts(params: dict, idx, config: GPTConfig):
@@ -1096,8 +1130,55 @@ def routed_rows(params: dict, idx, config: GPTConfig):
 
 
 def loss_fn(params: dict, idx, targets, config: GPTConfig):
-    """Next-token cross-entropy; logits in f32 for a stable softmax."""
+    """Next-token cross-entropy; logits in f32 for a stable softmax. With more
+    than one loop step, the exit objective (``_exit_loss``)."""
+    if config.loop_steps > 1:
+        return _exit_loss(params, idx, targets, config)
     logits = forward(params, idx, config)
     B, T, V = logits.shape
     logits = ttorch.reshape(logits.float(), (B * T, V))
     return ttorch.cross_entropy(logits, ttorch.reshape(targets, (B * T,)))
+
+
+def _exit_log_probs(gate_logits: list) -> list:
+    """log p_t of the exit distribution from the gate's logits z_1..z_L:
+    p_t = λ_t Π_{j<t}(1 - λ_j) for t < L, and the last step takes what is left,
+    p_L = Π_{j<L}(1 - λ_j), so z_L decides nothing; λ = σ(z), so log λ = logσ(z)
+    and log(1 - λ) = logσ(-z)."""
+    out, stayed = [], None
+    for z in gate_logits[:-1]:
+        out.append(ttorch.logsigmoid(z) if stayed is None else stayed + ttorch.logsigmoid(z))
+        stay = ttorch.logsigmoid(-z)
+        stayed = stay if stayed is None else stayed + stay
+    return out + [stayed]
+
+
+def _exit_loss(params: dict, idx, targets, config: GPTConfig):
+    """Ouro's objective: mean over tokens of Σ_t p_t ℓ_t − β H(p), ℓ_t each
+    token's cross-entropy at loop step t, p the exit distribution
+    (``_exit_log_probs``) of the gate σ(w · s_t + b) on each step's normed state
+    s_t, H(p) = −Σ_t p_t log p_t, β = ``exit_entropy``. Each step's head and
+    cross-entropy are region ``exit.heads``; the gate, the distribution and the
+    entropy ``exit.gate``."""
+    states: list = []
+    last = _hidden(params, idx, config, states=states)
+    states.append(last)
+    B, T = idx.shape
+    flat_targets = ttorch.reshape(targets, (B * T,))
+    losses, gate_logits = [], []
+    for s in states:
+        with region("exit.heads"):
+            logits = _head(s, params, config)
+            V = logits.shape[-1]
+            losses.append(ttorch.cross_entropy(ttorch.reshape(logits.float(), (B * T, V)), flat_targets,
+                                               reduction="none"))
+        with region("exit.gate"):  # the last step's is read by nothing, and goes with the trace's dead code
+            z = ttorch.linear(s, params["exit_gate_w"], params["exit_gate_b"])
+            gate_logits.append(ttorch.reshape(z.float(), (B * T,)))
+    with region("exit.gate"):
+        total = None
+        for loss, log_p in zip(losses, _exit_log_probs(gate_logits)):
+            p = ttorch.exp(log_p)
+            term = p * loss + config.exit_entropy * (p * log_p)  # − β H(p) = β Σ p log p
+            total = term if total is None else total + term
+        return ttorch.mean(total)

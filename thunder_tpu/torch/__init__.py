@@ -2028,7 +2028,8 @@ def sdpa_bwd_res(g, query, key, value, out, lse, attn_mask=None, is_causal: bool
 
 @torchsymbol(id="torch.cross_entropy_bwd")
 def cross_entropy_bwd(g, input, target, ignore_index: int = -100, reduction: str = "mean"):
-    """dlogits of fused cross-entropy: (softmax − onehot) · g/count. The
+    """dlogits of fused cross-entropy: (softmax − onehot) · g/count, or with
+    ``reduction`` "sum" or "none" (``g`` a row's cotangent, (N,)) · g. The
     Pallas executor replaces this whole op (reference analogue: the Triton
     CE backward kernels, triton_crossentropy.py:270,343)."""
     N, C = input.shape
@@ -2041,7 +2042,7 @@ def cross_entropy_bwd(g, input, target, ignore_index: int = -100, reduction: str
     if reduction == "mean":
         count = clang.maximum(clang.sum(validf, None), 1.0)
         row_scale = clang.true_divide(clang.mul(g, validf), count)
-    else:  # sum
+    else:  # sum, or none: g a scalar or a row's own
         row_scale = clang.mul(g, validf)
     d = clang.mul(clang.sub(p, onehot), clang.unsqueeze(row_scale, 1))
     return clang.maybe_convert_to_dtype(d, input.dtype)
@@ -2079,7 +2080,7 @@ def _register_composite_vjps():
         return grads_by_name(bsym, names, {"query": dq, "key": dk, "value": dv})
 
     def _ce_checker(input, target, weight=None, ignore_index=-100, reduction="mean", label_smoothing=0.0):
-        return weight is None and float(pyval(label_smoothing)) == 0.0 and reduction in ("mean", "sum")
+        return weight is None and float(pyval(label_smoothing)) == 0.0 and reduction in ("mean", "sum", "none")
 
     @register_vjp("torch.cross_entropy", checker=_ce_checker)
     def _ce_vjp(bsym, g):

@@ -37,7 +37,7 @@ from thunder_tpu.core.prims import PrimIDs
 from thunder_tpu.core.proxies import NumberProxy, Proxy, TensorProxy, Variable, variableify
 from thunder_tpu.core.pytree import tree_flatten, tree_map, tree_unflatten
 from thunder_tpu.core.symbol import BoundSymbol
-from thunder_tpu.core.trace import TraceCtx, from_trace, tracectx, wrap_in_trace_provenance
+from thunder_tpu.core.trace import TraceCtx, from_trace, get_tracectx, tracectx, wrap_in_trace_provenance
 from thunder_tpu.transforms.common import dce
 
 
@@ -786,7 +786,11 @@ class BackwardBuilder:
         return self.env.get(variableify(proxy))
 
     def run(self, flat_bsyms: Sequence[BoundSymbol]) -> None:
+        trace = get_tracectx()
+        outer = trace.region
         for bsym in reversed(flat_bsyms):
+            # What is written for a forward symbol of region r is in r/bwd (core/trace.py::region).
+            trace.region = outer if bsym.region is None else f"{bsym.region}/bwd"
             outs = bsym.flat_proxy_outs
             cts = [self.env.get(variableify(o)) for o in outs]
             if not any(c is not None for c in cts):
@@ -807,6 +811,7 @@ class BackwardBuilder:
             self._accumulate_grads(
                 tuple(bsym.args) + tuple(bsym.kwargs.values()), grads
             )
+        trace.region = outer
 
     def _accumulate_grads(self, args, grads) -> None:
         for a, g in zip(args, grads):
