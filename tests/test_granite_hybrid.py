@@ -225,8 +225,9 @@ def _bf16_sibling(chunk=128):
 
 def test_pallas_claims_every_scan_at_the_kernels_shapes(monkeypatch):
     """bf16, 32 heads of 64 on a state of 128, 256 positions in chunks of 128: ``pallas`` owns the four scans
-    (interpreted here; since PR 46 as ``ssm_scan_packed``, on the convolution's result whole: ``tests/test_ssm_layout.py``),
-    ``flash`` the two attention calls, and the logits are the unclaimed program's."""
+    (interpreted here; as ``ssm_conv_scan``, which convolves in the scan's call on the projection's columns:
+    ``tests/test_ssm_layout.py``), ``flash`` the two attention calls, and the logits are
+    the unclaimed program's."""
     import jax.numpy as jnp
 
     cfg, params = _bf16_sibling()
@@ -237,7 +238,7 @@ def test_pallas_claims_every_scan_at_the_kernels_shapes(monkeypatch):
     claimed = jfn(params, idx)
     owners = [(b.sym.name, b.sym.executor.name) for b in thunder_tpu.last_traces(jfn)[-1].bound_symbols
               if b.sym.executor is not None and b.sym.executor.name in ("flash", "pallas")]
-    scan, attention = ("ssm_scan_packed", "pallas"), ("scaled_dot_product_attention", "flash")
+    scan, attention = ("ssm_conv_scan", "pallas"), ("scaled_dot_product_attention", "flash")
     assert owners == [scan, attention, scan, scan, attention, scan]
     assert rel(np.asarray(claimed.astype(jnp.float32)), np.asarray(plain.astype(jnp.float32))) < 2e-2
     # at the stand-in's own chunk of 64 the scans are the decomposition's, as before

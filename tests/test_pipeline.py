@@ -134,8 +134,10 @@ def test_the_rewrites_stand_in_this_order_and_these_counts_go_into_the_transform
     fold (PR 46) is the last, after the attention fold."""
     assert [step.__name__ for step in pipeline.REWRITES] == [
         "save_sdpa_residuals_joint", "fold_cross_entropy_upcasts", "fold_attention_layouts", "fold_ssm_layouts"]
-    assert pipeline._COUNTED == (cross_entropy_upcast.FOLDED_TAG, attention_layout.FOLDED_TAG, ssm_layout.FOLDED_TAG)
-    assert pipeline._COUNTED == ("cross_entropy_upcasts_folded", "attention_layouts_folded", "ssm_layouts_folded")
+    assert pipeline._COUNTED == (cross_entropy_upcast.FOLDED_TAG, attention_layout.FOLDED_TAG, ssm_layout.FOLDED_TAG,
+                                 ssm_layout.FUSED_TAG)
+    assert pipeline._COUNTED == ("cross_entropy_upcasts_folded", "attention_layouts_folded", "ssm_layouts_folded",
+                                 "ssm_convs_fused")
 
 
 @pytest.mark.parametrize("entry", ENTRY_POINTS)
@@ -206,7 +208,7 @@ def test_a_rewrite_leaves_the_trace_it_declines_as_it_was(case, cell):
     assert len(after.bound_symbols) == len(before) and all(a is b for a, b in zip(after.bound_symbols, before))
     if said is not None:
         assert after.tags[said[0]] == said[1]
-    assert ssm_layout.FOLDED_TAG not in after.tags
+    assert ssm_layout.FOLDED_TAG not in after.tags and ssm_layout.FUSED_TAG not in after.tags
 
 
 @pytest.mark.parametrize("cell", ["pythia-410m.train", "mistral-7b.train"])

@@ -3,7 +3,9 @@ they lie. The pass rewrites the idiom ``models/gpt.py::_mamba`` writes (the
 convolution on a slice of ``in_proj``'s result, ``ssm_scan`` on three slices of
 the convolution's), declines whatever it cannot prove is that idiom or that
 ``pallas`` would not take, and the rewritten program computes what was written,
-to the bit: the same kernel reads the same bytes."""
+to the bit: the same kernel reads the same bytes. Where ``pallas`` would also
+convolve in the scan's call, the ranged convolution and the packed scan become
+one ``ssm_conv_scan`` line, whose kernel computes what the two computed."""
 
 import numpy as np
 import pytest
@@ -23,7 +25,7 @@ from test_granite_hybrid import _bf16_sibling, batch
 from test_ssm_scan_kernel import like, proxy
 
 B, T, C, K = 2, 256, 64, 4
-FOLDED = ssm_layout.FOLDED_TAG
+FOLDED, FUSED = ssm_layout.FOLDED_TAG, ssm_layout.FUSED_TAG
 
 
 @pytest.fixture(autouse=True)
@@ -38,16 +40,19 @@ def _draw(*shape, seed=0, scale=0.5, dtype="bfloat16"):
 
 
 def _mixer(H=4, P=64, G=1, N=128, layers=1, dtype="bfloat16", skip=True, chunk=128, conv_reads="a_slice", order="xBC",
-           spare=0, xbc_read_twice=False, xbc_returned=False, holds_a_backward=False):
+           spare=0, xbc_read_twice=False, xbc_returned=False, holds_a_backward=False, taps=K, gap=0):
     """``_mamba`` of ``models/gpt.py``, ``layers`` times over, with a plain gate
     for its gated norm: (program, arguments). ``conv_reads`` "an_activation": the
     convolution's input is no slice. ``order`` "xCB": C's columns before B's.
-    ``spare`` widens the convolution by columns that no slice reads."""
+    ``spare`` widens the convolution by columns that no slice reads. ``taps`` is
+    the convolution's K; ``gap`` columns of the projection that nothing reads lie
+    between z and the convolution's."""
     inner, GN = H * P, G * N
     conv = inner + 2 * GN + spare
     args = [_draw(B, T, C, dtype=dtype)]
     for i in range(layers):
-        args += [_draw(inner + conv + H, C, seed=7 * i + 1, scale=0.1, dtype=dtype), _draw(conv, K, seed=7 * i + 2, dtype=dtype),
+        args += [_draw(inner + gap + conv + H, C, seed=7 * i + 1, scale=0.1, dtype=dtype),
+                 _draw(conv, taps, seed=7 * i + 2, dtype=dtype),
                  _draw(conv, seed=7 * i + 3, scale=0.1, dtype=dtype), _draw(H, seed=7 * i + 4, dtype="float32") - 3.0,
                  np.log(np.linspace(1.0, 8.0, H)).astype(np.float32), _draw(H, seed=7 * i + 5, dtype="float32"),
                  _draw(C, inner, seed=7 * i + 6, scale=0.1, dtype=dtype)]
@@ -57,7 +62,7 @@ def _mixer(H=4, P=64, G=1, N=128, layers=1, dtype="bfloat16", skip=True, chunk=1
         kept = []
         for in_w, conv_w, conv_b, dt_bias, A_log, D, out_w in zip(*(weights[j::7] for j in range(7))):
             lin = ttorch.linear(x, in_w)
-            z, xbc, dt = lin[..., :inner], lin[..., inner:inner + conv], lin[..., inner + conv:]
+            z, xbc, dt = lin[..., :inner], lin[..., inner + gap:inner + gap + conv], lin[..., inner + gap + conv:]
             if conv_reads == "an_activation":
                 xbc = ttorch.silu(xbc)
             with region("ssm.conv"):
@@ -118,21 +123,31 @@ def test_rewrites_the_idiom_and_computes_what_was_written(monkeypatch, idiom):
     make, sites, ranged = IDIOMS[idiom]
     program, args = make()
     trc = _folded_trace(program, args)
-    assert trc.tags[FOLDED] == sites
+    assert trc.tags[FOLDED] == sites and trc.tags[FUSED] == (sites if ranged else 0)
     lines = trc.bound_symbols
     ids = [b.sym.id for b in lines]
-    assert ids.count("torch.ssm_scan_packed") == ids.count("torch.causal_conv_silu") == sites and "torch.ssm_scan" not in ids
-    for conv, scan in zip((b for b in lines if b.sym.id == "torch.causal_conv_silu"),
-                          (b for b in lines if b.sym.id == "torch.ssm_scan_packed")):
+    assert "torch.ssm_scan" not in ids
+    if ranged:  # the ranged convolution and the packed scan, one line in the scan's region that stands for both
+        fused = [b for b in lines if b.sym.id == "torch.ssm_conv_scan"]
+        assert len(fused) == sites and "torch.causal_conv_silu" not in ids and "torch.ssm_scan_packed" not in ids
+        pairs = [b.subsymbols for b in fused]
+        for line, (conv, scan) in zip(fused, pairs):
+            assert line.region == "ssm.scan" and line.output is scan.output and line.args[0] is conv.args[0]
+            assert line.kwargs["columns"] == conv.kwargs["columns"] and line.args[3] is scan.args[1]
+    else:
+        assert ids.count("torch.ssm_scan_packed") == ids.count("torch.causal_conv_silu") == sites
+        pairs = list(zip((b for b in lines if b.sym.id == "torch.causal_conv_silu"),
+                         (b for b in lines if b.sym.id == "torch.ssm_scan_packed")))
+    for conv, scan in pairs:
         xbc, lin = conv.output, conv.args[0]
         assert scan.args[0] is xbc and conv.region == "ssm.conv" and scan.region == "ssm.scan"
         # nothing between them: the packed call is the array's one reader, and no line cuts it
-        assert [b.sym.id for b in lines if any(p is xbc for p in b.flat_proxy_args)] == ["torch.ssm_scan_packed"]
+        assert [b.sym.id for b in lines if any(p is xbc for p in b.flat_proxy_args)] == ([] if ranged else ["torch.ssm_scan_packed"])
         width = xbc.shape[-1]
         if ranged:  # the projection whole, and where in it
             inner = lin.shape[-1] - width - scan.kwargs["heads"]
             assert conv.kwargs["columns"] == (inner, inner + width)
-            assert [b.sym.id for b in lines if any(p is lin for p in b.flat_proxy_args)] == ["torch.getitem"] * 2 + ["torch.causal_conv_silu"]
+            assert [b.sym.id for b in lines if any(p is lin for p in b.flat_proxy_args)] == ["torch.getitem"] * 2 + ["torch.ssm_conv_scan"]
         else:
             assert "columns" not in conv.kwargs and tuple(lin.shape) == tuple(xbc.shape)
         assert [s.sym.id for s in scan.subsymbols] == ["torch.getitem", "torch.reshape"] * 3 + ["torch.ssm_scan"]
@@ -140,15 +155,16 @@ def test_rewrites_the_idiom_and_computes_what_was_written(monkeypatch, idiom):
     folded = thunder_tpu.jit(program)
     got = folded(*args)
     src = thunder_tpu.last_traces(folded)[-1].python()
-    assert _transforms_record(folded)[FOLDED] == sites
-    assert src.count("pallas_ssm_scan_packed(") == sites and "pallas_ssm_scan(" not in src
+    assert _transforms_record(folded)[FOLDED] == sites and _transforms_record(folded)[FUSED] == (sites if ranged else 0)
+    assert src.count("pallas_ssm_conv_scan(" if ranged else "pallas_ssm_scan_packed(") == sites and "pallas_ssm_scan(" not in src
     with monkeypatch.context() as m:
         _without_the_pass(m)
         written = thunder_tpu.jit(program)
         want = written(*args)
-    assert FOLDED not in _transforms_record(written)
+    assert FOLDED not in _transforms_record(written) and FUSED not in _transforms_record(written)
     assert thunder_tpu.last_traces(written)[-1].python().count("pallas_ssm_scan(") == sites
-    assert got.dtype == want.dtype and np.array_equal(*_bits(got), *_bits(want))  # the same kernel on the same bytes
+    # the same kernel on the same bytes; convolving in its step, the convolution's own arithmetic in its order
+    assert got.dtype == want.dtype and np.array_equal(*_bits(got), *_bits(want))
 
 
 def test_a_claim_that_fails_later_runs_the_program_as_written():
@@ -186,18 +202,56 @@ def test_declines_and_leaves_the_program_as_written(why):
     trc = dce(trc)
     before = list(trc.bound_symbols)
     after = ssm_layout.fold_ssm_layouts(trc, resolve_executors(executors))
-    assert after is trc and after.tags[FOLDED] == 0
+    assert after is trc and after.tags[FOLDED] == after.tags[FUSED] == 0
     assert len(after.bound_symbols) == len(before) and all(a is b for a, b in zip(after.bound_symbols, before))
     claimed = pipeline.compile_trace(trc, resolve_executors(executors)).claimed
     scans = [b.sym.executor.name for b in claimed.bound_symbols if b.sym.name == "ssm_scan"]
     assert scans == ([owner] if owner else []) and "ssm_scan_packed" not in claimed.python()
 
 
+UNFUSED = {
+    # why: (program and arguments, the packed scan's folds); where they are 0 the program stays as written
+    # x's columns 128 after a multiple of H P: no block of the projection (B's are still whole lane groups)
+    "a_column_start_no_multiple_of_H_P": (lambda: _mixer(gap=128), 1),
+    "taps_of_9": (lambda: _mixer(taps=9), 1),  # 8 rows to look back: more than a step keeps of the chunk before
+    "float32": (lambda: _mixer(dtype="float32"), 0),
+    "a_trace_that_holds_a_backward": (lambda: _mixer(holds_a_backward=True), 0),
+}
+
+
+@pytest.mark.parametrize("why", UNFUSED)
+def test_where_the_fused_call_is_declined_the_ranged_convolution_and_the_packed_scan_stand(monkeypatch, why):
+    """Where ``pallas`` would not convolve in the scan's call, the fold writes
+    what it wrote before the fused line existed: the convolution on its
+    projection's columns in ``ssm.conv`` and the packed scan in ``ssm.scan``, or
+    nothing at all where the packed scan is declined too; and the program
+    computes what was written, to the bit."""
+    make, folds = UNFUSED[why]
+    program, args = make()
+    trc = _folded_trace(program, args)
+    assert trc.tags[FOLDED] == folds and trc.tags[FUSED] == 0
+    ids = [str(b.sym.id) for b in trc.bound_symbols]
+    assert "torch.ssm_conv_scan" not in ids and ids.count("torch.ssm_scan_packed") == folds
+    convs = [b for b in trc.bound_symbols if b.sym.id == "torch.causal_conv_silu"]
+    assert len(convs) == 1 and convs[0].region == "ssm.conv"
+    if folds:
+        (scan,) = [b for b in trc.bound_symbols if b.sym.id == "torch.ssm_scan_packed"]
+        inner = convs[0].args[0].shape[-1] - convs[0].output.shape[-1] - scan.kwargs["heads"]
+        assert convs[0].kwargs["columns"] == (inner, inner + convs[0].output.shape[-1]) and scan.args[0] is convs[0].output
+    folded = thunder_tpu.jit(program)
+    got = folded(*args)
+    assert thunder_tpu.last_traces(folded)[-1].python().count("pallas_ssm_conv_scan(") == 0
+    with monkeypatch.context() as m:
+        _without_the_pass(m)
+        want = thunder_tpu.jit(program)(*args)
+    assert np.array_equal(*_bits(got), *_bits(want))
+
+
 def test_a_trace_without_a_scan_comes_back_the_same_object_and_says_nothing():
     _, trc = trace_program(lambda x, w: ttorch.causal_conv_silu(ttorch.linear(x, w)[..., 64:], w[:64, :K]),
                            (_draw(B, T, C), _draw(128, C)), {})
     trc = dce(trc)
-    assert ssm_layout.fold_ssm_layouts(trc, resolve_executors(None)) is trc and FOLDED not in trc.tags
+    assert ssm_layout.fold_ssm_layouts(trc, resolve_executors(None)) is trc and FOLDED not in trc.tags and FUSED not in trc.tags
 
 
 CHECKS = {
@@ -258,6 +312,73 @@ def test_the_kernel_on_the_packed_array_is_the_kernel_on_its_three_slices(heads,
     assert np.array_equal(*_bits(packed(xbc, dt, A, D)), *_bits(old))
 
 
+CONV_KERNEL = {
+    # batch, positions (chunks of 128), heads, groups, state, taps, bias, D, x's block of H P columns in the projection
+    "granite_s_form_x_at_block_1": (1, 384, 4, 1, 128, 4, True, True, 1),
+    "a_batch_of_2_two_groups_on_a_state_of_64": (2, 384, 4, 2, 64, 4, True, True, 1),
+    "taps_of_2_without_a_bias": (1, 384, 4, 1, 128, 2, False, True, 1),
+    "without_D_x_at_block_0": (1, 384, 4, 1, 128, 4, True, False, 0),
+    "sixteen_heads_x_at_block_2_four_chunks": (1, 512, 16, 1, 128, 4, False, False, 2),
+}
+
+
+@pytest.mark.parametrize("b,t,heads,groups,state,taps,bias,skip,block", CONV_KERNEL.values(), ids=CONV_KERNEL)
+def test_the_kernel_that_convolves_in_its_step_is_the_convolution_then_the_packed_kernel(b, t, heads, groups, state, taps,
+                                                                                       bias, skip, block):
+    """``pallas``'s ``ssm_conv_scan`` on the projection's columns against
+    ``causal_conv_silu`` on the same columns followed by the packed kernel: every
+    chunk after the first reads the rows the chunk before kept, and the sum, the
+    bias, the SiLU and the rounding are the convolution's, so the two agree to the
+    bit. The symbol's decomposition is those two lines, whoever runs it."""
+    from thunder_tpu.executors import pallasex
+
+    inner, GN = heads * 64, groups * state
+    width = inner + 2 * GN
+    lo = block * inner
+    lin = _draw(b, t, lo + width + 3 * 64 + heads, seed=1)
+    w, bb = _draw(width, taps, seed=2), _draw(width, seed=3, scale=0.1) if bias else None
+    dt = np.exp(np.random.RandomState(2).uniform(np.log(1e-3), np.log(0.1), (b, t, heads))).astype(np.float32)
+    A, D = -np.linspace(1.0, 16.0, heads).astype(np.float32), np.linspace(-1.0, 1.0, heads).astype(np.float32) if skip else None
+    how = dict(heads=heads, groups=groups, state=state, chunk=128)
+    columns = (lo, lo + width)
+    assert pallasex._ssm_conv_scan_checker(like(lin), like(w), None if bb is None else like(bb), like(dt), like(A),
+                                           None if D is None else like(D), columns=columns, **how)
+    got = pallasex._ssm_conv_scan_impl(lin, w, bb, dt, A, D, columns=columns, **how)
+    conv = thunder_tpu.jit(lambda lin, w, bb: ttorch.causal_conv_silu(lin, w, bb, columns=columns), executors=["jax"])
+    want = pallasex._ssm_scan_packed_impl(conv(lin, w, bb), dt, A, D, **how)
+    assert got.shape == want.shape == (b, t, heads, 64) and np.array_equal(*_bits(got), *_bits(want))
+    fused = thunder_tpu.jit(lambda *a: ttorch.ssm_conv_scan(*a, columns=columns, **how), executors=["jax"])
+    packed = thunder_tpu.jit(lambda xbc, dt, A, D: ttorch.ssm_scan_packed(xbc, dt, A, D, **how), executors=["jax"])
+    assert np.array_equal(*_bits(fused(lin, w, bb, dt, A, D)), *_bits(packed(conv(lin, w, bb), dt, A, D)))
+
+
+CONV_CHECKS = {
+    # (the projection's width, x's first column, taps, dtype), taken: granite-4.0-h-micro.fwd-t16k's mixer
+    "granite_4_0_h_micro_fwd_t16k": ((8512, 4096, 4, dtypes.bfloat16), True),
+    "x_at_block_0": ((4352, 0, 4, dtypes.bfloat16), True),
+    "taps_of_8": ((8512, 4096, 8, dtypes.bfloat16), True),
+    "taps_of_9": ((8512, 4096, 9, dtypes.bfloat16), False),
+    "x_off_its_block": ((8512 + 128, 4096 + 128, 4, dtypes.bfloat16), False),
+    "float32": ((8512, 4096, 4, dtypes.float32), False),
+}
+
+
+@pytest.mark.parametrize("case", CONV_CHECKS)
+def test_the_fused_checker_is_the_packed_one_and_whole_blocks_of_the_projection(monkeypatch, case):
+    from thunder_tpu.executors import pallasex
+
+    monkeypatch.setattr(pallasex, "_device_kind", lambda: "TPU v5 lite")
+    (total, lo, taps, dtype), taken = CONV_CHECKS[case]
+    f32, width = dtypes.float32, 4352
+    call = [proxy((1, 16384, total), dtype), proxy((width, taps), dtype), proxy((width,), dtype), proxy((1, 16384, 64), f32),
+            proxy((64,), f32), proxy((64,), f32)]
+    how = dict(heads=64, groups=1, state=128, chunk=256)
+    assert pallasex._ssm_conv_scan_checker(*call, columns=(lo, lo + width), **how) is taken
+    if taken:  # the step, convolving, fits the scope the packed step asks for
+        needed = pallasex._ssm_scan_vmem(256, 64, 128, 1, 2, convolves=True)
+        assert pallasex._ssm_scan_scope(needed) == pallasex._ssm_scan_scope(pallasex._ssm_scan_vmem(256, 64, 128, 1, 2))
+
+
 @pytest.mark.parametrize("columns", [(0, 96), (32, 128), (16, 112)], ids=["the_first", "the_last", "the_middle"])
 @pytest.mark.parametrize("bias", [True, False], ids=["bias", "no_bias"])
 def test_the_convolution_on_columns_is_the_convolution_on_the_slice(columns, bias):
@@ -272,22 +393,24 @@ def test_the_convolution_on_columns_is_the_convolution_on_the_slice(columns, bia
 
 
 def test_the_model_folds_a_site_a_mamba_layer_and_computes_what_was_written(monkeypatch):
-    """``models/gpt.py::forward`` on the Granite stand-in at the kernel's shapes: a site a Mamba layer, the packed
-    call ``pallas``'s in region ``ssm.scan`` beside ``flash``'s attention, the logits the unfolded program's bits."""
+    """``models/gpt.py::forward`` on the Granite stand-in at the kernel's shapes: a site a Mamba layer, where the
+    convolution and the scan are one call ``pallas``'s in region ``ssm.scan`` beside ``flash``'s attention, the
+    logits the unfolded program's bits."""
     cfg, params = _bf16_sibling()
     idx = batch(256)
     mamba = [cfg.layer_mixer(i) for i in range(cfg.n_layer)].count("mamba")
     jfn = thunder_tpu.jit(lambda p, i: gpt.forward(p, i, cfg, last=32))
     got = jfn(params, idx)
-    assert mamba == 4 and _transforms_record(jfn)[FOLDED] == mamba
+    assert mamba == 4 and _transforms_record(jfn)[FOLDED] == _transforms_record(jfn)[FUSED] == mamba
     (made,) = [t for t in thunder_tpu.last_traces(jfn) if t.pass_name() == "State-space layout folding"]
-    convs = [b for b in made.bound_symbols if b.sym.id == "torch.causal_conv_silu"]
-    assert len(convs) == mamba and all(b.kwargs["columns"] == (cfg.ssm_inner, cfg.ssm_inner + cfg.ssm_conv_channels)
-                                       and b.args[0].shape[-1] > cfg.ssm_conv_channels for b in convs)
+    assert not [b for b in made.bound_symbols if b.sym.id in ("torch.causal_conv_silu", "torch.ssm_scan_packed")]
+    fused = [b for b in made.bound_symbols if b.sym.id == "torch.ssm_conv_scan"]
+    assert len(fused) == mamba and all(b.kwargs["columns"] == (cfg.ssm_inner, cfg.ssm_inner + cfg.ssm_conv_channels)
+                                       and b.args[0].shape[-1] > cfg.ssm_conv_channels for b in fused)
     claimed = thunder_tpu.last_traces(jfn)[-1]
     owners = [(b.sym.name, b.sym.executor.name, b.region) for b in claimed.bound_symbols
               if b.sym.executor is not None and b.sym.executor.name in ("flash", "pallas")]
-    scan, attention = ("ssm_scan_packed", "pallas", "ssm.scan"), ("scaled_dot_product_attention", "flash", "attn.full")
+    scan, attention = ("ssm_conv_scan", "pallas", "ssm.scan"), ("scaled_dot_product_attention", "flash", "attn.full")
     assert owners == [scan, attention, scan, scan, attention, scan]
     with monkeypatch.context() as m:
         _without_the_pass(m)

@@ -1166,6 +1166,28 @@ ex.register_implementation("torch.window_attention", fn=_window_attend_impl, che
 # and one more of G N. That wants G N whole lane groups and H P a multiple of
 # G N; a packed call that is not so is its decomposition's, the three slices
 # and ``ssm_scan``.
+#
+# That array is the causal convolution's result, and the scan its one reader:
+# written by XLA's pass and read back once by the kernel, 143 MB each way a
+# layer of that cell, in a pass at a third of its bound (PERF.md: 38 ms of a
+# 750 ms call). ``torch.ssm_conv_scan`` (written where the convolution
+# reads a column range of its projection for this call alone) is the same call
+# on the projection: x, B and C are blocks of it at their columns' index, and a
+# step convolves B and C before the turns and each pair's lane group of x in
+# the turn that reads it, into VMEM scratches the body reads where it read the
+# blocks. A causal convolution wants the K - 1 rows before the chunk: the grid
+# walks a sequence's chunks in order, so the last 8 input rows of a chunk stay
+# in a scratch for the next one, zeroed at chunk 0 as the state is. A lane
+# group at a time, its input rows in float32 below those 8 in a scratch of
+# their own, each tap is a load of that scratch from its row, and the sum (in
+# the decomposition's order, tap 0 first and the bias last), the SiLU with an
+# exact division and the one rounding are the decomposition's. That wants K - 1
+# <= 7, x's columns at a multiple of H P and B's at a multiple of G N; any
+# other site keeps the packed call. By the compiler's schedule a step of that
+# cell is 24,255 bundles (the packed call's 19,324): 27 vector operations a
+# float32 register of the convolution, 13 of them the exact division, which
+# the turns' matmuls leave room for; convolved first, in a loop of its own,
+# 27,815; with the next turn's x convolved in each turn, 25,145 to 27,564.
 
 # Measured on the v5e at granite-4.0-h-micro.fwd-t16k's shapes, a layer's call alone with the two copies that turn x
 # and y between (T, 64, 64) and (T, 4096) around it (PERF.md, PR 45; XLA's decomposition reads 8.38 ms there): a turn of
@@ -1185,17 +1207,28 @@ def _ssm_scan_turn(H: int, G: int):
     return u if R % u == 0 and u % 2 == 0 else None
 
 
-def _ssm_scan_vmem(L: int, H: int, N: int, G: int, itemsize: int) -> int:
+_SSM_CONV_HALO = 8  # input rows a step keeps for the next: one sublane tile, so K - 1 <= 7 taps look back
+
+
+def _ssm_scan_vmem(L: int, H: int, N: int, G: int, itemsize: int, convolves: bool = False) -> int:
     """What a step holds in VMEM: the blocks of x and y, of dt (its heads padded
     to the lanes) and of B and C, each twice for the pipeline; the state, the
     turns' columns and rows, ``C B^T`` and B turned in float32 and C a group;
     the triangle and the running sums before the loop, and some twelve float32
-    arrays of a tile of queries by a lane group inside it."""
+    arrays of a tile of queries by a lane group inside it. Where the step
+    convolves too: the taps and the bias, twice; a turn's x and B and C
+    convolved; the kept rows of every column, and a pair's rows below them in
+    float32; and some eight float32 arrays of a lane group's rows."""
     u, P = _ssm_scan_turn(H, G) or 2, _SSM_SCAN_HEAD
     blocks = 4 * L * H * P * itemsize + 2 * L * max(H, _LANE) * 4 + 4 * L * max(G * N, _LANE) * itemsize
     scratch = (N * H * P * 4 + (H // u) * L * (_LANE + 3 * 8) * 4 + G * (L * L + max(N, 8) * L) * 4
                + G * L * max(N, _LANE) * itemsize)
-    return blocks + scratch + 2 * L * L * 4 + 6 * L * max(H, _LANE) * 4 + 12 * _LANE * _LANE * 4
+    needed = blocks + scratch + 2 * L * L * 4 + 6 * L * max(H, _LANE) * 4 + 12 * _LANE * _LANE * 4
+    if convolves:
+        W = H * P + 2 * G * N
+        needed += (4 * _SSM_CONV_HALO * W * 4 + L * (u * P + 2 * G * N) * itemsize + _SSM_CONV_HALO * W * 4
+                   + u // 2 * (_SSM_CONV_HALO + L) * _LANE * 4 + 8 * L * _LANE * 4)
+    return needed
 
 
 def _ssm_scan_scope(needed: int) -> int:
@@ -1224,15 +1257,49 @@ def _ssm_scan_checker(x, dt, A, B, C, D=None, chunk=None) -> bool:
     if not (P == _SSM_SCAN_HEAD and N in (64, 128) and H % G == 0 and _ssm_scan_turn(int(H), int(G)) is not None
             and L % _LANE == 0 and T % L == 0 and Bn % batch_shards() == 0):
         return False
-    needed = _ssm_scan_vmem(L, int(H), int(N), int(G), 2)
+    return _ssm_scan_fits(L, int(H), int(N), int(G))
+
+
+def _ssm_scan_fits(L: int, H: int, N: int, G: int, convolves: bool = False) -> bool:
+    needed = _ssm_scan_vmem(L, H, N, G, 2, convolves)
     return needed <= 3 * _ssm_scan_scope(needed) // 4
 
 
-def _ssm_scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, N: int, G: int, u: int):
+def _ssm_conv_lanes(new, at, w_ref, bias_ref, halo_scr, ext):
+    """One lane group of the chunk's ``silu(conv(x) + bias)`` in float32, to be
+    rounded once: ``new`` its input rows (L, 128), ``at`` its first column of
+    ``[x | B | C]`` (of the taps ``w_ref`` (K, W) and the bias (1, W) in
+    float32, and of the last rows of the chunk before in ``halo_scr``). The rows
+    go below the kept ones in ``ext`` (8 + L, 128), tap j is the load that
+    starts ``K - 1 - j`` rows above the chunk's first, and the group's last rows
+    are kept for the next chunk."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    K, L, halo = w_ref.shape[0], new.shape[0], _SSM_CONV_HALO
+    cols = pl.ds(at, _LANE)
+    ext[0:halo, :] = halo_scr[:, cols]
+    ext[halo:, :] = new.astype(jnp.float32)
+    taps = w_ref[:, cols]
+    c = ext[pl.ds(halo - (K - 1), L), :] * taps[0:1]
+    for j in range(1, K):
+        c = c + ext[pl.ds(halo - (K - 1) + j, L), :] * taps[j:j + 1]
+    if bias_ref is not None:
+        c = c + bias_ref[:, cols]
+    halo_scr[:, cols] = ext[L:, :]
+    return c * (1.0 / (jnp.exp(-c) + 1.0))
+
+
+def _ssm_scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, N: int, G: int, u: int, conv: int = 0):
+    """``conv``: the convolution's operands ahead of D, 0 where the step does not
+    convolve, 1 (the taps) or 2 (the taps and the bias)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    conv_refs, refs = refs[:conv], refs[conv:]
+    if conv:
+        refs, (xs_scr, bc_scr, halo_scr, ext_scr) = refs[:-4], refs[-4:]
     *d_ref, y_ref, s_scr, cols_scr, rows_scr, cb_scr, bt_scr, c_scr = refs
     d_ref = d_ref[0] if d_ref else None  # D, (H,) in SMEM, where the call has one
     L, H = dt_ref.shape[1], dt_ref.shape[2]
@@ -1245,6 +1312,19 @@ def _ssm_scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, N: int, G: int, 
     @pl.when(pl.program_id(1) == 0)
     def _():
         s_scr[...] = jnp.zeros(s_scr.shape, f32)
+        if conv:
+            halo_scr[...] = jnp.zeros(halo_scr.shape, f32)
+
+    if not conv:  # x, B and C are the blocks
+        keys_of, queries_of = (lambda g, ref=ref: ref[0, :, g * N:(g + 1) * N] for ref in (b_ref, c_ref))
+    else:  # the blocks are the convolution's input: B and C convolved here, x a lane group before a pair of heads reads it
+        w_ref, bias_ref = (*conv_refs, None)[:2]
+        GN = G * N
+        for k in range(2 * GN // tile):
+            src, lanes = (b_ref, c_ref)[k * tile // GN], slice(k * tile % GN, k * tile % GN + tile)
+            bc_scr[:, k * tile:(k + 1) * tile] = _ssm_conv_lanes(
+                src[0, :, lanes], H * P + k * tile, w_ref, bias_ref, halo_scr, ext_scr.at[k % ext_scr.shape[0]]).astype(lowp)
+        keys_of, queries_of = (lambda g, at=at: bc_scr[:, at + g * N:at + (g + 1) * N] for at in (0, GN))
 
     def summed(ones, terms, dims):
         """``ones`` (exact in bf16) against float32 ``terms``, exact to the float32 accumulation: the terms split
@@ -1270,7 +1350,7 @@ def _ssm_scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, N: int, G: int, 
         for k, rows in enumerate((cum_t, dt_t, to_end)):
             rows_scr[o, k, 0:u, :] = rows[o * u:(o + 1) * u, :]
     for g in range(G):
-        keys, queries = b_ref[0, :, g * N:(g + 1) * N], c_ref[0, :, g * N:(g + 1) * N]
+        keys, queries = keys_of(g), queries_of(g)
         cb_scr[g] = jax.lax.dot_general(queries, keys, nt, preferred_element_type=f32)
         # B turned, (N, L), by a product with the identity: one nonzero a sum, exact, and no transpose to lay out
         bt_scr[g] = jax.lax.dot_general(identity(N, lowp), keys, nt, preferred_element_type=f32)
@@ -1288,6 +1368,13 @@ def _ssm_scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, N: int, G: int, 
         for pair in range(0, u, 2):
             heads = (pair, pair + 1)
             lanes = pl.ds(pl.multiple_of((first + pair) * P, tile), tile)
+            if not conv:
+                x_of = lambda rows, lanes=lanes: x_ref[0, rows, lanes]
+            else:  # the pair's lane group of x, convolved where the turn's matmuls leave the vector unit room
+                mine = slice(pair * P, pair * P + tile)
+                xs_scr[:, mine] = _ssm_conv_lanes(x_ref[0, :, lanes], lanes.start, w_ref, bias_ref, halo_scr,
+                                                  ext_scr.at[pair // 2]).astype(lowp)
+                x_of = lambda rows, mine=mine: xs_scr[rows, mine]
             state = s_scr[:, lanes]                                             # (N, 128)
             entering = state.astype(lowp)
             for r in range(L // tile):  # a tile of queries
@@ -1300,15 +1387,15 @@ def _ssm_scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, N: int, G: int, 
                         if t == r:
                             ahead = jnp.where(on_or_below, ahead, -jnp.inf)
                         m = (jnp.exp(ahead) * cb_scr[g, tiles[r], tiles[t]] * dt_j[h:h + 1, tiles[t]]).astype(lowp)
-                        part = jnp.dot(m, x_ref[0, tiles[t], lanes], preferred_element_type=f32)
+                        part = jnp.dot(m, x_of(tiles[t]), preferred_element_type=f32)
                         acc = part if t == 0 else acc + part
                     within.append(acc)
                 y = y + spread(*within)
                 if d_ref is not None:
-                    y = y + x_ref[0, tiles[r], lanes].astype(f32) * spread(*(d_ref[first + h] for h in heads))
+                    y = y + x_of(tiles[r]).astype(f32) * spread(*(d_ref[first + h] for h in heads))
                 y_ref[0, tiles[r], lanes] = y.astype(y_ref.dtype)
             # what the chunk adds to each head's state: B^T (to_end x), to_end along B^T's lanes
-            adds = [jnp.dot((bt_scr[g] * to_end_j[h:h + 1, :]).astype(lowp), x_ref[0, :, lanes], preferred_element_type=f32)
+            adds = [jnp.dot((bt_scr[g] * to_end_j[h:h + 1, :]).astype(lowp), x_of(slice(None)), preferred_element_type=f32)
                     for h in heads]
             decay = spread(*(jnp.exp(cum_j[h:h + 1, L - 1:L]) for h in heads))  # a chunk's whole decay, (1, 128)
             s_scr[:, lanes] = state * decay + spread(*adds)
@@ -1318,7 +1405,8 @@ def _ssm_scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, N: int, G: int, 
 
 
 @lru_cache(maxsize=32)
-def _ssm_scan_call(L: int, u: int, scope: int, interpret: bool, packed: tuple | None = None):
+def _ssm_scan_call(L: int, u: int, scope: int, interpret: bool, packed: tuple | None = None,
+                   conv: tuple | None = None):
     """The call for chunks of L positions and turns of u heads in a scope of
     VMEM, as one jitted function: a model's layers call it one after another
     with the same shapes, and jax traces the kernel's body and lowers it for
@@ -1327,60 +1415,79 @@ def _ssm_scan_call(L: int, u: int, scope: int, interpret: bool, packed: tuple | 
     Everything the trace reads outside its operands is in the cache's key.
     ``packed`` (G, N): x, B and C are one array, ``[x | B | C]`` (B, T, H P + 2 G
     N), given three times; the same three blocks are read out of it by their
-    index along its last dimension, and the kernel sees the refs it always saw."""
+    index along its last dimension, and the kernel sees the refs it always saw.
+    ``conv`` (operands, a): the array is the projection whose columns from a on
+    are the convolution's input ``[x | B | C]``, the taps (K, H P + 2 G N) and
+    the bias (1, H P + 2 G N) where ``operands`` is 2 follow A, and each step
+    convolves its chunk before it scans it."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    def call(x, dt, B, C, A, *D):
+    def call(x, dt, B, C, A, *rest):
         Bn, T, H = dt.shape
+        weights, D = (rest[:conv[0]], rest[conv[0]:]) if conv else ((), rest)
         if packed is None:
-            P, (G, N), b_at, c_at = x.shape[3], B.shape[2:], 0, 0
+            P, (G, N), x_at, b_at, c_at = x.shape[3], B.shape[2:], 0, 0, 0
             x, B, C = x.reshape(Bn, T, H * P), B.reshape(Bn, T, G * N), C.reshape(Bn, T, G * N)
-        else:  # x is block 0 of H P columns; B and C are the two blocks of G N columns behind it
+        else:  # x is a block of H P columns; B and C are the two blocks of G N columns behind it
             G, N = packed
-            P = (x.shape[2] - 2 * G * N) // H
-            b_at = H * P // (G * N)
+            lo, W = (conv[1], weights[0].shape[1]) if conv else (0, x.shape[2])
+            P = (W - 2 * G * N) // H
+            x_at, b_at = lo // (H * P), (lo + H * P) // (G * N)
             c_at = b_at + 1
         a_chunk = lambda width, at=0: pl.BlockSpec((1, L, width), lambda b, c: (b, c, at), memory_space=pltpu.VMEM)
-        in_specs = [a_chunk(H * P), a_chunk(H), pl.BlockSpec((1, H), lambda b, c: (0, 0), memory_space=pltpu.VMEM),
-                    a_chunk(G * N, b_at), a_chunk(G * N, c_at), *([pl.BlockSpec(memory_space=pltpu.SMEM)] if D else [])]
+        whole = lambda a: pl.BlockSpec(a.shape, lambda b, c: (0, 0), memory_space=pltpu.VMEM)
+        in_specs = [a_chunk(H * P, x_at), a_chunk(H), whole(A.reshape(1, H)), a_chunk(G * N, b_at),
+                    a_chunk(G * N, c_at), *map(whole, weights), *([pl.BlockSpec(memory_space=pltpu.SMEM)] if D else [])]
+        scratch = [pltpu.VMEM((N, H * P), jnp.float32), pltpu.VMEM((H // u, L, _LANE), jnp.float32),
+                   pltpu.VMEM((H // u, 3, 8, L), jnp.float32), pltpu.VMEM((G, L, L), jnp.float32),
+                   pltpu.VMEM((G, N, L), jnp.float32), pltpu.VMEM((G, L, N), x.dtype)]
+        if conv:  # a turn's x and B and C convolved, the rows kept for the next chunk, a pair's rows below them
+            W = H * P + 2 * G * N
+            scratch += [pltpu.VMEM((L, u * P), x.dtype), pltpu.VMEM((L, 2 * G * N), x.dtype),
+                        pltpu.VMEM((_SSM_CONV_HALO, W), jnp.float32),
+                        pltpu.VMEM((u // 2, _SSM_CONV_HALO + L, _LANE), jnp.float32)]
         out = pl.pallas_call(
-            partial(_ssm_scan_kernel, N=N, G=G, u=u),
+            partial(_ssm_scan_kernel, N=N, G=G, u=u, **({"conv": conv[0]} if conv else {})),
             grid=(Bn, T // L),
             in_specs=in_specs,
             out_specs=a_chunk(H * P),
             out_shape=jax.ShapeDtypeStruct((Bn, T, H * P), x.dtype),
-            scratch_shapes=[pltpu.VMEM((N, H * P), jnp.float32), pltpu.VMEM((H // u, L, _LANE), jnp.float32),
-                            pltpu.VMEM((H // u, 3, 8, L), jnp.float32), pltpu.VMEM((G, L, L), jnp.float32),
-                            pltpu.VMEM((G, N, L), jnp.float32), pltpu.VMEM((G, L, N), x.dtype)],
+            scratch_shapes=scratch,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
                 **({} if scope == _SCOPED_VMEM_DEFAULT else {"vmem_limit_bytes": scope})),
             name="ssm_scan_fwd",
             interpret=interpret,
-        )(x, dt, A.reshape(1, H), B, C, *D)
+        )(x, dt, A.reshape(1, H), B, C, *weights, *D)
         return out.reshape(Bn, T, H, P)
 
     return jax.jit(call)
 
 
-def _ssm_scan_impl(x, dt, A, B, C, D=None, chunk=None, packed=None):
+def _ssm_scan_impl(x, dt, A, B, C, D=None, chunk=None, packed=None, conv=()):
+    """``conv``: (the taps (K, W), the bias (1, W) or None, the first column) in
+    float32 where x, B and C are the convolution's input."""
     chaos.kernel_seam("pallas", "ssm_scan")
     import jax
     import jax.numpy as jnp
 
-    def shard(x, dt, B, C, A, *D):
+    weights = tuple(a for a in conv[:2] if a is not None)
+    how = (len(weights), conv[2]) if conv else None
+
+    def shard(x, dt, B, C, A, *rest):
         (_, T, H), (G, N) = dt.shape, packed or B.shape[2:]
         L = _ssm_scan_chunk(chunk, T)
-        scope = _ssm_scan_scope(_ssm_scan_vmem(L, H, N, G, x.dtype.itemsize))
-        return _ssm_scan_call(L, _ssm_scan_turn(H, G), scope, _interpret(), packed)(x, dt, B, C, A, *D)
+        scope = _ssm_scan_scope(_ssm_scan_vmem(L, H, N, G, x.dtype.itemsize, bool(weights)))
+        return _ssm_scan_call(L, _ssm_scan_turn(H, G), scope, _interpret(), packed, how)(x, dt, B, C, A, *rest)
 
     f32 = jnp.float32
     skip = () if D is None else (D.astype(f32),)
     with jax.enable_x64(False):
-        return per_batch_shard(shard, x, dt.astype(f32), B, C, A.astype(f32), *skip, replicated=(4, 5))
+        return per_batch_shard(shard, x, dt.astype(f32), B, C, A.astype(f32), *weights, *skip,
+                               replicated=tuple(range(4, 6 + len(weights))))
 
 
 def _ssm_scan_packed_checker(xbc, dt, A, D=None, *, heads, groups, state, chunk=None) -> bool:
@@ -1402,5 +1509,34 @@ def _ssm_scan_packed_impl(xbc, dt, A, D=None, *, heads, groups, state, chunk=Non
     return _ssm_scan_impl(xbc, dt, A, xbc, xbc, D, chunk, packed=(groups, state))
 
 
+def _ssm_conv_scan_checker(lin, w, bias, dt, A, D=None, *, columns, heads, groups, state, chunk=None) -> bool:
+    """``_ssm_scan_packed_checker`` on the convolution's result, and what
+    convolving in the step needs besides: taps that look back at most 7 rows, x's
+    columns at a multiple of H P and B's at a multiple of G N in the projection,
+    and the step's VMEM with the convolution's scratches. Any other call is its
+    decomposition's, and so the packed call's."""
+    if len(getattr(lin, "shape", ())) != 3 or len(getattr(w, "shape", ())) != 2:
+        return False
+    (Bn, T, _), (lo, hi), (W, K) = lin.shape, (int(pyval(c)) for c in columns), w.shape
+    H, G, N = int(pyval(heads)), int(pyval(groups)), int(pyval(state))
+    inner = W - 2 * G * N
+    if (hi - lo != W or not 1 <= K <= _SSM_CONV_HALO or inner <= 0 or lo % inner or (lo + inner) % (G * N)
+            or (bias is not None and tuple(bias.shape) != (W,))):
+        return False
+    xbc = SimpleNamespace(shape=(Bn, T, W), dtype=lin.dtype)
+    if not _ssm_scan_packed_checker(xbc, dt, A, D, heads=H, groups=G, state=N, chunk=chunk):
+        return False
+    return _ssm_scan_fits(_ssm_scan_chunk(pyval(chunk), T), H, N, G, convolves=True)
+
+
+def _ssm_conv_scan_impl(lin, w, bias, dt, A, D=None, *, columns, heads, groups, state, chunk=None):
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    conv = (w.astype(f32).T, None if bias is None else bias.astype(f32).reshape(1, -1), int(columns[0]))
+    return _ssm_scan_impl(lin, dt, A, lin, lin, D, chunk, packed=(groups, state), conv=conv)
+
+
 ex.register_implementation("torch.ssm_scan", fn=_ssm_scan_impl, checker=_ssm_scan_checker)
 ex.register_implementation("torch.ssm_scan_packed", fn=_ssm_scan_packed_impl, checker=_ssm_scan_packed_checker)
+ex.register_implementation("torch.ssm_conv_scan", fn=_ssm_conv_scan_impl, checker=_ssm_conv_scan_checker)

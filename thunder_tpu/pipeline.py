@@ -29,6 +29,7 @@ from thunder_tpu.transforms.common import cse, dce
 from thunder_tpu.transforms.cross_entropy_upcast import FOLDED_TAG, fold_cross_entropy_upcasts
 from thunder_tpu.transforms.rng import functionalize_rng_ops
 from thunder_tpu.transforms.ssm_layout import FOLDED_TAG as SSM_LAYOUTS_FOLDED_TAG
+from thunder_tpu.transforms.ssm_layout import FUSED_TAG as SSM_CONVS_FUSED_TAG
 from thunder_tpu.transforms.ssm_layout import fold_ssm_layouts
 
 # trace -> trace: what every front end does to an acquired trace
@@ -44,7 +45,7 @@ CLAIM = transform_for_execution
 
 # What the ``transforms`` phase record carries beside its seconds, by presence:
 # a compile that ran no rewrite carries none.
-_COUNTED = (FOLDED_TAG, LAYOUTS_FOLDED_TAG, SSM_LAYOUTS_FOLDED_TAG)
+_COUNTED = (FOLDED_TAG, LAYOUTS_FOLDED_TAG, SSM_LAYOUTS_FOLDED_TAG, SSM_CONVS_FUSED_TAG)
 
 
 class Compiled(NamedTuple):

@@ -1929,6 +1929,19 @@ def ssm_scan_packed(xbc, dt, A, D=None, *, heads: int, groups: int, state: int, 
     return ssm_scan(x, dt, A, B, C, D, chunk=chunk)
 
 
+@torchsymbol(id="torch.ssm_conv_scan")
+def ssm_conv_scan(lin, w, bias, dt, A, D=None, *, columns: tuple, heads: int, groups: int, state: int,
+                  chunk: Optional[int] = None):
+    """``ssm_scan_packed`` on ``causal_conv_silu(lin, w, bias, columns=columns)``:
+    the convolution and the recurrence of a Mamba-2 mixer as one line, lin the
+    projection whose columns ``columns`` hold ``xBC`` -> (B, T, H, P). What
+    ``transforms/ssm_layout.py`` writes where the convolution's result is read by
+    the packed scan alone: an executor that claims it convolves each chunk where
+    it scans it, and the decomposition is the two lines the program had."""
+    xbc = causal_conv_silu(lin, w, bias, columns=columns)
+    return ssm_scan_packed(xbc, dt, A, D, heads=heads, groups=groups, state=state, chunk=chunk)
+
+
 @torchsymbol(id="torch.sdpa_fwd_res")
 def sdpa_fwd_res(query, key, value, attn_mask=None, is_causal: bool = False,
                  scale: Optional[float] = None, enable_gqa: bool = False):

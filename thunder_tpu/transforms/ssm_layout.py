@@ -28,6 +28,16 @@ Forward programs only, and only where ``pallas`` would take the packed call
 chunk, and that B's and C's columns are whole lane groups that x's are a
 multiple of). Anything else stays as written, and ``ssm_scan``'s own claim on
 three arrays stands.
+
+Then the packed array exists only to be written by the convolution and read
+back once by the scan (PERF.md: 38 ms of a 750 ms call, and 10 GB to HBM
+and back). Where the convolution takes its projection's columns and
+``pallas`` would take the two as one call (asked of its checker: the taps look
+back at most 7 rows, and x's and B's columns lie at whole blocks of the
+projection), the ranged convolution and the packed scan become one
+``ssm_conv_scan`` line in the scan's region, bound over those two lines, which
+convolves each chunk where it scans it. Where the checker declines, the two
+lines stand.
 """
 
 from __future__ import annotations
@@ -40,6 +50,7 @@ from thunder_tpu.executors.passes import would_claim
 from thunder_tpu.transforms.uses import Uses, last_dim_slice
 
 FOLDED_TAG = "ssm_layouts_folded"  # how many scans the pass handed their packed array; on a trace that holds a scan
+FUSED_TAG = "ssm_convs_fused"  # how many of those convolve in the scan's call; beside FOLDED_TAG
 
 _SCAN = "torch.ssm_scan"
 _CONV = "torch.causal_conv_silu"
@@ -47,6 +58,7 @@ _CONV = "torch.causal_conv_silu"
 
 _SCAN_ARGS = ("x", "dt", "A", "B", "C", "D", "chunk")
 _CONV_ARGS = ("x", "w", "bias", "columns")
+_PACKED_ARGS = ("xbc", "dt", "A", "D", "heads", "groups", "state", "chunk")
 
 
 def _bound(bsym, names) -> dict:
@@ -117,14 +129,28 @@ def _ranged_conv(trc: TraceCtx, uses: Uses, m: dict):
     return line.from_bsym_swap_proxies({variableify(xbc): m["xbc"]})
 
 
+def _conv_scan(conv, scan):
+    """``ssm_conv_scan`` in the packed scan's place and region, bound over the
+    ranged convolution and the packed scan, which are its decomposition."""
+    import thunder_tpu.torch as ltorch
+
+    c, s = _bound(conv, _CONV_ARGS), _bound(scan, _PACKED_ARGS)
+    line = ltorch.ssm_conv_scan._symbol.bind(
+        c["x"], c["w"], c["bias"], s["dt"], s["A"], s["D"], columns=c["columns"], heads=s["heads"],
+        groups=s["groups"], state=s["state"], chunk=s["chunk"], output=scan.output, subsymbols=(conv, scan))
+    line.region = scan.region
+    return line
+
+
 def fold_ssm_layouts(trc: TraceCtx, executors) -> TraceCtx:
     """Forward-trace pass. A trace that holds no ``ssm_scan`` comes back as it
     was; one that does says how many sites were rewritten under
-    ``trc.tags[FOLDED_TAG]``."""
+    ``trc.tags[FOLDED_TAG]``, and how many of them convolve in the scan's call
+    under ``trc.tags[FUSED_TAG]``."""
     ids = {str(b.sym.id) for b in trc.bound_symbols}
     if _SCAN not in ids:
         return trc
-    trc.tags[FOLDED_TAG] = 0
+    trc.tags[FOLDED_TAG] = trc.tags[FUSED_TAG] = 0
     executors = tuple(executors or ())
     if "pallas" not in {getattr(e, "name", None) for e in executors} or any("_bwd" in i for i in ids):
         return trc
@@ -132,7 +158,7 @@ def fold_ssm_layouts(trc: TraceCtx, executors) -> TraceCtx:
     uses = Uses(trc)
     put: dict[int, object] = {}
     gone: set[int] = set()
-    sites = 0
+    sites = fused = 0
     for at, call in enumerate(uses.bsyms):
         if call.sym.id != _SCAN:
             continue
@@ -145,14 +171,22 @@ def fold_ssm_layouts(trc: TraceCtx, executors) -> TraceCtx:
             continue
         put[at] = scan
         gone.update(m["cut"])
-        if m["taken"] is not None:
-            put[m["conv_at"]] = _ranged_conv(trc, uses, m)
-            gone.add(m["taken"][3])
         sites += 1
+        if m["taken"] is None:
+            continue
+        conv = _ranged_conv(trc, uses, m)
+        gone.add(m["taken"][3])
+        both = _conv_scan(conv, scan)
+        if would_claim(both, executors) == "pallas":
+            put[at] = both
+            gone.add(m["conv_at"])
+            fused += 1
+        else:
+            put[m["conv_at"]] = conv
 
     if not sites:
         return trc
     new = from_trace(trc)
     new.bound_symbols.extend(put.get(i, bsym) for i, bsym in enumerate(uses.bsyms) if i not in gone)
-    new.tags[FOLDED_TAG] = sites
+    new.tags[FOLDED_TAG], new.tags[FUSED_TAG] = sites, fused
     return wrap_in_trace_provenance(new, "State-space layout folding", start)
