@@ -35,6 +35,7 @@ from thunder_tpu.api import (  # noqa: F401
     cache_misses,
     cache_info,
     compile_phases,
+    dispatch_phases,
     set_execution_callback_file,
 )
 from thunder_tpu.common import (  # noqa: F401
@@ -56,7 +57,7 @@ __all__ = [
     "jit", "grad", "value_and_grad", "vmap", "jvp", "seed",
     "compile_data", "compile_stats", "last_traces", "last_prologue_traces",
     "last_backward_traces", "last_compile_options", "cache_hits",
-    "cache_misses", "cache_info", "compile_phases",
+    "cache_misses", "cache_info", "compile_phases", "dispatch_phases",
     "set_execution_callback_file",
     "CACHE_OPTIONS", "SHARP_EDGES_OPTIONS",
     "ThunderSharpEdgeError", "ThunderSharpEdgeWarning",

@@ -265,15 +265,16 @@ class CompileStats:
         self.trace_seconds: float = 0.0
         self.first_run_seconds: float = 0.0
         self.cache_lookup_ns: int = 0
-        # nanosecond timers
+        # nanosecond timers. The last call's: host = the whole call, cache =
+        # its lookup, launch = the program called (api.dispatch_phases).
         self.last_trace_host_start: int = 0
         self.last_trace_host_stop: int = 0
         self.last_trace_cache_start: int = 0
         self.last_trace_cache_stop: int = 0
+        self.last_trace_launch_start: int = 0
+        self.last_trace_launch_stop: int = 0
         self.last_trace_tracing_start: int = 0
         self.last_trace_tracing_stop: int = 0
-        self.last_trace_host_execution_start: int = 0
-        self.last_trace_host_execution_stop: int = 0
 
     @property
     def last_compile_time_ms(self) -> float:
