@@ -21,7 +21,7 @@ from thunder_tpu.core import dtypes
 from thunder_tpu.extend import resolve_executors
 from thunder_tpu.models import gpt
 from thunder_tpu.parallel.train import _compile_loss_and_grads
-from thunder_tpu.transforms import attention_layout, cross_entropy_upcast, ssm_layout
+from thunder_tpu.transforms import attention_layout, autodiff, cross_entropy_upcast, ssm_layout
 from thunder_tpu.transforms.attention_residuals import save_sdpa_residuals_joint
 from thunder_tpu.transforms.autodiff import grad_transform
 from thunder_tpu.transforms.common import cse, dce
@@ -135,9 +135,9 @@ def test_the_rewrites_stand_in_this_order_and_these_counts_go_into_the_transform
     assert [step.__name__ for step in pipeline.REWRITES] == [
         "save_sdpa_residuals_joint", "fold_cross_entropy_upcasts", "fold_attention_layouts", "fold_ssm_layouts"]
     assert pipeline._COUNTED == (cross_entropy_upcast.FOLDED_TAG, attention_layout.FOLDED_TAG, ssm_layout.FOLDED_TAG,
-                                 ssm_layout.FUSED_TAG)
+                                 ssm_layout.FUSED_TAG, autodiff.KEPT_WHOLE_TAG)
     assert pipeline._COUNTED == ("cross_entropy_upcasts_folded", "attention_layouts_folded", "ssm_layouts_folded",
-                                 "ssm_convs_fused")
+                                 "ssm_convs_fused", "grad_matmuls_kept_whole")
 
 
 @pytest.mark.parametrize("entry", ENTRY_POINTS)

@@ -270,7 +270,7 @@ _FREE_IDS = {
     PrimIDs.CHECK_TENSOR_SHAPE_AND_METADATA, PrimIDs.CHECK_NUMBER_TYPE_AND_VALUE,
     PrimIDs.CHECK_STRING_VALUE, PrimIDs.CHECK_LEN, PrimIDs.CHECK_KEYS,
     PrimIDs.CHECK_NONE, PrimIDs.CHECK_DIM_BUCKET,
-    PrimIDs.SHALLOW_COPY, PrimIDs.STOP_GRADIENT, PrimIDs.ITEM,
+    PrimIDs.SHALLOW_COPY, PrimIDs.STOP_GRADIENT, PrimIDs.OPTIMIZATION_BARRIER, PrimIDs.ITEM,
 }
 
 # Layout-only ops XLA compiles away (no data movement charged).

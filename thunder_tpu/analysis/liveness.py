@@ -68,7 +68,7 @@ _BOOKKEEPING_IDS = {
 # Layout/alias ops XLA lowers to views: zero bytes; output aliases arg 0.
 _ALIAS_IDS = {
     PrimIDs.RESHAPE, PrimIDs.SQUEEZE, PrimIDs.BROADCAST_IN_DIM,
-    PrimIDs.SHALLOW_COPY, PrimIDs.STOP_GRADIENT,
+    PrimIDs.SHALLOW_COPY, PrimIDs.STOP_GRADIENT, PrimIDs.OPTIMIZATION_BARRIER,
 }
 
 

@@ -98,6 +98,7 @@ class PrimIDs(enum.Enum):
     COPY_ = enum.auto()
     SHALLOW_COPY = enum.auto()
     STOP_GRADIENT = enum.auto()
+    OPTIMIZATION_BARRIER = enum.auto()
     # Tensor creation
     FULL = enum.auto()
     IOTA = enum.auto()
@@ -764,6 +765,16 @@ def _stop_gradient_meta(a: TensorProxy) -> TensorProxy:
 
 
 stop_gradient = make_prim(PrimIDs.STOP_GRADIENT, "stop_gradient", _stop_gradient_meta)
+
+
+def _optimization_barrier_meta(a: TensorProxy) -> TensorProxy:
+    return TensorProxy(like=a)
+
+
+# The identity, run as ``jax.lax.optimization_barrier``: XLA fuses nothing across
+# it, so the op that wrote ``a`` is compiled as a fusion of its own. ``cse``
+# never merges two (transforms/common.py).
+optimization_barrier = make_prim(PrimIDs.OPTIMIZATION_BARRIER, "optimization_barrier", _optimization_barrier_meta)
 
 
 def _copy__meta(src: TensorProxy, dst: TensorProxy) -> TensorProxy:

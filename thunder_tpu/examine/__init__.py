@@ -295,7 +295,7 @@ _NO_ALLOC_IDS = {
     PrimIDs.CHECK_TENSOR_SHAPE_AND_METADATA, PrimIDs.CHECK_NUMBER_TYPE_AND_VALUE,
     PrimIDs.CHECK_STRING_VALUE, PrimIDs.CHECK_LEN, PrimIDs.CHECK_KEYS, PrimIDs.CHECK_NONE,
     PrimIDs.CHECK_DIM_BUCKET,
-    PrimIDs.SHALLOW_COPY, PrimIDs.STOP_GRADIENT,
+    PrimIDs.SHALLOW_COPY, PrimIDs.STOP_GRADIENT, PrimIDs.OPTIMIZATION_BARRIER,
 }
 
 

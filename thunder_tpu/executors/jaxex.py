@@ -58,6 +58,7 @@ _reg(PrimIDs.DEVICE_PUT, lambda a, device: a)
 _reg(PrimIDs.ITEM, lambda a: a.item())
 _reg(PrimIDs.SHALLOW_COPY, lambda a: a)
 _reg(PrimIDs.STOP_GRADIENT, lax.stop_gradient)
+_reg(PrimIDs.OPTIMIZATION_BARRIER, lax.optimization_barrier)
 _reg(PrimIDs.COPY_, lambda src, dst: jnp.broadcast_to(src, dst.shape).astype(dst.dtype))
 
 

@@ -34,7 +34,7 @@ def iter_prim_level(bound_symbols) -> Iterable:
 
 _SAMESHAPE = {
     PrimIDs.CONVERT_ELEMENT_TYPE, PrimIDs.SHALLOW_COPY, PrimIDs.STOP_GRADIENT,
-    PrimIDs.DEVICE_PUT, PrimIDs.COPY_, PrimIDs.WHERE,
+    PrimIDs.OPTIMIZATION_BARRIER, PrimIDs.DEVICE_PUT, PrimIDs.COPY_, PrimIDs.WHERE,
     # elementwise unary
     PrimIDs.ABS, PrimIDs.ACOS, PrimIDs.ACOSH, PrimIDs.ASIN, PrimIDs.ASINH,
     PrimIDs.ATAN, PrimIDs.ATANH, PrimIDs.BITWISE_NOT, PrimIDs.CEIL, PrimIDs.COS,

@@ -25,6 +25,7 @@ from thunder_tpu.executors.passes import transform_for_execution
 from thunder_tpu.transforms.attention_layout import FOLDED_TAG as LAYOUTS_FOLDED_TAG
 from thunder_tpu.transforms.attention_layout import fold_attention_layouts
 from thunder_tpu.transforms.attention_residuals import save_sdpa_residuals_joint
+from thunder_tpu.transforms.autodiff import KEPT_WHOLE_TAG
 from thunder_tpu.transforms.common import cse, dce
 from thunder_tpu.transforms.cross_entropy_upcast import FOLDED_TAG, fold_cross_entropy_upcasts
 from thunder_tpu.transforms.rng import functionalize_rng_ops
@@ -44,8 +45,9 @@ LOWER = (functionalize_rng_ops,)
 CLAIM = transform_for_execution
 
 # What the ``transforms`` phase record carries beside its seconds, by presence:
-# a compile that ran no rewrite carries none.
-_COUNTED = (FOLDED_TAG, LAYOUTS_FOLDED_TAG, SSM_LAYOUTS_FOLDED_TAG, SSM_CONVS_FUSED_TAG)
+# a compile that ran no rewrite carries none. The last is the grad transform's
+# (``transforms/autodiff.py::_linear_vjp``).
+_COUNTED = (FOLDED_TAG, LAYOUTS_FOLDED_TAG, SSM_LAYOUTS_FOLDED_TAG, SSM_CONVS_FUSED_TAG, KEPT_WHOLE_TAG)
 
 
 class Compiled(NamedTuple):

@@ -63,8 +63,10 @@ def cse(trace: TraceCtx) -> TraceCtx:
         # Effectful ops (SIDE_EFFECT/IN_PLACE) must never be merged: two
         # identical copy_ calls are two observable writes, not one value —
         # same tag model as DCE and the verifier's dce.dead-symbol rule.
+        # Nor are two barriers: each fences the readers it was placed for.
         if (
-            has_tag(bsym, OpTags.RANDOM_OP)
+            bsym.sym.id is PrimIDs.OPTIMIZATION_BARRIER
+            or has_tag(bsym, OpTags.RANDOM_OP)
             or has_tag(bsym, OpTags.DONT_DCE)
             or has_tag(bsym, OpTags.SIDE_EFFECT)
             or has_tag(bsym, OpTags.IN_PLACE)

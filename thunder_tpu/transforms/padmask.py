@@ -58,6 +58,7 @@ from thunder_tpu.core.trace import TraceCtx, from_trace, tracectx, wrap_in_trace
 _IDENTITY_IDS = {
     PrimIDs.CONVERT_ELEMENT_TYPE,
     PrimIDs.STOP_GRADIENT,
+    PrimIDs.OPTIMIZATION_BARRIER,
     PrimIDs.SHALLOW_COPY,
     PrimIDs.DEVICE_PUT,
     # Padding sits at the END of each dim, so prefix scans over real rows are
@@ -316,6 +317,7 @@ def _carry_sum_info(pm: _PadMasker, src, out) -> None:
 _VALUE_PRESERVING_IDS = {
     PrimIDs.CONVERT_ELEMENT_TYPE,
     PrimIDs.STOP_GRADIENT,
+    PrimIDs.OPTIMIZATION_BARRIER,
     PrimIDs.SHALLOW_COPY,
     PrimIDs.DEVICE_PUT,
 }
